@@ -220,8 +220,6 @@ class CheckpointManager:
         # deserializer; without it PyTreeRestore silently restores every
         # array single-device (an all-gather-to-chip-0 OOM at 7B).
         restore_args = ocp.checkpoint_utils.construct_restore_args(item)
-        # partial restore spelled per installed orbax (partial_restore=
-        # kwarg vs the legacy transforms={} idiom) — utils/compat.py.
         return ckptr.restore(
             item_dir,
             args=compat.pytree_restore_args(ocp, item, restore_args),
@@ -240,8 +238,7 @@ class CheckpointManager:
         """Top-level keys of the saved state tree at ``step`` (read from
         the item's own pytree metadata — the manager's item_metadata needs
         a handler registry this codepath doesn't keep), or None when the
-        metadata cannot be read. Metadata SHAPE differs per orbax
-        version (utils/compat.py)."""
+        metadata cannot be read."""
         try:
             return compat.pytree_metadata_keys(
                 ocp, os.path.join(self.dir, str(step), "state"))

@@ -405,8 +405,8 @@ class ImageFolderDataset:
 def write_jpeg_tar_shard(path: str, n: int, rng, *, start_key: int = 0,
                          size_range: tuple[int, int] = (256, 513),
                          fixed_size: int | None = None,
-                         num_classes: int = 1000, quality: int = 85,
-                         per_image=None) -> None:
+                         num_classes: int = 1000,
+                         quality: int = 85) -> None:
     """Synthesize ONE WebDataset-convention tar shard of photo-like JPEGs.
 
     The single writer for the ``<key>.jpg + <key>.cls`` layout that
@@ -415,9 +415,8 @@ def write_jpeg_tar_shard(path: str, n: int, rng, *, start_key: int = 0,
     so the shard contract lives in exactly one place. "Photo-like" =
     low-res noise upsampled smooth: JPEG entropy (and decode cost) tracks
     real photos, where raw noise is the pathological worst case.
-    ``per_image`` (optional) is called once per written image (progress /
-    watchdog touch hooks). Writes directly to ``path`` — callers needing
-    atomicity write to a temp name and rename.
+    Writes directly to ``path`` — callers needing atomicity write to a
+    temp name and rename.
     """
     import io
     import tarfile
@@ -444,8 +443,6 @@ def write_jpeg_tar_shard(path: str, n: int, rng, *, start_key: int = 0,
             info = tarfile.TarInfo(f"{start_key + k:06d}.cls")
             info.size = len(cls)
             tf.addfile(info, io.BytesIO(cls))
-            if per_image is not None:
-                per_image()
 
 
 class TarShardImageDataset(ImageFolderDataset):

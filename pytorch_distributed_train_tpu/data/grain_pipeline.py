@@ -8,8 +8,8 @@ Selected via ``DataConfig.loader = "grain"``. Duck-types HostDataLoader
 input pipeline — producer thread, HBM prefetch, sync checks — is shared.
 
 Reuses the datasets unchanged. The element grain moves is a whole HOST
-BATCH of record indices (round-5 restructure — BASELINE.md "grain
-gap"): batching lives in the SOURCE, before grain's worker sharding,
+BATCH of record indices (round-5 restructure of the "grain gap"):
+batching lives in the SOURCE, before grain's worker sharding,
 so batch composition is invariant to worker_count and a mid-epoch
 resume slices the epoch order at exact batch boundaries (see
 _BatchIndexSource for why operation-level gp.Batch cannot give
@@ -57,8 +57,8 @@ def _decode_pool():
         # runs PIL item decode (GIL-holding Python framing), and the
         # native budget's x2 I/O allowance composed pathologically with
         # data.mp_workers — N forked workers x 2x-their-core-share PIL
-        # threads oversubscribed the host into the LKG pil_grain_mp8
-        # regression (424 vs 444 img/s; ISSUE 14 satellite).
+        # threads oversubscribed the host into the pil_grain_mp8
+        # regression (ISSUE 14 satellite).
         _DECODE_POOL = (os.getpid(), ThreadPoolExecutor(
             max_workers=workers_lib.python_thread_budget(
                 min(8, os.cpu_count() or 1)),
@@ -89,7 +89,7 @@ def bounded_workers(requested: int, avail: int | None = None, *,
     (torch:utils/data/_utils/worker.py:244 — same rationale); on a host
     with no core to spare they only add spawn+IPC contention against the
     consumer. Measured on this repo's 1-core sandbox: the uncapped
-    process arm produced no batch within 550 s (BASELINE.md r2 "DNF"),
+    process arm produced no batch within 550 s,
     while worker_count=0 (in-process loading, Grain's supported
     degenerate mode) streams fine. Cap = cpu_count - 1 (one core stays
     with the consumer/train loop), never more than requested.
@@ -213,7 +213,7 @@ def _make_load_transform(dataset, item_style: bool, train: bool,
     """One MapTransform per host BATCH (an index array element).
 
     get_batch datasets get ONE dataset call per batch — round-5
-    profiling (BASELINE.md, tools/grain_profile.py) measured
+    profiling (tools/grain_profile.py) measured
     ~1.1 ms/record of pure grain machinery in the per-record
     formulation, and batch-of-1 calls starved the native batch decoder
     (native/jpegdec.cpp); whole-batch elements amortize the machinery
@@ -238,8 +238,8 @@ class GrainHostDataLoader:
         self.train = train
         # NOTE: the defaults initialize the device backend (process_count
         # → jax.devices()); host-only callers (benches, tools) must pass
-        # num_hosts/host_id explicitly so a wedged accelerator lease can
-        # never stall a pure-host data pipeline.
+        # num_hosts/host_id explicitly so a pure-host data pipeline
+        # never touches the device.
         self.num_hosts = (num_hosts if num_hosts is not None
                           else jax.process_count())
         self.host_id = host_id if host_id is not None else jax.process_index()
@@ -272,8 +272,8 @@ class GrainHostDataLoader:
                                              False):
             # mp pool + grain ITEM-style decode: each forked worker also
             # fans out a PIL decode thread pool. Uncapped that composed
-            # pathologically (LKG pil_grain_mp8: 424 img/s vs plain
-            # threads' 444) — workers.python_thread_budget now clamps
+            # pathologically (pil_grain_mp8 ran slower than plain
+            # threads) — workers.python_thread_budget now clamps
             # each worker to its core share; surface the decision once
             # (log + gauge) so the throughput math is inspectable.
             avail = os.cpu_count() or 1

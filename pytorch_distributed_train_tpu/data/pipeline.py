@@ -80,7 +80,7 @@ def _item_pool(num_workers: int) -> ThreadPoolExecutor:
 
         # python_thread_budget (no x2): PIL item decode holds the GIL
         # through its Python framing — inside a forked mp worker the
-        # pool clamps to exactly the worker's core share (the LKG
+        # pool clamps to exactly the worker's core share (the
         # pil_grain_mp8 oversubscription fix, ISSUE 14 satellite).
         _ITEM_POOL = (os.getpid(), ThreadPoolExecutor(
             max_workers=workers_lib.python_thread_budget(num_workers)))

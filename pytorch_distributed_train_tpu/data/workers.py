@@ -1,8 +1,8 @@
 """Multi-process shared-memory decode plane (ROADMAP item 2, ISSUE 12a).
 
-The GIL wall this replaces: one chip consumes 2541 ResNet images/s
-(BENCH_LKG) while the host pipeline delivers 340-985 img/s — the decode
-and augment work runs in ONE Python process, and threads only help
+The GIL wall this replaces: one chip consumes ResNet images several
+times faster than one Python process delivers them (not measured on
+today's stack) — the decode and augment work runs in ONE Python process, and threads only help
 where PIL/numpy drop the GIL. This pool runs the decode in N forked
 worker PROCESSES (the torch DataLoader worker model, SURVEY C17,
 torch:utils/data/_utils/worker.py:244) with one crucial difference:
@@ -91,8 +91,8 @@ def python_thread_budget(solo_threads: int) -> int:
     The x2 was sized for C++ decode (libjpeg/imgops release the GIL for
     the whole call); PIL item decode holds the GIL through its Python
     framing, so N pool workers each running 2x their core share contend
-    instead of overlapping — the LKG ``pil_grain_mp8`` regression (424
-    img/s vs plain threads' 444, ISSUE 14 satellite): 8 forked workers
+    instead of overlapping — the ``pil_grain_mp8`` regression (slower
+    than plain threads, ISSUE 14 satellite): 8 forked workers
     x (2 cores x2 = 4) PIL threads = 32 GIL-bound threads on a 24-core
     host. Inside a pool worker this clamps to exactly the worker's
     PDTT_NATIVE_THREADS core share."""

@@ -109,7 +109,7 @@ def chunked_causal_ce(x, kernel, input_ids, loss_mask=None,
 
     The torch-era pattern materializes logits (B, S, V) and hands them to
     the loss; at Llama vocab (32k) and seq 2048 that is ~2 GB of fp32 HLO
-    temps live through the backward (measured, BASELINE.md 2026-07-30).
+    temps live through the backward.
     Computing ``head_matmul → CE → scalar`` per sequence chunk under
     `jax.checkpoint` keeps one (B, chunk, V) tile live at a time and saves
     only two scalars per chunk; backward recomputes tiles (the same

@@ -55,6 +55,9 @@ def build_model(model_cfg, precision_cfg, mesh=None, mesh_cfg=None):
     ``mesh`` + ``mesh_cfg`` activate context parallelism: when the mesh's
     context axis is >1 the transformer models route attention through
     ring/Ulysses (SURVEY §5.7) and constrain activations seq-sharded.
+    Any mesh of more than one device hands attention its axes, so the
+    Pallas kernel can run as a manual region inside the sharded program
+    (ops/attention.py _sharded_flash).
     """
     _populate()
     name = model_cfg.name
@@ -66,7 +69,7 @@ def build_model(model_cfg, precision_cfg, mesh=None, mesh_cfg=None):
     dtype = jnp.dtype(precision_cfg.compute_dtype)
     param_dtype = jnp.dtype(precision_cfg.param_dtype)
     cp = None
-    if mesh is not None and mesh_cfg is not None and mesh.shape.get("context", 1) > 1:
+    if mesh is not None and mesh_cfg is not None and mesh.size > 1:
         from pytorch_distributed_train_tpu.ops.attention import (
             ContextParallelConfig,
         )
@@ -80,7 +83,10 @@ def build_model(model_cfg, precision_cfg, mesh=None, mesh_cfg=None):
     if name == "llama_pp":
         if mesh is None:
             raise ValueError("model 'llama_pp' needs a mesh (stage axis)")
-        return _REGISTRY[name](model_cfg, dtype, param_dtype, cp=cp, mesh=mesh)
+        # the pipeline body is already a manual region over 'stage': it
+        # takes the mesh axes only for an active context axis
+        return _REGISTRY[name](model_cfg, dtype, param_dtype, mesh=mesh,
+                               cp=cp if cp is not None and cp.active else None)
     if name.startswith(("llama", "bert", "gpt")):
         from pytorch_distributed_train_tpu.parallel.mesh import (
             activation_sharding_for,

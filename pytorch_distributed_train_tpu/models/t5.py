@@ -402,7 +402,7 @@ def t5(cfg, dtype, param_dtype, cp=None, act=None) -> T5ForConditionalGeneration
     """Registry ctor. Encoder-decoder context parallelism is not
     implemented — refuse loudly rather than silently train without the
     ring/Ulysses path the mesh asked for."""
-    if cp is not None:
+    if cp is not None and cp.active:
         raise ValueError(
             "t5 does not support context parallelism (mesh context>1): "
             "the encoder-decoder attention stack has no ring/Ulysses "

@@ -36,7 +36,14 @@ def _lib():
                 u8p, f32p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
                 ctypes.c_int, f32p, f32p, ctypes.c_int]
             _LIB = lib
-        except (RuntimeError, OSError):
+        except (RuntimeError, OSError) as e:
+            # Said once per process: a fallback nobody hears about reads
+            # as a slow input pipeline later.
+            import sys
+
+            print(f"[native] imgops unavailable — using the numpy path: "
+                  f"{str(e).splitlines()[0] if str(e) else type(e).__name__}",
+                  file=sys.stderr, flush=True)
             _LIB = None
     return _LIB
 

@@ -41,7 +41,14 @@ def _lib():
                 f32p, f32p, f32p, ctypes.c_int]
             lib.jpegdec_decode_batch.restype = ctypes.c_int
             _LIB = lib
-        except (RuntimeError, OSError):
+        except (RuntimeError, OSError) as e:
+            # Said once per process: a fallback nobody hears about reads
+            # as a slow input pipeline later.
+            import sys
+
+            print(f"[native] jpegdec unavailable — using the PIL path: "
+                  f"{str(e).splitlines()[0] if str(e) else type(e).__name__}",
+                  file=sys.stderr, flush=True)
             _LIB = None
     return _LIB
 

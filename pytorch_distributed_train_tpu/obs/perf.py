@@ -21,9 +21,10 @@ with. Three instruments, one module:
    time their read / decode / augment work through ``stage(name)``
    and the device assembly path times host→device transfer (``h2d``),
    all accumulated in a process-global :class:`InputStageStats`
-   mirrored into ``input_stage_seconds_total{stage=}``. The 2541
-   img/s-chip vs 340–445 img/s-host wall (BENCH_LKG) is then "decode is
-   83% of the stall", not one opaque bucket. Stage clocks are
+   mirrored into ``input_stage_seconds_total{stage=}``. A gap between
+   the chip's rate and the host's is then "decode is most of the
+   stall" (not measured on today's stack), not one opaque bucket.
+   Stage clocks are
    ``time.monotonic()`` (the monotonic-clock pass stance: durations
    must not jump with NTP).
 
@@ -43,7 +44,6 @@ login-host tools import this without touching a device backend.
 from __future__ import annotations
 
 import contextlib
-import datetime
 import hashlib
 import json
 import os
@@ -62,9 +62,11 @@ from pytorch_distributed_train_tpu.obs.registry import get_registry
 # can stack them and the ledger's stall split is comparable across runs.
 STAGES = ("read", "decode", "augment", "h2d")
 
-# Default ledger filename — repo-root for bench history, run-dir for
-# trainer rows (docs/performance.md).
-LEDGER_BASENAME = "PERF_LEDGER.jsonl"
+# Default filename of the program's own bench-history ledger at the repo
+# root (trainer rows go to the run dir — docs/performance.md). Not
+# PERF_LEDGER.jsonl: that name at the repo root is the driver's record
+# and nothing here writes it.
+LEDGER_BASENAME = "bench_ledger.jsonl"
 ENV_LEDGER = "PDTT_PERF_LEDGER"
 
 
@@ -250,7 +252,7 @@ def config_digest(obj) -> str:
 
 
 def default_ledger_path(repo_root: str | None = None) -> str:
-    """PDTT_PERF_LEDGER env override, else <repo_root>/PERF_LEDGER.jsonl
+    """PDTT_PERF_LEDGER env override, else <repo_root>/bench_ledger.jsonl
     (repo root = next to bench.py, two levels above this package)."""
     env = os.environ.get(ENV_LEDGER)
     if env:
@@ -277,7 +279,7 @@ class PerfLedger:
 
     Append never rewrites history (the whole point is a trajectory the
     regression gate can trust); a read-only checkout degrades to the
-    printed record, same stance as bench.py's LKG store.
+    printed record.
     """
 
     def __init__(self, path: str):
@@ -317,8 +319,7 @@ class PerfLedger:
 
     def append_record(self, record: dict, source: str = "") -> dict | None:
         """Append a bench.py-style record (``{metric, value, unit,
-        ...}``); rows without a measured metric (tpu_unavailable) are
-        skipped."""
+        ...}``); rows without a measured metric are skipped."""
         if not record.get("metric") or record.get("value") is None:
             return None
         extra = {k: v for k, v in record.items()
@@ -419,13 +420,7 @@ class PerfLedger:
         import is idempotent."""
         import glob
 
-        rows0 = self.load()
-        have = {r.get("source") for r in rows0}
-        # LKG dedupe identity, maintained incrementally as rows append
-        # (consecutive outage rounds re-snapshot the same table; a
-        # re-read per file would be O(files x ledger))
-        seen_meas = {(r.get("metric"), r.get("measured"), r.get("value"))
-                     for r in rows0}
+        have = {r.get("source") for r in self.load()}
         n = 0
         for path in sorted(glob.glob(os.path.join(repo_root,
                                                   "BENCH_r*.json"))):
@@ -439,53 +434,12 @@ class PerfLedger:
             except (OSError, ValueError):
                 continue
             parsed = rec.get("parsed") if isinstance(rec, dict) else None
-            if not isinstance(parsed, dict):
+            # a round that measured nothing carries no metric: skipped
+            if not isinstance(parsed, dict) or not parsed.get("metric"):
                 continue
-            if parsed.get("metric"):
-                row = self.append_record({**parsed, "ts": mtime},
-                                         source=src)
-                if row is not None:
-                    n += 1
-                continue
-            # TPU-outage round (tpu_unavailable): nothing was measured,
-            # but a stale round may carry the last-known-good rows the
-            # driver snapshotted — prior SUCCESSFUL measurements, each
-            # with its own 'measured' date. Import those so the gate
-            # judges against the full trajectory instead of a history
-            # with an outage-shaped hole. Same idempotency stamp (the
-            # whole file's source is in `have` after the first import).
-            lkg = (parsed.get("last_known_good") or {}).get("rows")
-            if not isinstance(lkg, dict):
-                continue
-            # consecutive outage rounds re-snapshot the SAME LKG table:
-            # dedupe by measurement identity (metric, measured date,
-            # value) against everything already in the ledger, or each
-            # outage file would re-import identical rows and bias the
-            # gate's median toward whichever era wedged more often
-            for metric, r in sorted(lkg.items()):
-                if not isinstance(r, dict) or r.get("value") is None:
-                    continue
-                ident = (metric, r.get("measured"), float(r["value"]))
-                if ident in seen_meas:
-                    continue
-                seen_meas.add(ident)
-                ts = mtime
-                measured = r.get("measured")
-                if measured:
-                    try:
-                        ts = datetime.datetime.strptime(
-                            str(measured), "%Y-%m-%d").replace(
-                            tzinfo=datetime.timezone.utc).timestamp()
-                    except ValueError:
-                        pass
-                extra = {k: v for k, v in r.items()
-                         if k not in ("value", "unit", "measured")}
-                row = self.append(metric, r["value"],
-                                  unit=r.get("unit", ""), source=src,
-                                  ts=ts, measured=measured,
-                                  stale_source=True, **extra)
-                if row is not None:
-                    n += 1
+            row = self.append_record({**parsed, "ts": mtime}, source=src)
+            if row is not None:
+                n += 1
         return n
 
 

@@ -28,8 +28,8 @@ Scale layouts (quant.py):
   why it cannot be factored out of the matmul after the fact.
 
 Validated like the flash kernels: interpret-mode numerics on CPU
-(tests/test_quant_matmul.py) + deviceless v5e Mosaic AOT compile
-(tools/mosaic_aot_battery.py). Integration into the decode model path
+(tests/test_quant_matmul.py) + a Mosaic compile for a described v5e
+(tests/test_tpu_compile.py). Integration into the decode model path
 is the documented follow-up — the kernel is the hard part the cost
 model demanded.
 """

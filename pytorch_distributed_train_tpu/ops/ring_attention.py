@@ -188,9 +188,8 @@ def _resolve_chunk_impl(q, k, n_ring, impl: str):
     """Map an attention ``impl`` request onto (chunk_impl, interpret) for
     the ring body, mirroring dot_product_attention's pallas gating: an
     explicit 'pallas' forces the kernel anywhere (interpret off-TPU — what
-    parity tests want); 'auto' takes it only on a TPU backend that can
-    compile Mosaic and at shard sizes where it pays; 'xla'/'chunked' keep
-    the einsum path."""
+    parity tests want); 'auto' takes it only on a TPU, at shard sizes where
+    it pays; 'xla'/'chunked' keep the einsum path."""
     from pytorch_distributed_train_tpu.ops import attention as attention_lib
     from pytorch_distributed_train_tpu.ops import flash_attention as _fa
 
@@ -209,7 +208,7 @@ def _resolve_chunk_impl(q, k, n_ring, impl: str):
     on_tpu = attention_lib._on_tpu()
     if impl == "pallas":
         return "pallas", not on_tpu
-    if on_tpu and attention_lib._pallas_usable() and _fa.profitable(local):
+    if on_tpu and _fa.profitable(local):
         return "pallas", False
     return "einsum", False
 

@@ -64,7 +64,7 @@ def _constrain_microbatch(x_mb, mesh: Mesh,
     scanned dim) — and GSPMD cannot move sharding BETWEEN dims in one hop:
     it falls back to replicate-then-repartition with a loud
     spmd_partitioner.cc "Involuntary full rematerialization" warning
-    (observed in MULTICHIP_r02), and the same fallback fires inside the
+    (seen in the CPU dry run), and the same fallback fires inside the
     shard_map entry every step. The dim-move is staged here as two
     transitions the partitioner IS efficient at:
       1. constrain to fully-replicated — one all-gather over the batch

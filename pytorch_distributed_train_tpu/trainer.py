@@ -43,10 +43,27 @@ from pytorch_distributed_train_tpu.parallel.mesh import build_mesh
 from pytorch_distributed_train_tpu.parallel.partition import rules_for_model
 from pytorch_distributed_train_tpu.sentinel import numeric as sentinel_numeric
 from pytorch_distributed_train_tpu.train_state import DynamicScale, TrainState
+from pytorch_distributed_train_tpu.utils import compile_cache
 from pytorch_distributed_train_tpu.utils import debug as debug_lib
 from pytorch_distributed_train_tpu.utils import flops as flops_lib
 from pytorch_distributed_train_tpu.utils.metrics import Meter, MetricLogger
 from pytorch_distributed_train_tpu.utils.watchdog import FlightRecorder, Heartbeat
+
+
+def _compile_cache_preference(configured: str) -> str:
+    """The cache directory this process would like when
+    JAX_COMPILATION_CACHE_DIR does not decide: ``obs.compile_cache_dir``,
+    else the launcher's per-worker PDTT_COMPILE_CACHE_DIR, else the
+    helper's default ("") — with a per-worker subdirectory, keyed by the
+    stable rank, whenever a launcher runs several workers."""
+    if not configured and os.environ.get("PDTT_COMPILE_CACHE_DIR"):
+        return os.environ["PDTT_COMPILE_CACHE_DIR"]
+    wid = os.environ.get("PROCESS_ID")
+    if wid is None:
+        return configured
+    from pytorch_distributed_train_tpu.elastic import worker_cache_dir
+
+    return worker_cache_dir(configured or compile_cache.DEFAULT_DIR, wid)
 
 
 class Trainer:
@@ -91,30 +108,18 @@ class Trainer:
             max_delay_s=cfg.faults.retry_max_delay_s))
         if cfg.obs.debug_nans:
             debug_lib.enable_nan_debugging()
-        cache_dir = cfg.obs.compile_cache_dir
-        if cache_dir:
-            # Per-worker subdir under tpurun: this container's jax loads
-            # truncated cache entries without validation, so a worker
-            # killed mid-cache-write (crash drill, SIGKILL escalation)
-            # would poison every sibling and later generation sharing
-            # the dir (CHANGES PR 3 gotcha). Worker id is stable across
-            # restart generations, so each worker still reuses ITS cache.
-            wid = os.environ.get("PROCESS_ID")
-            if wid is not None:
-                from pytorch_distributed_train_tpu.elastic import (
-                    worker_cache_dir,
-                )
-
-                cache_dir = worker_cache_dir(cache_dir, wid)
-        elif os.environ.get("PDTT_COMPILE_CACHE_DIR"):
-            # tpurun --compile-cache-dir derived a per-worker dir for us
-            cache_dir = os.environ["PDTT_COMPILE_CACHE_DIR"]
-        if cache_dir:
-            # Persistent XLA compile cache: restart-and-resume (the SPMD
-            # elasticity model, SURVEY §5.3) skips the minutes-scale GSPMD
-            # recompiles of large models.
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # Persistent XLA compile cache (utils/compile_cache.py):
+        # restart-and-resume (the SPMD elasticity model, SURVEY §5.3)
+        # skips the minutes-scale GSPMD recompiles of large models.
+        # Per-worker subdir under tpurun: jax loads truncated cache
+        # entries without validation, so a worker killed mid-cache-write
+        # (crash drill, SIGKILL escalation) would poison every sibling
+        # and later generation sharing the dir. Worker id is stable
+        # across restart generations, so each worker still reuses ITS
+        # cache. tpurun --compile-cache-dir hands over a per-worker dir
+        # already (PDTT_COMPILE_CACHE_DIR).
+        self.compile_cache_dir = compile_cache.enable(
+            _compile_cache_preference(cfg.obs.compile_cache_dir))
         if (getattr(cfg.optim, "swa_update_bn_batches", 0) > 0
                 and cfg.optim.ema_decay == 0.0
                 and getattr(cfg.optim, "swa_start_step", 0) == 0):
@@ -488,14 +493,12 @@ class Trainer:
         self.logger = MetricLogger(jsonl, tb_dir)
         self.meter = Meter()
         # MFU accounting (utils/flops.py): analytic train FLOPs per
-        # throughput item over the chip's bf16 peak; either side unknown
-        # (unlisted model, CPU backend) disables the metric, never the run.
+        # throughput item over the chip's bf16 peak. An unlisted model or
+        # the CPU backend disables the metric; a TPU kind missing from
+        # the peaks table raises.
         self._flops_per_item = flops_lib.train_flops_per_item(
             cfg.model, getattr(cfg.data, "seq_len", None) or None)
-        try:
-            self._peak_flops = flops_lib.device_peak_flops(jax.devices()[0])
-        except Exception:
-            self._peak_flops = None
+        self._peak_flops = flops_lib.device_peak_flops(jax.devices()[0])
         self.recorder = FlightRecorder(dump_dir=cfg.checkpoint.dir)
         self.recorder.install_signal_dump()
         # Graceful preemption (faults/preemption.py): SIGTERM sets a
@@ -815,7 +818,18 @@ class Trainer:
         probes: list[dict] = []
 
         def fits(gb: int) -> bool:
-            rep = self.compile_report(batch_size=gb)
+            try:
+                rep = self.compile_report(batch_size=gb)
+            except jax.errors.JaxRuntimeError as e:
+                # The TPU compiler enforces the device's memory itself: a
+                # batch past it is refused at buffer assignment
+                # ("RESOURCE_EXHAUSTED ... Ran out of memory in memory
+                # space hbm"), which IS the answer here. Anything else
+                # the compiler raises is a fault and propagates.
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                rep = {"global_batch": gb,
+                       "compiler_refused": str(e).splitlines()[0][:300]}
             rep["fits"] = (rep.get("resident_bytes", budget_bytes + 1)
                            <= budget_bytes)
             probes.append(rep)

@@ -1,86 +1,46 @@
-"""JAX API compatibility shims.
+"""The installed spellings of the few JAX / orbax calls the codebase
+shares, kept in one place.
 
-The codebase targets the current public API; the deployment containers
-sometimes pin an older jax. Each shim resolves the modern spelling when
-present and falls back to the legacy one, so the same source runs on
-both — the alternative (pinning the old spelling) rots the moment the
-container catches up.
+``shard_map``: public ``jax.shard_map``; ``check_vma`` / ``axis_names``
+are forwarded only when the caller sets them, so JAX's defaults apply.
 
-``shard_map``: public ``jax.shard_map`` (with ``check_vma`` /
-``axis_names``) vs legacy ``jax.experimental.shard_map.shard_map``
-(``check_rep`` / complementary ``auto``). Semantics map 1:1:
-``check_vma`` and ``check_rep`` are the same per-shard replication
-check under its two names, and legacy ``auto`` is the complement of
-``axis_names`` over the mesh axes (modern: which axes ARE manual;
-legacy: which axes are NOT).
-
-``pytree_restore_args``: modern orbax spells partial restore as
-``PyTreeRestore(..., partial_restore=True)``; older orbax (this
-container's 0.7.0) rejects the kwarg but expresses the same contract
-with ``transforms={}`` — with ``transforms_default_to_original=True``
-(the default) an empty transforms dict restores exactly the template's
-leaves from their original saved values and never materializes subtrees
-the template does not name.
+``pytree_restore_args``: orbax spells partial restore as
+``PyTreeRestore(..., partial_restore=True)`` — ``item`` names only the
+subtrees to restore and nothing else in the checkpoint is deserialized.
 """
 
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               check_vma: bool | None = None, axis_names=None):
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _legacy
-
     kw = {}
     if check_vma is not None:
-        kw["check_rep"] = check_vma
-    # ``axis_names`` is intentionally NOT mapped to legacy ``auto``:
-    # partial-auto regions on old jax lower axis_index to a PartitionId
-    # instruction old XLA's SPMD partitioner rejects ("meaning is
-    # ambiguous"). Full-manual is numerically identical — axes the
-    # caller wanted auto just see replicated data (in_specs that do not
-    # name them), costing redundant compute on those axes only under
-    # legacy jax.
-    return _legacy(f, mesh, in_specs, out_specs, **kw)
+        kw["check_vma"] = check_vma
+    if axis_names is not None:
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pytree_metadata_tree(ocp, item_dir: str) -> dict:
     """A saved pytree item's metadata TREE (leaves expose .shape/.dtype).
-    Modern orbax returns a metadata object exposing
-    ``.item_metadata.tree``; legacy orbax (0.7.x) returns the tree
-    itself as a plain dict. Raises whatever the underlying reader
-    raises — the caller decides whether unreadable metadata is an error
-    or a "trust the layout" fallback."""
+    Raises whatever the underlying reader raises — the caller decides
+    whether unreadable metadata is an error or a "trust the layout"
+    fallback."""
     meta = ocp.PyTreeCheckpointer().metadata(item_dir)
-    if isinstance(meta, dict):
-        return meta
     return dict(meta.item_metadata.tree)
 
 
 def pytree_metadata_keys(ocp, item_dir: str) -> set[str]:
-    """Top-level keys of a saved pytree item, either orbax spelling."""
+    """Top-level keys of a saved pytree item."""
     return set(pytree_metadata_tree(ocp, item_dir).keys())
 
 
 def pytree_restore_args(ocp, item, restore_args):
-    """``ocp.args.PyTreeRestore`` for a PARTIAL restore, spelled for
-    whichever orbax is installed (see module docstring). ``item`` names
-    only the subtrees to restore; everything else in the checkpoint is
-    never deserialized on either spelling."""
-    params = inspect.signature(ocp.args.PyTreeRestore.__init__).parameters
-    if "partial_restore" in params:
-        return ocp.args.PyTreeRestore(item=item, restore_args=restore_args,
-                                      partial_restore=True)
+    """``ocp.args.PyTreeRestore`` for a PARTIAL restore: ``item`` names
+    only the subtrees to restore."""
     return ocp.args.PyTreeRestore(item=item, restore_args=restore_args,
-                                  transforms={})
+                                  partial_restore=True)

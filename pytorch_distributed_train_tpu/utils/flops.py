@@ -24,8 +24,8 @@ Conventions (stated once, used everywhere):
   appendix; a causal model that skips half the score tile gets the
   benefit as higher measured MFU, not a smaller denominator.
 
-Peak table: bf16 systolic-array peak per chip, from the public TPU spec
-sheets, keyed by PJRT ``device_kind`` substrings.
+Peak table: bf16 systolic-array peak and HBM bandwidth per chip, from
+the public TPU spec sheets, keyed by the exact PJRT ``device_kind``.
 """
 
 from __future__ import annotations
@@ -33,67 +33,59 @@ from __future__ import annotations
 import math
 from typing import Any
 
-# bf16 peak TFLOP/s per chip by device_kind (PJRT strings observed in the
-# wild: "TPU v5 lite", "TPU v5p", "TPU v4", "TPU v6 lite", "TPU v3").
-# Ordered: first substring match wins, so "v5 lite" must precede "v5".
-_PEAK_TFLOPS_BF16: tuple[tuple[str, float], ...] = (
-    ("v6 lite", 918.0),   # Trillium / v6e
-    ("v6e", 918.0),
-    ("v5 lite", 197.0),   # v5e
-    ("v5e", 197.0),
-    ("v5p", 459.0),
-    ("v5", 459.0),
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-)
+# device_kind -> (bf16 peak TFLOP/s, HBM GB/s) per chip. Source: Google
+# Cloud TPU documentation, the "System architecture" page of each
+# generation (v5e: 197 / 819, v5p: 459 / 2765, v6e: 918 / 1638,
+# v4: 275 / 1228, v3: 123 / 900, v2: 45 / 700). The keys are the strings PJRT reports, with both spellings JAX itself pairs
+# up per generation (jax/_src/pallas/mosaic/tpu_info.py). Exact match
+# only: a kind that is not listed is an error on a TPU, never another
+# chip's rate. Decode is bandwidth-bound, so MBU — bytes moved per second
+# over the HBM peak — is its utilization measure, as MFU is training's.
+_PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v6 lite": (918.0, 1638.0),  # Trillium / v6e
+    "TPU v6e": (918.0, 1638.0),
+    "TPU v5 lite": (197.0, 819.0),   # v5e
+    "TPU v5e": (197.0, 819.0),
+    "TPU v5p": (459.0, 2765.0),
+    "TPU v5": (459.0, 2765.0),       # v5p's other PJRT spelling
+    "TPU v4": (275.0, 1228.0),
+    "TPU v3": (123.0, 900.0),
+    "TPU v2": (45.0, 700.0),
+}
 
-# HBM bandwidth GB/s per chip (public spec sheets), same matching rules.
-# Decode is bandwidth-bound, so MBU — bytes actually moved per second
-# over this peak — is its utilization measure, as MFU is training's.
-_HBM_GBPS: tuple[tuple[str, float], ...] = (
-    ("v6 lite", 1638.0),
-    ("v6e", 1638.0),
-    ("v5 lite", 819.0),
-    ("v5e", 819.0),
-    ("v5p", 2765.0),
-    ("v5", 2765.0),
-    ("v4", 1228.0),
-    ("v3", 900.0),
-    ("v2", 700.0),
-)
+
+def _peaks(device: Any) -> tuple[float, float] | None:
+    """The table row of ``device`` (default jax.devices()[0]). None off-TPU
+    (an "MFU" against a host core would be noise); an unlisted TPU kind
+    raises, so a utilization number never silently vanishes or borrows
+    another chip's peak."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if getattr(device, "platform", "") != "tpu":
+        return None
+    kind = getattr(device, "device_kind", "")
+    try:
+        return _PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"TPU device_kind {kind!r} is not in the peaks table "
+            f"(utils/flops.py _PEAKS: {sorted(_PEAKS)}); add its published "
+            "bf16 peak and HBM bandwidth with their source") from None
 
 
 def device_hbm_bandwidth(device: Any = None) -> float | None:
     """HBM bytes/sec of ``device`` (default jax.devices()[0]); None off-TPU."""
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    if getattr(device, "platform", "") != "tpu":
-        return None
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, gbps in _HBM_GBPS:
-        if sub in kind:
-            return gbps * 1e9
-    return None
+    row = _peaks(device)
+    return None if row is None else row[1] * 1e9
 
 
 def device_peak_flops(device: Any = None) -> float | None:
-    """bf16 peak FLOP/s of ``device`` (default: jax.devices()[0]), or
-    None when the platform has no meaningful MXU peak (CPU backend —
-    reporting an "MFU" against a host core would be noise)."""
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    if getattr(device, "platform", "") != "tpu":
-        return None
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, tflops in _PEAK_TFLOPS_BF16:
-        if sub in kind:
-            return tflops * 1e12
-    return None
+    """bf16 peak FLOP/s of ``device`` (default jax.devices()[0]); None
+    off-TPU."""
+    row = _peaks(device)
+    return None if row is None else row[0] * 1e12
 
 
 # ---------------------------------------------------------------------------

@@ -41,7 +41,7 @@ def classify_op(name: str) -> str:
     return "other"
 
 
-# Perf-attribution taxonomy (obs/perf.py; docs/performance.md): a CLOSED
+# Perf-attribution op classes (obs/perf.py; docs/performance.md): a CLOSED
 # roofline-meaningful vocabulary, distinct from the human report buckets
 # above. Ordered — first match wins — so attention fusions (named
 # "...attn..."/"flash..." by the pallas kernels and xla fusion naming)

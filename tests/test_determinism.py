@@ -53,6 +53,9 @@ def test_compile_cache_knob(tmp_path, monkeypatch):
 
     cache = f"{tmp_path}/xla_cache"
     prev = jax.config.jax_compilation_cache_dir
+    # the suite runs with the cache off (conftest); this test is about it
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_enable_compilation_cache", True)
     try:
         rows = _run(tmp_path, "m", extra=(
             "--set", "obs.log_memory=true",
@@ -63,7 +66,11 @@ def test_compile_cache_knob(tmp_path, monkeypatch):
         assert jax.config.jax_compilation_cache_dir == cache
         assert os.path.isdir(cache)
     finally:
+        from jax.experimental.compilation_cache import compilation_cache
+
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
 
 
 def test_device_memory_metrics_helper(monkeypatch):

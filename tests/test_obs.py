@@ -145,7 +145,8 @@ def test_registry_kind_conflict_and_sanitize():
         "grad_norm_encoder_block_0"
     reg.set_from_mapping({"a/b": 1.0, "text": "skip", "n": 2}, prefix="train")
     series = _parse_prom(reg.render())
-    assert series["train_a_b"] == 1.0
+    # "family/module" keys become ONE family with a module= label
+    assert series['train_a{module="b"}'] == 1.0
     assert series["train_n"] == 2.0
     assert not any("text" in k for k in series)
 

@@ -83,7 +83,7 @@ def test_obs_layer_end_to_end(tmp_path):
     assert 'input_stall_seconds_total{split="train"}' in series
     t.close()
 
-    # --- Chrome trace with the span taxonomy
+    # --- Chrome trace with the span vocabulary
     trace_path = os.path.join(cfg.checkpoint.dir, "trace.json")
     with open(trace_path) as f:
         trace = json.load(f)

@@ -212,8 +212,8 @@ def test_pipeline_train_step(devices8):
 
 def test_pipeline_compiles_without_involuntary_remat(devices8, capfd):
     """The PP×DP×FSDP step must compile with no spmd_partitioner
-    "Involuntary full rematerialization" diagnostics (VERDICT r2 #2: the
-    MULTICHIP_r02 artifact carried one — the microbatch reshape left
+    "Involuntary full rematerialization" diagnostics (an earlier CPU dry
+    run carried one — the microbatch reshape left
     batch-sharding on the scanned dim and GSPMD replicated a tensor every
     step as its last-resort cross-dim reshard). The staged gather→slice
     constraints in parallel/pipeline.py::_constrain_microbatch are what

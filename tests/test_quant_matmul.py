@@ -5,7 +5,7 @@ f32 — fusing the dequant into the tile stream changes WHERE the
 scales multiply (VMEM, inside the pallas_call), never the math. Run
 in interpret mode on CPU, same discipline as the flash-attention
 kernels; the v5e Mosaic compile is covered by
-tools/mosaic_aot_battery.py.
+tests/test_tpu_compile.py.
 """
 
 import numpy as np

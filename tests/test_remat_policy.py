@@ -51,4 +51,5 @@ def test_invalid_policy_raises():
     with pytest.raises(ValueError, match="remat_policy"):
         remat_block(object, True, "everything")
     assert remat_block(object, False, "bogus") is object  # disabled: no check
-    assert set(POLICIES) == {"full", "dots", "dots_no_batch"}
+    assert set(POLICIES) == {"full", "dots", "dots_no_batch",
+                             "no_fused_epilogue"}

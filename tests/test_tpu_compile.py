@@ -1,0 +1,167 @@
+"""The Pallas kernels of the main path compile for a TPU v5e at real
+widths — asked of the chip's own compiler, for a DESCRIBED v5e:2x2 with
+no chip attached (on-chip-measurement guide, section 2, rehearsal 3).
+
+What this guards: Mosaic refusals interpret mode cannot see (VMEM
+budgets, lane padding of D=64, block-shape alignment, the 4-axis
+backward grid, (S, 1) i32 position refs) at GPT-2 small's attention
+shape — the shape chip_smoke.py trains — and at the S 2048 / D 128
+causal, GQA and window shapes, plus the ring chunk kernel on a 4-device
+mesh and the W8/W4 GEMV kernels. A compile that passes here is a
+compile, not a chip run.
+
+Rules this file keeps (only one process at a time may load the TPU
+library, and the suite runs under several xdist workers): the topology
+is described inside a module-scoped fixture that skips when it cannot
+be — nothing at import, not autouse, not in conftest.py; every compile
+runs in this test's own process; all such tests live in this one file;
+the persistent compile cache stays off around them.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache off here.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compiled
+
+
+def _attn_loss(q, k, v, **kw):
+    return fa.flash_attention(q, k, v, **kw).astype(jnp.float32).sum()
+
+
+# (id, B, S, H, Hkv, D, window). gpt2_small is the preset's attention
+# shape at chip_smoke.py's batch; the rest are the long-sequence shapes
+# (llama-style heads of 128, GQA 4:1, Mistral-style window).
+FLASH_SHAPES = [
+    ("gpt2_small", 8, 1024, 12, 12, 64, 0),
+    ("s2048_d128", 2, 2048, 8, 8, 128, 0),
+    ("s2048_d128_gqa", 2, 2048, 8, 2, 128, 0),
+    ("s2048_d128_window512", 2, 2048, 8, 8, 128, 512),
+]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("name,B,S,H,Hkv,D,window", FLASH_SHAPES,
+                         ids=[s[0] for s in FLASH_SHAPES])
+def test_flash_attention_compiles(one_chip, name, B, S, H, Hkv, D, window,
+                                  direction):
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                              sharding=one_chip)
+    # the dispatch's own gates must agree these shapes take the kernel
+    assert fa.supported(q, kv, kv, causal=True, mask=None, window=window)
+    assert fa.profitable(q)
+    loss = functools.partial(_attn_loss, causal=True, window=window)
+    fn = loss if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    _compile(fn, q, kv, kv)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("D,Hkv", [(64, 12), (128, 4)],
+                         ids=["d64_mha", "d128_gqa"])
+def test_ring_chunk_kernel_compiles(one_chip, D, Hkv, direction):
+    """The ring's inner kernel: traced global positions as (S, 1) i32
+    refs, GQA unexpanded, fp32 (o, lse) out."""
+    B, S, H = 2, 512, 12
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv = sds((B, S, H, D)), sds((B, S, Hkv, D))
+    pos = sds((S,), jnp.int32)
+    assert fa.chunk_supported(q, kv, kv)
+
+    def loss(q_, k_, v_, qp, kp):
+        o, lse = fa.flash_attention_chunk(q_, k_, v_, qp, kp, causal=True)
+        return o.sum() + jnp.where(lse > fa.NEG_INF / 2, lse, 0.0).sum()
+
+    fn = loss if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    _compile(fn, q, kv, kv, pos, pos)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
+def test_ring_attention_compiles_on_four_chips(topo, direction):
+    """Mosaic INSIDE shard_map with ppermute over the described 4-device
+    mesh. ring_attention_local is called with interpret=False: the public
+    wrapper keys interpret on the RUNTIME backend, which is the CPU here,
+    and the point is the TPU lowering."""
+    from pytorch_distributed_train_tpu.ops.ring_attention import (
+        ring_attention_local,
+    )
+    from pytorch_distributed_train_tpu.utils.compat import shard_map
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("context",))
+    spec = P(None, "context", None, None)
+    sharding = NamedSharding(mesh, spec)
+    B, S, H, Hkv, D = 1, 4096, 8, 2, 128
+
+    def ring(q, k, v):
+        body = functools.partial(
+            ring_attention_local, axis_name="context", axis_size=4,
+            causal=True, chunk_impl="pallas", interpret=False)
+        out = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec, check_vma=False)(q, k, v)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                              sharding=sharding)
+    fn = ring if direction == "fwd" else jax.grad(ring, argnums=(0, 1, 2))
+    text = _compile(fn, q, kv, kv).as_text()
+    assert "collective-permute" in text
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quant_gemv_compiles(one_chip, bits):
+    """Fused weight-dequant GEMV (ops/quant_matmul.py) at the ~1B llama
+    decode shape the serving benches use: (1, 2048) x (2048, 5504)."""
+    from pytorch_distributed_train_tpu import quant
+    from pytorch_distributed_train_tpu.ops.quant_matmul import quant_matmul
+
+    H, N = 2048, 5504
+    w = jax.ShapeDtypeStruct((H, N), jnp.float32)
+    leaf = jax.eval_shape(
+        quant.quantize_leaf if bits == 8 else quant.quantize_leaf_int4, w)
+    q = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+         for k, v in leaf.items()}
+    x = jax.ShapeDtypeStruct((1, H), jnp.bfloat16, sharding=one_chip)
+    _compile(quant_matmul, x, q)

@@ -71,7 +71,7 @@ def test_op_class_classification():
     }
     for name, want in cases.items():
         assert xplane.classify_op_class(name) == want, name
-    # the taxonomy is the closed vocabulary the gauges label by
+    # the op classes are the closed vocabulary the gauges label by
     for name in cases.values():
         assert name in xplane.PERF_OP_CLASSES + ("other",)
 
@@ -259,20 +259,40 @@ def test_ledger_check_names_seeded_regression(tmp_path):
 
 
 @pytest.mark.analysis
-def test_ledger_cli_check_smoke_on_repo_history(tmp_path):
-    """`--import` then `--check` against the real BENCH_r*.json history
-    in a scratch ledger: the CI smoke — import is idempotent and the
-    gate runs clean on the repo's own trajectory."""
+def test_ledger_cli_check_smoke_on_round_history(tmp_path):
+    """`--import` then `--check` against a BENCH_r*.json round history
+    the test writes itself, in a scratch ledger: the CI smoke — import
+    is idempotent and the gate runs clean on a steady trajectory."""
     import perf_ledger as perf_ledger_cli
 
+    repo = tmp_path / "repo"
+    repo.mkdir()
+    for i, value in enumerate((2490.0, 2510.0, 2500.0, 2530.0, 2520.0), 1):
+        path = repo / f"BENCH_r{i:02d}.json"
+        path.write_text(json.dumps({"n": i, "rc": 0, "parsed": {
+            "metric": "resnet50_images_per_sec_per_chip", "value": value,
+            "unit": "images/sec/chip", "mfu_pct": 31.0,
+            "platform": "tpu", "device_kind": "TPU v5 lite",
+            "device_count": 1}}))
+        os.utime(path, (1000.0 * i, 1000.0 * i))
     path = str(tmp_path / "repo.jsonl")
-    rc = perf_ledger_cli.main(["--path", path, "--import"])
+    rc = perf_ledger_cli.main(["--path", path, "--import",
+                               "--repo", str(repo)])
     assert rc == 0
     ledger = perf_lib.PerfLedger(path)
-    n = len(ledger.load())
-    assert n >= 1  # at least the r05 measured round imports
-    assert ledger.import_bench_history(REPO) == 0  # idempotent
+    assert len(ledger.load()) == 5
+    assert ledger.import_bench_history(str(repo)) == 0  # idempotent
     assert perf_ledger_cli.main(["--path", path, "--check"]) == 0
+
+
+def test_program_ledger_is_not_the_drivers_file(monkeypatch):
+    """PERF_LEDGER.jsonl at the repo root is the driver's record: the
+    program's own default ledger has another name."""
+    monkeypatch.delenv(perf_lib.ENV_LEDGER, raising=False)
+    path = perf_lib.default_ledger_path()
+    assert os.path.dirname(path) == REPO
+    assert os.path.basename(path) == perf_lib.LEDGER_BASENAME
+    assert os.path.basename(path).lower() != "perf_ledger.jsonl"
 
 
 @pytest.mark.analysis

@@ -210,9 +210,8 @@ def mesh_feasibility(root: str, sizes: dict[str, int], *,
 
         from pytorch_distributed_train_tpu.utils import compat
 
-        # metadata SHAPE differs per orbax version (utils/compat.py) —
-        # the raw object would flatten as one shapeless leaf on modern
-        # orbax and every divisibility check would silently vanish
+        # the raw metadata object would flatten as one shapeless leaf
+        # and every divisibility check would silently vanish
         try:
             state_meta = compat.pytree_metadata_tree(
                 ocp, os.path.join(root, str(step), "state"))

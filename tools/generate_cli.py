@@ -88,6 +88,9 @@ def main(argv=None) -> int:
         generate,
         shard_decode_params,
     )
+    from pytorch_distributed_train_tpu.utils import compile_cache
+
+    compile_cache.enable()  # first thing: everything below compiles
     try:
         cfg = get_preset(args.config)
         cfg.apply_overrides(args.set)

@@ -1,8 +1,8 @@
 """Profile the grain-vs-threads loader gap (VERDICT r4 weak #4).
 
-BASELINE.md: at native JPEG decode the grain loader does 340 img/s/core
-against the threads loader's 445 (-24%), root-caused only as "grain
-machinery overhead". This tool reproduces both arms on the same
+At native JPEG decode the grain loader ran about a quarter slower per
+core than the threads loader (not measured on today's stack), root-caused
+only as "grain machinery overhead". This tool reproduces both arms on the same
 synthetic tar shard and cProfiles the GRAIN run so the overhead has
 names: per-record time in grain's iterator machinery, the batch-of-1
 dict repack in the load transform, rng construction, and the final

@@ -15,7 +15,7 @@ Arms:
   s2d_stem     — space-to-depth stem rewrite (exact; MXU-friendly C_in 12)
 
 Keep arms additive and honest: any adopted change must land in the model
-code with its measured delta recorded in BASELINE.md.
+code with its measured delta recorded in PERF.md.
 """
 
 from __future__ import annotations

@@ -15,7 +15,7 @@ compares every metric's NEWEST row against the prior rows' median+MAD
 regressed metric, so a throughput/MFU regression fails loudly instead
 of drifting into the history it will later be judged against.
 
-Default ledger path: $PDTT_PERF_LEDGER, else <repo>/PERF_LEDGER.jsonl.
+Default ledger path: $PDTT_PERF_LEDGER, else <repo>/bench_ledger.jsonl.
 Pure stdlib + the repo's obs package; no jax import — safe on a login
 host.
 """
@@ -82,7 +82,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--path", default="",
                    help="ledger JSONL (default $PDTT_PERF_LEDGER or "
-                        "<repo>/PERF_LEDGER.jsonl)")
+                        "<repo>/bench_ledger.jsonl)")
     p.add_argument("--show", action="store_true",
                    help="print the newest rows")
     p.add_argument("--tail", type=int, default=20)

@@ -1661,6 +1661,9 @@ def build_service(args) -> BatcherService:
         load_params_for_serving,
     )
 
+    from pytorch_distributed_train_tpu.utils import compile_cache
+
+    compile_cache.enable()  # first thing: everything below compiles
     cfg = get_preset(args.config)
     cfg.apply_overrides(args.set)
     tok = load_tokenizer(args.tokenizer)
