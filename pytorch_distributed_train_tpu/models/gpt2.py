@@ -289,11 +289,12 @@ class GPT2LMHead(nn.Module):
             return chunked_causal_ce(x.astype(self.dtype), emb, input_ids,
                                      loss_mask=loss_mask,
                                      transpose_kernel=True)
-        logits = jax.lax.dot_general(
-            x.astype(self.dtype), emb,
-            (((x.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        with jax.named_scope("lm_head"):  # a phase of the step: steps.py
+            logits = jax.lax.dot_general(
+                x.astype(self.dtype), emb,
+                (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
         return logits.astype(jnp.float32)
 
 

@@ -3,16 +3,30 @@
 The device side of "where does wall-clock go" is already covered by the
 xplane profiler window (utils/xplane.py); what was missing is the HOST
 side — compile vs step vs input stall vs checkpoint vs eval vs request
-handling. ``span("checkpoint.save")`` costs two ``perf_counter`` calls
-and one ring slot, cheap enough for per-step use; the ring holds the
-last ``capacity`` completed spans so the watchdog can dump "what was the
-host doing" on abort (utils/watchdog.py attaches the recorder next to
-the FlightRecorder event ring).
+handling. ``span("checkpoint.save")`` costs three clock reads, one ring
+slot and one histogram observation (a few microseconds, PERF.md), cheap
+enough for per-step use; the ring holds the last ``capacity`` completed
+spans so the watchdog can dump "what was the host doing" on abort
+(utils/watchdog.py attaches the recorder next to the FlightRecorder
+event ring).
+
+Clocks: a span's start is ``time.time_ns()`` (``t0_ns``; ``t0`` is the
+same instant in float seconds), its length ``perf_counter_ns`` (``dur_ns``
+/ ``dur_s``). An xplane trace's ``start_ns`` counts from the profiler
+session's start, whose own epoch time is the ``profile_start_time`` stat
+of its ``Task Environment`` plane: add that and the trace is on this
+clock (checked on a v5e; docs/observability.md "Correlating with xplane
+device traces"), so ``cover_names`` can name a device gap by the span
+that covers it.
+
+Parents: every span gets a process-unique ``seq`` when it opens and
+keeps the ``seq`` of the span that was open round it on its thread
+(``parent_seq``), traced or not, so a reader can take a span's self time
+(its length less its children's; benchmark/span_readers.py does).
 
 Export is the Chrome ``trace.json`` array format (``ph: "X"`` complete
 events, microsecond timestamps) — load it in chrome://tracing or
-Perfetto alongside the xplane-derived device trace; both clocks are
-host epoch-anchored so the two align (docs/observability.md).
+Perfetto alongside the xplane-derived device trace.
 
 Thread model: completed spans append under the GIL (list assignment into
 a preallocated ring is atomic enough, same design as FlightRecorder);
@@ -35,10 +49,13 @@ co-resident trainer was doing.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
+
+from pytorch_distributed_train_tpu.obs.registry import get_registry
 
 # ---------------------------------------------------------- trace context
 # The active (trace_id, parent_span_id) of the calling thread, None when
@@ -105,12 +122,15 @@ class Span:
     """One completed timed region."""
 
     __slots__ = ("name", "t0", "dur_s", "thread", "depth", "args",
-                 "trace_id", "span_id", "parent_id", "corr")
+                 "trace_id", "span_id", "parent_id", "corr",
+                 "t0_ns", "dur_ns", "seq", "parent_seq")
 
     def __init__(self, name: str, t0: float, dur_s: float, thread: str,
                  depth: int, args: dict, trace_id: str | None = None,
                  span_id: str | None = None, parent_id: str | None = None,
-                 corr: dict | None = None):
+                 corr: dict | None = None, *, t0_ns: int | None = None,
+                 dur_ns: int | None = None, seq: int | None = None,
+                 parent_seq: int | None = None):
         self.name = name
         self.t0 = t0  # epoch seconds (time.time clock)
         self.dur_s = dur_s
@@ -121,6 +141,12 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.corr = corr
+        # the same start and length as whole nanoseconds (time.time_ns /
+        # perf_counter_ns), for joins with a profiler trace
+        self.t0_ns = int(t0 * 1e9) if t0_ns is None else t0_ns
+        self.dur_ns = int(dur_s * 1e9) if dur_ns is None else dur_ns
+        self.seq = seq  # open order, unique in the recorder
+        self.parent_seq = parent_seq  # seq of the enclosing span, if any
 
     def to_chrome(self, pid: int) -> dict:
         ev = {
@@ -143,6 +169,66 @@ class Span:
         return ev
 
 
+class _OpenSpan:
+    """What ``SpanRecorder.span`` returns: the context manager, and while
+    the region is open (and after) a handle on its clock reads, so a
+    caller that accounts the same boundary elsewhere (the trainer's
+    goodput buckets) reads no second clock: ``start_s`` is the
+    ``perf_counter`` reading at entry, ``dur_s`` the length once closed.
+    ``args`` is the dict the span will carry: a caller may add to it until
+    the region closes."""
+
+    __slots__ = ("_rec", "name", "args", "seq", "parent_seq", "t0_ns",
+                 "_p0", "dur_s", "_tr", "_span_id")
+
+    def __init__(self, rec: "SpanRecorder", name: str, args: dict):
+        self._rec = rec
+        self.name = name
+        self.args = args
+        self.dur_s = None
+
+    @property
+    def start_s(self) -> float:
+        return self._p0 * 1e-9
+
+    def __enter__(self) -> "_OpenSpan":
+        stack = self._rec._stack()
+        self.parent_seq = stack[-1].seq if stack else None
+        self.seq = next(self._rec._seq)
+        stack.append(self)
+        self._tr = tr = getattr(_TL_TRACE, "ctx", None)
+        self._span_id = None
+        if tr is not None:
+            self._span_id = _rand_id(8)
+            _TL_TRACE.ctx = (tr[0], self._span_id)
+        self.t0_ns = time.time_ns()
+        self._p0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dur_ns = time.perf_counter_ns() - self._p0
+        self.dur_s = dur_ns * 1e-9
+        tr = self._tr
+        if tr is not None:
+            _TL_TRACE.ctx = tr
+        stack = self._rec._stack()
+        depth = len(stack) - 1
+        stack.pop()
+        args = self.args
+        if exc_type is not None:
+            args = {**args, "error": True}
+        self._rec._commit(Span(
+            self.name, self.t0_ns * 1e-9, self.dur_s,
+            threading.current_thread().name, depth, args,
+            trace_id=tr[0] if tr is not None else None,
+            span_id=self._span_id,
+            parent_id=tr[1] if tr is not None else None,
+            corr=dict(_CORR) if _CORR else None,
+            t0_ns=self.t0_ns, dur_ns=dur_ns, seq=self.seq,
+            parent_seq=self.parent_seq))
+        return False
+
+
 class SpanRecorder:
     """Fixed-capacity ring of completed spans + thread-local nest stacks."""
 
@@ -150,6 +236,7 @@ class SpanRecorder:
         self.capacity = capacity
         self.buf: list[Span | None] = [None] * capacity
         self.n = 0  # total spans ever completed
+        self._seq = itertools.count()  # next() is one bytecode: GIL-atomic
         self._local = threading.local()
         self._feed_registry = feed_registry
         # slot-claim + n++ is a read-modify-write pair; concurrent
@@ -172,39 +259,14 @@ class SpanRecorder:
             self._stacks[threading.current_thread().name] = st
         return st
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Time a region. Nesting is tracked per thread (``depth``);
-        exceptions propagate — the span still records, flagged
-        ``error=True`` so an aborted checkpoint save is visible in the
-        dump. Under an active ``trace_scope`` the span gets trace ids
-        and becomes the parent of spans nested inside it."""
-        stack = self._stack()
-        stack.append(name)
-        tr = getattr(_TL_TRACE, "ctx", None)
-        trace_id = span_id = parent_id = None
-        if tr is not None:
-            trace_id, parent_id = tr
-            span_id = _rand_id(8)
-            _TL_TRACE.ctx = (trace_id, span_id)
-        wall0 = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield
-        except BaseException:
-            args = {**args, "error": True}
-            raise
-        finally:
-            if tr is not None:
-                _TL_TRACE.ctx = tr
-            dur = time.perf_counter() - t0
-            depth = len(stack) - 1
-            stack.pop()
-            sp = Span(name, wall0, dur, threading.current_thread().name,
-                      depth, args, trace_id=trace_id, span_id=span_id,
-                      parent_id=parent_id,
-                      corr=dict(_CORR) if _CORR else None)
-            self._commit(sp)
+    def span(self, name: str, **args) -> _OpenSpan:
+        """Time a region: ``with rec.span("x") as sp``. Nesting is
+        tracked per thread (``depth``, ``parent_seq``); exceptions
+        propagate — the span still records, flagged ``error=True`` so
+        an aborted checkpoint save is visible in the dump. Under an
+        active ``trace_scope`` the span gets trace ids and becomes the
+        parent of spans nested inside it."""
+        return _OpenSpan(self, name, args)
 
     def record(self, name: str, t0_wall: float, dur_s: float, *,
                trace: tuple[str, str | None] | None = None,
@@ -214,17 +276,24 @@ class SpanRecorder:
         scheduler records each request's queue / prefill / per-quantum
         decode spans from the step loop). ``trace`` is
         ``(trace_id, parent_span_id)``; None reads the calling thread's
-        active scope. Returns the new span id (None when untraced)."""
+        active scope. Without ``thread`` the span belongs to the calling
+        thread and nests under the span open there (a compile reported
+        by JAX while ``train.compile`` is open). Returns the new span id
+        (None when untraced)."""
         if trace is None:
             trace = current_trace()
         trace_id = parent_id = span_id = None
         if trace is not None:
             trace_id, parent_id = trace
             span_id = _rand_id(8)
+        stack = self._stack() if thread is None else ()
         sp = Span(name, t0_wall, dur_s,
-                  thread or threading.current_thread().name, 0, args,
-                  trace_id=trace_id, span_id=span_id, parent_id=parent_id,
-                  corr=dict(_CORR) if _CORR else None)
+                  thread or threading.current_thread().name, len(stack),
+                  args, trace_id=trace_id, span_id=span_id,
+                  parent_id=parent_id,
+                  corr=dict(_CORR) if _CORR else None,
+                  seq=next(self._seq),
+                  parent_seq=stack[-1].seq if stack else None)
         self._commit(sp)
         return span_id
 
@@ -237,10 +306,6 @@ class SpanRecorder:
         if self._feed_registry:
             # every span is scrape-visible as a labeled histogram —
             # the decode-wait / ckpt-time numbers come for free
-            from pytorch_distributed_train_tpu.obs.registry import (
-                get_registry,
-            )
-
             get_registry().histogram(
                 "span_seconds", labels={"name": sp.name},
                 help="duration of host trace spans by span name",
@@ -259,12 +324,13 @@ class SpanRecorder:
 
     def active(self) -> list[str]:
         """This thread's currently-open span names, outermost first."""
-        return list(self._stack())
+        return [sp.name for sp in self._stack()]
 
     def active_all(self) -> dict[str, list[str]]:
         """EVERY thread's open spans (non-empty stacks only) — the abort
         dump runs on the heartbeat thread, where ``active()`` is vacuous."""
-        return {t: list(st) for t, st in list(self._stacks.items()) if st}
+        return {t: [sp.name for sp in list(st)]
+                for t, st in list(self._stacks.items()) if st}
 
     def clear(self) -> None:
         self.buf = [None] * self.capacity
@@ -314,3 +380,60 @@ def get_recorder() -> SpanRecorder:
 def span(name: str, **args):
     """``with span("trainer.eval"): ...`` against the global recorder."""
     return get_recorder().span(name, **args)
+
+
+# ------------------------------------------------------------ JAX compiles
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_LISTENER = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, fun_name: str = "",
+                     **_kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    args = {"fun": fun_name}
+    if "step" in _CORR:
+        args["step"] = _CORR["step"]
+    get_recorder().record("jax.compile", time.time() - duration_secs,
+                          duration_secs, **args)
+
+
+def install_compile_listener() -> None:
+    """Every XLA compile (or load from the persistent cache) JAX reports
+    becomes a ``jax.compile`` span of the thread that asked for it, with
+    JAX's own duration, the function's name and the trainer's ``step``
+    tag: which step recompiled, and what ``train.compile`` and the first
+    ``train.log`` spend compiling. Once a process: JAX keeps listeners
+    for good."""
+    global _COMPILE_LISTENER
+    with _GLOBAL_LOCK:
+        if _COMPILE_LISTENER:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _COMPILE_LISTENER = True
+
+
+# ----------------------------------------------------- reading the ring
+BETWEEN_SPANS = "between_spans"
+
+
+def cover_names(intervals, spans, thread: str | None = None) -> list[str]:
+    """For each ``(start_s, end_s)`` (epoch seconds, the spans' clock): the
+    name of the innermost span of ``thread`` (the main thread unless given)
+    that covers more than half of it, else ``"between_spans"`` — what the
+    host was doing while, say, the device sat idle for that interval."""
+    thread = thread or threading.main_thread().name
+    mine = [s for s in spans if s.thread == thread]
+    names = []
+    for a, b in intervals:
+        best = None
+        for s in mine:
+            lo, hi = s.t0_ns * 1e-9, (s.t0_ns + s.dur_ns) * 1e-9
+            if 2.0 * (min(b, hi) - max(a, lo)) > b - a and (
+                    best is None or s.depth > best.depth):
+                best = s
+        names.append(best.name if best is not None else BETWEEN_SPANS)
+    return names

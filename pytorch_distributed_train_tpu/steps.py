@@ -224,11 +224,17 @@ def make_train_step(model, loss_fn: Callable, tx,
             # non-stop_gradient outputs.
             if param_transform is not None:
                 p = param_transform(p)
-            logits, new_stats, model_aux = apply_model(
-                model, p, stats, batch, train=True,
-                dropout_rng=dropout_rng,
-            )
-            loss, aux = loss_fn(logits, batch)
+            # Phase names (``grad_reduce`` and ``optimizer`` below, and
+            # ``lm_head`` in the model): they ride every device operation's
+            # op_name, the backward pass as ``transpose(jvp(forward))``,
+            # so a trace can be split by phase (docs/observability.md).
+            with jax.named_scope("forward"):
+                logits, new_stats, model_aux = apply_model(
+                    model, p, stats, batch, train=True,
+                    dropout_rng=dropout_rng,
+                )
+            with jax.named_scope("loss"):
+                loss, aux = loss_fn(logits, batch)
             total = loss + model_aux  # sown losses (MoE aux) join the objective
             scaled = total * scale if scale is not None else total
             return scaled, (loss, aux, model_aux, new_stats)
@@ -271,7 +277,8 @@ def make_train_step(model, loss_fn: Callable, tx,
                 # Overlap hook: per-BUCKET collectives issued HERE, so
                 # microbatch i's reductions overlap microbatch i+1's
                 # compute under the latency-hiding scheduler.
-                grads = reduce_grads(grads)
+                with jax.named_scope("grad_reduce"):
+                    grads = reduce_grads(grads)
             grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
             stats = new_stats if new_stats is not None else stats
             return (grad_acc, stats), (loss, aux, model_aux)
@@ -286,6 +293,13 @@ def make_train_step(model, loss_fn: Callable, tx,
         model_aux = jnp.mean(model_auxes)
         new_stats = stats if state.batch_stats else None
         return grads, (loss, aux, model_aux, new_stats)
+
+    def apply_update(state, grads, new_stats, loss):
+        with jax.named_scope("optimizer"):
+            return state.apply_gradients(tx, grads, new_stats,
+                                         ema_decay=ema_decay,
+                                         swa_start=swa_start,
+                                         swa_every=swa_every, loss=loss)
 
     def train_step(state: TrainState, batch: dict, rng: jax.Array):
         # Per-step dropout key: fold the step counter into the base key —
@@ -303,12 +317,14 @@ def make_train_step(model, loss_fn: Callable, tx,
             grads, (loss, aux, model_aux, new_stats) = grad_one_batch(
                 state.params, state.batch_stats, one, dropout_rng, scale)
             if reduce_grads is not None:
-                grads = reduce_grads(grads)
+                with jax.named_scope("grad_reduce"):
+                    grads = reduce_grads(grads)
         if reduce_grads_accum is not None:
             # Monolithic post-backward reduction (the baseline arm the
             # bucketed overlap is measured against): ONE whole-tree
             # collective on the accumulated grads.
-            grads = reduce_grads_accum(grads)
+            with jax.named_scope("grad_reduce"):
+                grads = reduce_grads_accum(grads)
         if reduce_metrics is not None:
             # shard_map: loss/metrics are per-shard means — average
             # across the batch shards so every replica logs (and the
@@ -338,10 +354,7 @@ def make_train_step(model, loss_fn: Callable, tx,
                 # but real: an inf loss whose grad zeroed out) must not
                 # feed the EMA/plateau machinery a poisoned loss either.
                 finite &= jnp.isfinite(loss)
-            stepped = state.apply_gradients(tx, grads, new_stats,
-                                            ema_decay=ema_decay,
-                                            swa_start=swa_start,
-                                            swa_every=swa_every, loss=loss)
+            stepped = apply_update(state, grads, new_stats, loss)
             skipped = state.replace(step=state.step + 1)  # step advances either way
             new_state = jax.tree.map(
                 lambda new, old: jnp.where(finite, new, old), stepped, skipped
@@ -361,10 +374,7 @@ def make_train_step(model, loss_fn: Callable, tx,
             # numeric guard): both branches are computed in-graph and the
             # select is elementwise — no host round-trip, no recompile.
             finite = _tree_finite(grads) & jnp.isfinite(loss)
-            stepped = state.apply_gradients(tx, grads, new_stats,
-                                            ema_decay=ema_decay,
-                                            swa_start=swa_start,
-                                            swa_every=swa_every, loss=loss)
+            stepped = apply_update(state, grads, new_stats, loss)
             skipped = state.replace(step=state.step + 1)
             new_state = jax.tree.map(
                 lambda new, old: jnp.where(finite, new, old), stepped, skipped
@@ -374,11 +384,7 @@ def make_train_step(model, loss_fn: Callable, tx,
                 "update_skipped": 1.0 - finite.astype(jnp.float32),
             }
         else:
-            new_state = state.apply_gradients(tx, grads, new_stats,
-                                              ema_decay=ema_decay,
-                                              swa_start=swa_start,
-                                              swa_every=swa_every,
-                                              loss=loss)
+            new_state = apply_update(state, grads, new_stats, loss)
             metrics_extra = {}
 
         gnorm = optax_global_norm(grads)
@@ -436,8 +442,9 @@ def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,
             "update_skipped": 1.0 - finite.astype(jnp.float32),
         }
 
-    new_params, new_opt_state, gnorm = fused_update(
-        grads, state.opt_state, state.params, finite=finite)
+    with jax.named_scope("optimizer"):
+        new_params, new_opt_state, gnorm = fused_update(
+            grads, state.opt_state, state.params, finite=finite)
     stats = state.batch_stats
     if new_stats is not None:
         # The chain path's skip branch keeps the OLD stats (the whole

@@ -8,6 +8,7 @@ its TPU-native mechanism per SURVEY §7.2.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -68,6 +69,19 @@ def _compile_cache_preference(configured: str) -> str:
 
 class Trainer:
     def __init__(self, cfg: TrainConfig, mesh=None):
+        # ``train.init`` with one child a large part (obs/spans.py): where
+        # set-up goes, on the clock a profiler trace can be joined with.
+        self.spans = spans_lib.get_recorder()
+        spans_lib.install_compile_listener()
+        with self.spans.span("train.init"), \
+                contextlib.ExitStack() as part:
+            def phase(name: str) -> None:
+                part.close()  # the part that was open ends where this starts
+                part.enter_context(self.spans.span(name))
+
+            self._build(cfg, mesh, phase)
+
+    def _build(self, cfg: TrainConfig, mesh, phase) -> None:
         # Goodput clock starts at construction: mesh/model/data/restore
         # time is the init bucket (obs/goodput.py) — a job that spends
         # minutes rebuilding state per restart should see it in the
@@ -128,6 +142,7 @@ class Trainer:
                 "(set optim.swa_start_step or optim.ema_decay) — "
                 "silently ignoring the knob would ship stale-stats "
                 "results the user believes were re-estimated")
+        phase("train.init.mesh")
         self.mesh = mesh if mesh is not None else build_mesh(cfg.mesh)
         self.batch_axes = tuple(cfg.mesh.batch_axes)
         self.model = build_model(cfg.model, cfg.precision,
@@ -239,6 +254,7 @@ class Trainer:
 
         # ---- data
         dw = self.data_world or (None, None)
+        phase("train.init.data")
         self.train_ds = build_dataset(cfg.data, cfg.model, train=True)
         self.train_loader, self.train_epoch_fn = build_input_pipeline(
             self.train_ds, cfg.data, self.mesh, train=True,
@@ -246,6 +262,7 @@ class Trainer:
             sync_check_every=cfg.obs.check_input_sync_every,
             num_hosts=dw[0], host_id=dw[1],
         )
+        phase("train.init.eval_data")
         self.eval_ds = build_dataset(cfg.data, cfg.model, train=False)
         self.eval_loader, self.eval_epoch_fn = build_input_pipeline(
             self.eval_ds, cfg.data, self.mesh, train=False,
@@ -254,6 +271,7 @@ class Trainer:
         )
 
         # ---- horizon
+        phase("train.init.state")
         self.steps_per_epoch = self.train_loader.steps_per_epoch
         if cfg.epochs > 0:
             self.total_steps = cfg.epochs * self.steps_per_epoch
@@ -359,6 +377,7 @@ class Trainer:
             )(init_rng)
 
         # ---- jitted steps
+        phase("train.init.steps")
         from pytorch_distributed_train_tpu.ops.device_augment import (
             build_device_augment,
         )
@@ -440,6 +459,7 @@ class Trainer:
         # from a plain restart (and refuse a silently-changed global
         # batch — the one bookkeeping mistake that would corrupt LR/data
         # semantics without any error).
+        phase("train.init.checkpoint")
         self.ckpt = build_checkpoint_manager(
             cfg.checkpoint, cfg.to_json(), goodput=self.goodput,
             run_meta={"world": self.world,
@@ -488,6 +508,7 @@ class Trainer:
                 )
 
         # ---- observability
+        phase("train.init.planes")
         jsonl = cfg.obs.jsonl_path or f"{cfg.checkpoint.dir}/metrics.jsonl"
         tb_dir = f"{cfg.checkpoint.dir}/tb" if cfg.obs.tensorboard else ""
         self.logger = MetricLogger(jsonl, tb_dir)
@@ -525,7 +546,6 @@ class Trainer:
         # One process-wide span ring — checkpoint saves, data producer
         # threads and the step loop interleave on a single exported
         # timeline; the watchdog dumps it on abort next to its events.
-        self.spans = spans_lib.get_recorder()
         self.recorder.attach_spans(self.spans)
         self.registry = get_registry()
         self._step_hist = self.registry.histogram(
@@ -880,170 +900,191 @@ class Trainer:
                 if start_b >= self.steps_per_epoch:
                     start_b = 0  # stale epoch meta; just run a fresh epoch
                 rewound = False
-                for batch in self._timed_batches(
-                        self.train_epoch_fn(epoch, start_b)):
-                    if step >= limit:
-                        break
-                    self.profiler.on_step(step)
-                    # Sentinel drill points (flag-kind: firing only
-                    # reports a match; the corruption is ours to stage).
-                    # step.nan@step=N poisons the batch of the step that
-                    # completes as N+1 — the in-graph guard must then
-                    # skip exactly that update. step.loss_spike inflates
-                    # only the OBSERVED loss (detection drill; params
-                    # untouched).
-                    inflate_loss = self.faults.maybe_fire(
-                        "step.loss_spike", step=step)
-                    # step.grad_spike inflates only the OBSERVED grad/
-                    # update telemetry (post-backward, pre-anything the
-                    # monitor reads) — the early-warning drill: the
-                    # model-health plane must fire on it while the loss
-                    # stays healthy, so the sentinel never trips.
-                    inflate_grads = self.faults.maybe_fire(
-                        "step.grad_spike", step=step)
-                    if self.faults.maybe_fire("step.nan", step=step):
-                        batch = _poison_batch_nan(batch)
-                    # First execution per process = jit trace + compile
-                    # (+ one step); goodput attributes it to the compile
-                    # bucket — recompile cost on restart-heavy jobs is
-                    # precisely what goodput accounting exists to show.
-                    is_first = not self._stepped
-                    t_body = time.perf_counter()
-                    # (gen, step) correlation tag: every span completed
-                    # from here on — step-loop, ckpt, producer threads —
-                    # carries the trainer's position, the id serving
-                    # traces correlate against (obs/tracing.py).
-                    spans_lib.set_correlation_tags(step=step)
-                    with self.spans.span(
-                            "train.compile" if is_first else "train.step",
-                            step=step):
-                        self.state, metrics = self.train_step(
-                            self.state, batch, self.step_rng
-                        )
-                    self._stepped = True
-                    if inflate_loss:
-                        # step.loss_spike drill: corrupt the OBSERVED
-                        # loss everywhere one observation is read —
-                        # the log record, the scrape mirror the fleet
-                        # collector reads, and the sentinel below all
-                        # see the same spike; params stay healthy.
-                        # (Lazy jnp multiply: no device sync here.)
-                        metrics = dict(metrics,
-                                       loss=metrics["loss"] * 1e6)
-                    if inflate_grads:
-                        # step.grad_spike drill: same observation-only
-                        # stance — every grad/update telemetry reader
-                        # (log record, scrape mirror, fleet collector,
-                        # model-health monitor) sees the spike; params
-                        # and the loss stay healthy. (Lazy jnp multiply:
-                        # no device sync here.)
-                        metrics = {
-                            k: (v * 1e3 if k.startswith(
-                                ("grad_norm", "update_norm",
-                                 "update_ratio")) else v)
-                            for k, v in metrics.items()}
-                    # Host-side step counter: int(state.step) every step
-                    # would sync the device and serialize async dispatch
-                    # (the jitted step increments state.step identically,
-                    # including loss-scale skip steps).
-                    step += 1
-                    self._maybe_inject_fault(step)
-                    self._maybe_inject_stall(step)
-                    dt_tick = self.meter.tick()
-                    if dt_tick is not None:
-                        self._step_hist.observe(dt_tick)
-                        # step-time regression detector (anomaly plane):
-                        # a meter tick that spikes off the rolling
-                        # median+MAD baseline journals an anomaly and
-                        # (opt-in) opens a capture window
-                        self.profiler.observe_step_time(dt_tick, step)
-                    if dt_tick is None:
-                        # Priming tick (first step after a clock reset —
-                        # epoch boundary or mid-epoch eval): its interval
-                        # is excluded from meter.total_s, so drop the
-                        # matching stall seconds (the producer cold-start
-                        # wait) from the numerator too. Numerator and
-                        # denominator must cover the SAME intervals or
-                        # input_stall_pct can exceed 100% and spuriously
-                        # fail the sustained drill's <5% gate.
-                        stats = getattr(self.train_loader, "stall_stats",
-                                        None)
-                        if stats is not None:
-                            self._stall_prev = (stats.wait_s,
-                                                self.meter.total_s)
-                    self.heartbeat.beat()
-                    if self.liveness is not None:
-                        self.liveness.beat(step)
-                    self.recorder.record("step", step)
-                    if step % cfg.obs.log_every_steps == 0 or step == limit:
-                        host_rec = self._log_train(step, metrics)
-                        if (self.health is not None
-                                and self.health.observe(step, host_rec)):
-                            # Early-warning rewind: the model-health
-                            # monitor armed on divergence PRECURSORS
-                            # (grad/update norms, reward/KL) — same
-                            # restore+cooldown path as the loss
-                            # sentinel, steps earlier.
+                # One turn of this loop is one ``train.iteration`` span,
+                # opened BEFORE the loader's next() so that the wait for
+                # the batch (``train.input_wait``) lies inside it; what the
+                # turn's children do not cover is this loop's own
+                # bookkeeping. StepTraceAnnotation puts the same turn in a
+                # profiler capture's host trace (an atomic load otherwise).
+                batches = self._timed_batches(
+                    self.train_epoch_fn(epoch, start_b))
+                while step < limit:
+                    with self.spans.span("train.iteration",
+                                         step=step) as turn, \
+                            jax.profiler.StepTraceAnnotation(
+                                "train", step_num=step):
+                        batch = next(batches, None)
+                        if batch is None:
+                            # the epoch is exhausted: a turn that only
+                            # waited, and no iteration to a reader
+                            turn.args["epoch_end"] = True
+                            break
+                        self.profiler.on_step(step)
+                        # Sentinel drill points (flag-kind: firing only
+                        # reports a match; the corruption is ours to stage).
+                        # step.nan@step=N poisons the batch of the step that
+                        # completes as N+1 — the in-graph guard must then
+                        # skip exactly that update. step.loss_spike inflates
+                        # only the OBSERVED loss (detection drill; params
+                        # untouched).
+                        inflate_loss = self.faults.maybe_fire(
+                            "step.loss_spike", step=step)
+                        # step.grad_spike inflates only the OBSERVED grad/
+                        # update telemetry (post-backward, pre-anything the
+                        # monitor reads) — the early-warning drill: the
+                        # model-health plane must fire on it while the loss
+                        # stays healthy, so the sentinel never trips.
+                        inflate_grads = self.faults.maybe_fire(
+                            "step.grad_spike", step=step)
+                        if self.faults.maybe_fire("step.nan", step=step):
+                            batch = _poison_batch_nan(batch)
+                        # First execution per process = jit trace + compile
+                        # (+ one step); goodput attributes it to the compile
+                        # bucket — recompile cost on restart-heavy jobs is
+                        # precisely what goodput accounting exists to show.
+                        is_first = not self._stepped
+                        # (gen, step) correlation tag: every span completed
+                        # from here on — step-loop, ckpt, producer threads —
+                        # carries the trainer's position, the id serving
+                        # traces correlate against (obs/tracing.py).
+                        spans_lib.set_correlation_tags(step=step)
+                        with self.spans.span(
+                                "train.compile" if is_first else "train.step",
+                                step=step) as dispatch:
+                            self.state, metrics = self.train_step(
+                                self.state, batch, self.step_rng
+                            )
+                        self._stepped = True
+                        if inflate_loss:
+                            # step.loss_spike drill: corrupt the OBSERVED
+                            # loss everywhere one observation is read —
+                            # the log record, the scrape mirror the fleet
+                            # collector reads, and the sentinel below all
+                            # see the same spike; params stay healthy.
+                            # (Lazy jnp multiply: no device sync here.)
+                            metrics = dict(metrics,
+                                           loss=metrics["loss"] * 1e6)
+                        if inflate_grads:
+                            # step.grad_spike drill: same observation-only
+                            # stance — every grad/update telemetry reader
+                            # (log record, scrape mirror, fleet collector,
+                            # model-health monitor) sees the spike; params
+                            # and the loss stay healthy. (Lazy jnp multiply:
+                            # no device sync here.)
+                            metrics = {
+                                k: (v * 1e3 if k.startswith(
+                                    ("grad_norm", "update_norm",
+                                     "update_ratio")) else v)
+                                for k, v in metrics.items()}
+                        # Host-side step counter: int(state.step) every step
+                        # would sync the device and serialize async dispatch
+                        # (the jitted step increments state.step identically,
+                        # including loss-scale skip steps).
+                        step += 1
+                        self._maybe_inject_fault(step)
+                        self._maybe_inject_stall(step)
+                        dt_tick = self.meter.tick()
+                        if dt_tick is not None:
+                            self._step_hist.observe(dt_tick)
+                            # step-time regression detector (anomaly plane):
+                            # a meter tick that spikes off the rolling
+                            # median+MAD baseline journals an anomaly and
+                            # (opt-in) opens a capture window
+                            self.profiler.observe_step_time(dt_tick, step)
+                        if dt_tick is None:
+                            # Priming tick (first step after a clock reset —
+                            # epoch boundary or mid-epoch eval): its interval
+                            # is excluded from meter.total_s, so drop the
+                            # matching stall seconds (the producer cold-start
+                            # wait) from the numerator too. Numerator and
+                            # denominator must cover the SAME intervals or
+                            # input_stall_pct can exceed 100% and spuriously
+                            # fail the sustained drill's <5% gate.
+                            stats = getattr(self.train_loader, "stall_stats",
+                                            None)
+                            if stats is not None:
+                                self._stall_prev = (stats.wait_s,
+                                                    self.meter.total_s)
+                        self.heartbeat.beat()
+                        if self.liveness is not None:
+                            self.liveness.beat(step)
+                        self.recorder.record("step", step)
+                        if (step % cfg.obs.log_every_steps == 0
+                                or step == limit):
+                            with self.spans.span("train.log", step=step):
+                                host_rec = self._log_train(step, metrics)
+                            if (self.health is not None
+                                    and self.health.observe(step, host_rec)):
+                                # Early-warning rewind: the model-health
+                                # monitor armed on divergence PRECURSORS
+                                # (grad/update norms, reward/KL) — same
+                                # restore+cooldown path as the loss
+                                # sentinel, steps earlier.
+                                step = self._sentinel_rewind(step)
+                                epoch = step // max(self.steps_per_epoch, 1)
+                                self.meter.reset_clock()
+                                rewound = True
+                                break
+                        # The step bucket closes AFTER the (cadenced) log:
+                        # _log_train's device sync is where async-dispatched
+                        # compute gets waited on host-side, and that wait is
+                        # step time, not idle. The bucket opened with the
+                        # dispatch span: one clock read for both.
+                        self.goodput.account(
+                            "compile" if is_first else "step",
+                            time.perf_counter() - dispatch.start_s)
+                        if self._sentinel_on and self._sentinel_observe(
+                                step, metrics):
+                            # Auto-rewind: BEFORE the cadence save below, so
+                            # the diverged state is never checkpointed on
+                            # the way out. The while loop re-enters with the
+                            # rewound step and the exact mid-epoch
+                            # start_batch fast-forward.
                             step = self._sentinel_rewind(step)
                             epoch = step // max(self.steps_per_epoch, 1)
                             self.meter.reset_clock()
                             rewound = True
                             break
-                    # The step bucket closes AFTER the (cadenced) log:
-                    # _log_train's device sync is where async-dispatched
-                    # compute gets waited on host-side, and that wait is
-                    # step time, not idle.
-                    self.goodput.account(
-                        "compile" if is_first else "step",
-                        time.perf_counter() - t_body)
-                    if self._sentinel_on and self._sentinel_observe(
-                            step, metrics):
-                        # Auto-rewind: BEFORE the cadence save below, so
-                        # the diverged state is never checkpointed on
-                        # the way out. The while loop re-enters with the
-                        # rewound step and the exact mid-epoch
-                        # start_batch fast-forward.
-                        step = self._sentinel_rewind(step)
-                        epoch = step // max(self.steps_per_epoch, 1)
-                        self.meter.reset_clock()
-                        rewound = True
-                        break
-                    with self.goodput.measure("ckpt"):
-                        # A state under suspicion (mid bad-streak: spiking
-                        # but finite, so updates DID apply) must not be
-                        # checkpointed — the coming rewind would otherwise
-                        # restore the very divergence it escapes.
-                        if self._bad_streak == 0 and self.ckpt.maybe_save(
-                                self.state, epoch=epoch, step=step):
-                            self.recorder.record("ckpt", step)
-                            events_lib.emit("ckpt", "save", step=step,
-                                            epoch=epoch)
-                            if self.liveness is not None:
-                                # A synchronous cadence save (or a tiered
-                                # back-pressure drain) can outlast
-                                # hang_timeout_s on a loaded host; saving
-                                # is progress, not a wedge.
-                                self.liveness.pulse()
-                    if (cfg.eval_every_steps and
-                            step % cfg.eval_every_steps == 0):
-                        with self.goodput.measure("eval"):
-                            self.evaluate(step)
-                        # Mid-epoch eval: keep its wall time out of the
-                        # step-time percentiles AND the input-stall
-                        # denominator (meter.total_s).
-                        self.meter.reset_clock()
-                    if self.preempt is not None and self.preempt.requested:
-                        # Graceful preemption: stop at this step boundary;
-                        # fit()'s finally force-saves the synchronized
-                        # checkpoint and the summary carries the marker.
-                        self._preempted = True
-                        self.recorder.record("preempt", step)
-                        events_lib.emit("preempt", "sigterm", step=step)
-                        if jax.process_index() == 0:
-                            print(f"[preempt] stopping at step {step}; "
-                                  "checkpointing and exiting cleanly",
-                                  flush=True)
-                        break
+                        with self.goodput.measure("ckpt"):
+                            # A state under suspicion (mid bad-streak: spiking
+                            # but finite, so updates DID apply) must not be
+                            # checkpointed — the coming rewind would otherwise
+                            # restore the very divergence it escapes.
+                            if self._bad_streak == 0 and self.ckpt.maybe_save(
+                                    self.state, epoch=epoch, step=step):
+                                self.recorder.record("ckpt", step)
+                                events_lib.emit("ckpt", "save", step=step,
+                                                epoch=epoch)
+                                if self.liveness is not None:
+                                    # A synchronous cadence save (or a tiered
+                                    # back-pressure drain) can outlast
+                                    # hang_timeout_s on a loaded host; saving
+                                    # is progress, not a wedge.
+                                    self.liveness.pulse()
+                        if (cfg.eval_every_steps and
+                                step % cfg.eval_every_steps == 0):
+                            with self.goodput.measure("eval"):
+                                self.evaluate(step)
+                            # Mid-epoch eval: keep its wall time out of the
+                            # step-time percentiles AND the input-stall
+                            # denominator (meter.total_s).
+                            self.meter.reset_clock()
+                        if self.preempt is not None and self.preempt.requested:
+                            # Graceful preemption: stop at this step boundary;
+                            # fit()'s finally force-saves the synchronized
+                            # checkpoint and the summary carries the marker.
+                            self._preempted = True
+                            self.recorder.record("preempt", step)
+                            events_lib.emit("preempt", "sigterm", step=step)
+                            if jax.process_index() == 0:
+                                print(f"[preempt] stopping at step {step}; "
+                                      "checkpointing and exiting cleanly",
+                                      flush=True)
+                            break
+                # Early exits (step cap, rewind, preemption) stop the
+                # loader's producer NOW; after an exception the frame's
+                # end does, as it did for the for-loop this replaces.
+                batches.close()
                 if self._preempted:
                     break
                 if rewound:
@@ -1205,10 +1246,10 @@ class Trainer:
         _done = object()
         try:
             while True:
-                t0 = time.perf_counter()
-                batch = next(it, _done)
-                self.goodput.account("input_stall",
-                                     time.perf_counter() - t0)
+                # the span and the bucket share one pair of clock reads
+                with self.spans.span("train.input_wait") as wait:
+                    batch = next(it, _done)
+                self.goodput.account("input_stall", wait.dur_s)
                 if batch is _done:
                     return
                 yield batch
@@ -1237,7 +1278,10 @@ class Trainer:
         """Build + emit the host-side train record; returns it so the
         fit loop can feed the model-health monitor without a second
         device transfer."""
-        host = {k: float(np.asarray(v)) for k, v in metrics.items()}
+        with self.spans.span("train.log.sync", step=step):
+            # the device fetch: waits until every step dispatched so far
+            # has run, so the device idles from here to the next dispatch
+            host = {k: float(np.asarray(v)) for k, v in metrics.items()}
         # the schedule counts optimizer updates, not micro-steps
         host["lr"] = float(self.lr_schedule(step // max(self.cfg.optim.accum_steps, 1)))
         if self.cfg.optim.plateau_factor > 0:
