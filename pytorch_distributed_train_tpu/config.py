@@ -401,8 +401,9 @@ class MeshConfig:
     # (ops/ring_attention.py::zigzag_perm). Exact at any size; costs one
     # gather each way per attention call. The ~2× causal saving is
     # realized by the pallas chunk backend's block skipping, which needs
-    # the half-chunk to cover ≥1 KV block: S_local/2 ≥ block_k (i.e.
-    # seq/ring ≥ 2048 at the default 1024-wide blocks) — exactly the
+    # the half-chunk to cover ≥1 KV chunk of the kernel's tile rule:
+    # S_local/2 ≥ block_k (i.e. seq/ring ≥ 1024 at the 512-wide chunks
+    # ops/flash_attention.tile_sizes gives such lengths) — the
     # long-context regime CP exists for. Below that (or on the einsum
     # backend) zigzag is correct but pays the gathers for no win.
     # Ignored by ulysses / non-causal attention.

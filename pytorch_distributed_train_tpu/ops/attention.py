@@ -55,18 +55,22 @@ _resolutions_logged: set[tuple] = set()
 
 
 def _log_resolution(impl: str, q, k, *, causal: bool, window: int,
-                    interpret: bool = False) -> None:
+                    interpret: bool = False, plan=None) -> None:
     """Say once per distinct call signature, at trace time, which
     implementation the dispatch resolved to — a run's log then shows
-    whether the Pallas kernel really took the call. On stderr: stdout
-    is the product of the generation CLIs."""
+    whether the Pallas kernel really took the call, and with ``plan``
+    (flash_attention.tile_plan of the call) how many of a head's score
+    tiles it enters and how many of those build a mask: ``tiles=3/4
+    masked=2``. On stderr: stdout is the product of the generation CLIs."""
     key = (impl, q.shape, k.shape[2], str(q.dtype), causal, window, interpret)
     if key in _resolutions_logged:
         return
     _resolutions_logged.add(key)
+    tiles = "" if plan is None else \
+        f" tiles={plan.executed}/{plan.total} masked={plan.masked}"
     print(f"[attention] impl={impl} q={tuple(q.shape)} kv_heads={k.shape[2]} "
           f"dtype={q.dtype} causal={causal} window={window} "
-          f"interpret={interpret}", file=sys.stderr, flush=True)
+          f"interpret={interpret}{tiles}", file=sys.stderr, flush=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -213,8 +217,10 @@ def dot_product_attention(
             if impl == "pallas" or (on_tpu and _fa.profitable(q)):
                 # GQA is native in the kernel (KV BlockSpec index_map
                 # b // rep) — no expanded K/V copy in HBM.
-                _log_resolution("pallas", q, k, causal=causal, window=window,
-                                interpret=not on_tpu)
+                _log_resolution(
+                    "pallas", q, k, causal=causal, window=window,
+                    interpret=not on_tpu,
+                    plan=_fa.call_plan(q, k, causal=causal, window=window))
                 flash = functools.partial(
                     _fa.flash_attention, causal=causal, window=window,
                     interpret=not on_tpu)

@@ -2,15 +2,54 @@
 
 The TPU counterpart of the reference stack's fused attention kernels
 (torch SDPA/cuDNN flash path — SURVEY C23): never materialises the (S, S)
-score matrix in HBM. Forward keeps per-row running max/sum accumulators in
-VMEM and streams KV blocks through the MXU (the flash-attention-2
-formulation); backward recomputes P per block from the saved logsumexp and
-accumulates dQ / dK / dV in two kernels.
+score matrix in HBM. Forward streams KV through the MXU under a running
+max/sum (the flash-attention-2 formulation); backward recomputes P per
+tile from the saved logsumexp and produces dQ and dK/dV in two kernels.
 
-Layout: inputs (B, S, H, D) are reshaped to (B·H, S, D); the kernel grid is
-(B·H, S/block_q) with an inner arbitrary-order sweep over S/block_k. D must
-be 64/128/256 (lane-aligned); S must divide by the block sizes. Softmax math
-is fp32 regardless of input dtype (matches ops.attention policy).
+Layout. Inputs (B, S, H, D) are reshaped to (B·H, S, D). Inside a kernel
+the scores live TRANSPOSED, keys on sublanes and queries on lanes
+(S^T = K Q^T), so every per-query statistic — running max and sum, lse,
+delta, the queries' positions — is a lane-dense (1, block_q) row that
+broadcasts along sublanes, the softmax reductions run down sublanes (VPU
+work, no cross-lane shuffles), dV = P^T dO and dK = dS^T Q are plain
+products, and only the small (D, block_q) results O^T and dQ^T are
+transposed, once, on the way out. lse and delta are (B·H, 1, S) in HBM: a
+row a head, not an (S, 1) column padded to 128 lanes. D must be
+64/128/256; S must divide by 128.
+
+Tiling is two-level (:func:`tile_sizes` is the one rule). The GRID stays
+coarse, (B·H, S/block_q, S/major): a grid step has fixed costs. A step
+holds ``major`` rows of its streamed side in VMEM (K and V in the forward
+and dQ kernels; Q, dO, lse and delta in the dK/dV kernel, whose step owns
+one (block_k, D) output tile); with one major block (S <= 2048) the
+resident block's index does not depend on the other index, so it is
+fetched once a head.
+
+What is skipped and what is masked, full-sequence entry (positions implied
+by the grid). Which (block_q, block_k) score tiles a step enters follows
+from one number, the offset between its first query and its first key,
+and the grid can produce only a few such offsets: each gets its own
+straight-line specialisation under a ``pl.when`` on the grid indices.
+Tiles wholly above the causal diagonal or below the window band are not in
+it at all; neighbouring tiles wholly inside are ONE wide segment with no
+mask code; only the tiles that cross the diagonal or the band's edge build
+a mask (lane - sublane iota against one scalar). :func:`tile_plan` counts
+the three kinds: causal S 1024 at 512 x 512 enters 3 of 4 tiles a head and
+masks 2. Sliding-window attention (``window`` > 0) therefore scales
+O(S·window) like the chunked XLA path. A step with one major block writes
+its output itself: no running state, no scratch, no init/finalize — on a
+v5e that state cost a third of the old forward (PERF.md, PR 25). Several
+major blocks (S > 2048) keep the running max/sum/accumulator in VMEM
+across them; a major block wholly above the diagonal runs nothing but is
+still fetched.
+
+Precision: scores, softmax statistics, ``exp``, lse, delta and every
+accumulator are fp32 regardless of input dtype (matches ops.attention
+policy). Products take stored operands (q, k, v, dO) in their stored
+dtype with fp32 accumulation — a bf16 x bf16 product is exact in fp32 —
+and computed ones (P, dS) as fp32, which Mosaic's default-precision dot
+feeds to the MXU in one bf16 pass on a v5e: casting them first changed
+neither the result nor the time (PERF.md, PR 25).
 
 GQA is native (r4): K/V stay at Hkv heads in HBM; the batch-major head
 order makes q row b's KV row exactly b // rep (rep = H/Hkv), so sharing is
@@ -18,11 +57,6 @@ a BlockSpec index_map, not a materialised repeat — K/V read bandwidth drops
 by rep. The dK/dV backward adds a rep grid axis that revisits each KV tile
 once per query head in its group (first visit zeroes the accumulators,
 last writes out).
-
-Causal masking skips whole KV blocks above the diagonal (no wasted MXU work)
-and applies an iota mask only on diagonal blocks. Sliding-window attention
-(``window > 0``) additionally skips KV blocks entirely below the band, so
-compute scales O(S·window) like the chunked XLA path.
 
 Two entry points:
 - :func:`flash_attention` — full self-attention, positions implied by the
@@ -32,10 +66,12 @@ Two entry points:
   the logsumexp. This is the ring-attention inner kernel (SURVEY §5.7):
   the ring rotates K/V chunks (and their position vectors) around the
   'context' axis and merges chunk results with the flash rule, so the mask
-  depends on traced positions, not grid indices. Its custom VJP folds the
-  incoming lse cotangent into the flash2 ``delta`` term
-  (ds = p∘(dp − (delta − dlse))), so the same backward kernels serve both
-  entry points.
+  depends on traced positions, not grid indices: there is no static
+  diagonal, so every block_k chunk of the resident block is entered under
+  a predicate on its positions' min/max and masked from the positions.
+  Same algorithm, different bound. Its custom VJP folds the incoming lse
+  cotangent into the flash2 ``delta`` term (ds = p∘(dp − (delta − dlse))),
+  so the same backward kernels serve both entry points.
 
 Enable/disable: dispatched from ops.attention.dot_product_attention; tests
 run interpret=True on CPU against the XLA reference implementation
@@ -45,6 +81,8 @@ run interpret=True on CPU against the XLA reference implementation
 from __future__ import annotations
 
 import functools
+import operator
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -54,98 +92,123 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Tuned on TPU v5e (S=2048, D=128, bf16): large tiles amortize per-program
-# overhead — 128x128 ran ~3.5x slower than 512x1024. VMEM check: the f32
-# score tile is block_q x block_k x 4B = 2 MB, well inside the ~16 MB budget
-# with q/k/v/acc blocks.
+# Score tiles of 512 x 512 where S divides (else 256, else 128), inside
+# grid steps that hold up to 2048 rows (and at most 512 KiB) of the
+# streamed side. Read on a v5e at (16, 1024, 12, 64) bf16 causal (PERF.md,
+# PR 25): the kernels are bound by the MXU and by what a grid step costs,
+# not by the VPU (dropping mask, exp, max and sum together moved the
+# forward 12 %), so tiles are as large as still leaves a diagonal to skip:
+# 512 enters 3 of 4 tiles at S 1024 and ran 2.37 ms forward + backward a
+# layer against 2.78 at 256 (10 of 16) and 2.67 with nothing skipped. At
+# (8, 2048, 8, 128): 2.46 ms with one 2048-row major block, 3.09 with two
+# of 1024. VMEM at D 128: K and V double-buffered 2 MiB, a (2048, 512)
+# fp32 score segment 4 MiB, a few of them live: inside the scoped default.
 DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 1024
+DEFAULT_BLOCK_K = 512
+DEFAULT_BLOCK_K_MAJOR = 2048
+_MAJOR_BYTES = 512 << 10
+
+class Tiles(NamedTuple):
+    block_q: int  # Q rows of a score tile (and of the fwd/dQ grid step)
+    block_k: int  # KV columns of a score tile (and of the dK/dV grid step)
+    major_q: int  # Q rows resident in a dK/dV grid step
+    major_k: int  # KV rows resident in a forward / dQ grid step
+
+
+class TilePlan(NamedTuple):
+    total: int     # score tiles in the S x S square of one head
+    executed: int  # entered by the kernels
+    masked: int    # of those, the ones that build a mask
+
+
+def _fit(S: int, cap: int, unit: int) -> int:
+    """Largest multiple of ``unit`` that divides S and is at most ``cap``."""
+    best = unit
+    for m in range(unit, min(S, cap) + 1, unit):
+        if S % m == 0:
+            best = m
+    return best
+
+
+def tile_sizes(Sq: int, Sk: int, D: int, itemsize: int, *,
+               block_q: int | None = None, block_k: int | None = None,
+               block_k_major: int | None = None) -> Tiles:
+    """The one tile rule, from what a call can see. Explicit sizes (tests)
+    override the rule's; every size must divide its sequence length."""
+    def sub(S, want, default):
+        if not want:
+            want = next(t for t in (default, 256, 128) if S % t == 0)
+        return min(want, S)
+
+    bq = sub(Sq, block_q, DEFAULT_BLOCK_Q)
+    bk = sub(Sk, block_k, DEFAULT_BLOCK_K)
+    cap = block_k_major or min(DEFAULT_BLOCK_K_MAJOR,
+                               _MAJOR_BYTES // (D * itemsize))
+    if Sq % bq or Sk % bk or bq % 128 or bk % 128:
+        raise ValueError(
+            f"flash attention tiles ({bq}, {bk}) do not fit S=({Sq}, {Sk}): "
+            "both must be multiples of 128 that divide the sequence")
+    return Tiles(bq, bk, _fit(Sq, cap, bq), _fit(Sk, cap, bk))
+
+
+def tile_plan(S: int, block_q: int, block_k: int, *, causal: bool,
+              window: int = 0) -> TilePlan:
+    """Score tiles of one head of the full-sequence entry: in the square,
+    entered, and masked. The same set in all three kernels (forward and dQ
+    sweep a Q tile's KV tiles, dK/dV a KV tile's Q tiles); static per call
+    site, so it costs a step nothing. With d = row - col: a tile is entered
+    unless every d < 0 (causal) or every d >= window; it is masked when
+    some d < 0 or some d >= window."""
+    total = executed = masked = 0
+    for q0 in range(0, S, block_q):
+        for k0 in range(0, S, block_k):
+            total += 1
+            d_max = q0 + block_q - 1 - k0
+            d_min = q0 - (k0 + block_k - 1)
+            if (causal and d_max < 0) or (window and d_min >= window):
+                continue
+            executed += 1
+            masked += bool((causal and d_min < 0)
+                           or (window and d_max >= window))
+    return TilePlan(total, executed, masked)
+
+
+def call_plan(q, k, *, causal: bool, window: int = 0) -> TilePlan:
+    """:func:`tile_plan` of a full-sequence call under the tile rule — what
+    the dispatch's resolution line prints."""
+    block_q, block_k, _, _ = tile_sizes(q.shape[1], k.shape[1], q.shape[3],
+                                        q.dtype.itemsize)
+    return tile_plan(q.shape[1], block_q, block_k, causal=causal,
+                     window=window)
+
+
+def _tileable(q, k) -> bool:
+    B, Sq, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+    if D not in (64, 128, 256):
+        return False
+    if Hkv != H and (Hkv == 0 or H % Hkv != 0):
+        return False  # invalid GQA ratio — let the XLA path raise clearly
+    return Sq % 128 == 0 and Sk % 128 == 0
 
 
 def supported(q, k, v, *, causal: bool, mask, window: int = 0) -> bool:
-    # window composes with any supported shape (masking + band block skip);
+    # window composes with any supported shape (masking + band tile skip);
     # it is accepted for API symmetry with the other backends.
     del window
     if mask is not None:
         return False
-    B, Sq, H, D = q.shape
-    Sk = k.shape[1]
-    if Sq != Sk:  # self-attention only (no KV-cache decode shapes)
+    if q.shape[1] != k.shape[1]:  # self-attention only (no KV-cache decode)
         return False
-    if D not in (64, 128, 256):
-        return False
-    H, Hkv = q.shape[2], k.shape[2]
-    if Hkv != H and (Hkv == 0 or H % Hkv != 0):
-        return False  # invalid GQA ratio — let the XLA path raise clearly
-    bq = min(DEFAULT_BLOCK_Q, Sq)
-    bk = min(DEFAULT_BLOCK_K, Sk)
-    return Sq % bq == 0 and Sk % bk == 0 and bq % 8 == 0 and bk % 128 == 0
+    return _tileable(q, k)
 
 
 def chunk_supported(q, k, v) -> bool:
     """Shape gate for :func:`flash_attention_chunk` (ring inner kernel):
     GQA-or-MHA heads (Hkv divides H — native in-kernel sharing, r4),
-    lane-aligned D, block-divisible LOCAL seq lens (Sq is the device's Q
+    lane-aligned D, tile-divisible LOCAL seq lens (Sq is the device's Q
     shard, Sk the rotating chunk — they may differ)."""
-    B, Sq, H, D = q.shape
-    Sk = k.shape[1]
-    Hkv = k.shape[2]
-    if k.shape != v.shape:
-        return False
-    if Hkv != H and (Hkv == 0 or H % Hkv != 0):
-        return False
-    if D not in (64, 128, 256):
-        return False
-    bq = min(DEFAULT_BLOCK_Q, Sq)
-    bk = min(DEFAULT_BLOCK_K, Sk)
-    return Sq % bq == 0 and Sk % bk == 0 and bq % 8 == 0 and bk % 128 == 0
-
-
-# ------------------------------------------------------------- mask helpers
-#
-# Shared by all kernels. Positions: iota-from-grid for the full-seq entry,
-# explicit (S, 1) i32 refs for the ring-chunk entry (traced, device-local).
-
-def _block_keep(q_start, k_start, qpos_ref, kpos_ref, block_q, block_k,
-                causal, window):
-    """(block_q, block_k) keep-mask, or None when nothing masks."""
-    if not causal and not window:
-        return None
-    if qpos_ref is not None:
-        rows = qpos_ref[...].astype(jnp.int32)  # (block_q, 1)
-        cols = kpos_ref[...].astype(jnp.int32).reshape(1, block_k)
-    else:
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-    keep = rows >= cols if causal else None
-    if window:
-        band = (rows - cols) < window
-        keep = band if keep is None else jnp.logical_and(keep, band)
-    return keep
-
-
-def _block_needed(q_start, k_start, qpos_ref, kpos_ref, block_q, block_k,
-                  causal, window):
-    """Scalar predicate: does this (Q block, KV block) pair intersect the
-    causal triangle ∩ window band at all? None → always needed."""
-    if not causal and not window:
-        return None
-    if qpos_ref is not None:
-        qp = qpos_ref[...]
-        kp = kpos_ref[...]
-        q_min, q_max = jnp.min(qp), jnp.max(qp)
-        k_min, k_max = jnp.min(kp), jnp.max(kp)
-    else:
-        q_min, q_max = q_start, q_start + block_q - 1
-        k_min, k_max = k_start, k_start + block_k - 1
-    needed = q_max >= k_min if causal else None
-    if window:
-        in_band = k_max > q_min - window
-        needed = in_band if needed is None else jnp.logical_and(needed,
-                                                                in_band)
-    return needed
+    return k.shape == v.shape and _tileable(q, k)
 
 
 def profitable(q) -> bool:
@@ -154,86 +217,264 @@ def profitable(q) -> bool:
     return q.shape[1] >= 1024
 
 
+# ------------------------------------------------ score tiles and masks
+#
+# Shared by all kernels. A grid step meets ``major`` rows of its streamed
+# side; which (block_q, block_k) score tiles of that meeting are entered,
+# and which of them build a mask, follows from ONE number: the offset
+# between the step's first query and first key. Positions: implied by the
+# grid for the full-seq entry, explicit i32 refs for the ring-chunk entry
+# (traced, device-local): queries a (1, Sq) row, keys an (Sk, 1) column.
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims):
+    """fp32-accumulated product; operands of two dtypes meet in the wider."""
+    if a.dtype != b.dtype:
+        t = jnp.promote_types(a.dtype, b.dtype)
+        a, b = a.astype(t), b.astype(t)
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _cdiv(a, b):
+    return (a + b - 1) // b
+
+
+def _clamp_ranges(lo, full_lo, full_hi, hi):
+    a = min(max(full_lo, lo), hi)
+    b = min(max(full_hi, a), hi)
+    return lo, a, b, hi
+
+
+def _kv_ranges(off, n, block_q, block_k, causal, window):
+    """One Q tile against n KV tiles, ``off`` = its first row less their
+    first column: (lo, a, b, hi) in tiles — [lo, a) cross the band's lower
+    edge, [a, b) lie wholly inside, [b, hi) cross the diagonal; tiles
+    outside [lo, hi) are never entered."""
+    def pos(x):
+        return max(x, 0)
+
+    hi = min(n, _cdiv(pos(off + block_q), block_k)) if causal else n
+    full_hi = pos(off + 1) // block_k if causal else n
+    lo = pos(off - window + 1) // block_k if window else 0
+    full_lo = _cdiv(pos(off + block_q - window), block_k) if window else 0
+    return _clamp_ranges(lo, full_lo, full_hi, hi)
+
+
+def _q_ranges(off, n, block_q, block_k, causal, window):
+    """One KV tile against n Q tiles, ``off`` = its first column less
+    their first row: [lo, a) cross the diagonal, [a, b) lie wholly inside,
+    [b, hi) cross the band's lower edge."""
+    def pos(x):
+        return max(x, 0)
+
+    lo = pos(off) // block_q if causal else 0
+    full_lo = _cdiv(pos(off + block_k - 1), block_q) if causal else 0
+    hi = min(n, _cdiv(pos(off + block_k - 1 + window), block_q)) \
+        if window else n
+    full_hi = pos(off + window) // block_q if window else n
+    return _clamp_ranges(lo, full_lo, full_hi, hi)
+
+
+def _segments(ranges, unit, mask_off):
+    """(lo, a, b, hi) in tiles -> ((start, stop, mask), ...) along the
+    streamed side: neighbouring tiles of one kind become ONE wide
+    segment, so a grid step runs at most three straight-line pieces
+    however fine the tiles. ``mask`` is None for a segment wholly inside,
+    else the scalar that turns local row - local col into a keep-mask
+    there (``mask_off(start)``)."""
+    lo, a, b, hi = ranges
+    return tuple(
+        (x * unit, y * unit, mask_off(x * unit) if masked else None)
+        for x, y, masked in ((lo, a, True), (a, b, False), (b, hi, True))
+        if x < y)
+
+
+def _static_dispatch(off, offsets, segments_of, update):
+    """The full-seq entry: the step's offset is an expression of grid
+    indices, and the grid can produce only ``offsets``. Neighbouring
+    offsets with one segment list share a straight-line specialisation of
+    ``update`` under one ``pl.when``; offsets with nothing to enter get
+    none (with one major block every offset enters its diagonal). A
+    single list for every offset needs no branch."""
+    runs = []
+    for o in sorted(set(offsets)):
+        segs = segments_of(o)
+        if runs and runs[-1][2] == segs:
+            runs[-1][1] = o
+        else:
+            runs.append([o, o, segs])
+    if len(runs) == 1:
+        if runs[0][2]:
+            update(runs[0][2])
+        return
+    for first, last, segs in runs:
+        if segs:
+            cond = off == first if first == last else \
+                jnp.logical_and(off >= first, off <= last)
+            pl.when(cond)(functools.partial(update, segs))
+
+
+def _keep(diff, off, causal, window):
+    """Keep-mask of a score tile from ``diff - off`` = row - col."""
+    keep = diff >= off if causal else None
+    if window:
+        band = diff < off + window
+        keep = band if keep is None else jnp.logical_and(keep, band)
+    return keep
+
+
+def _iota_diff_t(keys, queries):
+    """(keys, queries) of local query - local key (lane - sublane): with
+    one scalar it is the whole mask of a transposed score tile whose
+    positions the grid implies."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (keys, queries), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (keys, queries), 0))
+
+
+def _pos_needed(qp, kp, causal, window):
+    """Scalar predicate from traced positions: does this (Q rows, KV
+    columns) pair intersect the causal triangle ∩ window band at all?"""
+    needed = jnp.max(qp) >= jnp.min(kp) if causal else None
+    if window:
+        in_band = jnp.max(kp) > jnp.min(qp) - window
+        needed = in_band if needed is None else jnp.logical_and(needed,
+                                                                in_band)
+    return needed
+
+
+def _safe(stat):
+    """Fully-masked rows carry NEG_INF statistics; exp(s - NEG_INF) would
+    be exp(0) = 1 for their masked entries — subtract 0 so p stays 0."""
+    return jnp.where(stat <= NEG_INF / 2, 0.0, stat)
+
+
+def _dispatch(update, *, major, unit, masks, needed_at, off, offsets,
+              segments_of):
+    """Hand ``update`` the segments of the step's resident block that it
+    has to enter. Nothing masks: the whole block, plain. Traced positions
+    (``needed_at`` given): no static diagonal, so every ``unit`` chunk
+    under its own predicate, masked from the positions themselves.
+    Else the static specialisations of :func:`_static_dispatch`."""
+    if not masks:
+        update(((0, major, None),))
+    elif needed_at is not None:
+        for c in range(0, major, unit):
+            pl.when(needed_at(c))(functools.partial(update,
+                                                    ((c, c + unit, 0),)))
+    else:
+        _static_dispatch(off, offsets, segments_of, update)
+
+
+def _kv_dispatch(update, q_ref, k_ref, qpos_ref, kpos_ref, *, block_k, grid,
+                 causal, window):
+    """Forward and dQ: the key segments of the resident K/V block that
+    this step's Q tile has to meet."""
+    block_q, major = q_ref.shape[1], k_ref.shape[1]
+    _dispatch(
+        update, major=major, unit=block_k, masks=causal or bool(window),
+        needed_at=None if qpos_ref is None else lambda c: _pos_needed(
+            qpos_ref[...], kpos_ref[c:c + block_k, :], causal, window),
+        off=pl.program_id(1) * block_q - pl.program_id(2) * major,
+        offsets=[i * block_q - j * major
+                 for i in range(grid[0]) for j in range(grid[1])],
+        segments_of=lambda off: _segments(
+            _kv_ranges(off, major // block_k, block_q, block_k, causal,
+                       window), block_k, lambda c0: c0 - off))
+
+
+def _unpack(refs, n_inputs, has_pos):
+    """(inputs, qpos_ref, kpos_ref, outputs and scratch): the position
+    refs follow the inputs when the call has them."""
+    refs = list(refs)
+    ins, rest = refs[:n_inputs], refs[n_inputs:]
+    if has_pos:
+        return ins, rest[0], rest[1], rest[2:]
+    return ins, None, None, rest
+
+
+def _scores_t(q, kb, mask, qpos, kpos, *, scale, causal, window):
+    """One segment's scores, TRANSPOSED: (keys, queries). ``mask`` None:
+    wholly inside, no mask code. Else the keep-mask comes from the traced
+    positions (``qpos`` a (1, queries) row, ``kpos`` a (keys, 1) column)
+    or, grid-implied, from lane - sublane against the scalar ``mask``."""
+    st = _dot(kb, q, _NT) * scale
+    if mask is None:
+        return st
+    if qpos is not None:
+        keep = _keep(qpos - kpos, 0, causal, window)
+    else:
+        keep = _keep(_iota_diff_t(*st.shape), mask, causal, window)
+    return jnp.where(keep, st, NEG_INF)
+
+
 # ================================================================= forward
 
-def _fwd_kernel(*refs, block_q, block_k, causal, scale, window, has_pos):
-    """Grid (BH, nq, nk): one (block_q, D) output tile, sweeping KV blocks."""
-    if has_pos:
-        (q_ref, k_ref, v_ref, qpos_ref, kpos_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-        qpos_ref = kpos_ref = None
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _fwd_kernel(*refs, block_k, grid, direct, causal, scale, window,
+                has_pos):
+    """Grid (BH, nq, n_major): one (block_q, D) output tile; each step
+    meets its resident (major, D) K and V in ONE softmax update over the
+    key segments it has to enter. With a single major block and static
+    positions the update writes the output itself and no state is kept."""
+    (q_ref, k_ref, v_ref), qpos_ref, kpos_ref, (o_ref, lse_ref, *state) = \
+        _unpack(refs, 3, has_pos)
+    masking = dict(causal=causal, window=window)
+    kmi = pl.program_id(2)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, D)
-        kb = k_ref[0].astype(jnp.float32)  # (block_k, D)
-        vb = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k)
-
-        keep = _block_keep(q_start, k_start, qpos_ref, kpos_ref,
-                           block_q, block_k, causal, window)
-        if keep is not None:
-            s = jnp.where(keep, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]  # (block_q, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # Rows with EVERY key masked so far (possible for ring chunks and
-        # window bands): m_new == NEG_INF, and exp(s - m_new) would be
-        # exp(0)=1 for the masked entries. Subtract 0 instead so p stays 0.
-        m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        alpha = jnp.exp(m_prev - m_safe)
-        p = jnp.exp(s - m_safe)  # (block_q, block_k)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    needed = _block_needed(q_start, k_start, qpos_ref, kpos_ref,
-                           block_q, block_k, causal, window)
-    if needed is None:
-        _body()
-    else:
-        pl.when(needed)(_body)
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        l = l_ref[:, :1]
+    def finish(m, l, acc_t):
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows → zeros
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:, :1] + jnp.log(l_safe)
+        o_ref[0] = (acc_t / l_safe).T.astype(o_ref.dtype)
+        lse_ref[0] = m + jnp.log(l_safe)
+
+    if not direct:
+        acc_ref, m_ref, l_ref = state
+
+        @pl.when(kmi == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+    def update(segs):
+        q = q_ref[0]  # (block_q, D)
+        scores = [_scores_t(q, k_ref[0, c0:c1, :], mask,
+                            qpos_ref[...] if has_pos else None,
+                            kpos_ref[c0:c1, :] if has_pos else None,
+                            scale=scale, **masking)
+                  for c0, c1, mask in segs]  # (c1 - c0, block_q) each
+        m_new = functools.reduce(jnp.maximum, (
+            jnp.max(st, axis=0, keepdims=True) for st in scores))
+        if not direct:
+            m_new = jnp.maximum(m_ref[...], m_new)  # (1, block_q)
+        m_safe = _safe(m_new)
+        probs = [jnp.exp(st - m_safe) for st in scores]
+        l_new = functools.reduce(operator.add, (
+            jnp.sum(pt, axis=0, keepdims=True) for pt in probs))
+        acc = functools.reduce(operator.add, (  # (D, block_q) = V^T P^T
+            _dot(v_ref[0, c0:c1, :], pt, _TN)
+            for (c0, c1, _), pt in zip(segs, probs)))
+        if direct:
+            finish(m_new, l_new, acc)
+        else:
+            alpha = jnp.exp(m_ref[...] - m_safe)
+            acc_ref[...] = acc_ref[...] * alpha + acc
+            l_ref[...] = l_ref[...] * alpha + l_new
+            m_ref[...] = m_new
+
+    _kv_dispatch(update, q_ref, k_ref, qpos_ref, kpos_ref, block_k=block_k,
+                 grid=grid, **masking)
+
+    if not direct:
+        @pl.when(kmi == pl.num_programs(2) - 1)
+        def _finalize():
+            finish(m_ref[...], l_ref[...], acc_ref[...])
 
 
-def _pos_specs(block_q, block_k):
-    """BlockSpecs for the (S, 1) / (Sk, 1) i32 position inputs (shared
-    across the BH grid axis)."""
-    return [
-        pl.BlockSpec((block_q, 1), lambda b, i, j: (i, 0)),
-        pl.BlockSpec((block_k, 1), lambda b, i, j: (j, 0)),
-    ]
-
-
-def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale,
-         block_q, block_k, window, interpret, out_dtype=None):
+def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
+         window, interpret, out_dtype=None):
     BH, Sq, D = q3.shape
     Sk = k3.shape[1]
     # GQA without HBM expansion (ROADMAP kernel follow-up): q3 is flattened
@@ -241,39 +482,45 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale,
     # and its KV row is simply b // rep — an index_map, not a materialized
     # repeat. rep == 1 is the MHA/pre-expanded case (identity map).
     rep = BH // k3.shape[0]
-    nq, nk = Sq // block_q, Sk // block_k
-    grid = (BH, nq, nk)
+    block_q, block_k, _, major = tiles
+    grid = (Sq // block_q, Sk // major)
     has_pos = q_pos is not None
     out_shape = [
         jax.ShapeDtypeStruct(q3.shape, out_dtype or q3.dtype),  # O
-        jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),  # LSE (trailing 1: TPU block-shape alignment)
+        jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),  # LSE, a row a head
     ]
+    # one resident block and static positions: the step writes its output
+    # itself and keeps no running state
+    direct = grid[1] == 1 and not has_pos
     kernel = functools.partial(
-        _fwd_kernel, block_q=block_q, block_k=block_k,
+        _fwd_kernel, block_k=block_k, grid=grid, direct=direct,
         causal=causal, scale=scale, window=window, has_pos=has_pos,
     )
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major, D), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major, D), lambda b, i, j: (b // rep, j, 0)),
     ]
     args = [q3, k3, v3]
     if has_pos:
-        in_specs += _pos_specs(block_q, block_k)
+        # i32 positions, shared across the BH grid axis: queries a
+        # (1, Sq) row, keys an (Sk, 1) column, as the scores lie
+        in_specs += [pl.BlockSpec((1, block_q), lambda b, i, j: (0, i)),
+                     pl.BlockSpec((major, 1), lambda b, i, j: (j, 0))]
         args += [q_pos, kv_pos]
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(BH, *grid),
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+        scratch_shapes=[] if direct else [
+            pltpu.VMEM((D, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -290,196 +537,194 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale,
 #   dS_ij = P_ij * (dP_ij - delta_i)
 #   dQ_i = scale * sum_j dS_ij K_j
 #   dK_j = scale * sum_i dS_ij^T Q_i
+# All of it on transposed tiles (P^T, dP^T = V dO^T, dS^T), so dV and dK
+# are plain products and dQ^T = K^T dS^T is transposed once on the way out,
+# where ``scale`` multiplies it too.
 
-def _bwd_dq_kernel(*refs, block_q, block_k, causal, scale, window, has_pos):
-    if has_pos:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         qpos_ref, kpos_ref, dq_ref, acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, acc_ref) = refs
-        qpos_ref = kpos_ref = None
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_dq_kernel(*refs, block_k, grid, direct, causal, scale, window,
+                   has_pos):
+    """Grid (BH, nq, n_major), as the forward: one (block_q, D) dQ tile."""
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qpos_ref, kpos_ref,
+     (dq_ref, *state)) = _unpack(refs, 6, has_pos)
+    masking = dict(causal=causal, window=window)
+    kmi = pl.program_id(2)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def finish(acc_t):
+        dq_ref[0] = (acc_t * scale).T.astype(dq_ref.dtype)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
+    if not direct:
+        acc_ref, = state
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # (block_q, 1)
+        @pl.when(kmi == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def update(segs):
+        q = q_ref[0]
+        do = do_ref[0]
+        lse = _safe(lse_ref[0])  # lse, delta: (1, block_q)
         delta = delta_ref[0]
+        parts = []
+        for c0, c1, mask in segs:
+            kb = k_ref[0, c0:c1, :]
+            vb = v_ref[0, c0:c1, :]
+            st = _scores_t(q, kb, mask, qpos_ref[...] if has_pos else None,
+                           kpos_ref[c0:c1, :] if has_pos else None,
+                           scale=scale, **masking)
+            dst = jnp.exp(st - lse) * (_dot(vb, do, _NT) - delta)
+            parts.append(_dot(kb, dst, _TN))  # (D, block_q) = K^T dS^T
+        acc = functools.reduce(operator.add, parts)
+        if direct:
+            finish(acc)
+        else:
+            acc_ref[...] += acc
 
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        keep = _block_keep(q_start, k_start, qpos_ref, kpos_ref,
-                           block_q, block_k, causal, window)
-        if keep is not None:
-            s = jnp.where(keep, s, NEG_INF)
-        # Fully-masked rows carry lse == NEG_INF; exp(s - lse) would be
-        # exp(0)=1 there — subtract 0 instead so p stays 0.
-        lse_safe = jnp.where(lse <= NEG_INF / 2, 0.0, lse)
-        p = jnp.exp(s - lse_safe)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _kv_dispatch(update, q_ref, k_ref, qpos_ref, kpos_ref, block_k=block_k,
+                 grid=grid, **masking)
 
-    needed = _block_needed(q_start, k_start, qpos_ref, kpos_ref,
-                           block_q, block_k, causal, window)
-    if needed is None:
-        _body()
-    else:
-        pl.when(needed)(_body)
-
-    @pl.when(ki == nk - 1)
-    def _fin():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+    if not direct:
+        @pl.when(kmi == pl.num_programs(2) - 1)
+        def _fin():
+            finish(acc_ref[...])
 
 
-def _bwd_dkv_kernel(*refs, block_q, block_k, causal, scale, window, has_pos):
-    """Grid (B·Hkv, nk, rep, nq): one (block_k, D) dK/dV tile. The rep axis
+def _bwd_dkv_kernel(*refs, block_q, grid, rep, direct, causal, scale,
+                    window, has_pos):
+    """Grid (B·Hkv, nk, rep, n_major): one (block_k, D) dK/dV tile; each
+    step meets the query segments it has to enter of its resident
+    (major, D) Q and dO and (1, major) lse and delta. The rep axis
     revisits the SAME KV tile for each of the rep query heads sharing it
-    (GQA) — first visit (r==0, qi==0) zeroes the accumulators, every visit
-    adds, the last (r==rep-1, qi==nq-1) writes out. rep==1 is MHA."""
-    if has_pos:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         qpos_ref, kpos_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        qpos_ref = kpos_ref = None
+    (GQA) — first visit (r==0, first major block) zeroes the accumulators,
+    every visit adds, the last writes out. rep==1 is MHA, and with one
+    major block besides the step writes dK/dV itself, no state kept."""
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qpos_ref, kpos_ref,
+     (dk_ref, dv_ref, *state)) = _unpack(refs, 6, has_pos)
+    major, block_k = q_ref.shape[1], k_ref.shape[1]
+    masking = dict(causal=causal, window=window)
     ki = pl.program_id(1)
     r = pl.program_id(2)
-    qi = pl.program_id(3)
-    rep = pl.num_programs(2)
-    nq = pl.num_programs(3)
+    qmi = pl.program_id(3)
 
-    @pl.when((qi == 0) & (r == 0))
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def finish(dk, dv):
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
+    if not direct:
+        dk_acc, dv_acc = state
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # (block_q, 1)
-        delta = delta_ref[0]
+        @pl.when((qmi == 0) & (r == 0))
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        keep = _block_keep(q_start, k_start, qpos_ref, kpos_ref,
-                           block_q, block_k, causal, window)
-        if keep is not None:
-            s = jnp.where(keep, s, NEG_INF)
-        lse_safe = jnp.where(lse <= NEG_INF / 2, 0.0, lse)
-        p = jnp.exp(s - lse_safe)  # (block_q, block_k)
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (block_k, D)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (block_k, D)
+    def update(segs):
+        kb = k_ref[0]
+        vb = v_ref[0]
+        dks, dvs = [], []
+        for r0, r1, mask in segs:
+            q = q_ref[0, r0:r1, :]
+            do = do_ref[0, r0:r1, :]
+            st = _scores_t(  # (block_k, r1 - r0)
+                q, kb, mask, qpos_ref[:, r0:r1] if has_pos else None,
+                kpos_ref[...] if has_pos else None, scale=scale, **masking)
+            pt = jnp.exp(st - _safe(lse_ref[0, :, r0:r1]))
+            dvs.append(_dot(pt, do, _NN))  # (block_k, D)
+            dst = pt * (_dot(vb, do, _NT) - delta_ref[0, :, r0:r1])
+            dks.append(_dot(dst, q, _NN))  # (block_k, D)
+        dk = functools.reduce(operator.add, dks)
+        dv = functools.reduce(operator.add, dvs)
+        if direct:
+            finish(dk, dv)
+        else:
+            dk_acc[...] += dk
+            dv_acc[...] += dv
 
-    needed = _block_needed(q_start, k_start, qpos_ref, kpos_ref,
-                           block_q, block_k, causal, window)
-    if needed is None:
-        _body()
-    else:
-        pl.when(needed)(_body)
+    _dispatch(
+        update, major=major, unit=block_q, masks=causal or bool(window),
+        needed_at=None if not has_pos else lambda r0: _pos_needed(
+            qpos_ref[:, r0:r0 + block_q], kpos_ref[...], causal, window),
+        off=ki * block_k - qmi * major,
+        offsets=[j * block_k - i * major
+                 for j in range(grid[0]) for i in range(grid[1])],
+        segments_of=lambda off: _segments(
+            _q_ranges(off, major // block_q, block_q, block_k, causal,
+                      window), block_q, lambda r0: off - r0))
 
-    @pl.when((qi == nq - 1) & (r == rep - 1))
-    def _fin():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    if not direct:
+        @pl.when((qmi == pl.num_programs(3) - 1) & (r == rep - 1))
+        def _fin():
+            finish(dk_acc[...], dv_acc[...])
 
 
 def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
-         scale, block_q, block_k, window, interpret, dlse=None):
+         scale, tiles, window, interpret, dlse=None):
     BH, Sq, D = q3.shape
     Sk = k3.shape[1]
     rep = BH // k3.shape[0]  # GQA group size (see _fwd); 1 = MHA
-    nq, nk = Sq // block_q, Sk // block_k
+    block_q, block_k, major_q, major_k = tiles
     has_pos = q_pos is not None
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)[..., None]
+                    axis=-1)[:, None, :]  # (BH, 1, Sq), a row a head as lse
     if dlse is not None:
         # Chunk entry: the lse output has its own cotangent. With
         # lse = logsumexp(s), d lse/d s = p, so ds gains +p·dlse — which
         # folds into the flash2 formula as delta' = delta − dlse.
         delta = delta - dlse
+    static = dict(causal=causal, scale=scale, window=window, has_pos=has_pos)
 
+    dq_grid = (Sq // block_q, Sk // major_k)
+    dq_direct = dq_grid[1] == 1 and not has_pos  # as the forward
     dq_in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major_k, D), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major_k, D), lambda b, i, j: (b // rep, j, 0)),
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
     ]
     dq_args = [q3, k3, v3, do3, lse, delta]
     if has_pos:
-        dq_in_specs += _pos_specs(block_q, block_k)
+        dq_in_specs += [pl.BlockSpec((1, block_q), lambda b, i, j: (0, i)),
+                        pl.BlockSpec((major_k, 1), lambda b, i, j: (j, 0))]
         dq_args += [q_pos, kv_pos]
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale, window=window,
-                          has_pos=has_pos),
-        grid=(BH, nq, nk),
+        functools.partial(_bwd_dq_kernel, block_k=block_k, grid=dq_grid,
+                          direct=dq_direct, **static),
+        grid=(BH, *dq_grid),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[] if dq_direct
+        else [pltpu.VMEM((D, block_q), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*dq_args)
 
-    # dK/dV grid (B·Hkv, nk, rep, nq): q-side rows for KV row b are
+    # dK/dV grid (B·Hkv, nk, rep, n_major): q-side rows for KV row b are
     # b·rep + r — the inverse of the forward's b // rep map.
+    dkv_grid = (Sk // block_k, Sq // major_q)
+    dkv_direct = dkv_grid[1] == 1 and rep == 1 and not has_pos
     dkv_in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
+        pl.BlockSpec((1, major_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, j, r, i: (b * rep + r, i, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, j, r, i: (b * rep + r, i, 0)),
+        pl.BlockSpec((1, major_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
+        pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
+        pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
     ]
     dkv_args = [q3, k3, v3, do3, lse, delta]
     if has_pos:
         dkv_in_specs += [
-            pl.BlockSpec((block_q, 1), lambda b, j, r, i: (i, 0)),
+            pl.BlockSpec((1, major_q), lambda b, j, r, i: (0, i)),
             pl.BlockSpec((block_k, 1), lambda b, j, r, i: (j, 0)),
         ]
         dkv_args += [q_pos, kv_pos]
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale, window=window,
-                          has_pos=has_pos),
-        grid=(BH // rep, nk, rep, nq),
+        functools.partial(_bwd_dkv_kernel, block_q=block_q, grid=dkv_grid,
+                          rep=rep, direct=dkv_direct, **static),
+        grid=(BH // rep, dkv_grid[0], rep, dkv_grid[1]),
         in_specs=dkv_in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
@@ -489,10 +734,9 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
             jax.ShapeDtypeStruct(k3.shape, k3.dtype),
             jax.ShapeDtypeStruct(v3.shape, v3.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
+        scratch_shapes=[] if dkv_direct
+        else [pltpu.VMEM((block_k, D), jnp.float32),
+              pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
@@ -505,25 +749,22 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
 # ============================================================== public API
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, causal, scale, block_sizes, interpret, window):
-    o, _ = _fwd(q3, k3, v3, causal=causal, scale=scale,
-                block_q=block_sizes[0], block_k=block_sizes[1],
+def _flash(q3, k3, v3, causal, scale, tiles, interpret, window):
+    o, _ = _fwd(q3, k3, v3, causal=causal, scale=scale, tiles=tiles,
                 window=window, interpret=interpret)
     return o
 
 
-def _flash_fwd(q3, k3, v3, causal, scale, block_sizes, interpret, window):
-    o, lse = _fwd(q3, k3, v3, causal=causal, scale=scale,
-                  block_q=block_sizes[0], block_k=block_sizes[1],
+def _flash_fwd(q3, k3, v3, causal, scale, tiles, interpret, window):
+    o, lse = _fwd(q3, k3, v3, causal=causal, scale=scale, tiles=tiles,
                   window=window, interpret=interpret)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash_bwd(causal, scale, block_sizes, interpret, window, res, do3):
+def _flash_bwd(causal, scale, tiles, interpret, window, res, do3):
     q3, k3, v3, o3, lse = res
     dq, dk, dv = _bwd(q3, k3, v3, o3, lse, do3, causal=causal, scale=scale,
-                      block_q=block_sizes[0], block_k=block_sizes[1],
-                      window=window, interpret=interpret)
+                      tiles=tiles, window=window, interpret=interpret)
     return dq, dk, dv
 
 
@@ -532,8 +773,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     window: int = 0,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: int | None = None,
+                    block_k: int | None = None,
+                    block_k_major: int | None = None,
                     interpret: bool = False) -> jax.Array:
     """(B, S, H, D) attention via the Pallas kernel. GQA (Hkv < H,
     H % Hkv == 0) is NATIVE: K/V stay at Hkv heads in HBM and the kernel's
@@ -541,7 +783,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     across its query group — no expanded copy is ever materialised
     (forward reads H/Hkv x less K/V bandwidth than an expand-first
     design). ``window`` > 0 restricts each query to its trailing
-    ``window`` keys (requires causal — enforced upstream)."""
+    ``window`` keys (requires causal — enforced upstream). Tile sizes
+    default to :func:`tile_sizes`' rule; tests pass their own."""
     if k.shape != v.shape:
         raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
     B, S, H, D = q.shape
@@ -549,14 +792,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if Hkv != H and (Hkv == 0 or H % Hkv != 0):
         raise ValueError(
             f"invalid GQA ratio: {H} query heads over {Hkv} KV heads")
-    bq = min(block_q, S)
-    bk = min(block_k, S)
+    tiles = tile_sizes(S, S, D, q.dtype.itemsize, block_q=block_q,
+                       block_k=block_k, block_k_major=block_k_major)
     scale = float(1.0 / (D ** 0.5))
 
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S, D)
 
-    o3 = _flash(to3(q), to3(k), to3(v), causal, scale, (bq, bk), interpret,
+    o3 = _flash(to3(q), to3(k), to3(v), causal, scale, tiles, interpret,
                 int(window))
     return o3.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
@@ -564,27 +807,26 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # ----------------------------------------------------- ring-chunk entry
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_chunk(q3, k3, v3, qp, kp, causal, scale, block_sizes, interpret,
+def _flash_chunk(q3, k3, v3, qp, kp, causal, scale, tiles, interpret,
                  window):
     o, lse = _fwd(q3, k3, v3, qp, kp, causal=causal, scale=scale,
-                  block_q=block_sizes[0], block_k=block_sizes[1],
-                  window=window, interpret=interpret, out_dtype=jnp.float32)
+                  tiles=tiles, window=window, interpret=interpret,
+                  out_dtype=jnp.float32)
     return o, lse
 
 
-def _flash_chunk_fwd(q3, k3, v3, qp, kp, causal, scale, block_sizes,
-                     interpret, window):
-    o, lse = _flash_chunk(q3, k3, v3, qp, kp, causal, scale, block_sizes,
+def _flash_chunk_fwd(q3, k3, v3, qp, kp, causal, scale, tiles, interpret,
+                     window):
+    o, lse = _flash_chunk(q3, k3, v3, qp, kp, causal, scale, tiles,
                           interpret, window)
     return (o, lse), (q3, k3, v3, qp, kp, o, lse)
 
 
-def _flash_chunk_bwd(causal, scale, block_sizes, interpret, window, res, ct):
+def _flash_chunk_bwd(causal, scale, tiles, interpret, window, res, ct):
     q3, k3, v3, qp, kp, o3, lse = res
     do3, dlse = ct
     dq, dk, dv = _bwd(q3, k3, v3, o3, lse, do3.astype(jnp.float32), qp, kp,
-                      causal=causal, scale=scale,
-                      block_q=block_sizes[0], block_k=block_sizes[1],
+                      causal=causal, scale=scale, tiles=tiles,
                       window=window, interpret=interpret, dlse=dlse)
     zero = lambda x: np.zeros(x.shape, jax.dtypes.float0)  # noqa: E731
     return dq, dk, dv, zero(qp), zero(kp)
@@ -595,8 +837,9 @@ _flash_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 
 def flash_attention_chunk(q, k, v, q_pos, kv_pos, *, causal: bool,
                           window: int = 0,
-                          block_q: int = DEFAULT_BLOCK_Q,
-                          block_k: int = DEFAULT_BLOCK_K,
+                          block_q: int | None = None,
+                          block_k: int | None = None,
+                          block_k_major: int | None = None,
                           interpret: bool = False):
     """One Q shard against ONE K/V chunk with explicit global positions —
     the ring-attention inner step (ops/ring_attention.py).
@@ -611,17 +854,17 @@ def flash_attention_chunk(q, k, v, q_pos, kv_pos, *, causal: bool,
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
+    tiles = tile_sizes(Sq, Sk, D, q.dtype.itemsize, block_q=block_q,
+                       block_k=block_k, block_k_major=block_k_major)
     scale = float(1.0 / (D ** 0.5))
-    qp = q_pos.astype(jnp.int32).reshape(Sq, 1)
-    kp = kv_pos.astype(jnp.int32).reshape(Sk, 1)
+    qp = q_pos.astype(jnp.int32).reshape(1, Sq)  # a row: queries on lanes
+    kp = kv_pos.astype(jnp.int32).reshape(Sk, 1)  # a column: keys on sublanes
 
     def to3(x):  # per-tensor head count: k/v stay at Hkv rows (GQA)
         return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2],
                                                x.shape[1], D)
 
     o3, lse = _flash_chunk(to3(q), to3(k), to3(v), qp, kp, causal, scale,
-                           (bq, bk), interpret, int(window))
+                           tiles, interpret, int(window))
     o = o3.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     return o, lse.reshape(B, H, Sq)

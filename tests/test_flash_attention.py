@@ -112,6 +112,166 @@ def test_windowed_gradients_match_xla():
                                    err_msg=f"d{name} mismatch")
 
 
+# (id, S, H, Hkv, block_q, major, block_k, causal, window): explicit tile
+# sizes, so every loop shape of the two-level tiling is walked in interpret
+# mode: several majors a row, tiles wider than tall and the reverse, a
+# window inside one tile and one that spans majors, GQA's revisit axis.
+TILED_CASES = [
+    ("s1024_tiles256", 1024, 1, 1, 256, 1024, 256, True, 0),
+    ("s1024_tiles128", 1024, 1, 1, 128, 1024, 128, True, 0),
+    ("s1024_major512_q256_k128", 1024, 1, 1, 256, 512, 128, True, 0),
+    ("s512_q128_k256_window200", 512, 2, 2, 128, 512, 256, True, 200),
+    ("s512_gqa_rep4", 512, 4, 1, 256, 256, 128, True, 0),
+    ("s512_window_inside_a_tile", 512, 2, 2, 128, 256, 128, True, 50),
+    ("s512_window_wider_than_major", 512, 2, 2, 128, 256, 128, True, 300),
+    ("s512_noncausal", 512, 2, 1, 128, 256, 128, False, 0),
+    ("s384_rule", 384, 2, 2, None, None, None, True, 0),
+]
+
+
+@pytest.mark.parametrize("name,S,H,Hkv,block_q,major,block_k,causal,window",
+                         TILED_CASES, ids=[c[0] for c in TILED_CASES])
+def test_tiled_kernels_match_xla(name, S, H, Hkv, block_q, major, block_k,
+                                 causal, window):
+    """Outputs and all three gradients of the tiled kernels against the
+    XLA reference (GQA: the reference expands K/V inside the loss)."""
+    q, _, _ = _make_qkv(B=1, S=S, H=H, D=64, seed=31)
+    _, k, v = _make_qkv(B=1, S=S, H=Hkv, D=64, seed=37)
+    rep = H // Hkv
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=block_q, block_k=block_k,
+                               block_k_major=major, interpret=True)
+
+    def ref(q, k, v):
+        return _xla_attention(q, jnp.repeat(k, rep, axis=2),
+                              jnp.repeat(v, rep, axis=2), causal=causal,
+                              mask=None, softmax_dtype=jnp.float32,
+                              window=window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, n in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4,
+                                   rtol=5e-3, err_msg=f"d{n} mismatch")
+
+
+@pytest.mark.parametrize("window", [0, 100])
+def test_chunk_entry_rotated_positions_match_xla(window):
+    """The ring's entry with positions as a zigzag ring hands them over:
+    two half-chunks out of order on both sides, so score tiles are entered
+    or skipped by their positions' min/max, and some rows see no key at
+    all: those return zeros and lse = NEG_INF."""
+    from pytorch_distributed_train_tpu.ops.flash_attention import (
+        NEG_INF,
+        flash_attention_chunk,
+    )
+
+    S, half = 512, 256
+    q, _, _ = _make_qkv(B=1, S=S, H=4, D=64, seed=41)
+    _, k, v = _make_qkv(B=1, S=S, H=2, D=64, seed=43)
+    ar = jnp.arange(half, dtype=jnp.int32)
+    q_pos = jnp.concatenate([ar + 256, ar + 1536])
+    kv_pos = jnp.concatenate([ar + 1024, ar + 384])
+    d = q_pos[:, None] - kv_pos[None, :]
+    keep = d >= 0
+    if window:
+        keep &= d < window
+    valid = np.asarray(keep.any(axis=1))
+    assert valid.any() and not valid.all()
+    rows = jnp.asarray(valid, jnp.float32)[None, :, None, None]
+
+    def flash(q, k, v):
+        o, lse = flash_attention_chunk(
+            q, k, v, q_pos, kv_pos, causal=True, window=window, block_q=128,
+            block_k=128, block_k_major=256, interpret=True)
+        return o, lse
+
+    def ref(q, k, v):
+        return _xla_attention(q, jnp.repeat(k, 2, axis=2),
+                              jnp.repeat(v, 2, axis=2), causal=False,
+                              mask=keep[None, None], softmax_dtype=jnp.float32)
+
+    o, lse = flash(q, k, v)
+    np.testing.assert_allclose(np.asarray(o)[:, valid],
+                               np.asarray(ref(q, k, v))[:, valid],
+                               atol=2e-5, rtol=2e-5)
+    assert float(jnp.abs(o[:, ~valid]).max()) == 0.0
+    assert float(lse[:, :, ~valid].max()) == np.float32(NEG_INF)
+    assert float(lse[:, :, valid].min()) > NEG_INF / 2
+    gf = jax.grad(lambda *a: jnp.sum((flash(*a)[0] * rows) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum((ref(*a) * rows) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, n in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4,
+                                   rtol=5e-3, err_msg=f"d{n} mismatch")
+
+
+def test_tile_plan_counts_and_tile_ranges():
+    """tile_plan's counts at the shapes PERF.md quotes, and the kernels'
+    own tile ranges (what a grid step enters, and where it masks) against
+    the same classification of every tile, for both sweep directions."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    assert fa.tile_plan(1024, 256, 256, causal=True) == (16, 10, 4)
+    assert fa.tile_plan(1024, 256, 256, causal=False) == (16, 16, 0)
+    assert fa.tile_plan(1024, 128, 128, causal=True) == (64, 36, 8)
+    assert fa.tile_plan(1024, 512, 1024, causal=True) == (2, 2, 2)  # PR 24's
+    assert fa.tile_plan(2048, 256, 256, causal=True, window=512) == (64, 21, 14)
+    # the rule at the benchmark's shape and at the long-sequence shape
+    assert fa.tile_sizes(1024, 1024, 64, 2) == (512, 512, 1024, 1024)
+    assert fa.tile_sizes(2048, 2048, 128, 2) == (512, 512, 2048, 2048)
+    assert fa.tile_sizes(384, 384, 64, 4) == (128, 128, 384, 384)
+    assert fa.tile_sizes(1024, 1024, 256, 4) == (512, 512, 512, 512)
+
+    def kinds(d_min, d_max, causal, window):
+        if (causal and d_max < 0) or (window and d_min >= window):
+            return "skip"
+        return "mask" if ((causal and d_min < 0)
+                          or (window and d_max >= window)) else "plain"
+
+    def from_ranges(ranges, n):
+        lo, a, b, hi = (int(x) for x in ranges)
+        return ["mask" if lo <= t < a or b <= t < hi else
+                "plain" if a <= t < b else "skip" for t in range(n)]
+
+    S = 1024
+    for bq, bk, major in ((256, 256, 1024), (128, 256, 512), (256, 128, 512),
+                          (512, 128, 1024)):
+        for causal, window in ((True, 0), (True, 50), (True, 300),
+                               (True, 700), (False, 0), (False, 130)):
+            executed = masked = 0
+            for q0 in range(0, S, bq):       # forward / dQ: a Q tile's KV tiles
+                for k0 in range(0, S, major):
+                    n = major // bk
+                    got = from_ranges(fa._kv_ranges(q0 - k0, n, bq, bk, causal,
+                                                    window), n)
+                    want = [kinds(q0 - (k0 + j * bk + bk - 1),
+                                  q0 + bq - 1 - (k0 + j * bk), causal, window)
+                            for j in range(n)]
+                    assert got == want, (bq, bk, causal, window, q0, k0)
+                    executed += sum(w != "skip" for w in want)
+                    masked += want.count("mask")
+            assert fa.tile_plan(S, bq, bk, causal=causal, window=window) == (
+                (S // bq) * (S // bk), executed, masked)
+            for k0 in range(0, S, bk):       # dK/dV: a KV tile's Q tiles
+                for q0 in range(0, S, major):
+                    n = major // bq
+                    got = from_ranges(fa._q_ranges(k0 - q0, n, bq, bk, causal,
+                                                   window), n)
+                    want = [kinds(q0 + i * bq - (k0 + bk - 1),
+                                  q0 + i * bq + bq - 1 - k0, causal, window)
+                            for i in range(n)]
+                    assert got == want, (bq, bk, causal, window, k0, q0)
+
+
 def test_chunk_entry_contract():
     """flash_attention_chunk: the ring inner kernel's (o, lse) contract —
     diagonal chunk == causal self-attention; all-future chunk returns
@@ -192,6 +352,25 @@ def test_dispatch_windowed_pallas_impl():
     ref = dot_product_attention(q, k, v, causal=True, window=64, impl="xla")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_resolution_line_prints_the_tile_plan(capsys):
+    """The dispatch's once-per-shape line says how many of a head's score
+    tiles the kernel enters and masks, and they are tile_plan's for the
+    call's shape under the kernel's own tile rule."""
+    from pytorch_distributed_train_tpu.ops import attention as attn
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    attn._resolutions_logged.clear()
+    jax.eval_shape(lambda a: attn.dot_product_attention(
+        a, a, a, causal=True, impl="pallas"), q)
+    line = capsys.readouterr().err.strip().splitlines()[-1]
+    plan = fa.call_plan(q, q, causal=True)
+    assert line.startswith("[attention] impl=pallas q=(1, 1024, 2, 64)")
+    assert line.endswith(
+        f"tiles={plan.executed}/{plan.total} masked={plan.masked}")
+    assert plan == (4, 3, 2)
 
 
 def test_dispatch_pallas_impl_covers_gqa_expansion():

@@ -4,11 +4,14 @@ no chip attached (on-chip-measurement guide, section 2, rehearsal 3).
 
 What this guards: Mosaic refusals interpret mode cannot see (VMEM
 budgets, lane padding of D=64, block-shape alignment, the 4-axis
-backward grid, (S, 1) i32 position refs) at GPT-2 small's attention
-shape — the shape chip_smoke.py trains — and at the S 2048 / D 128
-causal, GQA and window shapes, plus the ring chunk kernel on a 4-device
-mesh and the W8/W4 GEMV kernels. A compile that passes here is a
-compile, not a chip run.
+backward grid, (S, 1) i32 position refs, the per-offset specialisations
+and their static slices of the resident block) under the kernel's own
+tile rule at GPT-2 small's attention shape — the shape the benchmark's
+cells train — and at the S 2048 / D 128 causal, GQA and window shapes,
+plus the ring chunk kernel on a 4-device mesh and the W8/W4 GEMV
+kernels; and that the one-chip GPT-2 step keeps its 36 kernel calls
+under the names the benchmark finds them by. A compile that passes here
+is a compile, not a chip run.
 
 Rules this file keeps (only one process at a time may load the TPU
 library, and the suite runs under several xdist workers): the topology
@@ -69,10 +72,11 @@ def _attn_loss(q, k, v, **kw):
 
 
 # (id, B, S, H, Hkv, D, window). gpt2_small is the preset's attention
-# shape at chip_smoke.py's batch; the rest are the long-sequence shapes
-# (llama-style heads of 128, GQA 4:1, Mistral-style window).
+# shape at the benchmark cells' batch a chip; the rest are the
+# long-sequence shapes (llama-style heads of 128, GQA 4:1, Mistral-style
+# window).
 FLASH_SHAPES = [
-    ("gpt2_small", 8, 1024, 12, 12, 64, 0),
+    ("gpt2_small", 16, 1024, 12, 12, 64, 0),
     ("s2048_d128", 2, 2048, 8, 8, 128, 0),
     ("s2048_d128_gqa", 2, 2048, 8, 2, 128, 0),
     ("s2048_d128_window512", 2, 2048, 8, 8, 128, 512),
@@ -148,6 +152,63 @@ def test_ring_attention_compiles_on_four_chips(topo, direction):
     fn = ring if direction == "fwd" else jax.grad(ring, argnums=(0, 1, 2))
     text = _compile(fn, q, kv, kv).as_text()
     assert "collective-permute" in text
+
+
+def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
+    """The one-chip GPT-2 small step, lowered and compiled for the
+    described chip at the benchmark cell's batch: 12 layers x (forward,
+    dQ, dK/dV) = 36 instructions whose trace names (`%attn.N custom-call`)
+    match the configuration's `flash_kernel_pattern`. The benchmark's
+    `flash_attn_ms_per_step` finds the kernel by that name alone, so a
+    `name=` on a `pallas_call` or a renamed module would zero the metric."""
+    import json
+    import re
+
+    from pytorch_distributed_train_tpu import losses as losses_lib
+    from pytorch_distributed_train_tpu import steps as steps_lib
+    from pytorch_distributed_train_tpu.config import get_preset
+    from pytorch_distributed_train_tpu.models.registry import build_model
+    from pytorch_distributed_train_tpu.ops import attention as attention_lib
+    from pytorch_distributed_train_tpu.optim import make_optimizer
+    from pytorch_distributed_train_tpu.train_state import TrainState
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "gpt2_small.json"),
+              encoding="utf-8") as f:
+        bench = json.load(f)
+    # the dispatch asks the RUNTIME backend (the CPU here): steer it in
+    # the test, as the described-chip guide says, not through an option
+    monkeypatch.setattr(attention_lib, "_on_tpu", lambda: True)
+    cfg = get_preset(bench["preset"])
+    cfg.apply_overrides(list(bench["overrides"]) + ["data.batch_size=16"])
+    model = build_model(cfg.model, cfg.precision)
+    tx, _ = make_optimizer(cfg.optim, cfg.total_steps, 0)
+    dummy = steps_lib.dummy_inputs(cfg.loss, cfg.model, cfg.data)
+
+    def init(rng):
+        params = model.init({"params": rng}, *dummy, train=False)["params"]
+        return TrainState.create(params=params, tx=tx, batch_stats={},
+                                 dynamic_scale=None, ema=False, swa=False)
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    step = steps_lib.make_train_step(
+        model, losses_lib.get_loss_fn(cfg.loss,
+                                      label_smoothing=cfg.label_smoothing),
+        tx)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (cfg.data.batch_size, cfg.data.seq_len), jnp.int32,
+        sharding=one_chip)}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        described(jax.eval_shape(init, jax.random.PRNGKey(0))), batch,
+        rng).compile().as_text()
+    names = [f"{m.group(1)} custom-call" for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*? custom-call\(", text, re.M)]
+    kernels = [n for n in names if re.search(bench["flash_kernel_pattern"], n)]
+    assert len(kernels) == 3 * bench["n_layer"] == 36, kernels
 
 
 @pytest.mark.parametrize("bits", [8, 4])
