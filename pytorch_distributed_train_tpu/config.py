@@ -138,6 +138,35 @@ class ModelConfig:
     # "I understand the Zhou et al. caveat" opt-in.
     moe_router_allow_noncausal: bool = False
     moe_zloss_weight: float = 1e-3
+    # Hybrid decoder (model name "hybrid_lm"; models/hybrid.py): layers in
+    # groups of layer_group_size, the last of each group latent attention
+    # (MLA: kv_lora_rank, rope_head_dim rotated dims beside head_dim plain
+    # ones), the others Kimi Delta Attention (ops/kda.py: a causal
+    # depthwise conv of conv_kernel_size, a log-decay bounded below by
+    # kda_gate_lower_bound). head_dim 0 means
+    # hidden_size / num_heads. The first first_dense_layers layers carry a
+    # dense FFN of mlp_dim; the others ONE CHIP'S SHARE of a routed layer
+    # (ops/moe.py HeldExpertsMLP): the router spans num_experts in
+    # moe_groups groups of which a token uses moe_topk_groups, picks
+    # expert_top_k, scales by moe_routed_scale; experts_held experts from
+    # id experts_held_first on, of width moe_mlp_dim, live here (0 = all)
+    # beside one shared expert. There expert_capacity_factor bounds the
+    # grouped product's rows: that many times the pairs uniform routing
+    # would send to the held experts; a step past it keeps its old state
+    # and reports update_skipped.
+    head_dim: int = 0
+    layer_group_size: int = 0
+    first_dense_layers: int = 0
+    kv_lora_rank: int = 512
+    rope_head_dim: int = 64
+    conv_kernel_size: int = 4
+    kda_gate_lower_bound: float = -5.0
+    moe_mlp_dim: int = 0
+    moe_groups: int = 1
+    moe_topk_groups: int = 1
+    moe_routed_scale: float = 1.0
+    experts_held: int = 0
+    experts_held_first: int = 0
     # Fused elementwise block epilogues (ops/fused_update.py; vit/bert):
     # the bias+GELU MLP epilogue and (post-LN bert) the residual-add+
     # LayerNorm epilogue compute as single tagged expressions XLA keeps
@@ -1086,6 +1115,47 @@ def _mixtral_8x7b() -> TrainConfig:
     return c
 
 
+def _ling3_flash_lm_ep64() -> TrainConfig:
+    """One chip's share of Ling-3.0-flash's language model (inclusionAI,
+    https://huggingface.co/inclusionAI/Ling-3.0-flash-VL config.json; the
+    vision tower and the MTP head are left out): every width as published;
+    the leading dense layer and one period of five KDA layers to one MLA
+    layer (42 layers published); 8 of each layer's 512 routed experts, as
+    one of 64 expert-parallel chips holds them; an eighth of the 157184-row
+    vocabulary. 715 M parameters, 11.4 GB with AdamW's float32 state
+    (benchmark/configs/ling3_flash_lm_ep64.json says what was assumed)."""
+    c = TrainConfig(preset="ling3_flash_lm_ep64")
+    c.model = ModelConfig(
+        name="hybrid_lm", hidden_size=2560, num_layers=6, num_heads=32,
+        head_dim=128, mlp_dim=6144, vocab_size=19648, max_seq_len=8192,
+        rope_theta=6e6, rms_norm_eps=1e-6, remat=True,
+        layer_group_size=6, first_dense_layers=1, kv_lora_rank=512,
+        rope_head_dim=64, conv_kernel_size=4, kda_gate_lower_bound=-5.0,
+        num_experts=512, expert_top_k=8, moe_groups=8, moe_topk_groups=4,
+        moe_routed_scale=2.5, moe_mlp_dim=768, experts_held=8,
+        experts_held_first=0, expert_capacity_factor=4.0,
+    )
+    # 4096 synthetic sequences are 33 M tokens (gpt2_small's 51200 x 1024
+    # are 52 M); the default 51200 x 8192 took a minute of set-up to draw
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=2, seq_len=8192,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        # decay the matrices and the embedding only: not the norms, the
+        # conv taps, the decay's A_log and dt_bias, the router's bias
+        decay_exclude=r"scale$,bias$,_conv$,A_log$",
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    # a step of 16384 tokens takes over a second on one v5e: a log every
+    # twenty steps, where the presets of faster steps log every fifty
+    c.obs.log_every_steps = 20
+    c.total_steps = 500000
+    c.loss = "causal_lm_xent"
+    return c
+
+
 def _t5_small() -> TrainConfig:
     """T5-small seq2seq pretrain (model-zoo extension beyond the BASELINE
     matrix). HF-layout-compatible via interop's 't5' mapping
@@ -1122,6 +1192,7 @@ _PRESETS = {
     "gpt2_small": _gpt2_small,
     "t5_small": _t5_small,
     "mixtral_8x7b": _mixtral_8x7b,
+    "ling3_flash_lm_ep64": _ling3_flash_lm_ep64,
 }
 
 

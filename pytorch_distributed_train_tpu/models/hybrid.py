@@ -1,0 +1,336 @@
+"""A decoder whose layers differ in kind by a pattern (training path).
+
+Layers come in groups of ``layer_group_size``: the last of each group mixes
+tokens by latent attention (MLA, DeepSeek-V2 arXiv:2405.04434 section 2.1,
+expanded form), the others by Kimi Delta Attention (a gated delta rule with
+a per-channel decay, arXiv:2510.26692; ``ops/kda.py``). The first
+``first_dense_layers`` layers carry a dense SwiGLU FFN, the rest one chip's
+share of a routed expert layer with a shared expert (``ops/moe.py``
+``HeldExpertsMLP``). Pre-norm residual blocks, RMSNorm, untied head, no
+dropout, no auxiliary loss. This is the layout of the Ling-3.0-flash family's
+language model (preset ``ling3_flash_lm_ep64``); the equations are written
+out beside each module and in ``benchmark/references/ling3_flash_lm_ep64.py``,
+which shares no code with this file.
+
+Only the training path exists: no cache, no decode (a latent entry and a
+recurrent state in one cache manager are ROADMAP R2/R7's serving halves).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from pytorch_distributed_train_tpu.models.llama import (
+    LlamaMLP,
+    RMSNorm,
+    apply_rope,
+    rope_frequencies,
+)
+from pytorch_distributed_train_tpu.ops import kda as kda_ops
+from pytorch_distributed_train_tpu.ops.attention import (
+    ContextParallelConfig,
+    dot_product_attention,
+)
+from pytorch_distributed_train_tpu.ops.moe import (
+    HeldExpertsMLP,
+    HeldExpertsSpec,
+)
+
+_INIT = nn.initializers.normal(0.02)
+_F32_OUT = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
+
+
+def causal_short_conv(x, weight):
+    """Depthwise causal convolution along the sequence: x (B, S, H, d),
+    weight (K, H, d); y_t = sum_j weight[j] x_{t-(K-1-j)}, zeros before the
+    start (weight[K-1] meets the current token)."""
+    K, S = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0), (0, 0)))
+    return sum(padded[:, j:j + S] * weight[j] for j in range(K))
+
+
+def _head_gate(x, num_heads, dtype, param_dtype):
+    """sigmoid(W_g x): one scalar a head, float32 (B, S, H, 1)."""
+    gate = nn.Dense(num_heads, use_bias=False, dtype=dtype,
+                    param_dtype=param_dtype, kernel_init=_INIT,
+                    dot_general=_F32_OUT, name="g_proj")(x)
+    return jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+
+
+class KDAMixer(nn.Module):
+    """q, k, v = W x through a causal depthwise conv and SiLU; q and k
+    L2-normalised a head, q scaled by d^-1/2; log-decay
+    g = lower_bound * sigmoid(exp(A_log) (W_a x + dt_bias)) a key channel;
+    beta = sigmoid(W_beta x) a head; the delta-rule recurrence; RMSNorm over
+    each head's output, times a head-wise sigmoid gate; W_o. No rotary."""
+
+    num_heads: int
+    head_dim: int
+    conv_kernel_size: int
+    gate_lower_bound: float
+    rms_norm_eps: float
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        H, d, f32 = self.num_heads, self.head_dim, jnp.float32
+        proj = lambda name, **kw: nn.DenseGeneral(  # noqa: E731
+            (H, d), axis=-1, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=_INIT, name=name,
+            **kw)(x)
+
+        taps = [self.param(name, _INIT, (self.conv_kernel_size, H, d),
+                           self.param_dtype)
+                for name in ("q_conv", "k_conv", "v_conv")]
+        # exp(A_log) = 1 and dt_bias in (-5, -1) start the channels at
+        # decays of exp(-0.03) to exp(-1.3) a token: memories of 1 to 30
+        a_log = self.param("A_log", nn.initializers.zeros, (H,), f32)
+        dt_bias = self.param(
+            "dt_bias", lambda key, shape, dtype: jax.random.uniform(
+                key, shape, dtype, -5.0, -1.0), (H, d), f32)
+
+        @jax.checkpoint  # elementwise chains: recomputed, not kept
+        def shape_inputs(yq, yk, yv, a, taps, a_log, dt_bias):
+            def conv_silu(y, w):
+                return nn.silu(causal_short_conv(y.astype(f32),
+                                                 w.astype(f32)))
+
+            def unit(y):
+                return y * jax.lax.rsqrt(jnp.sum(y * y, -1, keepdims=True)
+                                         + 1e-6)
+
+            q = unit(conv_silu(yq, taps[0])) * d ** -0.5
+            k = unit(conv_silu(yk, taps[1]))
+            v = conv_silu(yv, taps[2])
+            g = self.gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None] * (a.astype(f32) + dt_bias))
+            return (q.astype(self.dtype), k.astype(self.dtype),
+                    v.astype(self.dtype), g)
+
+        q, k, v, g = shape_inputs(
+            proj("q_proj"), proj("k_proj"), proj("v_proj"),
+            proj("a_proj", dot_general=_F32_OUT), taps, a_log, dt_bias)
+        beta = jax.nn.sigmoid(nn.Dense(
+            H, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype,
+            kernel_init=_INIT, dot_general=_F32_OUT,
+            name="beta_proj")(x).astype(f32))
+        o = kda_ops.kda_chunked(
+            q, k, v, g, beta, chunk=min(kda_ops.DEFAULT_CHUNK, x.shape[1]),
+            lower_bound=self.gate_lower_bound)
+        o = RMSNorm(self.rms_norm_eps, name="o_norm")(o.astype(f32))
+        o = o * _head_gate(x, H, self.dtype, self.param_dtype)
+        return nn.DenseGeneral(
+            x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=_INIT,
+            name="o_proj")(o.astype(self.dtype))
+
+
+class MLAMixer(nn.Module):
+    """Latent attention, expanded (no query compression): q = W_q x, a head
+    [nope | rope]; c = RMSNorm(W_dkv x); k_rope = W_kr x, one for all heads;
+    [k_nope | v] a head = W_ukv c; RMSNorm over each head's whole q and
+    whole k = [k_nope | k_rope] before the rotation; RoPE on the rope
+    parts; causal softmax attention with scores over sqrt(nope + rope); a
+    head-wise sigmoid gate; W_o."""
+
+    num_heads: int
+    head_dim: int        # nope part of q and k, and v
+    rope_head_dim: int
+    kv_lora_rank: int
+    rope_theta: float
+    max_seq_len: int
+    rms_norm_eps: float
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+    cp: ContextParallelConfig | None = None
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, x):
+        H, dn, dr = self.num_heads, self.head_dim, self.rope_head_dim
+        B, S, _ = x.shape
+        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=_INIT, name=name)
+        norm = lambda name: RMSNorm(self.rms_norm_eps, name=name)  # noqa: E731
+        q = dense((H, dn + dr), "q_proj")(x)
+        c = norm("kv_norm")(dense(self.kv_lora_rank, "kv_down")(x))
+        k_rope = dense(dr, "k_rope_proj")(x)
+        kv = dense((H, 2 * dn), "kv_up")(c)
+        k = jnp.concatenate(
+            [kv[..., :dn],
+             jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], -1)
+        q, k = norm("q_norm")(q), norm("k_norm")(k)
+        cos, sin = rope_frequencies(dr, self.max_seq_len, self.rope_theta)
+        rotate = lambda t: jnp.concatenate(  # noqa: E731
+            [t[..., :dn], apply_rope(t[..., dn:], cos, sin)], -1)
+        y = dot_product_attention(rotate(q), rotate(k), kv[..., dn:],
+                                  causal=True, cp=self.cp,
+                                  impl=self.attn_impl)
+        y = y.astype(jnp.float32) * _head_gate(x, H, self.dtype,
+                                               self.param_dtype)
+        return nn.DenseGeneral(
+            x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=_INIT,
+            name="o_proj")(y.astype(self.dtype))
+
+
+class HybridBlock(nn.Module):
+    """x + mixer(norm x), then x + ffn(norm x). Returns (x, moe stats): the
+    expert layer's three row counts, zeros for a dense layer."""
+
+    latent: bool         # this layer's mixer: latent attention, else KDA
+    moe: HeldExpertsSpec | None
+    num_heads: int
+    head_dim: int
+    mlp_dim: int
+    moe_mlp_dim: int
+    rope_head_dim: int
+    kv_lora_rank: int
+    rope_theta: float
+    max_seq_len: int
+    conv_kernel_size: int
+    kda_gate_lower_bound: float
+    rms_norm_eps: float
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+    cp: ContextParallelConfig | None = None
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, x):
+        h = RMSNorm(self.rms_norm_eps, name="input_norm")(x)
+        if self.latent:
+            mixed = MLAMixer(
+                self.num_heads, self.head_dim, self.rope_head_dim,
+                self.kv_lora_rank, self.rope_theta, self.max_seq_len,
+                self.rms_norm_eps, self.dtype, self.param_dtype, cp=self.cp,
+                attn_impl=self.attn_impl, name="mla")(h)
+        else:
+            mixed = KDAMixer(
+                self.num_heads, self.head_dim, self.conv_kernel_size,
+                self.kda_gate_lower_bound, self.rms_norm_eps, self.dtype,
+                self.param_dtype, name="kda")(h)
+        x = x + mixed
+        h = RMSNorm(self.rms_norm_eps, name="post_attn_norm")(x)
+        if self.moe is None:
+            out = LlamaMLP(self.mlp_dim, self.dtype, self.param_dtype,
+                           name="mlp")(h)
+            stats = jnp.zeros((3,), jnp.float32)
+        else:
+            out, stats = HeldExpertsMLP(
+                self.moe, LlamaMLP, self.moe_mlp_dim, self.dtype,
+                self.param_dtype, name="moe")(h)
+        return x + out, stats
+
+
+class HybridLM(nn.Module):
+    """Input: (B, S) int ids. Output: (B, S, vocab) float32 logits. Sows
+    the expert layers' row counts (mean over those layers of the fullest
+    and of the mean held expert, sum of the pairs past the bound) into the
+    ``step_metrics`` collection, which the train step reports; the pairs
+    past the bound also as ``update_invalid``, the name by which the step
+    keeps its old state and reports ``update_skipped`` (steps.py)."""
+
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int
+    head_dim: int
+    mlp_dim: int
+    moe_mlp_dim: int
+    layer_group_size: int
+    first_dense_layers: int
+    moe: HeldExpertsSpec | None
+    rope_head_dim: int = 64
+    kv_lora_rank: int = 512
+    rope_theta: float = 10000.0
+    max_seq_len: int = 8192
+    conv_kernel_size: int = 4
+    kda_gate_lower_bound: float = -5.0
+    rms_norm_eps: float = 1e-6
+    remat: bool = False
+    remat_policy: str = "full"
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    cp: ContextParallelConfig | None = None
+    attn_impl: str = "auto"
+    act: "object | None" = None
+
+    def is_latent(self, i: int) -> bool:
+        return self.layer_group_size > 0 \
+            and (i + 1) % self.layer_group_size == 0
+
+    @nn.compact
+    def __call__(self, input_ids, train: bool = True, loss_mask=None):
+        del train, loss_mask  # no dropout; the loss masks outside
+        from pytorch_distributed_train_tpu.models.remat import remat_block
+
+        constrain = (lambda t: t) if self.act is None else self.act.constrain
+        x = constrain(nn.Embed(
+            self.vocab_size, self.hidden_size, embedding_init=_INIT,
+            param_dtype=self.param_dtype,
+            name="tok_embed")(input_ids).astype(self.dtype))
+        block_cls = remat_block(HybridBlock, self.remat, self.remat_policy)
+        stats = []
+        for i in range(self.num_layers):
+            moe = self.moe if i >= self.first_dense_layers else None
+            x, layer_stats = block_cls(
+                self.is_latent(i), moe, self.num_heads, self.head_dim,
+                self.mlp_dim, self.moe_mlp_dim, self.rope_head_dim,
+                self.kv_lora_rank, self.rope_theta, self.max_seq_len,
+                self.conv_kernel_size, self.kda_gate_lower_bound,
+                self.rms_norm_eps, self.dtype, self.param_dtype,
+                cp=self.cp, attn_impl=self.attn_impl,
+                name=f"layer{i}")(x)
+            x = constrain(x)
+            if moe is not None:
+                stats.append(layer_stats)
+        if stats:
+            fullest, mean, over = jnp.stack(stats).T
+            for name, value in (("moe_rows_fullest", jnp.mean(fullest)),
+                                ("moe_rows_mean", jnp.mean(mean)),
+                                ("moe_rows_over_bound", jnp.sum(over)),
+                                ("update_invalid", jnp.sum(over))):
+                self.sow("step_metrics", name, value,
+                         reduce_fn=lambda _, new: new, init_fn=lambda: 0.0)
+        x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
+        with jax.named_scope("lm_head"):  # a phase of the step: steps.py
+            logits = nn.Dense(
+                self.vocab_size, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, dot_general=_F32_OUT,
+                kernel_init=_INIT, name="lm_head")(x)
+        return logits.astype(jnp.float32)
+
+
+def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
+    moe = None
+    if cfg.num_experts > 1:
+        moe = HeldExpertsSpec(
+            num_experts=cfg.num_experts, top_k=cfg.expert_top_k,
+            n_groups=cfg.moe_groups, topk_groups=cfg.moe_topk_groups,
+            routed_scale=cfg.moe_routed_scale,
+            held_first=cfg.experts_held_first, held=cfg.experts_held,
+            capacity_factor=cfg.expert_capacity_factor)
+    return HybridLM(
+        cp=cp, act=act, moe=moe,
+        attn_impl=getattr(cfg, "attention_impl", "auto"),
+        vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
+        num_layers=cfg.num_layers, num_heads=cfg.num_heads,
+        head_dim=cfg.head_dim or cfg.hidden_size // cfg.num_heads,
+        mlp_dim=cfg.mlp_dim, moe_mlp_dim=cfg.moe_mlp_dim,
+        layer_group_size=cfg.layer_group_size,
+        first_dense_layers=cfg.first_dense_layers,
+        rope_head_dim=cfg.rope_head_dim, kv_lora_rank=cfg.kv_lora_rank,
+        rope_theta=cfg.rope_theta, max_seq_len=cfg.max_seq_len,
+        conv_kernel_size=cfg.conv_kernel_size,
+        kda_gate_lower_bound=cfg.kda_gate_lower_bound,
+        rms_norm_eps=cfg.rms_norm_eps, remat=cfg.remat,
+        remat_policy=getattr(cfg, "remat_policy", "full"),
+        dtype=dtype, param_dtype=param_dtype,
+    )

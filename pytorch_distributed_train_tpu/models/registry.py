@@ -42,6 +42,9 @@ def _populate():
 
     _REGISTRY["llama_pp"] = pipeline_lm.llama_pp
     _REGISTRY["t5"] = t5.t5
+    from pytorch_distributed_train_tpu.models import hybrid
+
+    _REGISTRY["hybrid_lm"] = hybrid.hybrid_lm
 
 
 def list_models() -> list[str]:
@@ -87,7 +90,7 @@ def build_model(model_cfg, precision_cfg, mesh=None, mesh_cfg=None):
         # takes the mesh axes only for an active context axis
         return _REGISTRY[name](model_cfg, dtype, param_dtype, mesh=mesh,
                                cp=cp if cp is not None and cp.active else None)
-    if name.startswith(("llama", "bert", "gpt")):
+    if name.startswith(("llama", "bert", "gpt", "hybrid")):
         from pytorch_distributed_train_tpu.parallel.mesh import (
             activation_sharding_for,
         )
@@ -98,4 +101,4 @@ def build_model(model_cfg, precision_cfg, mesh=None, mesh_cfg=None):
 
 
 def is_language_model(name: str) -> bool:
-    return name.startswith(("bert", "llama", "gpt", "t5"))
+    return name.startswith(("bert", "llama", "gpt", "t5", "hybrid"))

@@ -422,5 +422,7 @@ def _chunked_attention(q, k, v, *, causal, mask, softmax_dtype,
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v_t)
 
     out_tiles = jax.lax.map(jax.checkpoint(body), (q_tiles, starts))
-    out = out_tiles.transpose(1, 0, 2, 3, 4).reshape(B, n_chunks * chunk, H, D)
+    # V's own head dim (latent attention: 192-deep scores, 128-deep values)
+    out = out_tiles.transpose(1, 0, 2, 3, 4).reshape(
+        B, n_chunks * chunk, H, v.shape[-1])
     return out[:, :Sq]

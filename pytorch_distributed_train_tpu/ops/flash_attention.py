@@ -15,7 +15,7 @@ work, no cross-lane shuffles), dV = P^T dO and dK = dS^T Q are plain
 products, and only the small (D, block_q) results O^T and dQ^T are
 transposed, once, on the way out. lse and delta are (B·H, 1, S) in HBM: a
 row a head, not an (S, 1) column padded to 128 lanes. D must be
-64/128/256; S must divide by 128.
+64/128/192/256 (V's may differ from Q's and K's); S must divide by 128.
 
 Tiling is two-level (:func:`tile_sizes` is the one rule). The GRID stays
 coarse, (B·H, S/block_q, S/major): a grid step has fixed costs. A step
@@ -182,10 +182,17 @@ def call_plan(q, k, *, causal: bool, window: int = 0) -> TilePlan:
                      window=window)
 
 
-def _tileable(q, k) -> bool:
+# Head dims the kernels have compiled and run at on a v5e. 192 is latent
+# attention's Q and K (128 plain dims beside 64 rotated ones) beside V of
+# 128: native, not zero-padded to 256 — at (2, 8192, 32, 192/128) forward
+# and backward took 65.97 ms against 70.49 padded (my chip run, PR 26).
+HEAD_DIMS = (64, 128, 192, 256)
+
+
+def _tileable(q, k, v=None) -> bool:
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
-    if D not in (64, 128, 256):
+    if D not in HEAD_DIMS or (v is not None and v.shape[3] not in HEAD_DIMS):
         return False
     if Hkv != H and (Hkv == 0 or H % Hkv != 0):
         return False  # invalid GQA ratio — let the XLA path raise clearly
@@ -200,7 +207,7 @@ def supported(q, k, v, *, causal: bool, mask, window: int = 0) -> bool:
         return False
     if q.shape[1] != k.shape[1]:  # self-attention only (no KV-cache decode)
         return False
-    return _tileable(q, k)
+    return _tileable(q, k, v)
 
 
 def chunk_supported(q, k, v) -> bool:
@@ -476,7 +483,7 @@ def _fwd_kernel(*refs, block_k, grid, direct, causal, scale, window,
 def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
          window, interpret, out_dtype=None):
     BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
+    Sk, Dv = k3.shape[1], v3.shape[2]
     # GQA without HBM expansion (ROADMAP kernel follow-up): q3 is flattened
     # batch-major with heads in order, so q row b = (batch·Hkv + kvh)·rep + r
     # and its KV row is simply b // rep — an index_map, not a materialized
@@ -486,7 +493,7 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
     grid = (Sq // block_q, Sk // major)
     has_pos = q_pos is not None
     out_shape = [
-        jax.ShapeDtypeStruct(q3.shape, out_dtype or q3.dtype),  # O
+        jax.ShapeDtypeStruct((BH, Sq, Dv), out_dtype or q3.dtype),  # O
         jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),  # LSE, a row a head
     ]
     # one resident block and static positions: the step writes its output
@@ -499,7 +506,7 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, major, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, major, D), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major, Dv), lambda b, i, j: (b // rep, j, 0)),
     ]
     args = [q3, k3, v3]
     if has_pos:
@@ -514,11 +521,11 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         scratch_shapes=[] if direct else [
-            pltpu.VMEM((D, block_q), jnp.float32),
+            pltpu.VMEM((Dv, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
         ],
@@ -659,7 +666,7 @@ def _bwd_dkv_kernel(*refs, block_q, grid, rep, direct, causal, scale,
 def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
          scale, tiles, window, interpret, dlse=None):
     BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
+    Sk, Dv = k3.shape[1], v3.shape[2]
     rep = BH // k3.shape[0]  # GQA group size (see _fwd); 1 = MHA
     block_q, block_k, major_q, major_k = tiles
     has_pos = q_pos is not None
@@ -677,8 +684,8 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
     dq_in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, major_k, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, major_k, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, major_k, Dv), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
     ]
@@ -709,8 +716,8 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
     dkv_in_specs = [
         pl.BlockSpec((1, major_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
-        pl.BlockSpec((1, major_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
+        pl.BlockSpec((1, block_k, Dv), lambda b, j, r, i: (b, j, 0)),
+        pl.BlockSpec((1, major_q, Dv), lambda b, j, r, i: (b * rep + r, i, 0)),
         pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
         pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
     ]
@@ -728,7 +735,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
         in_specs=dkv_in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, r, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k3.shape, k3.dtype),
@@ -736,7 +743,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
         ],
         scratch_shapes=[] if dkv_direct
         else [pltpu.VMEM((block_k, D), jnp.float32),
-              pltpu.VMEM((block_k, D), jnp.float32)],
+              pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
@@ -784,9 +791,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     (forward reads H/Hkv x less K/V bandwidth than an expand-first
     design). ``window`` > 0 restricts each query to its trailing
     ``window`` keys (requires causal — enforced upstream). Tile sizes
-    default to :func:`tile_sizes`' rule; tests pass their own."""
-    if k.shape != v.shape:
-        raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
+    default to :func:`tile_sizes`' rule; tests pass their own.
+
+    V's head dim may differ from Q's and K's (latent attention: 192-deep
+    scores beside 128-deep values); the output has V's."""
+    if k.shape[:3] != v.shape[:3] or q.shape[3] != k.shape[3]:
+        raise ValueError(
+            f"q/k/v shapes do not fit: {q.shape}, {k.shape}, {v.shape}")
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     if Hkv != H and (Hkv == 0 or H % Hkv != 0):
@@ -797,11 +808,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     scale = float(1.0 / (D ** 0.5))
 
     def to3(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S, D)
+        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S, x.shape[3])
 
     o3 = _flash(to3(q), to3(k), to3(v), causal, scale, tiles, interpret,
                 int(window))
-    return o3.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    return o3.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 # ----------------------------------------------------- ring-chunk entry

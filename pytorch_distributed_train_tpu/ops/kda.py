@@ -1,0 +1,232 @@
+"""Gated delta rule with a per-channel decay (Kimi Delta Attention, Kimi
+Linear report arXiv:2510.26692): the recurrent mixer's core, chunked.
+
+A head keeps a state S in R^{d_k x d_v}, S_0 = 0, and for each token t with
+a query q_t and key k_t in R^{d_k}, a value v_t in R^{d_v}, a log-decay
+g_t in [lower_bound, 0]^{d_k} and a step size beta_t in [0, 1]:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+:func:`kda_recurrent` is that, token by token (the tests' ground truth).
+:func:`kda_chunked` computes the same in chunks of C tokens. With
+u_t = beta_t (v_t - (Diag(exp g_t) S_{t-1})^T k_t) the update reads
+S_t = Diag(exp g_t) S_{t-1} + k_t u_t^T, and inside a chunk that starts
+from S (G_t the inclusive sum of g over the chunk's tokens up to t):
+
+    A_tj = beta_t sum_c k_tc k_jc exp(G_tc - G_jc)      j <  t
+    B_tj =        sum_c q_tc k_jc exp(G_tc - G_jc)      j <= t
+    U = (I + A)^-1 (beta V  -  beta (K exp G) S)  =  U0 - W S
+    O = (Q exp G) S + B U
+    S' = Diag(exp G_C) S + (K exp(G_C - G))^T U
+
+A, B, U0 and W need no state, so they are computed for all chunks at once;
+only the three products with S run in sequence, one step a chunk.
+
+Numbers. The state, g and its sums are float32. exp(G_t - G_j) is never
+formed from exp(G_t) and exp(-G_j) over a whole chunk (32 x 5 overflows):
+rows are taken in blocks of 16 tokens, each against its own reference
+point R (the sum at the block's middle), so that queries carry
+exp(G_t - R) and keys exp(R - G_j), both within exp(+-8 |lower_bound|)
+inside the block and the keys' smaller before it: the bound on g is what
+makes the product form safe, and exp(+-40) times a small component stays
+a normal float32 (a reference at the block's start reached exp(-80), and
+the small components of q went denormal).
+(I + A)^-1 is the finite Neumann product (I - A)(I + A^2)(I + A^4)...,
+exact because A is strictly lower triangular. Its products and the one
+with the right-hand sides take float32 operands in three bfloat16 passes
+(``Precision.HIGH``, some 1e-5 of relative error, where W is rounded to
+bfloat16 next and every other product of the chunk has bfloat16 operands):
+at six passes (``HIGHEST``) these small batched products were two thirds
+of what the core costs the TPU's compiler (17.8 s an instance against 9.8,
+sandbox compile, PR 26; the step holds twenty instances).
+
+Memory. The sequence is walked in segments of ``segment_chunks`` chunks
+under ``jax.checkpoint``: the backward pass keeps one state a segment and
+recomputes a segment's chunk quantities and chunk states when it gets
+there, so no per-token state and no whole-sequence (C, C) table is ever
+kept.
+"""
+
+from __future__ import annotations
+
+import math
+import sys
+
+import jax
+import jax.numpy as jnp
+
+BLOCK = 16  # rows that share a reference point; a masked pair of one block
+# still multiplies exp(+8 |lower_bound|) twice, so BLOCK * |lower_bound| < 88
+
+# tokens a chunk: the hybrid cell's whole step on the v5e reads 1263 ms at
+# 32 against 1319 at 64 (PERF.md section 6, PR 26)
+DEFAULT_CHUNK = 32
+DEFAULT_SEGMENT_CHUNKS = 4
+SCOPE = "kda_chunk"  # jax.named_scope round the core
+_MASKED = -1e30  # exp() of it is 0, with a zero derivative
+_SOLVE = jax.lax.Precision.HIGH  # the inverse's float32 products
+
+_logged: set[tuple] = set()
+
+
+def log_plan(S: int, chunk: int, heads: int, d_k: int, d_v: int) -> None:
+    """Say once a shape, at trace time, how the sequence is cut (stderr,
+    like the attention dispatch's line): a run's log then shows the scan's
+    length and the state's size."""
+    key = (S, chunk, heads, d_k, d_v)
+    if key in _logged:
+        return
+    _logged.add(key)
+    print(f"[kda] S={S} chunk={chunk} chunks={S // chunk} heads={heads} "
+          f"d_k={d_k} d_v={d_v} state_dtype=float32", file=sys.stderr,
+          flush=True)
+
+
+def kda_recurrent(q, k, v, g, beta):
+    """Token by token. q, k, g: (B, S, H, d_k); v: (B, S, H, d_v); beta:
+    (B, S, H). Everything in float32. Returns o (B, S, H, d_v)."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    B, S, H, dk = q.shape
+
+    def step(state, xs):
+        q_t, k_t, v_t, g_t, b_t = xs  # (B, H, .)
+        state = state * jnp.exp(g_t)[..., None]
+        u = b_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
+        state = state + k_t[..., None] * u[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+    state0 = jnp.zeros((B, H, dk, v.shape[-1]), f32)
+    _, o = jax.lax.scan(step, state0, xs)
+    return jnp.moveaxis(o, 0, 1)
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for strictly lower triangular ``a`` (..., C, C), float32:
+    sum_k (-a)^k = (I - a)(I + a^2)(I + a^4)..., finite since a^C = 0."""
+    C = a.shape[-1]
+    eye = jnp.eye(C, dtype=a.dtype)
+    mm = lambda x, y: jnp.matmul(x, y, precision=_SOLVE)  # noqa: E731
+    out, power = eye - a, a
+    for _ in range(max(math.ceil(math.log2(C)) - 1, 0)):
+        power = mm(power, power)
+        out = mm(out, eye + power)
+    return out
+
+
+def _chunk_tables(q, k, v, g, beta, dtype):
+    """What a chunk needs that no state enters. Inputs (..., C, d) with the
+    chunk's tokens on the second-to-last axis; g float32. Returns
+    (qg, bmat, w, u0, kend, gend): Q exp G, B, W, U0, K exp(G_C - G) and
+    exp(G_C)."""
+    f32 = jnp.float32
+    C = q.shape[-2]
+    n = C // BLOCK
+    G = jnp.cumsum(g, axis=-2)  # inclusive, (..., C, dk)
+    # reference point of each block of rows: the sum at its middle
+    R = G[..., BLOCK // 2 - 1::BLOCK, :]  # (..., n, dk)
+    row_ref = jnp.repeat(R, BLOCK, axis=-2)  # (..., C, dk), of a row's block
+    row_decay = jnp.exp(G - row_ref)  # within exp(+-BLOCK/2 |lower_bound|)
+    # keys against each block's reference: (..., n, C, dk); keys after the
+    # block are out of its causal reach and masked before the exp
+    block_of = jnp.arange(C) // BLOCK
+    reach = block_of[None, :] <= jnp.arange(n)[:, None]  # (n, C)
+    expo = R[..., :, None, :] - G[..., None, :, :]
+    key_decay = jnp.exp(jnp.where(reach[..., None], expo, _MASKED))
+    keys = (k.astype(f32)[..., None, :, :] * key_decay).astype(dtype)
+
+    def table(rows):
+        rows = (rows.astype(f32) * row_decay).astype(dtype)
+        rows = rows.reshape(*rows.shape[:-2], n, BLOCK, rows.shape[-1])
+        t = jnp.einsum("...nik,...njk->...nij", rows, keys,
+                       preferred_element_type=f32)
+        return t.reshape(*t.shape[:-3], C, C)
+
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    bmat = jnp.where(tri, table(q), 0.0)
+    a = jnp.where(jnp.tril(tri, -1), table(k), 0.0) * beta[..., None]
+    inv = _unit_lower_inverse(a)
+    gamma = jnp.exp(G)
+    rhs = jnp.concatenate(
+        [k.astype(f32) * gamma, v.astype(f32)], axis=-1) * beta[..., None]
+    sol = jnp.matmul(inv, rhs, precision=_SOLVE)
+    w, u0 = sol[..., :k.shape[-1]], sol[..., k.shape[-1]:]
+    qg = q.astype(f32) * gamma
+    kend = k.astype(f32) * jnp.exp(G[..., -1:, :] - G)
+    return (qg.astype(dtype), bmat.astype(dtype), w.astype(dtype), u0,
+            kend.astype(dtype), jnp.exp(G[..., -1, :]))
+
+
+def _segment(state, xs, *, chunk, dtype):
+    """One segment of whole chunks from ``state`` (B, H, dk, dv) float32.
+    xs: q, k, v, g (B, L, H, d) and beta (B, L, H). Returns (state', o)."""
+    f32 = jnp.float32
+    q, k, v, g, beta = xs
+    B, L, H, _ = q.shape
+    n = L // chunk
+
+    def chunks(x):  # (B, L, H, ...) -> (n, B, H, chunk, ...)
+        x = x.reshape(B, n, chunk, H, *x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    tables = _chunk_tables(chunks(q), chunks(k), chunks(v),
+                           chunks(g).astype(f32),
+                           chunks(beta).astype(f32), dtype)
+
+    def step(s, t):
+        qg, bmat, w, u0, kend, gend = t
+        sd = s.astype(dtype)
+        dot = lambda eq, x, y: jnp.einsum(  # noqa: E731
+            eq, x, y, preferred_element_type=f32)
+        u = u0 - dot("bhck,bhkv->bhcv", w, sd)
+        o = dot("bhck,bhkv->bhcv", qg, sd) \
+            + dot("bhcj,bhjv->bhcv", bmat, u.astype(dtype))
+        s = s * gend[..., None] + dot("bhck,bhcv->bhkv", kend,
+                                      u.astype(dtype))
+        return s, o
+
+    state, o = jax.lax.scan(step, state, tables)
+    # (n, B, H, chunk, dv) -> (B, L, H, dv)
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, L, H, -1)
+    return state, o
+
+
+def kda_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
+                segment_chunks: int = DEFAULT_SEGMENT_CHUNKS,
+                lower_bound: float = -5.0):
+    """The chunked form (module docstring). q, k: (B, S, H, d_k), v:
+    (B, S, H, d_v), their dtype is the products' operand dtype; g:
+    (B, S, H, d_k) float32 log-decay in [lower_bound, 0]; beta: (B, S, H).
+    S must be whole chunks and ``chunk`` whole blocks of 16. Returns o in
+    q's dtype; the state and every sum of g stay float32."""
+    B, S, H, dk = q.shape
+    if BLOCK * abs(lower_bound) >= 88.0:
+        raise ValueError(
+            f"a log-decay as low as {lower_bound} overflows float32 over a "
+            f"block of {BLOCK} tokens")
+    if chunk % BLOCK or S % chunk:
+        raise ValueError(
+            f"kda: chunk {chunk} must be a multiple of {BLOCK} and divide "
+            f"the sequence length {S}")
+    n_chunks = S // chunk
+    seg = math.gcd(n_chunks, max(segment_chunks, 1)) * chunk
+    n_seg = S // seg
+    log_plan(S, chunk, H, dk, v.shape[-1])
+    g = g.astype(jnp.float32)
+
+    def segments(x):  # (B, S, ...) -> (n_seg, B, seg, ...)
+        return jnp.moveaxis(x.reshape(B, n_seg, seg, *x.shape[2:]), 1, 0)
+
+    body = jax.checkpoint(
+        lambda s, xs: _segment(s, xs, chunk=chunk, dtype=q.dtype))
+    state0 = jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32)
+    # The core's name in every device operation's op_name, forward and (as
+    # ``transpose(jvp(kda_chunk))``) backward: what a per-layer metric of
+    # the scan would select by (PERF.md section 7 (3)).
+    with jax.named_scope(SCOPE):
+        _, o = jax.lax.scan(body, state0,
+                            tuple(segments(x) for x in (q, k, v, g, beta)))
+        o = jnp.moveaxis(o, 0, 1).reshape(B, S, H, -1)
+    return o.astype(q.dtype)

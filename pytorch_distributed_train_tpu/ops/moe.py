@@ -19,11 +19,24 @@ design is the GShard/Switch recipe, shaped for the MXU and GSPMD:
 - **Aux losses** (load-balance + router z-loss) leave the layer through
   flax's ``sow`` into the 'losses' collection; the train step adds every
   sown scalar to the objective (steps.apply_model).
+
+Two layers live here (ROADMAP design debt "two dispatches in ops/moe.py"):
+:class:`MoeMLP` above, which holds every expert and drops overflow, and
+:class:`HeldExpertsMLP`, one chip's share of an expert-parallel layer: it
+routes over ALL the layer's experts (sigmoid scores, a selection-only bias,
+group-limited top-k, weights normalised over every chosen expert), is told
+which experts it holds, and computes their part of the result with one
+grouped product over the (token, choice) pairs that fall on them, by expert.
+No token is dropped silently: pairs past its static row bound are counted,
+and the model hands the count to the train step as ``update_invalid``, so
+such a step keeps its old state and reports ``update_skipped``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
+import sys
 
 import flax.linen as nn
 import jax
@@ -52,8 +65,6 @@ class MoeSpec:
 def expert_capacity(n_tokens: int, num_experts: int, top_k: int,
                     capacity_factor: float) -> int:
     """Static per-expert slot count; ≥1 so tiny probe batches still trace."""
-    import math
-
     return max(1, math.ceil(n_tokens * top_k / num_experts * capacity_factor))
 
 
@@ -193,3 +204,210 @@ class MoeMLP(nn.Module):
             "nec,ecd->nd", combine.astype(self.dtype), expert_out
         )
         return yf.reshape(B, S, D)
+
+
+# ------------------------------------------- one chip's share of the experts
+
+@dataclasses.dataclass(frozen=True)
+class HeldExpertsSpec:
+    """A routed layer as ONE chip of an expert-parallel deployment sees it:
+    the router's width and rule are the whole layer's, ``held`` experts
+    from id ``held_first`` on live here."""
+
+    num_experts: int          # the router's width: every expert of the layer
+    top_k: int
+    n_groups: int = 1         # experts are split into this many groups ...
+    topk_groups: int = 1      # ... of which a token may use this many
+    routed_scale: float = 1.0
+    held_first: int = 0
+    held: int = 0             # 0 -> all of them
+    # rows of the grouped product = this x the pairs expected on the held
+    # experts under uniform routing (tokens x top_k x held / num_experts)
+    capacity_factor: float = 4.0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+    def row_bound(self, n_tokens: int) -> int:
+        """Static rows of the grouped product: never more than the worst
+        case (every token on every held expert it can choose), whole
+        sublane tiles otherwise."""
+        worst = n_tokens * min(self.top_k, self.n_held)
+        want = math.ceil(self.capacity_factor * n_tokens * self.top_k
+                         * self.n_held / self.num_experts)
+        return min(worst, -(-want // 8) * 8)
+
+
+def group_limited_topk(scores, bias, spec: HeldExpertsSpec):
+    """DeepSeek-V3's rule (arXiv:2412.19437, 2.1.2) on float32 ``scores``
+    (N, E) in (0, 1): the selection score is scores + bias; a group's score
+    is the sum of its two largest; the ``topk_groups`` best groups stay; of
+    their experts the ``top_k`` largest are chosen. The weights use the
+    scores WITHOUT the bias, normalised over all chosen, times
+    ``routed_scale``. Returns (ids (N, k) int32, weights (N, k) float32);
+    the ids carry no gradient, so the bias gets none."""
+    N, E = scores.shape
+    select = scores + bias
+    if spec.n_groups > 1:
+        per = E // spec.n_groups
+        grouped = select.reshape(N, spec.n_groups, per)
+        group_score = jnp.sum(jax.lax.top_k(grouped, min(2, per))[0], -1)
+        _, keep = jax.lax.top_k(group_score, spec.topk_groups)
+        kept = jnp.any(keep[:, :, None] == jnp.arange(spec.n_groups), 1)
+        select = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(N, E)
+    _, ids = jax.lax.top_k(select, spec.top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=1)
+    weights = spec.routed_scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+    return ids.astype(jnp.int32), weights
+
+
+def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int):
+    """The (token, choice) pairs that fall on held experts, by expert and
+    inside an expert by token: (token (rows,) int32, weight (rows,) float32
+    of that pair, group_sizes (held,) int32 rows of each held expert inside
+    the bound, counts (held,) int32 pairs on each before the bound, over:
+    pairs past the bound). Rows past sum(group_sizes) belong to no expert
+    (token 0, weight 0).
+
+    A token chooses an expert at most once, so the pairs are the set
+    entries of an (expert, token) table, and the r-th row is the r-th set
+    entry: a running count and a binary search, no sort (a stable argsort
+    of the 131072 pairs of the hybrid cell took the TPU compiler 14 s a
+    layer: sandbox compile, PR 26)."""
+    N = ids.shape[0]
+    mine = spec.held_first + jnp.arange(spec.n_held)
+    hit = ids[:, :, None] == mine                     # (N, k, held)
+    on = jnp.any(hit, 1)                              # (N, held)
+    counts = jnp.sum(on, 0)
+    ends = jnp.minimum(jnp.cumsum(counts), rows)
+    sizes = jnp.diff(ends, prepend=0)
+    over = jnp.sum(counts) - ends[-1]
+    upto = jnp.cumsum(on.T.reshape(-1).astype(jnp.int32))
+    at = jnp.searchsorted(upto, jnp.arange(1, rows + 1, dtype=jnp.int32))
+    real = at < upto.shape[0]                         # the r-th entry exists
+    token = jnp.where(real, at % N, 0).astype(jnp.int32)
+    expert = jnp.where(real, at // N, 0)
+    held_weight = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), 1)
+    weight = jnp.where(real, held_weight[token, expert], 0.0)
+    return token, weight, sizes.astype(jnp.int32), counts.astype(jnp.int32), \
+        over
+
+
+_moe_logged: set[tuple] = set()
+
+
+def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int) -> None:
+    """Once a shape, at trace time, on stderr: what this chip holds."""
+    key = (spec, n_tokens)
+    if key in _moe_logged:
+        return
+    _moe_logged.add(key)
+    last = spec.held_first + spec.n_held - 1
+    print(f"[moe] experts={spec.num_experts} held={spec.n_held} "
+          f"ids={spec.held_first}-{last} top_k={spec.top_k} "
+          f"groups={spec.n_groups}/{spec.topk_groups} tokens={n_tokens} "
+          f"row_bound={rows}", file=sys.stderr, flush=True)
+
+
+class _Kernel(nn.Module):
+    """One ``kernel`` leaf under its own name, like a Dense's."""
+
+    shape: tuple
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.normal(0.02), self.shape,
+                          self.param_dtype)
+
+
+class _ExpertBank(nn.Module):
+    """The held experts' SwiGLU weights, stacked, applied to rows sorted by
+    expert: three grouped products (``jax.lax.ragged_dot``; on a TPU one
+    kernel that walks the groups, at the cost of one dense product over
+    the rows). Rows past sum(sizes) belong to no group, and the TPU's
+    kernel leaves whatever the buffer held there, forward AND backward (a
+    v5e returned gradients 36 000 times too large, PERF.md PR 26): every
+    such row is zeroed on the way in, between the products and on the way
+    out, so that neither a value nor a cotangent passes through one."""
+
+    held: int
+    mlp_dim: int
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, rows, sizes):
+        D, F = rows.shape[-1], self.mlp_dim
+        kernel = lambda name, shape: jnp.asarray(_Kernel(  # noqa: E731
+            (self.held, *shape), self.param_dtype, name=name)(), self.dtype)
+        in_group = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+        held = lambda a: jnp.where(in_group, a, 0)  # noqa: E731
+        gdot = lambda a, w: held(jax.lax.ragged_dot(  # noqa: E731
+            held(a), w, sizes, preferred_element_type=jnp.float32))
+        gate = gdot(rows, kernel("gate_proj", (D, F)))
+        up = gdot(rows, kernel("up_proj", (D, F)))
+        hidden = (nn.silu(gate) * up).astype(self.dtype)
+        return gdot(hidden, kernel("down_proj", (F, D)))
+
+
+class _Router(nn.Module):
+    """Sigmoid scores over every expert of the layer, float32 throughout,
+    and the bias that only selection sees (drawn at std 0.01; it gets no
+    gradient and no decay, so it stays as initialised: the balancing
+    update that would move it is not part of this program)."""
+
+    num_experts: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.normal(0.02),
+                            (x.shape[-1], self.num_experts), jnp.float32)
+        bias = self.param("bias", nn.initializers.normal(0.01),
+                          (self.num_experts,), jnp.float32)
+        logits = jnp.matmul(x.astype(jnp.float32), kernel,
+                            precision=jax.lax.Precision.HIGHEST)
+        return jax.nn.sigmoid(logits), bias
+
+
+class HeldExpertsMLP(nn.Module):
+    """(B, S, D) -> ((B, S, D), stats): the held experts' part of the
+    routed sum plus the shared expert. ``stats`` is float32 (3,): pairs on
+    the fullest held expert, on the mean one, and past the row bound.
+
+    Param tree: router/{kernel (D, E), bias (E,)}; experts/<proj>/kernel
+    with a leading (held,) dim; shared/<proj>/kernel.
+    """
+
+    spec: HeldExpertsSpec
+    mlp_module: type  # the dense SwiGLU class, for the shared expert
+    mlp_dim: int
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        B, S, D = x.shape
+        N, spec, F = B * S, self.spec, self.mlp_dim
+        rows = spec.row_bound(N)
+        _log_plan(spec, N, rows)
+        xf = x.reshape(N, D)
+        scores, bias = _Router(spec.num_experts, name="router")(xf)
+        ids, weights = group_limited_topk(scores, bias, spec)
+        token, weight, sizes, counts, over = held_rows(ids, weights, spec,
+                                                       rows)
+
+        out_rows = _ExpertBank(spec.n_held, F, self.dtype, self.param_dtype,
+                               name="experts")(
+            xf[token].astype(self.dtype), sizes)
+        # (rows past the held pairs belong to no expert: the bank zeroed them)
+        routed = jnp.zeros((N, D), jnp.float32).at[token].add(
+            out_rows * weight[:, None])
+        shared = self.mlp_module(self.mlp_dim, self.dtype, self.param_dtype,
+                                 name="shared")(x)
+        y = routed.reshape(B, S, D).astype(self.dtype) + shared
+        stats = jnp.stack([jnp.max(counts), jnp.mean(counts), over]
+                          ).astype(jnp.float32)
+        return y, stats

@@ -255,6 +255,30 @@ def llama_pp_rules() -> list[tuple[str, PartitionSpec]]:
     ]
 
 
+def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
+    """models/hybrid.py: the llama recipe over the new tree. Projections
+    keep heads on 'tensor' ((D, H, d) kernels, (H, d, D) for the outputs);
+    the held experts' stacked kernels put their leading dim on 'expert';
+    the latent down-projections, the router, the conv taps, A_log, dt_bias,
+    the head gates and every norm replicate (small, or per-head vectors)."""
+    return [
+        (r"tok_embed/embedding$", P("fsdp", None)),
+        (r"kda/(q_proj|k_proj|v_proj|a_proj)/kernel$",
+         P("fsdp", "tensor", None)),
+        (r"mla/(q_proj|kv_up)/kernel$", P("fsdp", "tensor", None)),
+        (r"(kda|mla)/o_proj/kernel$", P("tensor", None, "fsdp")),
+        (r"mla/(kv_down|k_rope_proj)/kernel$", P("fsdp", None)),
+        (r"experts/(gate_proj|up_proj)/kernel$",
+         P("expert", "fsdp", "tensor")),
+        (r"experts/down_proj/kernel$", P("expert", "tensor", "fsdp")),
+        (r"router/(kernel|bias)$", P()),
+        (r"(gate_proj|up_proj)/kernel$", P("fsdp", "tensor")),
+        (r"down_proj/kernel$", P("tensor", "fsdp")),
+        (r"lm_head/kernel$", P("fsdp", "tensor")),
+        (r".*", P()),
+    ]
+
+
 def gpt2_rules() -> list[tuple[str, PartitionSpec]]:
     """GPT-2: FSDP × TP. Tied head means the vocab-over-'fsdp' embedding is
     also the output projection; the logsumexp then reduces over 'fsdp'."""
@@ -328,6 +352,7 @@ _RULE_SETS: dict[str, Callable[[], list[tuple[str, PartitionSpec]]]] = {
     "gpt": gpt2_rules,
     "llama_pp": llama_pp_rules,  # must precede the "llama" prefix match
     "llama": llama_rules,
+    "hybrid": hybrid_rules,
     "t5": t5_rules,
     "dense": dense_rules,
 }

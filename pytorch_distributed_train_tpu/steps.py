@@ -74,16 +74,29 @@ def apply_model(model, params, batch_stats, batch, *, train: bool, dropout_rng):
     aux_loss is the sum of everything the model ``sow``ed into the 'losses'
     collection (MoE load-balance/z-loss, ops/moe.py) — 0.0 for dense models.
     """
+    return apply_model_metrics(model, params, batch_stats, batch,
+                               train=train, dropout_rng=dropout_rng)[:3]
+
+
+def apply_model_metrics(model, params, batch_stats, batch, *, train: bool,
+                        dropout_rng):
+    """:func:`apply_model` plus, fourth, the scalars a training model sowed
+    into its top-level 'step_metrics' collection ({name: value}; models/
+    hybrid.py: the expert layers' row counts) — they join the step's
+    metrics, and so the log and the registry. One name is the step's own:
+    ``update_invalid`` (> 0: the model computed this step on less than its
+    input, so the update must not be applied) is folded into the
+    skip-select and reported as ``update_skipped``."""
     variables: dict[str, Any] = {"params": params}
     # mutable must be False (not []) when there are no stats — flax returns a
     # (out, vars) tuple for ANY list, including an empty one.
     mutable: Any = False
     if train:
-        mutable = ["losses"]
+        mutable = ["losses", "step_metrics"]
     if batch_stats:
         variables["batch_stats"] = batch_stats
         if train:
-            mutable = ["batch_stats", "losses"]
+            mutable = ["batch_stats", "losses", "step_metrics"]
     rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
     kwargs = {}
     if "decoder_input_ids" in batch and "attention_mask" in batch:
@@ -105,8 +118,9 @@ def apply_model(model, params, batch_stats, batch, *, train: bool, dropout_rng):
                 updated.get("losses", {}))),
             start=jnp.float32(0.0),
         )
-        return logits, updated.get("batch_stats"), aux
-    return out, None, jnp.float32(0.0)
+        return (logits, updated.get("batch_stats"), aux,
+                dict(updated.get("step_metrics", {})))
+    return out, None, jnp.float32(0.0), {}
 
 
 def _tree_finite(tree) -> jnp.ndarray:
@@ -229,12 +243,12 @@ def make_train_step(model, loss_fn: Callable, tx,
             # op_name, the backward pass as ``transpose(jvp(forward))``,
             # so a trace can be split by phase (docs/observability.md).
             with jax.named_scope("forward"):
-                logits, new_stats, model_aux = apply_model(
-                    model, p, stats, batch, train=True,
-                    dropout_rng=dropout_rng,
-                )
+                logits, new_stats, model_aux, model_metrics = \
+                    apply_model_metrics(model, p, stats, batch, train=True,
+                                        dropout_rng=dropout_rng)
             with jax.named_scope("loss"):
                 loss, aux = loss_fn(logits, batch)
+            aux = {**aux, **model_metrics}
             total = loss + model_aux  # sown losses (MoE aux) join the objective
             scaled = total * scale if scale is not None else total
             return scaled, (loss, aux, model_aux, new_stats)
@@ -336,13 +350,28 @@ def make_train_step(model, loss_fn: Callable, tx,
                 # in sync, which the replicated-DP contract requires.
                 new_stats = reduce_metrics(new_stats)
 
+        # A model's veto (apply_model_metrics): None when it sows none.
+        model_ok = None
+        if "update_invalid" in aux:
+            aux = dict(aux)
+            model_ok = aux.pop("update_invalid") <= 0
+
         if fused_update is not None:
             return _fused_epilogue_step(
                 state, grads, loss, aux, model_aux, new_stats,
                 fused_update=fused_update, numeric_guard=numeric_guard,
                 module_grad_norms=module_grad_norms,
-                model_health=model_health)
+                model_health=model_health, model_ok=model_ok)
 
+        def select(ok, stepped):
+            # both branches are computed in-graph and the select is
+            # elementwise — no host round-trip, no recompile; the step
+            # counter advances either way
+            skipped = state.replace(step=state.step + 1)
+            return jax.tree.map(lambda new, old: jnp.where(ok, new, old),
+                                stepped, skipped)
+
+        gated = numeric_guard or model_ok is not None
         if state.dynamic_scale is not None:
             # GradScaler semantics (torch:amp/grad_scaler.py:302,375,484):
             # unscale, check finite, skip update on overflow, adjust scale.
@@ -354,11 +383,10 @@ def make_train_step(model, loss_fn: Callable, tx,
                 # but real: an inf loss whose grad zeroed out) must not
                 # feed the EMA/plateau machinery a poisoned loss either.
                 finite &= jnp.isfinite(loss)
-            stepped = apply_update(state, grads, new_stats, loss)
-            skipped = state.replace(step=state.step + 1)  # step advances either way
-            new_state = jax.tree.map(
-                lambda new, old: jnp.where(finite, new, old), stepped, skipped
-            )
+            if model_ok is not None:
+                finite &= model_ok
+            new_state = select(finite,
+                               apply_update(state, grads, new_stats, loss))
             # The scaler adjusts on GRAD overflow only (GradScaler
             # semantics): a non-finite loss with finite grads skips the
             # update above but must not shrink the loss scale.
@@ -366,23 +394,21 @@ def make_train_step(model, loss_fn: Callable, tx,
                 dynamic_scale=state.dynamic_scale.update(grads_ok)
             )
             metrics_extra = {"loss_scale": scale, "grads_finite": grads_ok}
-            if numeric_guard:
+            if gated:
                 metrics_extra["update_skipped"] = 1.0 - finite.astype(
                     jnp.float32)
-        elif numeric_guard:
+        elif gated:
             # Unscaled training gets the same skip-step gate (sentinel/
-            # numeric guard): both branches are computed in-graph and the
-            # select is elementwise — no host round-trip, no recompile.
-            finite = _tree_finite(grads) & jnp.isfinite(loss)
-            stepped = apply_update(state, grads, new_stats, loss)
-            skipped = state.replace(step=state.step + 1)
-            new_state = jax.tree.map(
-                lambda new, old: jnp.where(finite, new, old), stepped, skipped
-            )
-            metrics_extra = {
-                "grads_finite": finite,
-                "update_skipped": 1.0 - finite.astype(jnp.float32),
-            }
+            # numeric guard; a model's ``update_invalid``).
+            metrics_extra = {}
+            ok = model_ok
+            if numeric_guard:
+                finite = _tree_finite(grads) & jnp.isfinite(loss)
+                metrics_extra["grads_finite"] = finite
+                ok = finite if ok is None else finite & ok
+            new_state = select(ok,
+                               apply_update(state, grads, new_stats, loss))
+            metrics_extra["update_skipped"] = 1.0 - ok.astype(jnp.float32)
         else:
             new_state = apply_update(state, grads, new_stats, loss)
             metrics_extra = {}
@@ -412,7 +438,7 @@ def make_train_step(model, loss_fn: Callable, tx,
 def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,
                          new_stats, *, fused_update, numeric_guard: bool,
                          module_grad_norms: bool,
-                         model_health: bool = False):
+                         model_health: bool = False, model_ok=None):
     """Shared tail of train_step on the fused path: loss-scale unscale +
     finite gate + clip + optimizer update in ONE pass over the grad tree
     (ops/fused_update.py), instead of the chain's three passes plus the
@@ -441,6 +467,9 @@ def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,
             "grads_finite": finite,
             "update_skipped": 1.0 - finite.astype(jnp.float32),
         }
+    if model_ok is not None:  # a model's veto joins the gate
+        finite = model_ok if finite is None else finite & model_ok
+        metrics_extra["update_skipped"] = 1.0 - finite.astype(jnp.float32)
 
     with jax.named_scope("optimizer"):
         new_params, new_opt_state, gnorm = fused_update(

@@ -372,8 +372,11 @@ class Trainer:
             self.state_sharding = steps_lib.offload_state_shardings(
                 self.state_sharding)
         with self.mesh:
+            # runs once: the compiler's least effort (the hybrid preset's
+            # init compiled in 12 s against 20: sandbox compile, PR 26)
             self.state: TrainState = jax.jit(
-                self._init_state, out_shardings=self.state_sharding
+                self._init_state, out_shardings=self.state_sharding,
+                compiler_options={"exec_time_optimization_effort": -1.0},
             )(init_rng)
 
         # ---- jitted steps

@@ -194,6 +194,39 @@ def gpt2_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     return cfg.num_layers * per_layer + 2.0 * d * cfg.vocab_size
 
 
+def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
+    """models/hybrid.py, what THIS chip computes a token: the matrices a
+    token meets (of the routed experts only its expected share of the held
+    ones: top_k x held / num_experts), the latent layers' scores and values
+    (un-masked convention, as the other rows here), and the delta rule's
+    chunk products (in-chunk tables and the three products with the state).
+    The Neumann inverse's small products are left out (under 1 %)."""
+    from pytorch_distributed_train_tpu.ops.kda import DEFAULT_CHUNK
+
+    s = seq or cfg.max_seq_len
+    d, h = cfg.hidden_size, cfg.num_heads
+    dh = cfg.head_dim or d // h
+    dr, r, c = cfg.rope_head_dim, cfg.kv_lora_rank, min(DEFAULT_CHUNK, s)
+    n_latent = cfg.num_layers // cfg.layer_group_size \
+        if cfg.layer_group_size else 0
+    n_kda = cfg.num_layers - n_latent
+    n_dense = min(cfg.first_dense_layers, cfg.num_layers)
+    n_moe = cfg.num_layers - n_dense if cfg.num_experts > 1 else 0
+    kda = (2.0 * d * h * dh * 5        # q, k, v, decay, output projections
+           + 2.0 * d * h * 2           # beta and the head gate
+           + 2.0 * h * c * dh * 3      # tables A, B and B U, a token
+           + 2.0 * h * dh * dh * 3)    # W S, (Q exp G) S, the state's update
+    mla = (2.0 * d * h * (dh + dr) + 2.0 * d * (r + dr)
+           + 2.0 * r * h * 2 * dh + 2.0 * h * dh * d + 2.0 * d * h
+           + 2.0 * s * h * (dh + dr) + 2.0 * s * h * dh)
+    expert = 6.0 * d * cfg.moe_mlp_dim
+    held = cfg.experts_held or cfg.num_experts
+    moe = (2.0 * d * cfg.num_experts + expert
+           + expert * cfg.expert_top_k * held / max(cfg.num_experts, 1))
+    return (n_kda * kda + n_latent * mla + n_dense * 6.0 * d * cfg.mlp_dim
+            + n_moe * moe + 2.0 * d * cfg.vocab_size)
+
+
 def bert_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     """models/bert.py: post-LN MHA blocks + MLM head (dense D->D, GELU,
     LN, tied-embedding decode) computed at every position."""
@@ -240,6 +273,7 @@ _FWD = {
     "llama": (llama_fwd_flops_per_token, "token"),
     "llama_pp": (llama_fwd_flops_per_token, "token"),
     "gpt2": (gpt2_fwd_flops_per_token, "token"),
+    "hybrid_lm": (hybrid_fwd_flops_per_token, "token"),
     "bert_base": (bert_fwd_flops_per_token, "token"),
     "t5": (lambda cfg, seq: t5_fwd_flops_per_token(cfg, seq), "token"),
 }

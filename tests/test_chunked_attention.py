@@ -414,3 +414,22 @@ def test_flash_kernel_runs_as_manual_region_under_a_sharded_mesh(devices8):
     for a, b in zip(g_f, g_x):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("Sq", [512, 600], ids=["divisible", "padded"])
+def test_values_of_another_head_dim_than_scores(Sq):
+    """Latent attention's shapes: scores 48 deep, values 32. The tiles'
+    outputs are put back together at V's head dim, not Q's (a reshape to
+    Q's raised at (2, 8192, 32, 192/128) on the chip, PR 26)."""
+    q, k, _ = _qkv(Sq=Sq, Sk=Sq, D=48, seed=5)
+    v = _qkv(Sq=Sq, Sk=Sq, D=32, seed=6)[2]
+    run = lambda f, **kw: jax.value_and_grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(f(q, k, v, causal=True, mask=None,
+                                  softmax_dtype=jnp.float32, **kw) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    (a, ga), (b, gb) = run(_chunked_attention, chunk=128), run(_xla_attention)
+    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    for x, y in zip(ga, gb):
+        assert x.shape == y.shape
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5,
+                                   rtol=2e-5)

@@ -489,3 +489,41 @@ def test_default_impl_override(monkeypatch):
             attn.set_default_impl("nope")
     finally:
         attn._default_impl = orig
+
+
+@pytest.mark.parametrize("d_qk,d_v", [(192, 128), (128, 64), (256, 128)],
+                         ids=["mla_192_128", "128_64", "256_128"])
+def test_values_of_another_head_dim_than_scores_match_xla(d_qk, d_v):
+    """Latent attention: Q and K carry plain and rotated dims (192), V only
+    plain ones (128). V and O keep their own head dim through all three
+    kernels. Several KV blocks a step (tiles of 128 in a major block of
+    256), so the running state is exercised."""
+    rng = np.random.default_rng(11)
+    mk = lambda d: jnp.asarray(  # noqa: E731
+        rng.standard_normal((1, 512, 2, d)) * 0.5, jnp.float32)
+    q, k, v, w = mk(d_qk), mk(d_qk), mk(d_v), mk(d_v)
+    kernel = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, interpret=True, block_q=128, block_k=128,
+        block_k_major=256)
+    assert supported(q, k, v, causal=True, mask=None)
+    out = kernel(q, k, v)
+    assert out.shape == v.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_xla(q, k, v, True)),
+                               atol=2e-5, rtol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_xla(*a, True) * w),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=5e-5, err_msg=name)
+
+
+def test_head_dims_the_kernels_cannot_take_stay_unsupported():
+    mk = lambda d: jnp.zeros((1, 256, 2, d), jnp.float32)  # noqa: E731
+    assert not supported(mk(32), mk(32), mk(32), causal=True, mask=None)
+    assert not supported(mk(96), mk(96), mk(96), causal=True, mask=None)
+    assert not supported(mk(192), mk(192), mk(96), causal=True, mask=None)
+    assert supported(mk(192), mk(192), mk(128), causal=True, mask=None)
+    with pytest.raises(ValueError, match="do not fit"):
+        flash_attention(mk(192), mk(128), mk(128), interpret=True)

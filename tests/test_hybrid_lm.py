@@ -1,0 +1,364 @@
+"""models/hybrid.py and ops/moe.py's held-experts layer against the plain
+reference the benchmark keeps (benchmark/references/ling3_flash_lm_ep64.py,
+which imports nothing of the program): the router, each mixer and the whole
+model on seeded weights at tiny sizes; no token dropped; the shares of an
+expert-parallel layer add up to the uncut layer."""
+
+import importlib.util
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_distributed_train_tpu.config import get_preset
+from pytorch_distributed_train_tpu.models import hybrid
+from pytorch_distributed_train_tpu.models.llama import LlamaMLP
+from pytorch_distributed_train_tpu.models.registry import build_model
+from pytorch_distributed_train_tpu.ops import moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+F32 = jnp.float32
+
+
+@pytest.fixture(autouse=True)
+def _exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """(configuration file, its Reference at the rehearsal's sizes, the
+    program's config at the same sizes)."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    with open(os.path.join(BENCH, "configs", "ling3_flash_lm_ep64.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "ling3_reference", os.path.join(BENCH, "references",
+                                        config["reference"] + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    cfg = get_preset(config["preset"])
+    cfg.apply_overrides(config["rehearsal_overrides"])
+    return config, mod.Reference(config, rehearsal=True), cfg
+
+
+def _close(a, b, tol=2e-5):
+    scale = float(jnp.max(jnp.abs(b))) + 1e-30
+    assert float(jnp.max(jnp.abs(a - b))) < tol * scale
+
+
+def _spec(**kw):
+    base = dict(num_experts=32, top_k=4, n_groups=4, topk_groups=2,
+                routed_scale=2.5, held_first=0, held=4, capacity_factor=4.0)
+    return moe.HeldExpertsSpec(**{**base, **kw})
+
+
+# ------------------------------------------------------------- the router
+
+def test_router_groups_bias_for_selection_only_weights_over_all_chosen():
+    spec = _spec()
+    key = jax.random.PRNGKey(0)
+    scores = jax.nn.sigmoid(jax.random.normal(key, (64, 32)))
+    bias = jnp.zeros((32,)).at[5].set(10.0)  # expert 5 is always chosen
+    ids, w = moe.group_limited_topk(scores, bias, spec)
+    assert ids.shape == w.shape == (64, 4)
+    _close(jnp.sum(w, -1), jnp.full((64,), 2.5))       # over ALL chosen
+    assert bool(jnp.all(jnp.any(ids == 5, -1)))
+    chosen = jnp.take_along_axis(scores, ids, 1)        # scores, no bias
+    _close(w, 2.5 * chosen / jnp.sum(chosen, -1, keepdims=True))
+    # at most topk_groups of the groups (8 experts each) are used a token
+    groups_used = jax.vmap(lambda r: jnp.sum(jnp.bincount(
+        r // 8, length=4) > 0))(ids)
+    assert int(jnp.max(groups_used)) <= 2
+    # a group's score is the sum of its two largest selection scores
+    sel = (scores + bias).reshape(64, 4, 8)
+    want = jnp.argsort(-jnp.sum(jnp.sort(sel, -1)[..., -2:], -1), -1)[:, :2]
+    used = jnp.sort(jax.vmap(lambda r: jnp.unique(
+        r // 8, size=2, fill_value=99))(ids), -1)
+    full = groups_used == 2  # a token may fill its 4 from one group only
+    assert bool(jnp.all(jnp.where(full[:, None],
+                                  used == jnp.sort(want, -1), True)))
+
+
+def test_router_matches_the_reference(bench):
+    _, ref, cfg = bench
+    m = cfg.model
+    spec = _spec(held=m.experts_held)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
+    x = jax.random.normal(k1, (96, m.hidden_size))
+    p = {"kernel": 0.3 * jax.random.normal(k2, (m.hidden_size, 32)),
+         "bias": 0.05 * jax.random.normal(k3, (32,))}
+    ids, w = moe.group_limited_topk(jax.nn.sigmoid(x @ p["kernel"]),
+                                    p["bias"], spec)
+    dense = jnp.zeros((96, 32)).at[jnp.arange(96)[:, None], ids].set(w)
+    _close(dense[:, :m.experts_held], ref._route(p, x))
+    with_bias = np.asarray(ids)
+    without, _ = moe.group_limited_topk(jax.nn.sigmoid(x @ p["kernel"]),
+                                        jnp.zeros((32,)), spec)
+    assert (np.sort(with_bias, -1) != np.sort(np.asarray(without), -1)).any()
+
+
+# ------------------------------------------------------ the expert layer
+
+def _layer(spec, width=24):
+    return moe.HeldExpertsMLP(spec, LlamaMLP, width, F32, F32)
+
+
+def _swiglu(x, gate, up, down):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def test_every_token_on_one_held_expert_comes_back_exact_none_dropped():
+    spec = _spec()
+    layer = _layer(spec)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 40, 16))
+    params = layer.init(jax.random.PRNGKey(3), x)["params"]
+    # equal scores (0.5) everywhere; the bias picks held expert 2 and seven
+    # absent ones in two groups for every token
+    params["router"]["kernel"] = jnp.zeros_like(params["router"]["kernel"])
+    params["router"]["bias"] = jnp.zeros((32,)).at[
+        jnp.array([2, 9, 10, 11])].set(1.0)
+    y, stats = layer.apply({"params": params}, x)
+    e = {k: v["kernel"][2] for k, v in params["experts"].items()}
+    s = {k: v["kernel"] for k, v in params["shared"].items()}
+    want = _swiglu(x, s["gate_proj"], s["up_proj"], s["down_proj"]) \
+        + 2.5 / 4 * _swiglu(x, e["gate_proj"], e["up_proj"], e["down_proj"])
+    _close(y, want)
+    np.testing.assert_allclose(np.asarray(stats), [80.0, 20.0, 0.0])
+
+
+def test_pairs_past_the_row_bound_are_counted_never_silent():
+    spec = _spec(capacity_factor=0.5)   # 80 tokens: bound 24 < 80 pairs
+    assert spec.row_bound(80) == 24
+    ids = jnp.tile(jnp.array([[2, 9, 10, 11]]), (80, 1))
+    token, weight, sizes, counts, over = moe.held_rows(
+        ids, jnp.full((80, 4), 0.25), spec, 24)
+    assert int(over) == 80 - 24 and int(jnp.sum(sizes)) == 24
+    # by expert, inside an expert by token: the first 24 tokens of expert 2
+    np.testing.assert_array_equal(np.asarray(token), np.arange(24))
+    np.testing.assert_array_equal(np.asarray(weight), np.full(24, 0.25))
+    np.testing.assert_array_equal(np.asarray(counts), [0, 0, 80, 0])
+    # the worst case is never exceeded by the bound itself
+    assert _spec(capacity_factor=1e9).row_bound(80) == 80 * 4
+
+
+def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
+    from pytorch_distributed_train_tpu import losses, steps
+    from pytorch_distributed_train_tpu.optim import make_optimizer
+    from pytorch_distributed_train_tpu.train_state import TrainState
+
+    cfg = get_preset("ling3_flash_lm_ep64")
+    with open(os.path.join(BENCH, "configs",
+                           "ling3_flash_lm_ep64.json")) as f:
+        cfg.apply_overrides(json.load(f)["rehearsal_overrides"])
+    ids = jax.random.randint(jax.random.PRNGKey(4), (2, 64), 0, 256)
+    # (a step inside its bound reporting 0 is the benchmark rehearsal's
+    # `failed` 0: tests/benchmark/test_bench_rehearsal_ling3.py)
+    cfg.model.expert_capacity_factor = 0.05
+    model = build_model(cfg.model, cfg.precision)
+    tx, _ = make_optimizer(cfg.optim, 10, 0)
+    params = model.init({"params": jax.random.PRNGKey(5)}, ids,
+                        train=False)["params"]
+    state = TrainState.create(params=params, tx=tx, batch_stats={},
+                              dynamic_scale=None, ema=False, swa=False)
+    step = steps.make_train_step(model, losses.get_loss_fn(cfg.loss), tx)
+    new, metrics = jax.jit(step)(state, {"input_ids": ids},
+                                 jax.random.PRNGKey(6))
+    assert float(metrics["update_skipped"]) == 1.0
+    assert float(metrics["moe_rows_over_bound"]) > 0
+    assert "update_invalid" not in metrics  # the step's own name, folded
+    # the flag tells the truth: the old parameters and moments are kept,
+    # the step counter advances (as under the numeric guard)
+    assert int(new.step) == int(state.step) + 1
+    for kept, old in ((new.params, state.params),
+                      (new.opt_state, state.opt_state)):
+        for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(old)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # inside the bound the same step applies its update and reports 0
+    cfg.model.expert_capacity_factor = 4.0
+    step = steps.make_train_step(build_model(cfg.model, cfg.precision),
+                                 losses.get_loss_fn(cfg.loss), tx)
+    new, metrics = jax.jit(step)(state, {"input_ids": ids},
+                                 jax.random.PRNGKey(6))
+    assert float(metrics["update_skipped"]) == 0.0
+    # (the warm-up's first rate is 0: the moments move, the parameters not)
+    assert any(np.any(np.asarray(a) != np.asarray(b)) for a, b in zip(
+        jax.tree.leaves(new.opt_state), jax.tree.leaves(state.opt_state)))
+    assert float(metrics["moe_rows_fullest"]) \
+        >= float(metrics["moe_rows_mean"]) > 0
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 experts over 4 chips, 4 a chip: the routed parts that the four
+    shares compute, plus the shared expert ONCE, are the whole layer as a
+    plain loop over all 16 experts computes it."""
+    E, held, D, F, N = 16, 4, 16, 24, 48
+    ks = jax.random.split(jax.random.PRNGKey(7), 9)
+    x = jax.random.normal(ks[0], (1, N, D))
+    router = {"kernel": 0.5 * jax.random.normal(ks[1], (D, E)),
+              "bias": 0.05 * jax.random.normal(ks[2], (E,))}
+    full = {n: 0.3 * jax.random.normal(k, shape) for n, k, shape in (
+        ("gate_proj", ks[3], (E, D, F)), ("up_proj", ks[4], (E, D, F)),
+        ("down_proj", ks[5], (E, F, D)))}
+    shared = {n: {"kernel": 0.3 * jax.random.normal(k, shape)}
+              for n, k, shape in (("gate_proj", ks[6], (D, F)),
+                                  ("up_proj", ks[7], (D, F)),
+                                  ("down_proj", ks[8], (F, D)))}
+    kw = dict(num_experts=E, top_k=4, n_groups=4, topk_groups=2,
+              routed_scale=2.5, held=held)
+    # the uncut layer, by hand
+    s = jax.nn.sigmoid(x[0] @ router["kernel"])
+    ids, w = moe.group_limited_topk(s, router["bias"], _spec(**kw))
+    dense_w = jnp.zeros((N, E)).at[jnp.arange(N)[:, None], ids].set(w)
+    shared_out = _swiglu(x[0], *(shared[n]["kernel"] for n in (
+        "gate_proj", "up_proj", "down_proj")))
+    whole = shared_out + sum(
+        dense_w[:, e:e + 1] * _swiglu(x[0], full["gate_proj"][e],
+                                      full["up_proj"][e],
+                                      full["down_proj"][e])
+        for e in range(E))
+    routed_parts = 0.0
+    for first in range(0, E, held):
+        layer = _layer(_spec(**kw, held_first=first), F)
+        params = {"router": router, "shared": shared, "experts": {
+            n: {"kernel": v[first:first + held]} for n, v in full.items()}}
+        y, stats = layer.apply({"params": params}, x)
+        assert float(stats[2]) == 0.0
+        routed_parts = routed_parts + (y[0] - shared_out)
+    _close(routed_parts + shared_out, whole)
+    # and one share alone is NOT the layer: the cut leaves something out
+    assert float(jnp.max(jnp.abs(y[0] - whole))) > 1e-3
+
+
+# ------------------------------------------------ mixers and whole model
+
+def _mixer_params(ref, kind, seed):
+    params = ref.init_variables(seed)["params"]
+    name = next(n for n, p in params.items() if kind in p)
+    return params[name][kind]
+
+
+def test_mla_layer_matches_the_reference(bench):
+    _, ref, cfg = bench
+    m = cfg.model
+    p = _mixer_params(ref, "mla", 11)
+    p["q_norm"]["scale"] = 1.0 + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(1), p["q_norm"]["scale"].shape)
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 64, m.hidden_size))
+    mixer = hybrid.MLAMixer(m.num_heads, m.head_dim, m.rope_head_dim,
+                            m.kv_lora_rank, m.rope_theta, m.max_seq_len,
+                            m.rms_norm_eps, F32, F32, attn_impl="xla")
+    got = mixer.apply({"params": p}, x)
+    want = jnp.stack([ref._mla(p, x[b], lambda t: t) for b in range(2)])
+    _close(got, want)
+
+
+def test_kda_layer_matches_the_reference_token_by_token(bench):
+    _, ref, cfg = bench
+    m = cfg.model
+    p = _mixer_params(ref, "kda", 13)
+    x = jax.random.normal(jax.random.PRNGKey(14), (2, 128, m.hidden_size))
+    mixer = hybrid.KDAMixer(m.num_heads, m.head_dim, m.conv_kernel_size,
+                            m.kda_gate_lower_bound, m.rms_norm_eps, F32,
+                            F32)
+    got = mixer.apply({"params": p}, x)
+    want = jnp.stack([ref._kda(p, x[b], lambda t: t) for b in range(2)])
+    _close(got, want)
+
+
+def test_model_logits_match_the_reference_on_its_seeded_weights(bench):
+    _, ref, cfg = bench
+    model = build_model(cfg.model, cfg.precision)
+    params = ref.init_variables(17)["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(18), (2, 128), 0,
+                             cfg.model.vocab_size)
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
+    sig = lambda t: [(jax.tree_util.keystr(k), v.shape, str(v.dtype))  # noqa: E731
+                     for k, v in jax.tree_util.tree_flatten_with_path(t)[0]]
+    assert sig(shapes) == sig(params)  # names and shapes are the interface
+    got = model.apply({"params": params}, ids, train=False)
+    want = jnp.stack([ref._logits(params, ids[b], lambda t: t)[0]
+                      for b in range(2)])
+    _close(got, want)
+    # the layer pattern: KDA, KDA, then latent attention; dense FFN first
+    assert [("mla" in params[f"layer{i}"], "moe" in params[f"layer{i}"])
+            for i in range(3)] == [(False, False), (False, True),
+                                   (True, True)]
+
+
+def test_the_references_layer_by_layer_sweep_is_the_whole_models_gradient(
+        bench):
+    """``follow`` takes the backward pass a layer at a time from the host,
+    with programs shared by the layers of one kind; the same model in one
+    piece under ``jax.grad`` gives the same loss and the same gradient,
+    leaf by leaf (the router's bias gets none in either)."""
+    _, ref, cfg = bench
+    params = ref.init_variables(23)["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(24), (2, 64), 0,
+                             cfg.model.vocab_size)
+
+    def loss(p):
+        total = 0.0
+        for row in ids:
+            logp = jax.nn.log_softmax(
+                ref._logits(p, row, lambda t: t)[0][:-1], -1)
+            total -= jnp.sum(jnp.take_along_axis(logp, row[1:, None], -1))
+        return total
+
+    want_loss, want = jax.value_and_grad(loss)(params)
+    got_loss, got, chosen = ref._sweep("float32", params, ids, True)
+    assert abs(float(got_loss) - float(want_loss)) < 1e-4 * float(want_loss)
+    assert chosen.shape == (2, 2, 64, 4)  # routed layers, rows, S, held
+    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
+                      jax.tree_util.tree_flatten_with_path(t)[0]}
+    got, want = flat(got), flat(want)
+    assert set(got) == set(want)
+    for leaf, w in want.items():
+        if leaf.endswith("['router']['bias']"):
+            assert not np.any(np.asarray(got[leaf])) and not np.any(
+                np.asarray(w))
+        else:
+            _close(got[leaf], w, tol=1e-4)
+
+
+def test_preset_counts_decay_mask_flops_and_partition_rules():
+    from pytorch_distributed_train_tpu.optim import decay_mask_fn
+    from pytorch_distributed_train_tpu.parallel.partition import (
+        P,
+        rules_for_model,
+    )
+    from pytorch_distributed_train_tpu.utils import flops
+
+    cfg = get_preset("ling3_flash_lm_ep64")
+    model = build_model(cfg.model, cfg.precision)
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)},
+        jnp.zeros((1, 64), jnp.int32), train=False)["params"])
+    count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
+    assert count == 714_990_240  # ISSUE 26's table: 714.99 M, 11.44 GB
+    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
+    flat = {jax.tree_util.keystr(k): v for k, v in
+            jax.tree_util.tree_flatten_with_path(mask)[0]}
+    for leaf, decayed in flat.items():
+        plain = leaf.endswith("['kernel']") or leaf.endswith("['embedding']")
+        assert decayed == plain, leaf
+    assert not flat["['layer1']['moe']['router']['bias']"]
+    assert not flat["['layer0']['kda']['q_conv']"]
+    assert not flat["['layer0']['kda']['A_log']"]
+    assert not flat["['layer0']['kda']['dt_bias']"]
+    per_token = flops.train_flops_per_item(cfg.model, cfg.data.seq_len)
+    assert 3.0e9 < per_token < 3.3e9  # 3 x forward, attention un-masked
+    specs = rules_for_model("hybrid_lm").tree_specs(shapes)
+    assert specs["layer1"]["moe"]["experts"]["gate_proj"]["kernel"] \
+        == P("expert", "fsdp", "tensor")
+    assert specs["layer5"]["mla"]["kv_up"]["kernel"] \
+        == P("fsdp", "tensor", None)
+    assert specs["layer0"]["kda"]["dt_bias"] == P()

@@ -21,8 +21,9 @@ def _init_and_apply(model, *inputs, train=False):
 
 
 def test_registry_lists_all_families():
-    assert list_models() == ["bert_base", "gpt2", "llama", "llama_pp", "resnet18",
-                             "resnet50", "t5", "vit_b16"]
+    assert list_models() == ["bert_base", "gpt2", "hybrid_lm", "llama",
+                             "llama_pp", "resnet18", "resnet50", "t5",
+                             "vit_b16"]
 
 
 def test_resnet18_cifar_shapes():

@@ -100,6 +100,43 @@ def test_flash_attention_compiles(one_chip, name, B, S, H, Hkv, D, window,
 
 
 @pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
+def test_flash_attention_compiles_at_latent_attentions_head_dims(one_chip,
+                                                                 direction):
+    """The hybrid cell's one softmax layer (benchmark cell
+    ling3f-1chip-ep64-s8k): 192-deep scores (128 plain + 64 rotated dims,
+    blocks whose last dim is one and a half lane widths) beside 128-deep
+    values, at (2, 8192, 32): V and O block specs carry V's own head
+    dim."""
+    sds = lambda d: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 8192, 32, d), jnp.bfloat16, sharding=one_chip)
+    q, k, v = sds(192), sds(192), sds(128)
+    assert fa.supported(q, k, v, causal=True, mask=None)
+    assert fa.call_plan(q, k, causal=True) == fa.TilePlan(256, 136, 16)
+    loss = functools.partial(_attn_loss, causal=True)
+    fn = loss if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    out = jax.eval_shape(functools.partial(fa.flash_attention, causal=True),
+                         q, k, v)
+    assert out.shape == v.shape
+    _compile(fn, q, k, v)
+
+
+def test_kda_core_compiles_at_the_hybrid_cells_shape(one_chip):
+    """ops/kda.py forward and backward at (2, 8192, 32, 128), the default chunk:
+    plain XLA (no Mosaic kernel), inside the memory the cell leaves it."""
+    from pytorch_distributed_train_tpu.ops import kda
+
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    x = sds(2, 8192, 32, 128)
+    g, beta = sds(2, 8192, 32, 128, dtype=jnp.float32), \
+        sds(2, 8192, 32, dtype=jnp.float32)
+    loss = lambda *a: kda.kda_chunked(*a).astype(jnp.float32).sum()  # noqa: E731
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+@pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("D,Hkv", [(64, 12), (128, 4)],
                          ids=["d64_mha", "d128_gqa"])
 def test_ring_chunk_kernel_compiles(one_chip, D, Hkv, direction):
