@@ -206,6 +206,11 @@ class GPT2LMHead(nn.Module):
     # Packed-block document isolation (see llama.py segment_eos_id)
     segment_eos_id: int = -1
     act: "object | None" = None
+    # Ways the batch is split in pure data parallelism with the state
+    # replicated (steps.grad_reduce_plan; the trainer sets it, no option
+    # does): above 1 the tied table is read through _per_shard_table and
+    # its gradient is all-reduced once, not once a use.
+    tied_shards: int = 1
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, loss_mask=None):
@@ -256,7 +261,20 @@ class GPT2LMHead(nn.Module):
                 p_i = self.variable("cache", "pos_index",
                                     lambda: jnp.zeros(pos_shape, jnp.int32))
                 p_i.value = jnp.full(pos_shape, S, jnp.int32)
-        x = wte(input_ids) + pos
+        shards = self.tied_shards
+        if (self.cp is None or self.decode or self.fused_loss
+                or B % shards):
+            shards = 1
+        if shards > 1:
+            # one view of the tied table a shard of the batch (see
+            # _per_shard_table): lookup and head both read it, so their
+            # two gradient contributions meet BEFORE the sum over shards
+            table = _per_shard_table(wte.embedding, shards, self.cp)
+            tok = jax.vmap(lambda t, i: jnp.take(t, i, axis=0))(
+                table, input_ids.reshape(shards, B // shards, S))
+            x = tok.reshape(B, S, -1) + pos
+        else:
+            x = wte(input_ids) + pos
         x = nn.Dropout(self.dropout_rate)(x, deterministic=deterministic)
         x = x.astype(self.dtype)
         if self.act is not None:
@@ -282,7 +300,8 @@ class GPT2LMHead(nn.Module):
         x = nn.LayerNorm(epsilon=1e-5, dtype=jnp.float32,
                          param_dtype=jnp.float32, name="ln_f")(x)
         # Tied head, bf16 operands with fp32 accumulation (cf. bert.py).
-        emb = jnp.asarray(wte.embedding, self.dtype)  # (V, C)
+        emb = jnp.asarray(table if shards > 1 else wte.embedding,
+                          self.dtype)  # (V, C), or (shards, V, C)
         if self.fused_loss and not self.decode:
             from pytorch_distributed_train_tpu.losses import chunked_causal_ce
 
@@ -290,12 +309,34 @@ class GPT2LMHead(nn.Module):
                                      loss_mask=loss_mask,
                                      transpose_kernel=True)
         with jax.named_scope("lm_head"):  # a phase of the step: steps.py
-            logits = jax.lax.dot_general(
-                x.astype(self.dtype), emb,
-                (((x.ndim - 1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            if shards > 1:  # batched over shards, each its own view
+                logits = jax.lax.dot_general(
+                    x.astype(self.dtype).reshape(shards, B // shards, S, -1),
+                    emb, (((3,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32,
+                ).reshape(B, S, -1)
+            else:
+                logits = jax.lax.dot_general(
+                    x.astype(self.dtype), emb,
+                    (((x.ndim - 1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
         return logits.astype(jnp.float32)
+
+
+def _per_shard_table(table, shards: int, cp):
+    """(V, C) -> (shards, V, C), split over the batch axes: each device
+    keeps its own copy, which costs no communication. What reads it is
+    batched over shards, so the gradient arrives as (shards, V, C)
+    per-shard partial sums and the broadcast's transpose sums them across
+    devices once: ONE all-reduce for the leaf, where the partitioner
+    otherwise reduces each use's contribution by itself (the tied head's
+    and the lookup's: 154 MB in float32 each for GPT-2 small)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    out = jnp.broadcast_to(table[None], (shards, *table.shape))
+    return jax.lax.with_sharding_constraint(out, NamedSharding(
+        cp.mesh, PartitionSpec(tuple(cp.batch_axes), None, None)))
 
 
 def gpt2(cfg, dtype, param_dtype, cp=None, act=None) -> GPT2LMHead:

@@ -16,7 +16,8 @@ structure, and the '$'-anchored suffix regexes match either path.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+import math
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -731,26 +732,65 @@ def metrics_reducer(axis_names):
     return reduce_fn
 
 
+def sharded_leaves(state_sharding) -> list[str]:
+    """Names of the leaves of a sharding tree that are not fully
+    replicated: empty for the DDP layout (the whole TrainState on every
+    device, the batch axes data axes only)."""
+    from pytorch_distributed_train_tpu.parallel.partition import path_name
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(state_sharding)
+    return [path_name(path) for path, sh in flat
+            if hasattr(sh, "is_fully_replicated")
+            and not sh.is_fully_replicated]
+
+
 def assert_replicated_for_overlap(state_sharding) -> None:
     """The overlap path is the DDP analogue: pure data parallelism with
     the whole TrainState REPLICATED (the batch axes act as data axes
     only). A sharded param/opt leaf would silently compute garbage
     inside the full-manual shard_map body — refuse loudly instead."""
-    bad = []
-    flat, _ = jax.tree_util.tree_flatten_with_path(state_sharding)
-    for path, sh in flat:
-        if hasattr(sh, "is_fully_replicated") and not sh.is_fully_replicated:
-            from pytorch_distributed_train_tpu.parallel.partition import (
-                path_name,
-            )
-
-            bad.append(path_name(path))
+    bad = sharded_leaves(state_sharding)
     if bad:
         raise ValueError(
             "train.overlap_collectives needs the whole TrainState "
             "replicated (pure data parallelism — set mesh.fsdp=1 or a "
             f"replicating rule set); sharded leaves: {bad[:5]}"
             f"{'...' if len(bad) > 5 else ''}")
+
+
+class GradReducePlan(NamedTuple):
+    """How often the GSPMD step reduces a leaf's gradient across the
+    batch axes, and why."""
+
+    mode: str  # per_leaf | per_use
+    batch_devices: int
+    why: str
+
+
+def grad_reduce_plan(mesh, state_sharding,
+                     batch_axes=("data", "fsdp")) -> GradReducePlan:
+    """Read off the layout, with no option to set. Left to itself the
+    partitioner reduces every USE of a leaf where its dot or scatter
+    produces the contribution (``per_use``): a leaf used twice, the tied
+    embedding, is all-reduced twice (GPT-2 small: 154 MB in float32 each,
+    2.7 ms a step each on four v5e chips; PERF.md section 5). In pure
+    data parallelism (every device of the mesh on a batch axis, more
+    than one of them, and every leaf of the state replicated: the walk
+    ``assert_replicated_for_overlap`` makes) a model that has such a leaf
+    can be told how many ways the batch is split (``per_leaf``) and keep
+    the contributions as per-shard partial sums until they have met, so
+    that the leaf is reduced once."""
+    n = math.prod(mesh.shape.get(ax, 1) for ax in batch_axes)
+    if n == 1:
+        why = "one device on the batch axes: nothing to reduce"
+    elif mesh.size != n:
+        why = f"{mesh.size // n} devices a replica: not pure data parallelism"
+    elif (bad := sharded_leaves(state_sharding)):
+        why = f"{len(bad)} sharded leaves of the state, first {bad[0]}"
+    else:
+        return GradReducePlan("per_leaf", n, "pure data parallelism, "
+                              "the state replicated")
+    return GradReducePlan("per_use", n, why)
 
 
 def shard_rng_fold(rng: jax.Array, axis_names) -> jax.Array:

@@ -381,6 +381,24 @@ class Trainer:
 
         # ---- jitted steps
         phase("train.init.steps")
+        # How often a leaf's gradient is all-reduced (steps.grad_reduce_plan
+        # reads it off the layout; the train.compile span carries it): in
+        # pure data parallelism a model with a tied leaf is told how many
+        # ways the batch is split, and reduces that leaf once.
+        self.grad_reduce = steps_lib.grad_reduce_plan(
+            self.mesh, self.state_sharding, self.batch_axes)
+        if cfg.train.overlap_collectives:  # the shard_map step reduces itself
+            self.grad_reduce = self.grad_reduce._replace(
+                mode="bucketed_in_scan", why="train.overlap_collectives")
+        elif (self.grad_reduce.mode == "per_leaf"
+              and hasattr(self.model, "tied_shards")):
+            self.model = self.model.clone(
+                tied_shards=self.grad_reduce.batch_devices)
+        if jax.process_index() == 0:
+            print(f"[parallel] grad all-reduce: {self.grad_reduce.mode} "
+                  f"({self.grad_reduce.batch_devices} device(s) on "
+                  f"{'x'.join(self.batch_axes)}; {self.grad_reduce.why})",
+                  flush=True)
         from pytorch_distributed_train_tpu.ops.device_augment import (
             build_device_augment,
         )
@@ -953,7 +971,11 @@ class Trainer:
                         spans_lib.set_correlation_tags(step=step)
                         with self.spans.span(
                                 "train.compile" if is_first else "train.step",
-                                step=step) as dispatch:
+                                step=step, **(
+                                    {"grad_reduce": self.grad_reduce.mode,
+                                     "batch_devices":
+                                         self.grad_reduce.batch_devices}
+                                    if is_first else {})) as dispatch:
                             self.state, metrics = self.train_step(
                                 self.state, batch, self.step_rng
                             )

@@ -9,8 +9,9 @@ and their static slices of the resident block) under the kernel's own
 tile rule at GPT-2 small's attention shape — the shape the benchmark's
 cells train — and at the S 2048 / D 128 causal, GQA and window shapes,
 plus the ring chunk kernel on a 4-device mesh and the W8/W4 GEMV
-kernels; and that the one-chip GPT-2 step keeps its 36 kernel calls
-under the names the benchmark finds them by. A compile that passes here
+kernels; that the one-chip GPT-2 step keeps its 36 kernel calls under
+the names the benchmark finds them by; and that the four-chip
+data-parallel GPT-2 step all-reduces its tied table once. A compile that passes here
 is a compile, not a chip run.
 
 Rules this file keeps (only one process at a time may load the TPU
@@ -246,6 +247,84 @@ def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
         r"^\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*? custom-call\(", text, re.M)]
     kernels = [n for n in names if re.search(bench["flash_kernel_pattern"], n)]
     assert len(kernels) == 3 * bench["n_layer"] == 36, kernels
+
+
+def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
+    """The four-chip data-parallel GPT-2 small step (benchmark cell
+    gpt2s-dp4-b64: the cell's widths and 16 sequences a chip, depth cut to
+    2 for the suite's sake), compiled for the described v5e:2x2 the way
+    the trainer builds it: steps.grad_reduce_plan sees four devices on the
+    batch axes and a replicated state, the model is told the four ways,
+    and the compiled step holds ONE all-reduce of the (50304, 768) table,
+    in float32, where the partitioner alone leaves two (the head's
+    contribution and the lookup's). One chip of the same topology plans
+    per_use, and jit_train_step passes jax.jit no compile options either
+    way (the 36-kernel test below compiles that step)."""
+    import json
+    import re
+
+    from pytorch_distributed_train_tpu import losses as losses_lib
+    from pytorch_distributed_train_tpu import steps as steps_lib
+    from pytorch_distributed_train_tpu.config import get_preset
+    from pytorch_distributed_train_tpu.models.registry import build_model
+    from pytorch_distributed_train_tpu.ops import attention as attention_lib
+    from pytorch_distributed_train_tpu.optim import make_optimizer
+    from pytorch_distributed_train_tpu.parallel.mesh import build_mesh
+    from pytorch_distributed_train_tpu.parallel.partition import (
+        rules_for_model,
+    )
+    from pytorch_distributed_train_tpu.train_state import TrainState
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "gpt2_small.json"),
+              encoding="utf-8") as f:
+        bench = json.load(f)
+    monkeypatch.setattr(attention_lib, "_on_tpu", lambda: True)
+    cfg = get_preset(bench["preset"])
+    cfg.apply_overrides(list(bench["overrides"]) + [
+        "data.batch_size=64", "model.num_layers=2"])
+    mesh = build_mesh(cfg.mesh, devices=topo.devices)
+    batch_axes = tuple(cfg.mesh.batch_axes)
+    model = build_model(cfg.model, cfg.precision, mesh=mesh,
+                        mesh_cfg=cfg.mesh)
+    tx, _ = make_optimizer(cfg.optim, cfg.total_steps, 0)
+    dummy = steps_lib.dummy_inputs(cfg.loss, cfg.model, cfg.data)
+
+    def init(rng):
+        params = model.init({"params": rng}, *dummy, train=False)["params"]
+        return TrainState.create(params=params, tx=tx, batch_stats={},
+                                 dynamic_scale=None, ema=False, swa=False)
+
+    shape = jax.eval_shape(init, jax.random.PRNGKey(0))
+    rules = rules_for_model(cfg.model.name)
+    sharding = steps_lib.state_shardings(mesh, rules, shape)
+    plan = steps_lib.grad_reduce_plan(mesh, sharding, batch_axes)
+    assert (plan.mode, plan.batch_devices) == ("per_leaf", 4)
+    one = build_mesh(cfg.mesh, devices=topo.devices[:1])
+    assert steps_lib.grad_reduce_plan(
+        one, steps_lib.state_shardings(one, rules, shape),
+        batch_axes).mode == "per_use"
+
+    step = steps_lib.jit_train_step(
+        steps_lib.make_train_step(
+            model.clone(tied_shards=plan.batch_devices),
+            losses_lib.get_loss_fn(
+                cfg.loss, label_smoothing=cfg.label_smoothing), tx),
+        mesh, sharding, batch_axes)
+    state = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), shape, sharding)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (cfg.data.batch_size, cfg.data.seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P(batch_axes)))}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    text = step.lower(state, batch, rng).compile().as_text()
+    assert "tpu_custom_call" in text  # the flash kernel, under shard_map
+    table = f"[{cfg.model.vocab_size},{cfg.model.hidden_size}]"
+    reduced = [m.group(1) for m in re.finditer(
+        r"= (\w+\[[\d,]*\])[^\n]*? all-reduce\(", text[text.index("\nENTRY"):])
+        if m.group(1).endswith(table)]
+    assert reduced == ["f32" + table], reduced
 
 
 @pytest.mark.parametrize("bits", [8, 4])

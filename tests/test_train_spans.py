@@ -69,6 +69,18 @@ def test_one_iteration_a_step_with_wait_and_dispatch_inside(run):
     assert len(named(run, "train.step")) == STEPS - 1
 
 
+def test_the_compile_span_says_how_often_a_leaf_is_reduced(run):
+    """steps.grad_reduce_plan's verdict rides the one train.compile span:
+    the suite's virtual CPU devices all lie on the data axis with the
+    state replicated, so the tied table is reduced once (per_leaf)."""
+    import jax
+
+    (compile_span,) = named(run, "train.compile")
+    assert compile_span.args["grad_reduce"] == "per_leaf"
+    assert compile_span.args["batch_devices"] == len(jax.devices())
+    assert all("grad_reduce" not in s.args for s in named(run, "train.step"))
+
+
 def test_cadenced_steps_carry_a_log_with_its_sync(run):
     logs = named(run, "train.log")
     assert [s.args["step"] for s in logs] == [4, 8, 9]  # cadence, horizon

@@ -280,6 +280,74 @@ def test_overlap_refuses_sharded_state(setup, devices8):
         steps_lib.jit_overlap_train_step(ts, mesh, sharding)
 
 
+def _one_leaf_sharded(sharding, mesh):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    leaves, treedef = jax.tree_util.tree_flatten(sharding.params)
+    leaves[0] = NamedSharding(mesh, PartitionSpec("data"))
+    return sharding.replace(
+        params=jax.tree_util.tree_unflatten(treedef, leaves))
+
+
+# (id, mesh, layout, mode): a leaf is reduced once (per_leaf) where every
+# device lies on a batch axis, there is more than one, and the whole
+# state is replicated; the partitioner's once-a-use everywhere else.
+PLAN_CASES = [
+    ("data8-replicated", dict(data=8), "rules", "per_leaf"),
+    ("data4-replicated", dict(data=4), "rules", "per_leaf"),
+    ("data2-fsdp2-all-replicated", dict(data=2, fsdp=2), "replicated",
+     "per_leaf"),
+    ("one-device", dict(data=1), "rules", "per_use"),
+    ("data8-one-sharded-leaf", dict(data=8), "one_sharded", "per_use"),
+    ("data2-fsdp4-zero3", dict(data=2, fsdp=4), "rules", "per_use"),
+    ("data2-fsdp4-zero1", dict(data=2, fsdp=4), "zero1", "per_use"),
+    ("data4-tensor2-all-replicated", dict(data=4, tensor=2), "replicated",
+     "per_use"),
+    ("data4-context2", dict(data=4, context=2), "rules", "per_use"),
+]
+
+
+@pytest.mark.parametrize("mesh_kw,layout,mode",
+                         [c[1:] for c in PLAN_CASES],
+                         ids=[c[0] for c in PLAN_CASES])
+def test_grad_reduce_plan_reads_the_layout(setup, devices8, mesh_kw,
+                                           layout, mode):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from pytorch_distributed_train_tpu.parallel.partition import (
+        rules_for_model,
+    )
+
+    n = int(np.prod(list(mesh_kw.values())))
+    mesh = build_mesh(MeshConfig(**mesh_kw), devices8[:n])
+    if layout == "replicated":
+        sharding = jax.tree.map(
+            lambda _: NamedSharding(mesh, PartitionSpec()), setup["shape"])
+    else:
+        sharding = steps_lib.state_shardings(
+            mesh, rules_for_model("vit_b16"), setup["shape"],
+            zero_stage=1 if layout == "zero1" else 3)
+        if layout == "one_sharded":
+            sharding = _one_leaf_sharded(sharding, mesh)
+    plan = steps_lib.grad_reduce_plan(mesh, sharding)
+    assert plan.mode == mode, plan
+    assert plan.batch_devices == int(
+        np.prod([mesh_kw.get(a, 1) for a in ("data", "fsdp")]))
+
+
+def test_jit_train_step_takes_no_compile_options(setup, monkeypatch):
+    """Whatever the plan says, on one chip, on the CPU and under any
+    layout the step is jitted as before: the asynchronous-collective
+    options PR 27 tried there measured slower on the chip and are not
+    passed (PERF.md section 6)."""
+    seen = {}
+    monkeypatch.setattr(jax, "jit",
+                        lambda fn, **kw: seen.update(kw) or fn)
+    steps_lib.jit_train_step(lambda *a: a, setup["mesh"], setup["sharding"])
+    assert sorted(seen) == ["donate_argnums", "in_shardings",
+                            "out_shardings"]
+
+
 def test_trainer_validates_compute_knobs(tmp_path):
     from pytorch_distributed_train_tpu.config import get_preset
     from pytorch_distributed_train_tpu.trainer import Trainer
