@@ -8,33 +8,29 @@ config are pinned to the CPU before any backend is instantiated, and the
 persistent compile cache is off for the suite and its children: xdist
 workers and the kill drills must never share one
 (utils/compile_cache.py).
+
+Every test has one limit, ``TEST_LIMIT_S``: a drill that wedges fails
+alone, with every thread's stack, and takes its child processes with it,
+instead of holding its worker until the run's own ``timeout`` kills
+everything and the count becomes "how far the run got".
 """
 
 import faulthandler
 import os
 import signal
+import sys
+import threading
+import traceback
 
-# The -q suite occasionally dies SILENTLY (~13% of full runs): no
-# traceback, no failing test name — just a truncated dot line. Leave a
-# corpse next time: faulthandler catches hard crashes (SIGSEGV/SIGABRT
-# — e.g. a poisoned XLA compile-cache entry), the SIGTERM hook catches
-# the tier-1 `timeout` kill (dump every thread's stack, then chain to
-# the previous disposition), and PDTT_TEST_DUMP_AFTER_S arms a one-shot
-# all-stacks dump shortly before a known wall-clock cap (e.g. 840 for
-# the 870s tier-1 budget) so a WEDGED test names itself even if the
-# SIGTERM never lands. Best-effort: a test that installs its own
-# SIGTERM handler in-process (preemption drills) overrides the hook.
+# A hard crash (SIGSEGV, or XLA's own abort) leaves every thread's Python
+# stack; so does the SIGTERM of the tier-1 ``timeout``, which then chains
+# to the previous disposition. Best-effort: a test that installs its own
+# SIGTERM handler in-process (the preemption drills) overrides the hook.
 faulthandler.enable()
 try:
     faulthandler.register(signal.SIGTERM, chain=True)
 except (AttributeError, ValueError, OSError):
     pass  # platform without register(), or not the main thread
-_dump_after = os.environ.get("PDTT_TEST_DUMP_AFTER_S")
-if _dump_after:
-    try:
-        faulthandler.dump_traceback_later(float(_dump_after), exit=False)
-    except ValueError:
-        pass
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
@@ -56,7 +52,52 @@ from pytorch_distributed_train_tpu.utils import syncdbg as _syncdbg  # noqa: E40
 
 _syncdbg.maybe_activate()
 
+import psutil  # noqa: E402
 import pytest  # noqa: E402
+
+TEST_LIMIT_S = 300
+
+
+def _all_stacks() -> str:
+    names = {t.ident: t.name for t in threading.enumerate()}
+    return "\n".join(
+        f"--- thread {names.get(ident, ident)}\n"
+        + "".join(traceback.format_stack(frame, limit=16))
+        for ident, frame in sys._current_frames().items())
+
+
+@pytest.fixture(autouse=True)
+def _test_limit():
+    """Fail the test when it has run ``TEST_LIMIT_S``: the alarm raises in
+    the main thread (a sleep, a wait on a process or a lock all return to
+    it), with the stacks as they stood. On that way out the children the
+    test started are killed, whole trees: a drill's own ``finally`` may
+    never have been reached."""
+    me = psutil.Process()
+    before = {p.pid for p in me.children(recursive=True)}
+    fired = []
+
+    def on_alarm(signum, frame):
+        fired.append(True)
+        pytest.fail(f"test ran past its {TEST_LIMIT_S} s limit\n"
+                    + _all_stacks(), pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+        if fired:
+            mine = [p for p in me.children(recursive=True)
+                    if p.pid not in before]
+            for p in mine:
+                try:
+                    p.kill()
+                except psutil.NoSuchProcess:
+                    pass
+            psutil.wait_procs(mine, timeout=10)
 
 
 @pytest.fixture(scope="session")

@@ -3,6 +3,7 @@ data/augment.py) — the torchvision/timm recipe augmentations."""
 
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 
 import jax
 import jax.numpy as jnp
@@ -121,7 +122,7 @@ def test_mixup_in_train_step_trains():
     """The full jitted train step accepts the mixup transform (8-dev mesh)."""
     from pytorch_distributed_train_tpu import steps as steps_lib
     from pytorch_distributed_train_tpu.config import (
-        MeshConfig, ModelConfig, OptimConfig, PrecisionConfig,
+        MeshConfig, OptimConfig, PrecisionConfig,
     )
     from pytorch_distributed_train_tpu.models.registry import build_model
     from pytorch_distributed_train_tpu.optim import make_optimizer
@@ -130,20 +131,18 @@ def test_mixup_in_train_step_trains():
     from pytorch_distributed_train_tpu.train_state import TrainState
 
     mesh = build_mesh(MeshConfig(data=-1))
-    model = build_model(ModelConfig(name="resnet18", num_classes=10,
-                                    image_size=32),
+    model = build_model(tiny_cfg().model,
                         PrecisionConfig(compute_dtype="float32"))
     tx, _ = make_optimizer(OptimConfig(name="momentum", learning_rate=0.1),
                            total_steps=10)
 
     def init_state(rng):
-        variables = model.init({"params": rng}, jnp.zeros((2, 32, 32, 3)),
+        variables = model.init({"params": rng}, jnp.zeros((2, 8, 8, 3)),
                                train=False)
-        return TrainState.create(params=variables["params"], tx=tx,
-                                 batch_stats=variables["batch_stats"])
+        return TrainState.create(params=variables["params"], tx=tx)
 
     shape = jax.eval_shape(init_state, jax.random.PRNGKey(0))
-    sharding = steps_lib.state_shardings(mesh, rules_for_model("resnet18"),
+    sharding = steps_lib.state_shardings(mesh, rules_for_model("vit_b16"),
                                          shape)
     state = jax.jit(init_state, out_shardings=sharding)(jax.random.PRNGKey(0))
     mix = MixupCutmix(mixup_alpha=0.2, cutmix_alpha=1.0, num_classes=10)
@@ -151,7 +150,7 @@ def test_mixup_in_train_step_trains():
         steps_lib.make_train_step(model, get_loss_fn("softmax_xent"), tx,
                                   mixup=mix),
         mesh, sharding)
-    batch = _batch(B=16, H=32, W=32)
+    batch = _batch(B=16, H=8, W=8)
     state, metrics = step(state, batch, jax.random.PRNGKey(1))
     assert np.isfinite(float(metrics["loss"]))
     assert 0.0 <= float(metrics["accuracy"]) <= 1.0

@@ -2,10 +2,13 @@
 auto-resume, reshard-on-restore (save on one mesh layout, restore on
 another — the FSDP→GSPMD requirement of BASELINE.json:11)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 import numpy as np
+from tiny import tiny_cfg
 
 from pytorch_distributed_train_tpu import steps as steps_lib
 from pytorch_distributed_train_tpu.checkpoint import CheckpointManager
@@ -24,7 +27,12 @@ from pytorch_distributed_train_tpu.parallel.partition import rules_for_model
 from pytorch_distributed_train_tpu.train_state import TrainState
 
 
-def _build(mesh, model_cfg):
+@functools.cache
+def _programs(mesh):
+    """The model, its sharded init and its jitted step on ``mesh``,
+    compiled once a worker; the step donates its state, so ``_build``
+    hands every test a fresh one."""
+    model_cfg = ModelConfig(name="resnet18", num_classes=10, image_size=8)
     model = build_model(model_cfg, PrecisionConfig())
     tx, _ = make_optimizer(
         OptimConfig(name="momentum", learning_rate=0.1, schedule="constant",
@@ -38,15 +46,19 @@ def _build(mesh, model_cfg):
         return TrainState.create(params=variables["params"], tx=tx,
                                  batch_stats=variables.get("batch_stats", {}))
 
-    rng = jax.random.PRNGKey(0)
-    shape = jax.eval_shape(init_state, rng)
+    shape = jax.eval_shape(init_state, jax.random.PRNGKey(0))
     sharding = steps_lib.state_shardings(mesh, rules, shape)
-    state = jax.jit(init_state, out_shardings=sharding)(rng)
     step = steps_lib.jit_train_step(
         steps_lib.make_train_step(model, get_loss_fn("softmax_xent"), tx),
         mesh, sharding,
     )
-    return model, state, step, shape, sharding
+    return (model, jax.jit(init_state, out_shardings=sharding), step, shape,
+            sharding)
+
+
+def _build(mesh):
+    model, init, step, shape, sharding = _programs(mesh)
+    return model, init(jax.random.PRNGKey(0)), step, shape, sharding
 
 
 def _batch(seed=0):
@@ -67,8 +79,7 @@ def _abstract(shape, sharding):
 
 def test_roundtrip_bitwise(tmp_ckpt_dir, devices8):
     mesh = build_mesh(MeshConfig(data=8, fsdp=1, tensor=1, context=1), devices8)
-    cfg = ModelConfig(name="resnet18", num_classes=10, image_size=8)
-    model, state, step, shape, sharding = _build(mesh, cfg)
+    model, state, step, shape, sharding = _build(mesh)
     rng = jax.random.PRNGKey(1)
     for i in range(3):
         state, _ = step(state, _batch(i), rng)
@@ -98,8 +109,7 @@ def test_reshard_on_restore(tmp_ckpt_dir, devices8):
     """Save with DP layout (8,1), restore into FSDP layout (2,4) — the mesh
     changed between save and resume (SURVEY §5.4 'reshard-on-restore')."""
     mesh_dp = build_mesh(MeshConfig(data=8, fsdp=1, tensor=1, context=1), devices8)
-    cfg = ModelConfig(name="resnet18", num_classes=10, image_size=8)
-    _, state, step, _, _ = _build(mesh_dp, cfg)
+    _, state, step, _, _ = _build(mesh_dp)
     rng = jax.random.PRNGKey(1)
     state, _ = step(state, _batch(0), rng)
     ck = CheckpointManager(CheckpointConfig(dir=tmp_ckpt_dir, async_save=False))
@@ -107,7 +117,7 @@ def test_reshard_on_restore(tmp_ckpt_dir, devices8):
     ck.wait()
 
     mesh_fsdp = build_mesh(MeshConfig(data=2, fsdp=4, tensor=1, context=1), devices8)
-    _, _, step2, shape2, sharding2 = _build(mesh_fsdp, cfg)
+    _, _, step2, shape2, sharding2 = _build(mesh_fsdp)
     restored, _ = ck.restore(_abstract(shape2, sharding2))
     jax.tree.map(
         lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
@@ -123,8 +133,7 @@ def test_resume_continues_identically(tmp_ckpt_dir, devices8):
     """Train 2 steps, checkpoint, train 2 more; vs restore + 2 steps — same
     params (the kill-and-resume contract, SURVEY §5.3c)."""
     mesh = build_mesh(MeshConfig(data=8, fsdp=1, tensor=1, context=1), devices8)
-    cfg = ModelConfig(name="resnet18", num_classes=10, image_size=8)
-    _, state, step, shape, sharding = _build(mesh, cfg)
+    _, state, step, shape, sharding = _build(mesh)
     rng = jax.random.PRNGKey(1)
     for i in range(2):
         state, _ = step(state, _batch(i), rng)
@@ -150,30 +159,14 @@ def test_best_checkpoint_tracker(tmp_path, devices8):
     """`model_best.pth` semantics: <dir>/best holds the step whose eval
     metric was best, the watermark survives a restart, and a non-improving
     eval does not overwrite it."""
-    from pytorch_distributed_train_tpu.config import TrainConfig
     from pytorch_distributed_train_tpu.trainer import Trainer
 
     def make_cfg():
-        cfg = TrainConfig()
-        cfg.model.name = "resnet18"
-        cfg.model.num_classes = 10
-        cfg.model.image_size = 8
-        cfg.data.dataset = "synthetic_images"
-        cfg.data.synthetic_size = 128
-        cfg.data.batch_size = 32
-        cfg.data.num_workers = 1
-        cfg.optim.name = "momentum"
-        cfg.optim.learning_rate = 0.05
-        cfg.optim.schedule = "constant"
-        cfg.optim.warmup_steps = 0
-        cfg.total_steps = 4
-        cfg.eval_every_steps = 2
-        cfg.checkpoint.dir = str(tmp_path / "ckpt")
-        cfg.checkpoint.save_every_steps = 2
-        cfg.checkpoint.async_save = False
-        cfg.checkpoint.best_metric = "accuracy"
-        cfg.obs.log_every_steps = 100
-        return cfg
+        return tiny_cfg(
+            "data.synthetic_size=128", "data.batch_size=32", "total_steps=4",
+            "eval_every_steps=2", f"checkpoint.dir={tmp_path}/ckpt",
+            "checkpoint.save_every_steps=2",
+            "checkpoint.best_metric=accuracy", "obs.log_every_steps=100")
 
     t = Trainer(make_cfg())
     t.fit()

@@ -5,6 +5,7 @@ import os
 import sys
 
 import pytest
+from tiny import set_flags
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -22,18 +23,24 @@ def _overrides(tmp_path):
     ]
 
 
+def _tiny(tmp_path):
+    """The tiny trainer under the preset, for the tests whose contract is
+    the CLI's control flow and not ResNet-18's shapes."""
+    return [*set_flags(), *_overrides(tmp_path)]
+
+
 def test_train_then_eval_only(tmp_path, capfd):
     sys.path.insert(0, REPO)
     import train
 
     rc = train.main(["--config", "resnet18_cifar10", "--steps", "4",
-                     *_overrides(tmp_path)])
+                     *_tiny(tmp_path)])
     assert rc == 0
     out = capfd.readouterr().out
     assert "[train] step=4" in out
 
     rc = train.main(["--config", "resnet18_cifar10", "--eval-only",
-                     "--resume", "auto", *_overrides(tmp_path)])
+                     "--resume", "auto", *_tiny(tmp_path)])
     assert rc == 0
     out = capfd.readouterr().out
     assert "[resume] restored step 4" in out
@@ -45,7 +52,7 @@ def test_eval_only_refuses_random_init(tmp_path, capfd):
     import train
 
     rc = train.main(["--config", "resnet18_cifar10", "--eval-only",
-                     "--resume", "auto", *_overrides(tmp_path)])
+                     "--resume", "auto", *_tiny(tmp_path)])
     assert rc == 2
     assert "refusing to validate" in capfd.readouterr().err
 
@@ -307,7 +314,7 @@ def test_find_batch_size_bisects_to_budget(tmp_path, capfd):
     import train
 
     rc = train.main(["--config", "resnet18_cifar10", "--find-batch-size",
-                     "--hbm-gb", "1.0", *_overrides(tmp_path)])
+                     "--hbm-gb", "0.002", *_tiny(tmp_path)])
     assert rc == 0
     out = capfd.readouterr().out
     line = next(l for l in out.splitlines() if l.startswith("{"))
@@ -323,7 +330,7 @@ def test_find_batch_size_bisects_to_budget(tmp_path, capfd):
 
     # impossible budget: the configured batch itself does not fit
     rc = train.main(["--config", "resnet18_cifar10", "--find-batch-size",
-                     "--hbm-gb", "0.0001", *_overrides(tmp_path)])
+                     "--hbm-gb", "0.000001", *_tiny(tmp_path)])
     assert rc == 4
     out = capfd.readouterr().out
     line = next(l for l in out.splitlines() if l.startswith("{"))
@@ -343,7 +350,7 @@ def test_find_batch_size_takes_a_compiler_refusal_as_does_not_fit(
     from pytorch_distributed_train_tpu.trainer import Trainer
 
     trainer = Trainer(train.build_config(train.parse_args(
-        ["--config", "resnet18_cifar10", *_overrides(tmp_path)])))
+        ["--config", "resnet18_cifar10", *_tiny(tmp_path)])))
     try:
         def report(batch_size=None):
             if batch_size > 128:

@@ -13,19 +13,16 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+from tiny import set_flags  # noqa: E402
+
 
 def _run(tmp, tag, extra=()):
     import train
 
     rc = train.main([
         "--config", "resnet18_cifar10", "--steps", "4", "--resume", "none",
-        "--set", "data.dataset=synthetic_images",
-        "--set", "data.synthetic_size=256",
-        "--set", "data.batch_size=32",
-        "--set", "obs.log_every_steps=1",
-        "--set", f"checkpoint.dir={tmp}/{tag}",
-        "--set", "checkpoint.save_every_steps=0",
-        "--set", "checkpoint.async_save=false",
+        *set_flags(f"checkpoint.dir={tmp}/{tag}",
+                   "checkpoint.save_every_steps=0"),
         *extra,
     ])
     assert rc == 0

@@ -1,6 +1,8 @@
 """EMA (Polyak) weight averaging: recurrence math, eval routing, and
 sharded-train-step integration."""
 
+import functools
+
 import numpy as np
 
 import jax
@@ -22,8 +24,12 @@ from pytorch_distributed_train_tpu.train_state import TrainState
 DECAY = 0.9
 
 
-def _setup(devices8):
-    mesh = build_mesh(MeshConfig(data=8), devices8)
+@functools.cache
+def _compiled(devices8: tuple):
+    """The sharded init and the jitted EMA train step, compiled once a
+    worker: the step donates its state, so every test takes a fresh one
+    from ``_setup`` and shares only the programs."""
+    mesh = build_mesh(MeshConfig(data=8), list(devices8))
     cfg = ModelConfig(name="resnet18", num_classes=10, image_size=32)
     model = build_model(cfg, PrecisionConfig())
     tx = optax.sgd(0.1)
@@ -36,15 +42,20 @@ def _setup(devices8):
                                  batch_stats=variables["batch_stats"],
                                  ema=True)
 
-    rng = jax.random.PRNGKey(0)
-    shape = jax.eval_shape(init_state, rng)
+    shape = jax.eval_shape(init_state, jax.random.PRNGKey(0))
     sharding = steps_lib.state_shardings(mesh, rules, shape)
-    state = jax.jit(init_state, out_shardings=sharding)(rng)
     step = steps_lib.jit_train_step(
         steps_lib.make_train_step(model, get_loss_fn("softmax_xent"), tx,
                                   ema_decay=DECAY),
         mesh, sharding,
     )
+    return jax.jit(init_state, out_shardings=sharding), step
+
+
+def _setup(devices8):
+    init, step = _compiled(tuple(devices8))
+    rng = jax.random.PRNGKey(0)
+    state = init(rng)
     rng_np = np.random.default_rng(0)
     batch = {
         "image": jnp.asarray(rng_np.standard_normal((16, 32, 32, 3)),
@@ -80,21 +91,27 @@ def test_eval_uses_ema_params(devices8):
 
     model = build_model(ModelConfig(name="resnet18", num_classes=10,
                                     image_size=32), PrecisionConfig())
-    eval_step = steps_lib.make_eval_step(model, get_loss_fn("softmax_xent"))
+    # (every whole-model call on the sharded state is one jitted program,
+    # as in the trainer: op by op, their all-reduces on the 8 virtual
+    # devices can starve each other until XLA aborts the process; see
+    # test_update_bn_reestimates_stats_for_averaged_weights)
+    eval_step = jax.jit(
+        steps_lib.make_eval_step(model, get_loss_fn("softmax_xent")))
     got = eval_step(state, batch)
+
+    @jax.jit
+    def loss_of(params, stats):
+        logits = steps_lib.apply_model(
+            model, params, stats, batch, train=False, dropout_rng=None)[0]
+        return get_loss_fn("softmax_xent")(logits, batch)[0]
+
     # oracle: evaluate explicitly with the EMA params AND the EMA stats
     # mirror (matched pair — the r4 BN fix; see eval_batch_stats)
-    explicit = steps_lib.apply_model(
-        model, state.ema_params, state.eval_batch_stats, batch,
-        train=False, dropout_rng=None)[0]
-    loss_ref = get_loss_fn("softmax_xent")(explicit, batch)[0]
+    loss_ref = loss_of(state.ema_params, state.eval_batch_stats)
     np.testing.assert_allclose(float(got["loss"]), float(loss_ref),
                                atol=1e-6, rtol=1e-6)
     # and it differs from evaluating the raw params (they diverged)
-    raw = steps_lib.apply_model(
-        model, state.params, state.batch_stats, batch,
-        train=False, dropout_rng=None)[0]
-    loss_raw = get_loss_fn("softmax_xent")(raw, batch)[0]
+    loss_raw = loss_of(state.params, state.batch_stats)
     assert abs(float(loss_raw) - float(got["loss"])) > 1e-9
 
 
@@ -104,8 +121,9 @@ def test_ema_off_keeps_none(devices8):
     cfg = ModelConfig(name="resnet18", num_classes=10, image_size=32)
     model = build_model(cfg, PrecisionConfig())
     tx = optax.sgd(0.1)
-    variables = model.init({"params": jax.random.PRNGKey(0)},
-                           jnp.zeros((2, 32, 32, 3)), train=False)
+    variables = jax.jit(lambda: model.init(  # one program, not one an op
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((2, 32, 32, 3)),
+        train=False))()
     state = TrainState.create(params=variables["params"], tx=tx,
                               batch_stats=variables["batch_stats"])
     assert state.ema_params is None
@@ -235,14 +253,24 @@ def test_update_bn_reestimates_stats_for_averaged_weights(tmp_path):
     got = jax.tree.map(np.asarray, tr.state.batch_stats)
 
     # manual recomputation: momentum-0 probe over the same first 3 batches
+    # (under jit, as the trainer's own pass is: applied op by op, the
+    # model puts one small program per op in flight on the 8 virtual
+    # devices, their all-reduces starve each other of the CPU backend's
+    # threads, and XLA aborts the process when a rendezvous has waited
+    # 40 s: the worker crash this test was red by)
     probe = dataclasses.replace(tr.model, bn_momentum=0.0)
-    total, n = None, 0
-    for batch in tr.train_epoch_fn(0):
+
+    @jax.jit
+    def probe_stats(image):
         _, upd = probe.apply(
             {"params": state.eval_params,
              "batch_stats": state.batch_stats},
-            batch["image"], train=True, mutable=["batch_stats"])
-        stats = upd["batch_stats"]
+            image, train=True, mutable=["batch_stats"])
+        return upd["batch_stats"]
+
+    total, n = None, 0
+    for batch in tr.train_epoch_fn(0):
+        stats = probe_stats(batch["image"])
         total = stats if total is None else jax.tree.map(
             jnp.add, total, stats)
         n += 1
@@ -305,8 +333,8 @@ def test_eval_uses_ema_batch_stats(devices8):
         state, _ = step(state, batch, rng)
     cfg = ModelConfig(name="resnet18", num_classes=10, image_size=32)
     model = build_model(cfg, PrecisionConfig())
-    eval_step = steps_lib.make_eval_step(
-        model, get_loss_fn("softmax_xent"))
+    eval_step = jax.jit(steps_lib.make_eval_step(  # see the note above
+        model, get_loss_fn("softmax_xent")))
     base = float(eval_step(state, batch)["loss"])
     poisoned_traj = state.replace(batch_stats=jax.tree.map(
         lambda x: x + 100.0, state.batch_stats))

@@ -13,6 +13,7 @@ import sys
 
 import numpy as np
 import pytest
+from tiny import TINY
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -314,16 +315,11 @@ def test_degraded_mesh_resume_keeps_global_batch(tmp_path, devices8):
 
     def make_cfg(ckpt_dir):
         cfg = get_preset("resnet18_cifar10")
-        cfg.model.image_size = 32
-        cfg.data.dataset = "synthetic_images"
-        cfg.data.synthetic_size = 128
-        cfg.data.batch_size = 32  # divisible by both world shapes
-        cfg.checkpoint.dir = str(ckpt_dir)
-        cfg.checkpoint.save_every_steps = 3
-        cfg.checkpoint.async_save = False
-        cfg.eval_every_steps = 0
-        cfg.epochs = 0
-        cfg.obs.log_every_steps = 100
+        cfg.apply_overrides([
+            *TINY, "data.synthetic_size=128",
+            "data.batch_size=32",  # divisible by both world shapes
+            f"checkpoint.dir={ckpt_dir}", "checkpoint.save_every_steps=3",
+            "eval_every_steps=0", "epochs=0", "obs.log_every_steps=100"])
         return cfg
 
     def run(cfg, mesh, steps):

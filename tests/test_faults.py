@@ -325,10 +325,8 @@ def test_prune_manifests(tmp_path):
 def test_corrupt_latest_falls_back_to_previous_step(tmp_path, capsys):
     """Truncate a file inside the NEWEST checkpoint step — restore must
     skip it with a logged reason + counter and land on the previous
-    manifest-verified step (latest_good_step fallback). Lives here (late
-    alphabet) rather than test_checkpoint.py so the tier-1 870s prefix
-    on the 2-core box keeps its seed shape; uses a bare TrainState (no
-    mesh/model build) for the same reason."""
+    manifest-verified step (latest_good_step fallback). Uses a bare TrainState (no
+    mesh/model build): the contract is the manager's."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -102,8 +102,9 @@ def test_no_targets_is_loud():
     train zero parameters (resnet has no attention projections)."""
     model = build_model(ModelConfig(name="resnet18", num_classes=10,
                                     image_size=8), PrecisionConfig())
-    params = model.init({"params": jax.random.PRNGKey(0)},
-                        jnp.zeros((2, 8, 8, 3)), train=False)["params"]
+    params = jax.jit(lambda: model.init(  # one program, not one an op
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((2, 8, 8, 3)),
+        train=False))()["params"]
     with pytest.raises(ValueError, match="matched no 2-D/3-D kernel"):
         lora_lib.inject(jax.random.PRNGKey(1), params, LoraConfig(rank=4))
 

@@ -5,14 +5,14 @@ and the rollout/GRPO analytics oracles.
 
 The heavyweight acceptance drills (subprocess trainer storm -> fleet
 alert -> postmortem; overlap shard_map parity) live in
-tests/test_zmodel_health.py — late-alphabet on purpose, same stance as
-test_zcompute_step.py."""
+tests/test_zmodel_health.py."""
 
 import json
 import math
 
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +23,6 @@ from pytorch_distributed_train_tpu.config import (
     ModelConfig,
     OptimConfig,
     PrecisionConfig,
-    TrainConfig,
 )
 from pytorch_distributed_train_tpu.faults import registry as fregistry
 from pytorch_distributed_train_tpu.losses import get_loss_fn, make_grpo_loss
@@ -389,26 +388,11 @@ def test_trainer_grad_spike_warns_before_sentinel(tmp_path, monkeypatch):
     monkeypatch.delenv("RESTART_GENERATION", raising=False)
     monkeypatch.delenv(fregistry.ENV_VAR, raising=False)
     fregistry._reset_for_tests()
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 256
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.optim.name = "momentum"
-    cfg.optim.learning_rate = 0.05
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.total_steps = 14
-    cfg.checkpoint.dir = str(tmp_path / "ckpt")
-    cfg.checkpoint.async_save = False
-    cfg.obs.log_every_steps = 1
-    cfg.obs.jsonl_path = str(tmp_path / "metrics.jsonl")
-    cfg.obs.events_dir = str(tmp_path / "events")
-    cfg.obs.model_health = True
-    cfg.sentinel.enabled = True
+    cfg = tiny_cfg(
+        "total_steps=14", f"checkpoint.dir={tmp_path}/ckpt",
+        f"obs.jsonl_path={tmp_path}/metrics.jsonl",
+        f"obs.events_dir={tmp_path}/events", "obs.model_health=true",
+        "sentinel.enabled=true")
     # organic loss jitter can't reach 50% of median — the sentinel can
     # only trip on a loss spike, and this drill never inflates the loss
     cfg.sentinel.spike_min_rel = 0.5

@@ -8,28 +8,16 @@ import os
 import urllib.request
 
 import pytest
+from tiny import tiny_cfg
 
 from pytorch_distributed_train_tpu.config import TrainConfig
 
 
 def _tiny_cfg(tmp_path) -> TrainConfig:
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 128
-    cfg.data.batch_size = 32
-    cfg.data.num_workers = 1
-    cfg.optim.name = "sgd"
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.total_steps = 4
-    cfg.checkpoint.dir = str(tmp_path / "ckpt")
-    cfg.checkpoint.save_every_steps = 0
-    cfg.checkpoint.async_save = False
-    cfg.obs.log_every_steps = 1
-    return cfg
+    return tiny_cfg(
+        "data.synthetic_size=128", "data.batch_size=32", "optim.name=sgd",
+        "total_steps=4", f"checkpoint.dir={tmp_path}/ckpt",
+        "checkpoint.save_every_steps=0")
 
 
 @pytest.mark.slow

@@ -8,6 +8,7 @@ optax weight-decay transform (optim.decay_mask_fn).
 
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 
 import jax
 import jax.numpy as jnp
@@ -331,29 +332,14 @@ def test_reduce_on_plateau_scales_updates():
 
 
 def test_plateau_trains_end_to_end(tmp_path):
-    from pytorch_distributed_train_tpu.config import TrainConfig
     from pytorch_distributed_train_tpu.trainer import Trainer
 
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 64
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.optim.name = "momentum"
-    cfg.optim.learning_rate = 0.05
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.optim.plateau_factor = 0.5
-    cfg.optim.plateau_patience = 1
-    cfg.total_steps = 3
-    cfg.checkpoint.dir = str(tmp_path / "ckpt")
-    cfg.checkpoint.save_every_steps = 10**9
-    cfg.checkpoint.async_save = False
-    cfg.obs.log_every_steps = 1
-    cfg.obs.jsonl_path = str(tmp_path / "m.jsonl")
+    cfg = tiny_cfg(
+        "data.synthetic_size=64", "optim.plateau_factor=0.5",
+        "optim.plateau_patience=1", "total_steps=3",
+        f"checkpoint.dir={tmp_path}/ckpt",
+        f"checkpoint.save_every_steps={10**9}",
+        f"obs.jsonl_path={tmp_path}/m.jsonl")
     t = Trainer(cfg)
     t.fit()
     t.close()
@@ -416,28 +402,14 @@ def test_schedule_free_adamw_trains_and_evals(tmp_path):
         make_optimizer(OptimConfig(name="schedule_free_adamw",
                                    schedule="cosine"), total_steps=10)
 
-    from pytorch_distributed_train_tpu.config import TrainConfig
     from pytorch_distributed_train_tpu.trainer import Trainer
 
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 64
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.optim.name = "schedule_free_adamw"
-    cfg.optim.learning_rate = 1e-3
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.total_steps = 3
-    cfg.eval_every_steps = 2
-    cfg.checkpoint.dir = str(tmp_path / "ckpt")
-    cfg.checkpoint.save_every_steps = 10**9
-    cfg.checkpoint.async_save = False
-    cfg.obs.log_every_steps = 10
-    cfg.obs.jsonl_path = str(tmp_path / "m.jsonl")
+    cfg = tiny_cfg(
+        "data.synthetic_size=64", "optim.name=schedule_free_adamw",
+        "optim.learning_rate=1e-3", "total_steps=3", "eval_every_steps=2",
+        f"checkpoint.dir={tmp_path}/ckpt",
+        f"checkpoint.save_every_steps={10**9}", "obs.log_every_steps=10",
+        f"obs.jsonl_path={tmp_path}/m.jsonl")
     t = Trainer(cfg)
     t.fit()  # eval_every_steps=2 → eval (through schedule_free_eval) ran
     t.close()

@@ -4,10 +4,7 @@ last verified checkpoint with LR cooldown, cross-host hang diagnosis
 (blamed host + cluster flight-recorder dump + distinct rc + gang
 restart), plus the satellites: mid-epoch exact resume for both loaders,
 the elastic windowed restart budget + backoff, serve_http graceful
-drain, and the docs<->registry fault-point cross-check.
-
-Late-alphabet on purpose: the tier-1 870s cap on the 2-core box reaches
-an alphabetical prefix, and early files must stay fast (CHANGES.md)."""
+drain, and the docs<->registry fault-point cross-check."""
 
 import dataclasses
 import json
@@ -25,6 +22,8 @@ import jax
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
+
+from tiny import WORKER_HEAD, tiny_cfg
 
 from pytorch_distributed_train_tpu.config import DataConfig, TrainConfig
 from pytorch_distributed_train_tpu.faults import registry as fregistry
@@ -114,26 +113,10 @@ def test_cooldown_absent_is_none_and_passthrough():
 
 # --------------------------------------------------------------- e2e helpers
 def _tiny_cfg(tmp_path, tag: str) -> TrainConfig:
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 256
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.data.prefetch = 2
-    cfg.optim.name = "momentum"
-    cfg.optim.learning_rate = 0.05
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.checkpoint.dir = str(tmp_path / f"ckpt-{tag}")
-    cfg.checkpoint.async_save = False
-    cfg.checkpoint.max_to_keep = 20
-    cfg.obs.log_every_steps = 1
-    cfg.obs.jsonl_path = str(tmp_path / f"metrics-{tag}.jsonl")
-    cfg.sentinel.enabled = True
-    return cfg
+    return tiny_cfg(
+        f"checkpoint.dir={tmp_path}/ckpt-{tag}", "checkpoint.max_to_keep=20",
+        f"obs.jsonl_path={tmp_path}/metrics-{tag}.jsonl",
+        "sentinel.enabled=true")
 
 
 def _params_equal(a, b) -> bool:
@@ -348,48 +331,35 @@ def test_liveness_pulse_beats_outside_step_cadence():
 
 
 # --------------------------------------------- e2e: host hang (gang-level)
-HANG_WORKER = """
-import os, sys, time
-sys.path.insert(0, {repo!r})
-import jax
-jax.config.update("jax_platforms", "cpu")
-from pytorch_distributed_train_tpu.config import TrainConfig
+HANG_WORKER = WORKER_HEAD + """
 from pytorch_distributed_train_tpu.elastic import worker_store
-from pytorch_distributed_train_tpu.trainer import Trainer
 
-rank = int(os.environ["PROCESS_ID"])
-world = int(os.environ["NUM_PROCESSES"])
-gen = os.environ["RESTART_GENERATION"]
-cfg = TrainConfig()
-cfg.model.name = "resnet18"; cfg.model.num_classes = 10
-cfg.model.image_size = 8
-cfg.data.dataset = "synthetic_images"; cfg.data.synthetic_size = 256
-cfg.data.batch_size = 16; cfg.data.num_workers = 1; cfg.data.prefetch = 2
-cfg.optim.name = "momentum"; cfg.optim.learning_rate = 0.05
-cfg.optim.schedule = "constant"; cfg.optim.warmup_steps = 0
 cfg.total_steps = 6
 cfg.checkpoint.dir = os.path.join({out!r}, f"ckpt-{{rank}}")
 cfg.checkpoint.save_every_steps = 2
-cfg.checkpoint.async_save = False
-cfg.obs.log_every_steps = 1
 cfg.obs.jsonl_path = os.path.join({out!r}, f"metrics-{{rank}}.jsonl")
-# NO compile cache here, deliberately: the hang diagnosis ends rank 0
-# with os._exit, and this container's jax 0.4.37 cache loads truncated
-# entries without validation — an exit landing mid-cache-write poisons
-# every later generation with heap corruption (bisected: fresh/absent
-# cache is clean, the gen-0 cache dir reproducibly aborts). Each
-# generation pays the ~15s recompile instead.
-# Timeout scaled to the box: two jax workers + the pytest process on a
-# 2-core host stretch step/save times well past what a 4-core-or-better
-# box sees, and a 4s flat timeout then races the post-fit store barrier
-# (a healthy-but-waiting host can accrue staleness comparable to the
-# genuinely wedged one). Liveness semantics are unchanged — only the
-# drill's patience grows with contention.
-cfg.sentinel.hang_timeout_s = 4.0 * max(1.0, 4.0 / (os.cpu_count() or 1))
-cfg.sentinel.hang_poll_s = 0.5
-if rank == 1:
-    cfg.faults.inject = ("host.hang@step=3",)  # generation 0 only
+# (The suite runs with the compile cache off, and this drill needs it
+# off: the diagnosis ends rank 0 with os._exit, and an exit that lands
+# in a cache write poisons every later generation.)
+# The timeout has to outlast every phase of a HEALTHY host that does not
+# beat: the first compile after the restore's pulse and a synchronous
+# save, each a second or two alone and several under six xdist workers.
+# At 4 s the monitor blamed whichever host was saving or compiling, the
+# gang restarted into a generation without the fault, and the drill
+# never saw its wedge. Liveness semantics are unchanged: only the
+# drill's patience grows.
+cfg.sentinel.hang_timeout_s = 12.0
+cfg.sentinel.hang_poll_s = 0.25
+# generation 0 only. Host 0 must still be beating when host 1 wedges: a
+# host that has finished waits in the barrier below without a beat, and
+# the monitor blames whichever host goes stale first. The tiny trainer's
+# six steps take a fraction of a second, so the hosts enter fit together
+# and host 0 spends 4.5 s (in sleeps far under the timeout) on its steps
+# 4 to 6, which covers the skew of two compiles on a loaded box.
+cfg.faults.inject = (("host.hang@step=3",) if rank == 1 else
+                     ("step.straggle@step=4:count=3:delay=1.5",))
 t = Trainer(cfg)
+worker_store().barrier(f"built/{{gen}}", world, rank, timeout_ms=120000)
 t.fit()
 # SPMD stand-in: finished hosts block on their peers the way a real
 # collective would — rank 0 sits here while rank 1 is wedged, and only
@@ -408,7 +378,7 @@ def test_host_hang_diagnosed_dumped_and_gang_restarted(tmp_path, capfd):
     from pytorch_distributed_train_tpu.elastic import ElasticAgent, LaunchConfig
 
     script = tmp_path / "worker.py"
-    script.write_text(HANG_WORKER.format(repo=REPO, out=str(tmp_path)))
+    script.write_text(HANG_WORKER.format(out=str(tmp_path)))
     cfg = LaunchConfig(nprocs=2, max_restarts=2, monitor_interval_s=0.2,
                        shutdown_grace_s=2.0, backoff_base_s=0.05,
                        backoff_max_s=0.1, env=CPU_ENV)

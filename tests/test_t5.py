@@ -152,7 +152,10 @@ def test_remat_preserves_fwd_and_grad():
             return jnp.sum(model.apply({"params": p}, src, tgt,
                                        train=True) ** 2)
 
-        outs[remat] = (float(loss(params)), jax.grad(loss)(params))
+        # one program each, not one per op (op by op, every new shape
+        # compiles alone: most of this test's minute)
+        value, grads = jax.jit(jax.value_and_grad(loss))(params)
+        outs[remat] = (float(value), grads)
     np.testing.assert_allclose(outs[False][0], outs[True][0], rtol=1e-6)
     # remat reorders the recompute, so bit-exactness isn't guaranteed;
     # near-cancelling gradient elements carry fp32 accumulation noise

@@ -2,11 +2,7 @@
 async-vs-sync restore equivalence, snapshot-only blocking, back-pressure
 drain, kill-during-persist fallback to the newest sealed step, peer
 fetch over a fake store, retention pins, sentinel rewind tier hits, and
-the per-worker compile-cache satellite.
-
-Late-alphabet on purpose: the tier-1 870s cap only reaches an
-alphabetical prefix on this box, and early-alphabet files must stay
-fast (CHANGES PR 2/3)."""
+the per-worker compile-cache satellite."""
 
 import json
 import os
@@ -17,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from tiny import tiny_cfg
 
 from pytorch_distributed_train_tpu import faults as faults_lib
 from pytorch_distributed_train_tpu.checkpoint import CheckpointManager
@@ -368,28 +365,12 @@ def test_peer_fetch_restore_with_fake_store(tmp_path):
 
 # ----------------------------------------------------- sentinel rewind tiers
 def _e2e_cfg(d: str) -> TrainConfig:
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 256
-    cfg.data.batch_size = 32
-    cfg.data.num_workers = 1
-    cfg.optim.name = "momentum"
-    cfg.optim.learning_rate = 0.05
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.total_steps = 6
-    cfg.checkpoint.dir = d
-    cfg.checkpoint.save_every_steps = 2
-    cfg.checkpoint.tiered = True
-    cfg.checkpoint.peer_fetch = False
-    cfg.obs.log_every_steps = 100
-    cfg.sentinel.enabled = True
-    cfg.sentinel.max_consecutive_bad = 1
-    cfg.sentinel.spike_min_samples = 2
-    return cfg
+    return tiny_cfg(
+        "data.batch_size=32", "total_steps=6", f"checkpoint.dir={d}",
+        "checkpoint.save_every_steps=2", "checkpoint.async_save=true",
+        "checkpoint.tiered=true", "checkpoint.peer_fetch=false",
+        "obs.log_every_steps=100", "sentinel.enabled=true",
+        "sentinel.max_consecutive_bad=1", "sentinel.spike_min_samples=2")
 
 
 def test_sentinel_rewind_restores_from_ram_tier(tmp_path):

@@ -5,10 +5,7 @@ driven deterministically against a FAKE profiler backend, the
 docs<->emitters category cross-check, and the acceptance e2e: a seeded
 ``step.loss_spike`` drill producing a journaled anomaly, an automatic
 capture with an xplane top-ops summary, and a timeline_report showing
-the anomaly->capture->recovery causal chain.
-
-Late-alphabet on purpose: the tier-1 870s cap on the 2-core box reaches
-an alphabetical prefix, and early files must stay fast (CHANGES.md)."""
+the anomaly->capture->recovery causal chain."""
 
 import json
 import os
@@ -16,11 +13,12 @@ import sys
 import time
 
 import pytest
+from tiny import tiny_cfg
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-from pytorch_distributed_train_tpu.config import ObsConfig, TrainConfig
+from pytorch_distributed_train_tpu.config import ObsConfig
 from pytorch_distributed_train_tpu.faults import registry as fregistry
 from pytorch_distributed_train_tpu.obs import events as events_lib
 from pytorch_distributed_train_tpu.obs import profiler as profiler_lib
@@ -456,28 +454,13 @@ def test_e2e_spike_drill_journals_captures_and_reports(tmp_path, capfd):
 
     from pytorch_distributed_train_tpu.trainer import Trainer
 
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 256
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.optim.name = "momentum"
-    cfg.optim.learning_rate = 0.05
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.total_steps = 8
-    cfg.checkpoint.dir = str(tmp_path / "ckpt")
-    cfg.checkpoint.async_save = False
-    cfg.checkpoint.save_every_steps = 2
-    cfg.obs.log_every_steps = 1
-    cfg.obs.jsonl_path = str(tmp_path / "ckpt" / "metrics.jsonl")
-    cfg.obs.profile_dir = str(tmp_path / "ckpt" / "profiles")
-    cfg.obs.profile_on_anomaly = True
-    cfg.obs.profile_window_steps = 2
-    cfg.sentinel.enabled = True
+    cfg = tiny_cfg(
+        "total_steps=8", f"checkpoint.dir={tmp_path}/ckpt",
+        "checkpoint.save_every_steps=2",
+        f"obs.jsonl_path={tmp_path}/ckpt/metrics.jsonl",
+        f"obs.profile_dir={tmp_path}/ckpt/profiles",
+        "obs.profile_on_anomaly=true", "obs.profile_window_steps=2",
+        "sentinel.enabled=true")
     cfg.sentinel.spike_min_samples = 3
     cfg.sentinel.spike_min_rel = 0.5
     cfg.sentinel.max_consecutive_bad = 2

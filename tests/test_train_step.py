@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from tiny import TINY
 from jax.sharding import Mesh
 
 from pytorch_distributed_train_tpu import steps as steps_lib
@@ -210,8 +211,7 @@ def test_trainer_rejects_offload_on_cpu(tmp_path):
     from pytorch_distributed_train_tpu.trainer import Trainer
 
     cfg = get_preset("resnet18_cifar10")
-    cfg.data.synthetic_size = 64
-    cfg.data.batch_size = 16
+    cfg.apply_overrides([*TINY, "data.synthetic_size=64"])
     cfg.optim.offload_state = True
     cfg.checkpoint.dir = str(tmp_path / "ckpt")
     cfg.checkpoint.resume = "none"

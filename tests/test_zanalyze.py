@@ -2,8 +2,7 @@
 clean fixtures, baseline add/expire semantics, runner exit codes and
 JSON output, the checker shims, the pass-catalog doc contract, and the
 acceptance gate — the full analyzer over the repo with zero
-unsuppressed findings. Late-alphabet file per the tier-1 870s
-alphabetical-prefix constraint (CHANGES PR 2)."""
+unsuppressed findings."""
 
 import io
 import json

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from tiny import TINY
 
 from pytorch_distributed_train_tpu import steps as steps_lib
 from pytorch_distributed_train_tpu.config import (
@@ -396,19 +397,11 @@ def test_microbatched_resume_exact(tmp_path):
 
     def cfg_for(d):
         cfg = get_preset("resnet18_cifar10")
-        cfg.model.image_size = 8
-        cfg.data.dataset = "synthetic_images"
-        cfg.data.synthetic_size = 64
-        cfg.data.batch_size = 16
-        cfg.epochs = 0
-        cfg.total_steps = 4
-        cfg.optim.warmup_steps = 0
-        cfg.checkpoint.dir = str(d)
-        cfg.checkpoint.save_every_steps = 2
-        cfg.checkpoint.async_save = False
-        cfg.checkpoint.best_metric = ""
-        cfg.obs.events = False
-        cfg.train.grad_accum_steps = 2
+        cfg.apply_overrides([
+            *TINY, "data.synthetic_size=64", "epochs=0", "total_steps=4",
+            f"checkpoint.dir={d}", "checkpoint.save_every_steps=2",
+            "checkpoint.best_metric=", "obs.events=false",
+            "train.grad_accum_steps=2"])
         return cfg
 
     t1 = Trainer(cfg_for(tmp_path / "straight"))

@@ -5,8 +5,7 @@ hysteresis, bounds, budget latch + re-arm, cooled double-act guards)
 against fake collector/engine state, and the satellite drill:
 controller-initiated scale-in under live load with zero failed
 requests, session pinning respected, and the victim's slots verifiably
-reclaimed. Late-alphabet file per the tier-1 alphabetical-prefix
-budget; the full subprocess drill lives in test_zautoscale_drill.py
+reclaimed. The full subprocess drill lives in test_zautoscale_drill.py
 (slow)."""
 
 import json

@@ -5,8 +5,7 @@ alert-rule lifecycle (fire → resolve, cooldown, sinks, overrides), the
 sidecar port-collision fallback, memory telemetry, fleet_console
 --snapshot/--offline smokes, and the ISSUE-13 acceptance drill
 (2 subprocess fake-backend replicas + a tiny trainer, one launcher
-store, zero static scrape config). Late-alphabet file per the tier-1
-870s alphabetical-prefix constraint."""
+store, zero static scrape config)."""
 
 import json
 import os
@@ -25,6 +24,7 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 import fleet_console  # noqa: E402
 import timeline_report  # noqa: E402
+from tiny import WORKER_HEAD  # noqa: E402
 
 from pytorch_distributed_train_tpu.obs import events as events_lib  # noqa: E402
 from pytorch_distributed_train_tpu.obs.alerts import (  # noqa: E402
@@ -210,21 +210,23 @@ def test_collector_staleness_never_vs_stale(tmp_path):
             {"role": "serving", "host": "hostA", "addr": "127.0.0.1:9999"},
             {"role": "serving", "host": "hostB", "addr": "127.0.0.1:9998"},
         ],
-        poll_s=0.05, stale_after_s=0.2, fetch=fetch)
+        # (1 s, not 0.2: the poll and the evaluation after it have to fall
+        # inside it, on a box where six workers wait for the CPU)
+        poll_s=0.05, stale_after_s=1.0, fetch=fetch)
     engine = AlertEngine()
     col.poll()
     engine.evaluate(col)
     by_host = {t.host: t for t in col.targets}
     now = time.monotonic()
-    assert by_host["hostA"].state(now, 0.2) == "ok"
-    assert by_host["hostB"].state(now, 0.2) == "never"
+    assert by_host["hostA"].state(now, 1.0) == "ok"
+    assert by_host["hostB"].state(now, 1.0) == "never"
     alive["up"] = False
-    time.sleep(0.3)
+    time.sleep(1.2)
     col.poll()
     transitions = engine.evaluate(col)
     now = time.monotonic()
-    assert by_host["hostA"].state(now, 0.2) == "stale"
-    assert by_host["hostB"].state(now, 0.2) == "never"  # NOT stale
+    assert by_host["hostA"].state(now, 1.0) == "stale"
+    assert by_host["hostB"].state(now, 1.0) == "never"  # NOT stale
     fired = [(r["rule"], r["host"]) for r in transitions
              if r["event"] == "fired"]
     # the gone-stale host is blamed; the never-scraped one never is
@@ -475,34 +477,20 @@ def test_fleet_console_offline_report(tmp_path, capsys):
 
 # ----------------------------------------------------- acceptance drill
 
-TRAINER_WORKER = """
-import sys, time
-sys.path.insert(0, {repo!r})
-from pytorch_distributed_train_tpu.config import TrainConfig
-from pytorch_distributed_train_tpu.trainer import Trainer
-
-cfg = TrainConfig()
-cfg.model.name = "resnet18"
-cfg.model.num_classes = 10
-cfg.model.image_size = 8
-cfg.data.dataset = "synthetic_images"
+TRAINER_WORKER = WORKER_HEAD + """
 cfg.data.synthetic_size = 4096
 cfg.data.batch_size = 8
-cfg.data.num_workers = 1
-cfg.data.prefetch = 2
-cfg.optim.name = "momentum"
-cfg.optim.learning_rate = 0.05
-cfg.optim.schedule = "constant"
-cfg.optim.warmup_steps = 0
 cfg.total_steps = 100000
 cfg.checkpoint.dir = {ckpt!r}
-cfg.checkpoint.async_save = False
 cfg.checkpoint.save_every_steps = 1000000
-cfg.obs.log_every_steps = 1
 cfg.obs.metrics_port = -1
 cfg.obs.profile_dir = {ckpt!r} + "/profiles"  # alert-triggered POST
 # /profile captures must land in the drill tmp, not a cwd-relative dir
-cfg.faults.inject = ("step.loss_spike@step=40:count=100",)
+# step.straggle paces the loop (50 ms a step, whatever the box's load),
+# so the loss storm's 300 steps last the 15 s and more in which the
+# serving storm has to start: the two alerts overlap by construction
+cfg.faults.inject = ("step.straggle@step=1:count=100000:delay=0.05",
+                     "step.loss_spike@step=20:count=300")
 t = Trainer(cfg)
 try:
     t.fit()
@@ -523,7 +511,6 @@ def _spawn_replica(tmp_path, name, store_addr, proc_id, *, faults=""):
            "PDTT_PROFILE_DIR": str(tmp_path / f"prof_{name}")}
     if faults:
         env["PDTT_FAULTS"] = faults
-    env.pop("PDTT_TEST_DUMP_AFTER_S", None)
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tools", "serve_http.py"),
          "--fake-backend", "--fake-step-delay", "0.01", "--port", "0",
@@ -574,6 +561,10 @@ def test_e2e_drill_fleet_alerts(tmp_path):
 
     events_dir = tmp_path / "events"
     reg = get_registry()
+
+    def gauge(rule):
+        return reg.get_value("alerts_firing", {"rule": rule})
+
     with StoreServer() as srv:
         store_addr = f"127.0.0.1:{srv.port}"
         # a claimed endpoint that never comes up: the never-scraped case
@@ -583,16 +574,15 @@ def test_e2e_drill_fleet_alerts(tmp_path):
         c.close()
         proc_a, addr_a = _spawn_replica(
             tmp_path, "a", store_addr, 1,
-            faults="serve.slow_decode@call=400:count=100:delay=0.3")
+            faults="serve.slow_decode@call=150:count=40:delay=0.3")
         proc_b, addr_b = _spawn_replica(tmp_path, "b", store_addr, 2)
         trainer_script = tmp_path / "trainer_worker.py"
         trainer_script.write_text(TRAINER_WORKER.format(
-            repo=REPO, ckpt=str(tmp_path / "ckpt")))
+            ckpt=str(tmp_path / "ckpt")))
         tenv = {**os.environ, "JAX_PLATFORMS": "cpu",
                 "TPUSTORE_ADDR": store_addr,
                 "PDTT_EVENTS_DIR": str(events_dir)}
-        for k in ("PDTT_TEST_DUMP_AFTER_S", "PROCESS_ID",
-                  "NUM_PROCESSES"):
+        for k in ("PROCESS_ID", "NUM_PROCESSES"):
             tenv.pop(k, None)
         trainer_log = open(tmp_path / "trainer.log", "w")
         proc_t = subprocess.Popen(
@@ -676,12 +666,12 @@ def test_e2e_drill_fleet_alerts(tmp_path):
             # which begins a few hundred decode quanta into the
             # traffic — lands inside the loss storm and the two alerts
             # overlap deterministically
-            deadline = time.monotonic() + 300.0
+            deadline = time.monotonic() + 120.0
             while time.monotonic() < deadline:
                 if any(a["rule"] == "loss_spike"
                        for a in engine.firing()):
                     break
-                time.sleep(0.25)
+                time.sleep(0.05)
             assert any(a["rule"] == "loss_spike"
                        for a in engine.firing()), \
                 "trainer loss storm never fired the fleet rule"
@@ -692,14 +682,17 @@ def test_e2e_drill_fleet_alerts(tmp_path):
                 t.start()
 
             # -- both alert rules FIRE, simultaneously
-            deadline = time.monotonic() + 120.0
+            deadline = time.monotonic() + 60.0
             while time.monotonic() < deadline:
                 firing = {(a["rule"], a["host"])
                           for a in engine.firing()}
+                # (the engine sets a gauge after it lists the alert)
                 if (("loss_spike", trainer_host) in firing
-                        and ("ttft_regression", "host1") in firing):
+                        and ("ttft_regression", "host1") in firing
+                        and gauge("loss_spike")
+                        and gauge("ttft_regression")):
                     break
-                time.sleep(0.25)
+                time.sleep(0.05)
             firing = {(a["rule"], a["host"]) for a in engine.firing()}
             assert ("loss_spike", trainer_host) in firing, firing
             assert ("ttft_regression", "host1") in firing, firing
@@ -716,14 +709,16 @@ def test_e2e_drill_fleet_alerts(tmp_path):
             assert "FIRING ttft_regression" in text
 
             # -- storms exhaust → both RESOLVE
-            deadline = time.monotonic() + 300.0
+            deadline = time.monotonic() + 120.0
             while time.monotonic() < deadline:
                 firing = {(a["rule"], a["host"])
                           for a in engine.firing()}
                 if (("loss_spike", trainer_host) not in firing
-                        and ("ttft_regression", "host1") not in firing):
+                        and ("ttft_regression", "host1") not in firing
+                        and not gauge("loss_spike")
+                        and not gauge("ttft_regression")):
                     break
-                time.sleep(0.5)
+                time.sleep(0.1)
             firing = {(a["rule"], a["host"]) for a in engine.firing()}
             assert ("loss_spike", trainer_host) not in firing, firing
             assert ("ttft_regression", "host1") not in firing, firing

@@ -13,8 +13,6 @@ exist under:
   bit-for-bit under shared draws (the deterministic subset — RandAugment
   shares the op space, not the pixels, and is only required to be
   jit-clean and rng-deterministic).
-
-Late-alphabet filename per the 870s tier-1 prefix cap.
 """
 
 import os
@@ -22,6 +20,7 @@ import sys
 
 import numpy as np
 import pytest
+from tiny import set_flags
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -437,17 +436,11 @@ def test_pack_dataset_cli_smoke_and_two_step_train(tmp_path):
     rc = train.main([
         "--config", "resnet18_cifar10", "--steps", "2",
         "--resume", "none",
-        "--set", "data.dataset=packed_images",
-        "--set", f"data.data_dir={out}",
-        "--set", "data.batch_size=8",
-        "--set", "data.device_augment=true",
-        "--set", "data.mp_workers=2",
-        "--set", "model.image_size=16",
-        "--set", "model.num_classes=2",
-        "--set", "obs.log_every_steps=1",
-        "--set", f"checkpoint.dir={tmp_path}/run",
-        "--set", "checkpoint.save_every_steps=0",
-        "--set", "checkpoint.async_save=false",
+        *set_flags("data.dataset=packed_images", f"data.data_dir={out}",
+                   "data.batch_size=8", "data.device_augment=true",
+                   "data.mp_workers=2", "model.image_size=16",
+                   "model.num_classes=2", f"checkpoint.dir={tmp_path}/run",
+                   "checkpoint.save_every_steps=0"),
     ])
     assert rc == 0
     import json

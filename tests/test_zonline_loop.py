@@ -10,11 +10,7 @@ The slow acceptance drill additionally renders one cycle's trace with
 ``tools/timeline_report.py --traces <dir> --trace <id>`` and asserts
 the cross-process causal chain — rollout → train → publish → per-
 replica swap — with the old/new ``weight_version`` correlation tags
-visible on both the trainer and replica writers.
-
-Late-alphabet on purpose: the tier-1 870s cap only reaches an
-alphabetical prefix on this box, and early-alphabet files must stay
-fast (CHANGES PR 2/3)."""
+visible on both the trainer and replica writers."""
 
 import json
 import os

@@ -11,11 +11,7 @@ plumbing (docs/online_training.md, ISSUE 19).
 - GC keeps exactly ``KEEP_VERSIONS`` versions on the store.
 - ``WeightState``: stage/apply/busy/reject protocol, lag gauge.
 - ``group_advantages`` / ``to_grpo_batch`` layout, ``make_grpo_loss``
-  REINFORCE and clipped-ratio branches against a numpy oracle.
-
-Late-alphabet on purpose: the tier-1 870s cap only reaches an
-alphabetical prefix on this box, and early-alphabet files must stay
-fast (CHANGES PR 2/3)."""
+  REINFORCE and clipped-ratio branches against a numpy oracle."""
 
 import threading
 import time

@@ -2,8 +2,7 @@
 docs/performance.md): op-class classification, staged input-pipeline
 timers under BOTH host loaders, the analytic-vs-AOT FLOP cross-check,
 perf-ledger append/import/regression-gate, the kernel-gap audit, and
-the report/timeline surfaces. Late-alphabet file per the 870s tier-1
-alphabetical-prefix cap (CHANGES PR 2)."""
+the report/timeline surfaces."""
 
 import json
 import os

@@ -14,11 +14,7 @@ mid-epoch when the world changes.
   mid-epoch start_batch resume with a changed shard_count.
 - The 4→3 e2e drill: kill one host permanently; survivors re-rendezvous
   degraded, restore resharded, resume mid-epoch, and the loss
-  trajectory matches a fixed-3-host control run bit-exactly.
-
-Late-alphabet on purpose: the tier-1 870s cap only reaches an
-alphabetical prefix on this box, and early-alphabet files must stay
-fast (CHANGES PR 2/3)."""
+  trajectory matches a fixed-3-host control run bit-exactly."""
 
 import json
 import os
@@ -31,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from tiny import WORKER_HEAD, tiny_cfg
 
 from pytorch_distributed_train_tpu import steps as steps_lib
 from pytorch_distributed_train_tpu.checkpoint import CheckpointManager
@@ -400,25 +397,11 @@ def test_trainer_reshard_event_and_batch_guard(tmp_path, monkeypatch):
 
     monkeypatch.delenv("NUM_PROCESSES", raising=False)
     monkeypatch.delenv("PROCESS_ID", raising=False)
-    from pytorch_distributed_train_tpu.config import TrainConfig
-
-    cfg = TrainConfig()
-    cfg.model.name = "resnet18"
-    cfg.model.num_classes = 10
-    cfg.model.image_size = 8
-    cfg.data.dataset = "synthetic_images"
-    cfg.data.synthetic_size = 64
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.data.elastic_shards = True
-    cfg.optim.name = "momentum"
-    cfg.optim.schedule = "constant"
-    cfg.optim.warmup_steps = 0
-    cfg.total_steps = 3
-    cfg.checkpoint.dir = str(tmp_path / "ckpt")
-    cfg.checkpoint.save_every_steps = 2
-    cfg.checkpoint.async_save = False
-    cfg.obs.log_every_steps = 10
+    cfg = tiny_cfg(
+        "data.synthetic_size=64", "data.elastic_shards=true",
+        "optim.learning_rate=0.1", "total_steps=3",
+        f"checkpoint.dir={tmp_path}/ckpt", "checkpoint.save_every_steps=2",
+        "obs.log_every_steps=10")
     t = Trainer(cfg)
     t.fit()
     t.close()
@@ -487,30 +470,16 @@ def test_ckpt_inspect_mesh_feasibility(tmp_path, devices8):
 
 
 # --------------------------------------------------- e2e: 4 → 3 drill
-DRILL_WORKER = """
-import os, sys
-sys.path.insert(0, {repo!r})
-import jax
-jax.config.update("jax_platforms", "cpu")
-from pytorch_distributed_train_tpu.config import TrainConfig
-from pytorch_distributed_train_tpu.trainer import Trainer
-
-rank = int(os.environ["PROCESS_ID"])
-gen = os.environ.get("RESTART_GENERATION", "0")
+DRILL_WORKER = WORKER_HEAD + """
 control = os.environ.get("DRILL_CONTROL") == "1"
 out = {out!r}
-cfg = TrainConfig()
-cfg.model.name = "resnet18"; cfg.model.num_classes = 10
-cfg.model.image_size = 8
-cfg.data.dataset = "synthetic_images"; cfg.data.synthetic_size = 48
-cfg.data.batch_size = 12; cfg.data.num_workers = 1
+cfg.data.synthetic_size = 48
+cfg.data.batch_size = 12
 cfg.data.elastic_shards = True
-cfg.optim.name = "momentum"; cfg.optim.learning_rate = 0.05
-cfg.optim.schedule = "constant"; cfg.optim.warmup_steps = 0
 cfg.total_steps = 6
 cfg.checkpoint.save_every_steps = 2
+cfg.checkpoint.async_save = True
 cfg.checkpoint.tiered = True
-cfg.obs.log_every_steps = 1
 if control:
     cfg.checkpoint.dir = os.path.join(out, f"control-ckpt-{{rank}}")
     cfg.obs.jsonl_path = os.path.join(out, f"metrics-control-{{rank}}.jsonl")
@@ -518,9 +487,20 @@ else:
     cfg.checkpoint.dir = os.path.join(out, f"ckpt-{{rank}}")
     cfg.obs.jsonl_path = os.path.join(
         out, f"metrics-{{rank}}-gen{{gen}}.jsonl")
-    if rank == 3:
-        cfg.faults.inject = ("elastic.shrink@step=3",)  # gen 0 only
+    # generation 0 only: every survivor waits at its step 3 for node 3 to
+    # die at ITS step 3 (the agents' SIGTERM ends the wait; the delay is
+    # only its upper bound), and node 3 gives them 5 s at its step 2 to
+    # get there. The survivors then all force-save step 3: the restore
+    # takes the gang's newest step from the peer tier, which a control
+    # resuming from one rank's own copy can only follow when that rank
+    # has that step too.
+    cfg.faults.inject = (
+        ("step.straggle@step=2:count=1:delay=5", "elastic.shrink@step=3")
+        if rank == 3 else ("step.straggle@step=3:count=1:delay=60",))
 t = Trainer(cfg)
+if not control:  # the hosts enter fit together, whatever their start-up
+    from pytorch_distributed_train_tpu.elastic import worker_store
+    worker_store().barrier(f"built/{{gen}}", world, rank, timeout_ms=120000)
 t.fit()
 t.close()
 """
@@ -543,7 +523,7 @@ def test_shrink_4_to_3_resumes_bitexact_vs_control(tmp_path):
     )
 
     script = tmp_path / "worker.py"
-    script.write_text(DRILL_WORKER.format(repo=REPO, out=str(tmp_path)))
+    script.write_text(DRILL_WORKER.format(out=str(tmp_path)))
     with socket.socket() as s:
         s.bind(("", 0))
         port = s.getsockname()[1]
@@ -557,6 +537,10 @@ def test_shrink_4_to_3_resumes_bitexact_vs_control(tmp_path):
             nprocs=1, max_restarts=max_restarts, monitor_interval_s=0.1,
             nnodes=4, node_rank=node_rank, master_addr="127.0.0.1",
             store_port=port, min_nnodes=3, rendezvous_window_s=3.0,
+            # room for a survivor's force-save on a loaded box: the
+            # default 10 s was overrun one run in six, and a worker
+            # killed in its save leaves a torn step behind
+            shutdown_grace_s=60.0,
             backoff_base_s=0.05, backoff_max_s=0.1, env=env,
             events_dir=events_dir)
         rcs[node_rank] = ElasticAgent(

@@ -4,8 +4,7 @@ survivor, session pinning, hedging of stragglers, rolling restart with
 zero failed requests, store-based replica discovery, and the ISSUE-7
 acceptance drill (subprocess replicas: injected slow decode → anomaly +
 fake profiler capture + hedging; SIGTERM → drain → failover; deadline →
-504 with slots reclaimed; timeline shows the chain). Late-alphabet file
-per the tier-1 870s alphabetical-prefix constraint."""
+504 with slots reclaimed; timeline shows the chain)."""
 
 import json
 import os
@@ -362,7 +361,6 @@ def _spawn_replica(tmp_path, name, *, faults="", extra_env=None,
            **(extra_env or {})}
     if faults:
         env["PDTT_FAULTS"] = faults
-    env.pop("PDTT_TEST_DUMP_AFTER_S", None)
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tools", "serve_http.py"),
          "--fake-backend", "--fake-step-delay", "0.01", "--port", "0",

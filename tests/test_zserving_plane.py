@@ -2,8 +2,7 @@
 admission control, deadlines + 504 slot reclaim, the abandoned-stream
 slot-leak fix and its `serve.slot_leak` drill, tail-latency anomalies
 firing the (fake) managed profiler, the /healthz reliability surface,
-and the seeded SLO soak smoke. Late-alphabet file per the tier-1 870s
-alphabetical-prefix constraint (CHANGES PR 2)."""
+and the seeded SLO soak smoke."""
 
 import json
 import os

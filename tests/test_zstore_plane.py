@@ -8,8 +8,7 @@ all-stale signature), the store_degraded alert + fleet_stale hold, the
 controller's observe-only store latch, and the offline console /
 report / timeline surfaces. The end-to-end blackout drills (training
 gang + serving router, tools/store_outage_drill.py) ride along as slow
-tests. Late-alphabet file per the tier-1 870s alphabetical-prefix
-budget."""
+tests."""
 
 import json
 import os

@@ -4,9 +4,7 @@ hold-while-blocking, teardown unjoined-thread check, the deadlock
 watchdog's cycle naming + all-stack dump, journal/metric plumbing, a
 seeded inversion between LIVE components, the runtime-graph dump and
 the `--compare-runtime` static-vs-runtime diff, and (slow) the PR 7
-SLO soak under PDTT_SANITIZE=1 asserting zero findings end-to-end.
-Late-alphabet file per the tier-1 870s alphabetical-prefix constraint
-(CHANGES PR 2)."""
+SLO soak under PDTT_SANITIZE=1 asserting zero findings end-to-end."""
 
 import io
 import json

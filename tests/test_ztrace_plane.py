@@ -5,8 +5,7 @@ layer and THE acceptance drill — a hedged slow request under a
 serve.slow_decode storm yields ONE trace id whose tree spans router
 attempt A (slow), hedge attempt B (winner), admission, queue, prefill
 and decode quanta across two replica processes, while a fast healthy
-request under default knobs is NOT retained. Late-alphabet file per the
-tier-1 870s alphabetical-prefix constraint (CHANGES PR 2)."""
+request under default knobs is NOT retained."""
 
 import json
 import os
@@ -488,7 +487,6 @@ def _spawn_replica(tmp_path, name, pid, *, faults=""):
            "PROCESS_ID": str(pid)}
     if faults:
         env["PDTT_FAULTS"] = faults
-    env.pop("PDTT_TEST_DUMP_AFTER_S", None)
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tools", "serve_http.py"),
          "--fake-backend", "--fake-step-delay", "0.01", "--port", "0",
