@@ -12,6 +12,11 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from pytorch_distributed_train_tpu.ops.lm_head import (
+    HeadOperands,
+    head_token_xent,
+)
+
 
 def softmax_xent(logits, batch, *_, label_smoothing: float = 0.0):
     """Classification loss. batch: {'image':…, 'label': (B,) int}.
@@ -64,13 +69,27 @@ def causal_lm_xent(logits, batch, *_):
 
     Shifts inside the loss (logits[:, :-1] vs ids[:, 1:]) so the data
     pipeline ships one tensor, as the reference's LM collate does.
+
+    ``logits`` may be the head's operands instead (ops/lm_head.py
+    ``HeadOperands``, which a training step asks of a model that offers
+    them): the per-token loss then comes from the kernels that compute the
+    head's product, over ALL B*S rows so that they tile evenly, the shift
+    a weight of 0 on each sequence's last position.
     """
     ids = batch["input_ids"]
-    logits = logits[:, :-1]
+    fused = isinstance(logits, HeadOperands)
+    if not fused:
+        logits = logits[:, :-1]
     targets = ids[:, 1:]
     weights = batch.get("loss_mask", jnp.ones_like(ids, jnp.float32))[:, 1:]
     weights = weights.astype(jnp.float32)
-    per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
+    if fused:
+        pad = ((0, 0), (0, 1))
+        per_tok = head_token_xent(logits, jnp.pad(targets, pad))
+        weights = jnp.pad(weights, pad)
+    else:
+        per_tok = optax.softmax_cross_entropy_with_integer_labels(
+            logits, targets)
     denom = jnp.maximum(weights.sum(), 1.0)
     loss = (per_tok * weights).sum() / denom
     return loss, {"perplexity": jnp.exp(jnp.minimum(loss, 20.0))}

@@ -211,6 +211,11 @@ class GPT2LMHead(nn.Module):
     # does): above 1 the tied table is read through _per_shard_table and
     # its gradient is all-reduced once, not once a use.
     tied_shards: int = 1
+    # The training step asks for the head's OPERANDS in place of the logits
+    # (steps.make_train_step sets it when its loss is causal_lm_xent and
+    # nothing else reads the logits; no option does): the final hidden
+    # states and the table, or the per-shard view (ops/lm_head.py).
+    head_operands: bool = False
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, loss_mask=None):
@@ -308,20 +313,12 @@ class GPT2LMHead(nn.Module):
             return chunked_causal_ce(x.astype(self.dtype), emb, input_ids,
                                      loss_mask=loss_mask,
                                      transpose_kernel=True)
-        with jax.named_scope("lm_head"):  # a phase of the step: steps.py
-            if shards > 1:  # batched over shards, each its own view
-                logits = jax.lax.dot_general(
-                    x.astype(self.dtype).reshape(shards, B // shards, S, -1),
-                    emb, (((3,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                ).reshape(B, S, -1)
-            else:
-                logits = jax.lax.dot_general(
-                    x.astype(self.dtype), emb,
-                    (((x.ndim - 1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-        return logits.astype(jnp.float32)
+        from pytorch_distributed_train_tpu.ops import lm_head
+
+        head = lm_head.HeadOperands(x.astype(self.dtype), emb, self.cp)
+        if self.head_operands and not self.decode:
+            return head
+        return lm_head.logits(head)
 
 
 def _per_shard_table(table, shards: int, cp):

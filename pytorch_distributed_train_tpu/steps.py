@@ -30,6 +30,7 @@ from pytorch_distributed_train_tpu.config import (  # noqa: F401
     LATENCY_HIDING_XLA_FLAGS,
     ensure_latency_hiding_flags,
 )
+from pytorch_distributed_train_tpu.ops import lm_head
 from pytorch_distributed_train_tpu.train_state import TrainState
 
 
@@ -210,6 +211,10 @@ def make_train_step(model, loss_fn: Callable, tx,
             "mirror — disable optim.ema_decay/swa_start_step or the "
             "fused epilogue")
 
+    model, keeps_logits = _head_loss_plan(model, loss_fn, teacher_fn)
+    # what the trace resolved: the trainer's train.compile span carries it
+    resolved = {"head_loss": "none"}
+
     def transform_batch(batch, dropout_rng):
         """Per-(micro)batch input transforms, same fold-in discipline
         in every path."""
@@ -240,13 +245,25 @@ def make_train_step(model, loss_fn: Callable, tx,
             if param_transform is not None:
                 p = param_transform(p)
             # Phase names (``grad_reduce`` and ``optimizer`` below, and
-            # ``lm_head`` in the model): they ride every device operation's
+            # ``lm_head`` in ops/lm_head.py): they ride every device op's
             # op_name, the backward pass as ``transpose(jvp(forward))``,
             # so a trace can be split by phase (docs/observability.md).
             with jax.named_scope("forward"):
                 logits, new_stats, model_aux, model_metrics = \
                     apply_model_metrics(model, p, stats, batch, train=True,
                                         dropout_rng=dropout_rng)
+            if isinstance(logits, lm_head.HeadOperands):
+                # the kernels of ops/lm_head_loss.py, or for what they
+                # cannot take the logits path from the same operands
+                why = lm_head.unsupported(logits)
+                resolved["head_loss"] = lm_head.log_resolution(logits, why)
+                if why is not None:
+                    with jax.named_scope("forward"):
+                        logits = lm_head.logits(logits)
+            elif (keeps_logits and getattr(logits, "ndim", 0) == 3
+                  and "input_ids" in batch):
+                resolved["head_loss"] = lm_head.log_resolution(
+                    logits, keeps_logits)
             with jax.named_scope("loss"):
                 loss, aux = loss_fn(logits, batch)
             aux = {**aux, **model_metrics}
@@ -433,7 +450,30 @@ def make_train_step(model, loss_fn: Callable, tx,
                 metrics[f"grad_norm/{key}"] = optax_global_norm(sub)
         return new_state, metrics
 
+    train_step.resolved = resolved
     return train_step
+
+
+def _head_loss_plan(model, loss_fn, teacher_fn):
+    """(the model the step applies, why its LM head keeps the logits path).
+    Read off what the step is built from, with no option to set: when the
+    loss is ``causal_lm_xent``, nothing else reads the logits and the model
+    offers its head's operands (a ``head_operands`` field: GPT-2 today),
+    the step asks for them, and the loss comes from the kernels that
+    compute the head's products (ops/lm_head_loss.py) wherever they can
+    take the traced shapes. The reason is None when the operands are asked
+    for, and for a step this plan has nothing to say about (another loss:
+    its model traces what it traced)."""
+    from pytorch_distributed_train_tpu.losses import causal_lm_xent
+
+    if teacher_fn is not None:
+        return model, "the loss's teacher term reads the logits"
+    if loss_fn is not causal_lm_xent:
+        return model, None
+    if not hasattr(model, "head_operands"):
+        return model, (f"{type(model).__name__} does not offer its head's "
+                       "operands")
+    return model.clone(head_operands=True), None
 
 
 def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,

@@ -438,6 +438,7 @@ class Trainer:
             fused_update=self.fused_update,
             reduce_grads=reduce_grads,
             reduce_metrics=reduce_metrics)
+        self._step_resolved = train_step.resolved
         if cfg.optim.offload_state:
             train_step = steps_lib.offload_opt_state(
                 train_step, opt_dev_sharding, self.state_sharding.opt_state)
@@ -979,6 +980,8 @@ class Trainer:
                             self.state, metrics = self.train_step(
                                 self.state, batch, self.step_rng
                             )
+                            if is_first:  # what the trace resolved to
+                                dispatch.args.update(self._step_resolved)
                         self._stepped = True
                         if inflate_loss:
                             # step.loss_spike drill: corrupt the OBSERVED

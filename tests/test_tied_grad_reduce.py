@@ -93,6 +93,56 @@ def test_the_table_crosses_the_devices_once_not_twice(devices8, plain):
                               ids) == 1
 
 
+def test_the_view_survives_the_heads_kernels(devices8, monkeypatch):
+    """The training step's head and loss in the kernels of
+    ops/lm_head_loss.py (a device on its own sequences and its own view,
+    inside a shard_map over the batch axes; the interpreter here): the
+    values are the logits path's, and the table still crosses the devices
+    once: the view's gradient leaves the region as per-shard partial
+    sums."""
+    from pytorch_distributed_train_tpu.losses import causal_lm_xent
+    from pytorch_distributed_train_tpu.ops import attention, lm_head
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lm_head, "_interpret", lambda: True)
+    wide = ModelConfig(name="gpt2", vocab_size=V, hidden_size=128,
+                       num_layers=1, num_heads=2, mlp_dim=64, max_seq_len=S,
+                       dropout_rate=0.0)
+    mesh_cfg = MeshConfig(data=8)
+    mesh = build_mesh(mesh_cfg, devices8)
+    model = build_model(wide, F32, mesh=mesh, mesh_cfg=mesh_cfg).clone(
+        tied_shards=8)
+    ids = _ids(64)  # 8 sequences of 16 a device: 128 rows a kernel call
+    params = model.init({"params": jax.random.PRNGKey(0)}, ids[:2],
+                        train=False)["params"]
+
+    def loss_of(m):
+        def loss(p, ids):
+            out = m.apply({"params": p}, ids, train=True)
+            return causal_lm_xent(out, {"input_ids": ids})[0]
+        return loss
+
+    def grad_fn(m):
+        rep = NamedSharding(mesh, P())
+        return jax.jit(jax.value_and_grad(loss_of(m)), in_shardings=(
+            rep, NamedSharding(mesh, P(("data",)))), out_shardings=rep)
+
+    want_loss, want = grad_fn(model)(params, ids)
+    asked = model.clone(head_operands=True)
+    assert "pallas_call" in str(jax.make_jaxpr(loss_of(asked))(params, ids))
+    fused = grad_fn(asked)
+    lowered = fused.lower(params, ids)
+    got_loss, got = fused(params, ids)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(want)[0],
+                            jax.tree_util.tree_leaves(got)):
+        np.testing.assert_allclose(
+            b, a, rtol=2e-4, atol=1e-7, err_msg=jax.tree_util.keystr(path))
+    text = lowered.compile().as_text()
+    assert sum(len(re.findall(rf"f32\[{V},128\]", m.group(1)))
+               for m in re.finditer(r"= ([^\n]*?) all-reduce\(", text)) == 1
+
+
 FALLBACKS = [
     ("batch-does-not-divide", dict(tied_shards=8), 4, True),
     ("fused-head-and-loss", dict(tied_shards=8, fused_loss=True), 16, True),

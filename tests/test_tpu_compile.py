@@ -24,6 +24,7 @@ the persistent compile cache stays off around them.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,37 @@ def _compile(fn, *args):
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     return compiled
+
+
+def _custom_calls(text):
+    """Trace names of a compiled program's custom calls, as the benchmark's
+    trace reader sees them: ``%attn.12 custom-call``."""
+    return [f"{m.group(1)} custom-call" for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*? custom-call\(", text, re.M)]
+
+
+def _assert_head_rides_its_products(text, pattern, n_flash, rows, vocab):
+    """What a compiled GPT-2 training step holds since the loss's passes
+    ride the head's products (ops/lm_head_loss.py): the flash kernels under
+    the names the benchmark's pattern finds, the head's two kernels under
+    names it does NOT match (a Pallas call takes its enclosing scope's
+    name; counted into `flash_attn_ms_per_step` they would halve
+    `flash_attn_roofline`), and no elementwise pass over an array of the
+    logits' shape."""
+    names = _custom_calls(text)
+    kernels = [n for n in names if re.search(pattern, n)]
+    assert len(kernels) == n_flash, kernels
+    head = [n for n in names if "lm_head" in n]
+    assert len(head) == 2 and not set(head) & set(kernels), head
+    assert any("lm_head_fwd" in n for n in head), head
+    assert any("lm_head_bwd" in n for n in head), head
+    logits = re.compile(
+        rf"\[(\d+,\d+,{vocab}|{rows},{vocab}|{vocab},{rows})\]")
+    loops = [ln.strip()[:160] for ln in text.splitlines()
+             if "kind=kLoop" in ln and logits.search(ln)
+             # a product's operand re-described inside its fusion: no pass
+             and "calls=%bitcast_fusion" not in ln]
+    assert not loops, loops
 
 
 def _attn_loss(q, k, v, **kw):
@@ -198,9 +230,10 @@ def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
     dQ, dK/dV) = 36 instructions whose trace names (`%attn.N custom-call`)
     match the configuration's `flash_kernel_pattern`. The benchmark's
     `flash_attn_ms_per_step` finds the kernel by that name alone, so a
-    `name=` on a `pallas_call` or a renamed module would zero the metric."""
+    `name=` on a `pallas_call` or a renamed module would zero the metric.
+    The cell's own settings (loss causal_lm_xent, no option) land on the
+    head's kernels: `_assert_head_rides_its_products`."""
     import json
-    import re
 
     from pytorch_distributed_train_tpu import losses as losses_lib
     from pytorch_distributed_train_tpu import steps as steps_lib
@@ -243,10 +276,10 @@ def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
     text = jax.jit(step, donate_argnums=(0,)).lower(
         described(jax.eval_shape(init, jax.random.PRNGKey(0))), batch,
         rng).compile().as_text()
-    names = [f"{m.group(1)} custom-call" for m in re.finditer(
-        r"^\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*? custom-call\(", text, re.M)]
-    kernels = [n for n in names if re.search(bench["flash_kernel_pattern"], n)]
-    assert len(kernels) == 3 * bench["n_layer"] == 36, kernels
+    assert 3 * bench["n_layer"] == 36
+    _assert_head_rides_its_products(
+        text, bench["flash_kernel_pattern"], 36,
+        cfg.data.batch_size * cfg.data.seq_len, cfg.model.vocab_size)
 
 
 def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
@@ -259,9 +292,10 @@ def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
     in float32, where the partitioner alone leaves two (the head's
     contribution and the lookup's). One chip of the same topology plans
     per_use, and jit_train_step passes jax.jit no compile options either
-    way (the 36-kernel test below compiles that step)."""
+    way (the 36-kernel test above compiles that step). The head's kernels
+    run inside a shard_map of their own, a chip on its view of the table:
+    the view, and the one all-reduce, survive them."""
     import json
-    import re
 
     from pytorch_distributed_train_tpu import losses as losses_lib
     from pytorch_distributed_train_tpu import steps as steps_lib
@@ -319,7 +353,11 @@ def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
                                sharding=NamedSharding(mesh, P()))
     text = step.lower(state, batch, rng).compile().as_text()
-    assert "tpu_custom_call" in text  # the flash kernel, under shard_map
+    # the flash kernels under shard_map (three a layer), the head's two
+    # beside them under their own names, a chip's 16 sequences each
+    _assert_head_rides_its_products(
+        text, bench["flash_kernel_pattern"], 3 * cfg.model.num_layers,
+        cfg.data.batch_size // 4 * cfg.data.seq_len, cfg.model.vocab_size)
     table = f"[{cfg.model.vocab_size},{cfg.model.hidden_size}]"
     reduced = [m.group(1) for m in re.finditer(
         r"= (\w+\[[\d,]*\])[^\n]*? all-reduce\(", text[text.index("\nENTRY"):])

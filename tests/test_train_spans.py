@@ -81,6 +81,15 @@ def test_the_compile_span_says_how_often_a_leaf_is_reduced(run):
     assert all("grad_reduce" not in s.args for s in named(run, "train.step"))
 
 
+def test_the_compile_span_says_what_took_the_head_and_its_loss(run):
+    """What the trace resolved the LM head and its loss to (ops/lm_head.py)
+    rides the same span: on the CPU the step keeps the logits path, and
+    the attribute carries the reason its `[lm_head]` line gave."""
+    (compile_span,) = named(run, "train.compile")
+    assert compile_span.args["head_loss"] == "xla: the backend is not a TPU"
+    assert all("head_loss" not in s.args for s in named(run, "train.step"))
+
+
 def test_cadenced_steps_carry_a_log_with_its_sync(run):
     logs = named(run, "train.log")
     assert [s.args["step"] for s in logs] == [4, 8, 9]  # cadence, horizon
