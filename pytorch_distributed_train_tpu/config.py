@@ -61,10 +61,17 @@ class ModelConfig:
     # stretches the usable context to rope_scaling x the pretrain length
     # (set max_seq_len accordingly; positions divide by the factor).
     rope_scaling: float = 1.0
-    # "linear" (positions divide by the factor; fine-tune for quality) or
+    # "linear" (positions divide by the factor; fine-tune for quality),
     # "ntk" (base rescales, high frequencies preserved; often works
-    # zero-shot) — models/llama.py rope_frequencies
+    # zero-shot) or "yarn" (hybrid_lm's full-attention layers: a ramp
+    # between kept and divided frequencies, from the four numbers below;
+    # rope_attention_factor 0 = 0.1 ln(rope_scaling) + 1) — models/llama.py
+    # rope_frequencies
     rope_scaling_type: str = "linear"
+    rope_original_max_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: float = 0.0
     rms_norm_eps: float = 1e-5
     # T5 family (models/t5.py): decoder stack depth (0 → = num_layers) and
     # the bucketed relative-position-bias geometry.
@@ -153,8 +160,25 @@ class ModelConfig:
     # beside one shared expert. There expert_capacity_factor bounds the
     # grouped product's rows: that many times the pairs uniform routing
     # would send to the held experts; a step past it keeps its old state
-    # and reports update_skipped.
+    # and reports update_skipped. moe_score is the router's rule:
+    # "sigmoid" (with the bias that only selection sees) or "softmax"
+    # (over all the experts, no bias leaf).
+    # layer_kinds names each layer's mixer where "the last of each group"
+    # does not say it: kda | mla | gqa_full | gqa_window, one a layer
+    # (empty: derived from layer_group_size). The two gqa kinds are
+    # grouped-query softmax attention over num_kv_heads KV heads with a
+    # per-head sigmoid gate, layer_heads[i] query heads on layer i (empty:
+    # num_heads everywhere): gqa_full is causal, rotated by rope_theta
+    # under rope_scaling / rope_scaling_type over the first
+    # partial_rotary_factor of each head; gqa_window sees the trailing
+    # attention_window keys and is rotated by window_rope_theta, plain,
+    # over the whole head.
     head_dim: int = 0
+    layer_kinds: tuple[str, ...] = ()
+    layer_heads: tuple[int, ...] = ()
+    partial_rotary_factor: float = 1.0
+    window_rope_theta: float = 10000.0
+    moe_score: str = "sigmoid"
     layer_group_size: int = 0
     first_dense_layers: int = 0
     kv_lora_rank: int = 512
@@ -1156,6 +1180,49 @@ def _ling3_flash_lm_ep64() -> TrainConfig:
     return c
 
 
+def _laguna_s_lm_ep32() -> TrainConfig:
+    """One chip's share of Laguna-S-2.1's language model (poolside,
+    https://huggingface.co/poolside/Laguna-S-2.1 config.json): every width
+    as published; layers 0-4 of the 48 (a full-attention layer with the
+    dense FFN, three window-512 layers and a full one with expert FFNs:
+    the published 3 : 1 after the dense layer), 72 query heads on window
+    layers and 48 on full ones over 8 KV heads of 128, a per-head gate,
+    YaRN on half a head (full) and plain rope (window); 8 of each layer's
+    256 routed experts, as one of 32 expert-parallel chips holds them,
+    softmax scores, 10 a token; an eighth of the 100352-row vocabulary.
+    811 M parameters, 12.98 GB with AdamW's float32 state
+    (benchmark/configs/laguna_s_lm_ep32.json says what was assumed)."""
+    c = TrainConfig(preset="laguna_s_lm_ep32")
+    c.model = ModelConfig(
+        name="hybrid_lm", hidden_size=3072, num_layers=5, num_heads=48,
+        num_kv_heads=8, head_dim=128, mlp_dim=12288, vocab_size=12544,
+        max_seq_len=8192, rms_norm_eps=1e-6, remat=True,
+        layer_kinds=("gqa_full", "gqa_window", "gqa_window", "gqa_window",
+                     "gqa_full"),
+        layer_heads=(48, 72, 72, 72, 48), attention_window=512,
+        rope_theta=5e5, rope_scaling=128.0, rope_scaling_type="yarn",
+        rope_original_max_len=8192, rope_beta_fast=32.0, rope_beta_slow=1.0,
+        rope_attention_factor=1.4852030263919618, partial_rotary_factor=0.5,
+        window_rope_theta=1e4, first_dense_layers=1,
+        num_experts=256, expert_top_k=10, moe_score="softmax",
+        moe_routed_scale=2.5, moe_mlp_dim=1024, experts_held=8,
+        experts_held_first=0, expert_capacity_factor=4.0,
+    )
+    # 4096 synthetic sequences of 8192 tokens, as the other 8k preset
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=1, seq_len=8192,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        decay_exclude=r"scale$",  # the matrices and the embedding decay
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    c.total_steps = 500000
+    c.loss = "causal_lm_xent"
+    return c
+
+
 def _t5_small() -> TrainConfig:
     """T5-small seq2seq pretrain (model-zoo extension beyond the BASELINE
     matrix). HF-layout-compatible via interop's 't5' mapping
@@ -1193,6 +1260,7 @@ _PRESETS = {
     "t5_small": _t5_small,
     "mixtral_8x7b": _mixtral_8x7b,
     "ling3_flash_lm_ep64": _ling3_flash_lm_ep64,
+    "laguna_s_lm_ep32": _laguna_s_lm_ep32,
 }
 
 

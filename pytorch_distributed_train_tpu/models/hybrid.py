@@ -1,23 +1,30 @@
 """A decoder whose layers differ in kind by a pattern (training path).
 
-Layers come in groups of ``layer_group_size``: the last of each group mixes
-tokens by latent attention (MLA, DeepSeek-V2 arXiv:2405.04434 section 2.1,
-expanded form), the others by Kimi Delta Attention (a gated delta rule with
-a per-channel decay, arXiv:2510.26692; ``ops/kda.py``). The first
-``first_dense_layers`` layers carry a dense SwiGLU FFN, the rest one chip's
-share of a routed expert layer with a shared expert (``ops/moe.py``
-``HeldExpertsMLP``). Pre-norm residual blocks, RMSNorm, untied head, no
-dropout, no auxiliary loss. This is the layout of the Ling-3.0-flash family's
-language model (preset ``ling3_flash_lm_ep64``); the equations are written
-out beside each module and in ``benchmark/references/ling3_flash_lm_ep64.py``,
-which shares no code with this file.
+Each layer's mixer has a kind (``KINDS``, one a layer): ``kda`` is Kimi
+Delta Attention (a gated delta rule with a per-channel decay,
+arXiv:2510.26692; ``ops/kda.py``), ``mla`` latent attention (DeepSeek-V2
+arXiv:2405.04434 section 2.1, expanded form), ``gqa_full`` and
+``gqa_window`` grouped-query softmax attention whose query heads, window
+and rotation differ by kind. The first ``first_dense_layers`` layers carry
+a dense SwiGLU FFN, the rest one chip's share of a routed expert layer with
+a shared expert (``ops/moe.py`` ``HeldExpertsMLP``). Pre-norm residual
+blocks, RMSNorm, untied head, no dropout, no auxiliary loss. Two families'
+language models are laid out so: Ling-3.0-flash (preset
+``ling3_flash_lm_ep64``: groups of ``layer_group_size``, the last of each
+``mla``, the others ``kda``) and Laguna-S (preset ``laguna_s_lm_ep32``:
+``layer_kinds`` a layer, one ``gqa_full`` to three ``gqa_window``); the
+equations are written out beside each module and in
+``benchmark/references/<preset>.py``, which share no code with this file.
 
-Only the training path exists: no cache, no decode (a latent entry and a
-recurrent state in one cache manager are ROADMAP R2/R7's serving halves).
+Only the training path exists: no cache, no decode (a latent entry, a
+recurrent state and a window's ring in one cache manager are ROADMAP
+R2/R7's serving halves).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import sys
 from functools import partial
 
 import flax.linen as nn
@@ -41,6 +48,7 @@ from pytorch_distributed_train_tpu.ops.moe import (
 )
 
 _INIT = nn.initializers.normal(0.02)
+KINDS = ("kda", "mla", "gqa_full", "gqa_window")
 _F32_OUT = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
 
 
@@ -180,13 +188,77 @@ class MLAMixer(nn.Module):
             name="o_proj")(y.astype(self.dtype))
 
 
+@dataclasses.dataclass(frozen=True)
+class Rotation:
+    """One kind's rotary tables: ``rope_frequencies``' arguments, the
+    rotated width first (below the head's: the first dims rotate, the
+    rest pass)."""
+
+    width: int
+    theta: float
+    scaling: float = 1.0
+    scaling_type: str = "linear"
+    original_max_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
+
+    def tables(self, seq_len: int):
+        return rope_frequencies(
+            self.width, seq_len, self.theta, self.scaling, self.scaling_type,
+            original_max_len=self.original_max_len,
+            beta_fast=self.beta_fast, beta_slow=self.beta_slow,
+            attention_factor=self.attention_factor)
+
+
+class GQAMixer(nn.Module):
+    """Grouped-query softmax attention with a per-head gate: q = W_q x
+    (``num_heads`` x d), k, v = W_k x, W_v x (``num_kv_heads`` x d); query
+    head h reads KV head h // (num_heads / num_kv_heads); q and k rotated
+    by ``rotation``; scores q k^T / sqrt(d), softmax in float32 over the
+    keys j <= i, and with ``window`` > 0 also i - j < window; each head's
+    output times sigmoid(W_g x)_h; W_o. No q/k norm, no bias."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: int
+    rotation: Rotation
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+    cp: ContextParallelConfig | None = None
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, x):
+        proj = lambda heads, name: nn.DenseGeneral(  # noqa: E731
+            (heads, self.head_dim), axis=-1, use_bias=False,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            kernel_init=_INIT, name=name)(x)
+        cos, sin = self.rotation.tables(x.shape[1])
+        y = dot_product_attention(
+            apply_rope(proj(self.num_heads, "q_proj"), cos, sin),
+            apply_rope(proj(self.num_kv_heads, "k_proj"), cos, sin),
+            proj(self.num_kv_heads, "v_proj"), causal=True,
+            window=self.window, cp=self.cp, impl=self.attn_impl)
+        y = y.astype(jnp.float32) * _head_gate(x, self.num_heads, self.dtype,
+                                               self.param_dtype)
+        return nn.DenseGeneral(
+            x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=_INIT,
+            name="o_proj")(y.astype(self.dtype))
+
+
 class HybridBlock(nn.Module):
     """x + mixer(norm x), then x + ffn(norm x). Returns (x, moe stats): the
-    expert layer's three row counts, zeros for a dense layer."""
+    expert layer's three row counts, zeros for a dense layer. The mixer's
+    module carries its kind's name (``kda``, ``mla``, ``gqa`` for
+    ``gqa_full``, ``swa`` for ``gqa_window``): a device trace names the
+    attention kernel's events by it."""
 
-    latent: bool         # this layer's mixer: latent attention, else KDA
+    kind: str            # this layer's mixer, one of KINDS
     moe: HeldExpertsSpec | None
-    num_heads: int
+    num_heads: int       # this layer's query heads
     head_dim: int
     mlp_dim: int
     moe_mlp_dim: int
@@ -201,17 +273,27 @@ class HybridBlock(nn.Module):
     param_dtype: jnp.dtype
     cp: ContextParallelConfig | None = None
     attn_impl: str = "auto"
+    num_kv_heads: int = 0
+    window: int = 0
+    rotation: Rotation | None = None   # the gqa kinds'
 
     @nn.compact
     def __call__(self, x):
         h = RMSNorm(self.rms_norm_eps, name="input_norm")(x)
-        if self.latent:
+        if self.kind in ("gqa_full", "gqa_window"):
+            mixed = GQAMixer(
+                self.num_heads, self.num_kv_heads, self.head_dim,
+                self.window, self.rotation, self.dtype, self.param_dtype,
+                cp=self.cp, attn_impl=self.attn_impl,
+                name="gqa" if self.kind == "gqa_full" else "swa")(h)
+        elif self.kind == "mla":
             mixed = MLAMixer(
                 self.num_heads, self.head_dim, self.rope_head_dim,
                 self.kv_lora_rank, self.rope_theta, self.max_seq_len,
                 self.rms_norm_eps, self.dtype, self.param_dtype, cp=self.cp,
                 attn_impl=self.attn_impl, name="mla")(h)
         else:
+            assert self.kind == "kda", self.kind
             mixed = KDAMixer(
                 self.num_heads, self.head_dim, self.conv_kernel_size,
                 self.kda_gate_lower_bound, self.rms_norm_eps, self.dtype,
@@ -239,12 +321,11 @@ class HybridLM(nn.Module):
 
     vocab_size: int
     hidden_size: int
-    num_layers: int
-    num_heads: int
+    layer_kinds: tuple[str, ...]   # one of KINDS a layer
+    layer_heads: tuple[int, ...]   # query heads a layer
     head_dim: int
     mlp_dim: int
     moe_mlp_dim: int
-    layer_group_size: int
     first_dense_layers: int
     moe: HeldExpertsSpec | None
     rope_head_dim: int = 64
@@ -254,6 +335,10 @@ class HybridLM(nn.Module):
     conv_kernel_size: int = 4
     kda_gate_lower_bound: float = -5.0
     rms_norm_eps: float = 1e-6
+    num_kv_heads: int = 0
+    window: int = 0                       # gqa_window's
+    full_rotation: Rotation | None = None    # gqa_full's
+    window_rotation: Rotation | None = None  # gqa_window's
     remat: bool = False
     remat_policy: str = "full"
     dtype: jnp.dtype = jnp.float32
@@ -261,10 +346,6 @@ class HybridLM(nn.Module):
     cp: ContextParallelConfig | None = None
     attn_impl: str = "auto"
     act: "object | None" = None
-
-    def is_latent(self, i: int) -> bool:
-        return self.layer_group_size > 0 \
-            and (i + 1) % self.layer_group_size == 0
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, loss_mask=None):
@@ -278,16 +359,20 @@ class HybridLM(nn.Module):
             name="tok_embed")(input_ids).astype(self.dtype))
         block_cls = remat_block(HybridBlock, self.remat, self.remat_policy)
         stats = []
-        for i in range(self.num_layers):
+        for i, kind in enumerate(self.layer_kinds):
             moe = self.moe if i >= self.first_dense_layers else None
+            windowed = kind == "gqa_window"
             x, layer_stats = block_cls(
-                self.is_latent(i), moe, self.num_heads, self.head_dim,
+                kind, moe, self.layer_heads[i], self.head_dim,
                 self.mlp_dim, self.moe_mlp_dim, self.rope_head_dim,
                 self.kv_lora_rank, self.rope_theta, self.max_seq_len,
                 self.conv_kernel_size, self.kda_gate_lower_bound,
                 self.rms_norm_eps, self.dtype, self.param_dtype,
                 cp=self.cp, attn_impl=self.attn_impl,
-                name=f"layer{i}")(x)
+                num_kv_heads=self.num_kv_heads,
+                window=self.window if windowed else 0,
+                rotation=self.window_rotation if windowed
+                else self.full_rotation, name=f"layer{i}")(x)
             x = constrain(x)
             if moe is not None:
                 stats.append(layer_stats)
@@ -308,29 +393,80 @@ class HybridLM(nn.Module):
         return logits.astype(jnp.float32)
 
 
+def layer_kinds(cfg) -> tuple[str, ...]:
+    """Each layer's mixer kind: ``cfg.layer_kinds`` where it is given, else
+    groups of ``layer_group_size`` whose last is ``mla``, the others
+    ``kda``."""
+    if cfg.layer_kinds:
+        kinds = tuple(cfg.layer_kinds)
+        bad = sorted(set(kinds) - set(KINDS))
+        if bad or len(kinds) != cfg.num_layers:
+            raise ValueError(
+                f"model.layer_kinds must name one of {KINDS} for each of "
+                f"the {cfg.num_layers} layers, got {kinds}")
+        return kinds
+    g = cfg.layer_group_size
+    return tuple("mla" if g > 0 and (i + 1) % g == 0 else "kda"
+                 for i in range(cfg.num_layers))
+
+
+def layer_heads(cfg) -> tuple[int, ...]:
+    """Each layer's query heads: ``cfg.layer_heads`` where given (an
+    override hands them over as strings), else ``num_heads`` everywhere."""
+    heads = tuple(int(h) for h in cfg.layer_heads) \
+        or (cfg.num_heads,) * cfg.num_layers
+    if len(heads) != cfg.num_layers:
+        raise ValueError(f"model.layer_heads must give {cfg.num_layers} "
+                         f"layers' heads, got {heads}")
+    return heads
+
+
+_built_logged: set[tuple] = set()
+
+
 def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
     moe = None
     if cfg.num_experts > 1:
         moe = HeldExpertsSpec(
             num_experts=cfg.num_experts, top_k=cfg.expert_top_k,
             n_groups=cfg.moe_groups, topk_groups=cfg.moe_topk_groups,
-            routed_scale=cfg.moe_routed_scale,
+            routed_scale=cfg.moe_routed_scale, score=cfg.moe_score,
             held_first=cfg.experts_held_first, held=cfg.experts_held,
             capacity_factor=cfg.expert_capacity_factor)
+    kinds, heads = layer_kinds(cfg), layer_heads(cfg)
+    head_dim = cfg.head_dim or cfg.hidden_size // cfg.num_heads
+    kv_heads = cfg.num_kv_heads or cfg.num_heads
+    if "gqa_window" in kinds and cfg.attention_window <= 0:
+        raise ValueError("a gqa_window layer needs "
+                         "model.attention_window > 0")
+    said = (kinds, heads, kv_heads, cfg.attention_window)
+    if said not in _built_logged:  # once a layout, on stderr
+        _built_logged.add(said)
+        print(f"[hybrid] layers={len(kinds)} kinds={','.join(kinds)} "
+              f"heads={','.join(map(str, heads))} kv_heads={kv_heads} "
+              f"window={cfg.attention_window} "
+              f"dense_layers={cfg.first_dense_layers}", file=sys.stderr,
+              flush=True)
     return HybridLM(
         cp=cp, act=act, moe=moe,
         attn_impl=getattr(cfg, "attention_impl", "auto"),
         vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
-        num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-        head_dim=cfg.head_dim or cfg.hidden_size // cfg.num_heads,
+        layer_kinds=kinds, layer_heads=heads, head_dim=head_dim,
         mlp_dim=cfg.mlp_dim, moe_mlp_dim=cfg.moe_mlp_dim,
-        layer_group_size=cfg.layer_group_size,
         first_dense_layers=cfg.first_dense_layers,
         rope_head_dim=cfg.rope_head_dim, kv_lora_rank=cfg.kv_lora_rank,
         rope_theta=cfg.rope_theta, max_seq_len=cfg.max_seq_len,
         conv_kernel_size=cfg.conv_kernel_size,
         kda_gate_lower_bound=cfg.kda_gate_lower_bound,
-        rms_norm_eps=cfg.rms_norm_eps, remat=cfg.remat,
-        remat_policy=getattr(cfg, "remat_policy", "full"),
+        rms_norm_eps=cfg.rms_norm_eps,
+        # the grouped-query kinds' (no other kind reads them)
+        num_kv_heads=kv_heads, window=cfg.attention_window,
+        full_rotation=Rotation(
+            int(head_dim * cfg.partial_rotary_factor), cfg.rope_theta,
+            cfg.rope_scaling, cfg.rope_scaling_type,
+            cfg.rope_original_max_len, cfg.rope_beta_fast,
+            cfg.rope_beta_slow, cfg.rope_attention_factor),
+        window_rotation=Rotation(head_dim, cfg.window_rope_theta),
+        remat=cfg.remat, remat_policy=getattr(cfg, "remat_policy", "full"),
         dtype=dtype, param_dtype=param_dtype,
     )

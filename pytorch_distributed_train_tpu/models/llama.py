@@ -20,6 +20,7 @@ TPU-first notes:
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import flax.linen as nn
@@ -46,39 +47,73 @@ class RMSNorm(nn.Module):
 
 def rope_frequencies(head_dim: int, max_seq_len: int, theta: float,
                      scaling: float = 1.0,
-                     scaling_type: str = "linear") -> tuple:
-    """Precompute cos/sin tables (S, head_dim/2) in fp32.
+                     scaling_type: str = "linear", *,
+                     original_max_len: int = 0, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0,
+                     attention_factor: float = 0.0) -> tuple:
+    """Precompute cos/sin tables (S, head_dim/2) in fp32. ``head_dim`` is
+    the ROTATED width: below the head's own, :func:`apply_rope` rotates
+    the head's first dims and passes the rest (``partial_rotary_factor``).
 
     ``scaling`` > 1 stretches the usable context to scaling x the
-    pretrain length, two recipes (HF rope_scaling types):
+    pretrain length, three recipes (HF rope_scaling types):
     - "linear" (Chen et al. 2023): positions divide by the factor —
       rope(t, scaling=k) == rope(t/k) exactly; uniform compression.
     - "ntk" (NTK-aware, bloc97 2023 / HF "dynamic" at fixed factor):
       the BASE rescales (theta' = theta * k^(D/(D-2))) so the lowest
       frequencies stretch ~k x while the highest (local-order
       resolution) stay nearly untouched — often usable without any
-      fine-tuning, unlike linear."""
-    if scaling_type not in ("linear", "ntk"):
+      fine-tuning, unlike linear.
+    - "yarn" (Peng et al. 2023, arXiv:2309.00071; HF's
+      ``_compute_yarn_parameters``): pair i of frequency f_i keeps f_i
+      where it turns more than ``beta_fast`` times over the
+      ``original_max_len`` positions of pre-training, takes f_i / k
+      where it turns fewer than ``beta_slow`` times, and a linear ramp
+      between the two correction dims (floor and ceiling, clamped to
+      the rotated width); cos and sin are scaled by
+      ``attention_factor`` (0: 0.1 ln k + 1)."""
+    if scaling_type not in ("linear", "ntk", "yarn"):
         raise ValueError(
-            f"rope_scaling_type must be 'linear' or 'ntk', got "
+            f"rope_scaling_type must be 'linear', 'ntk' or 'yarn', got "
             f"{scaling_type!r}")
     if scaling_type == "ntk" and scaling != 1.0:
         theta = theta * scaling ** (head_dim / (head_dim - 2))
         scaling = 1.0  # positions stay integral; the base does the work
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling_type == "yarn":
+        if original_max_len <= 0:
+            raise ValueError("yarn needs rope_original_max_len > 0")
+
+        def correction_dim(turns):  # the pair that turns this often
+            return head_dim * math.log(
+                original_max_len / (turns * 2 * math.pi)) \
+                / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(beta_fast)), 0)
+        high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        inv_freq = inv_freq / scaling * ramp + inv_freq * (1.0 - ramp)
+        freqs = jnp.outer(jnp.arange(max_seq_len, dtype=jnp.float32),
+                          inv_freq)
+        factor = attention_factor or 0.1 * math.log(scaling) + 1.0
+        return jnp.cos(freqs) * factor, jnp.sin(freqs) * factor
     t = jnp.arange(max_seq_len, dtype=jnp.float32) / scaling
     freqs = jnp.outer(t, inv_freq)  # (S, D/2)
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
-    """x: (B, S, H, D). Rotates pairs (x[..., :D/2], x[..., D/2:]) — the
-    'split-half' convention (matches HF Llama, so checkpoints interop)."""
+    """x: (B, S, H, D). Rotates pairs (x[..., :R/2], x[..., R/2:R]) — the
+    'split-half' convention (matches HF Llama, so checkpoints interop) —
+    over the first R = 2 x the tables' width dims; dims past R pass."""
     B, S, H, D = x.shape
-    x1, x2 = x[..., : D // 2], x[..., D // 2:]
+    R = 2 * cos.shape[-1]
+    x1, x2 = x[..., : R // 2], x[..., R // 2: R]
     cos = cos[None, :S, None, :].astype(x.dtype)
     sin = sin[None, :S, None, :].astype(x.dtype)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+                           + ([x[..., R:]] if R < D else []), axis=-1)
 
 
 def packed_segments(input_ids: jnp.ndarray, eos_id: int):

@@ -23,10 +23,11 @@ design is the GShard/Switch recipe, shaped for the MXU and GSPMD:
 Two layers live here (ROADMAP design debt "two dispatches in ops/moe.py"):
 :class:`MoeMLP` above, which holds every expert and drops overflow, and
 :class:`HeldExpertsMLP`, one chip's share of an expert-parallel layer: it
-routes over ALL the layer's experts (sigmoid scores, a selection-only bias,
-group-limited top-k, weights normalised over every chosen expert), is told
-which experts it holds, and computes their part of the result with one
-grouped product over the (token, choice) pairs that fall on them, by expert.
+routes over ALL the layer's experts (sigmoid scores with a selection-only
+bias, or softmax scores with none; group-limited top-k, weights normalised
+over every chosen expert), is told which experts it holds, and computes
+their part of the result with one grouped product over the (token, choice)
+pairs that fall on them, by expert.
 No token is dropped silently: pairs past its static row bound are counted,
 and the model hands the count to the train step as ``update_invalid``, so
 such a step keeps its old state and reports ``update_skipped``.
@@ -219,6 +220,9 @@ class HeldExpertsSpec:
     n_groups: int = 1         # experts are split into this many groups ...
     topk_groups: int = 1      # ... of which a token may use this many
     routed_scale: float = 1.0
+    # the router's score rule: "sigmoid" (an expert's own score, and a bias
+    # that only selection sees) or "softmax" (over every expert, no bias)
+    score: str = "sigmoid"
     held_first: int = 0
     held: int = 0             # 0 -> all of them
     # rows of the grouped product = this x the pairs expected on the held
@@ -241,14 +245,15 @@ class HeldExpertsSpec:
 
 def group_limited_topk(scores, bias, spec: HeldExpertsSpec):
     """DeepSeek-V3's rule (arXiv:2412.19437, 2.1.2) on float32 ``scores``
-    (N, E) in (0, 1): the selection score is scores + bias; a group's score
-    is the sum of its two largest; the ``topk_groups`` best groups stay; of
-    their experts the ``top_k`` largest are chosen. The weights use the
-    scores WITHOUT the bias, normalised over all chosen, times
+    (N, E) in (0, 1): the selection score is scores + bias (the scores
+    alone where ``bias`` is None); a group's score is the sum of its two
+    largest; the ``topk_groups`` best groups stay; of their experts the
+    ``top_k`` largest are chosen (one group: plain top-k). The weights use
+    the scores WITHOUT the bias, normalised over all chosen, times
     ``routed_scale``. Returns (ids (N, k) int32, weights (N, k) float32);
     the ids carry no gradient, so the bias gets none."""
     N, E = scores.shape
-    select = scores + bias
+    select = scores if bias is None else scores + bias
     if spec.n_groups > 1:
         per = E // spec.n_groups
         grouped = select.reshape(N, spec.n_groups, per)
@@ -307,8 +312,8 @@ def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int) -> None:
     last = spec.held_first + spec.n_held - 1
     print(f"[moe] experts={spec.num_experts} held={spec.n_held} "
           f"ids={spec.held_first}-{last} top_k={spec.top_k} "
-          f"groups={spec.n_groups}/{spec.topk_groups} tokens={n_tokens} "
-          f"row_bound={rows}", file=sys.stderr, flush=True)
+          f"groups={spec.n_groups}/{spec.topk_groups} score={spec.score} "
+          f"tokens={n_tokens} row_bound={rows}", file=sys.stderr, flush=True)
 
 
 class _Kernel(nn.Module):
@@ -354,21 +359,30 @@ class _ExpertBank(nn.Module):
 
 
 class _Router(nn.Module):
-    """Sigmoid scores over every expert of the layer, float32 throughout,
-    and the bias that only selection sees (drawn at std 0.01; it gets no
-    gradient and no decay, so it stays as initialised: the balancing
-    update that would move it is not part of this program)."""
+    """Scores over every expert of the layer, float32 throughout, by the
+    spec's rule. ``sigmoid``: each expert's own, and the bias that only
+    selection sees (drawn at std 0.01; it gets no gradient and no decay,
+    so it stays as initialised: the balancing update that would move it is
+    not part of this program). ``softmax``: over all the experts, and no
+    bias (no such leaf)."""
 
     num_experts: int
+    score: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router score {self.score!r}; "
+                             "have sigmoid | softmax")
         kernel = self.param("kernel", nn.initializers.normal(0.02),
                             (x.shape[-1], self.num_experts), jnp.float32)
         bias = self.param("bias", nn.initializers.normal(0.01),
-                          (self.num_experts,), jnp.float32)
+                          (self.num_experts,), jnp.float32) \
+            if self.score == "sigmoid" else None
         logits = jnp.matmul(x.astype(jnp.float32), kernel,
                             precision=jax.lax.Precision.HIGHEST)
+        if self.score == "softmax":
+            return jax.nn.softmax(logits, axis=-1), None
         return jax.nn.sigmoid(logits), bias
 
 
@@ -377,7 +391,8 @@ class HeldExpertsMLP(nn.Module):
     routed sum plus the shared expert. ``stats`` is float32 (3,): pairs on
     the fullest held expert, on the mean one, and past the row bound.
 
-    Param tree: router/{kernel (D, E), bias (E,)}; experts/<proj>/kernel
+    Param tree: router/{kernel (D, E), bias (E,): the sigmoid rule's};
+    experts/<proj>/kernel
     with a leading (held,) dim; shared/<proj>/kernel.
     """
 
@@ -394,7 +409,8 @@ class HeldExpertsMLP(nn.Module):
         rows = spec.row_bound(N)
         _log_plan(spec, N, rows)
         xf = x.reshape(N, D)
-        scores, bias = _Router(spec.num_experts, name="router")(xf)
+        scores, bias = _Router(spec.num_experts, spec.score,
+                               name="router")(xf)
         ids, weights = group_limited_topk(scores, bias, spec)
         token, weight, sizes, counts, over = held_rows(ids, weights, spec,
                                                        rows)

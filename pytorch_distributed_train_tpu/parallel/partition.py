@@ -257,7 +257,9 @@ def llama_pp_rules() -> list[tuple[str, PartitionSpec]]:
 
 def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
     """models/hybrid.py: the llama recipe over the new tree. Projections
-    keep heads on 'tensor' ((D, H, d) kernels, (H, d, D) for the outputs);
+    keep heads on 'tensor' ((D, H, d) kernels, (H, d, D) for the outputs;
+    the grouped-query kinds' modules are `gqa` and `swa`, their K and V
+    kernels carry the KV heads);
     the held experts' stacked kernels put their leading dim on 'expert';
     the latent down-projections, the router, the conv taps, A_log, dt_bias,
     the head gates and every norm replicate (small, or per-head vectors)."""
@@ -266,7 +268,9 @@ def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
         (r"kda/(q_proj|k_proj|v_proj|a_proj)/kernel$",
          P("fsdp", "tensor", None)),
         (r"mla/(q_proj|kv_up)/kernel$", P("fsdp", "tensor", None)),
-        (r"(kda|mla)/o_proj/kernel$", P("tensor", None, "fsdp")),
+        (r"(gqa|swa)/(q_proj|k_proj|v_proj)/kernel$",
+         P("fsdp", "tensor", None)),
+        (r"(kda|mla|gqa|swa)/o_proj/kernel$", P("tensor", None, "fsdp")),
         (r"mla/(kv_down|k_rope_proj)/kernel$", P("fsdp", None)),
         (r"experts/(gate_proj|up_proj)/kernel$",
          P("expert", "fsdp", "tensor")),

@@ -194,22 +194,36 @@ def gpt2_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     return cfg.num_layers * per_layer + 2.0 * d * cfg.vocab_size
 
 
+def band_pairs_per_token(seq: int, window: int = 0) -> float:
+    """Mean (query, key) pairs a token of a causal band: query i meets
+    keys j <= i, and with ``window`` > 0 only i - j < window:
+    sum_i min(i + 1, window) / seq."""
+    w = min(window, seq) if window > 0 else seq
+    return (w * (w + 1) / 2.0 + (seq - w) * w) / seq
+
+
 def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     """models/hybrid.py, what THIS chip computes a token: the matrices a
     token meets (of the routed experts only its expected share of the held
     ones: top_k x held / num_experts), the latent layers' scores and values
-    (un-masked convention, as the other rows here), and the delta rule's
-    chunk products (in-chunk tables and the three products with the state).
-    The Neumann inverse's small products are left out (under 1 %)."""
+    (un-masked convention, as the other rows here), the grouped-query
+    kinds' scores and values over the pairs of their band alone (causal,
+    and inside the window: the work done, at each layer's own heads), and
+    the delta rule's chunk products (in-chunk tables and the three products
+    with the state). The Neumann inverse's small products are left out
+    (under 1 %)."""
+    from pytorch_distributed_train_tpu.models.hybrid import (
+        layer_heads,
+        layer_kinds,
+    )
     from pytorch_distributed_train_tpu.ops.kda import DEFAULT_CHUNK
 
     s = seq or cfg.max_seq_len
     d, h = cfg.hidden_size, cfg.num_heads
     dh = cfg.head_dim or d // h
     dr, r, c = cfg.rope_head_dim, cfg.kv_lora_rank, min(DEFAULT_CHUNK, s)
-    n_latent = cfg.num_layers // cfg.layer_group_size \
-        if cfg.layer_group_size else 0
-    n_kda = cfg.num_layers - n_latent
+    hkv = cfg.num_kv_heads or h
+    kinds = layer_kinds(cfg)
     n_dense = min(cfg.first_dense_layers, cfg.num_layers)
     n_moe = cfg.num_layers - n_dense if cfg.num_experts > 1 else 0
     kda = (2.0 * d * h * dh * 5        # q, k, v, decay, output projections
@@ -219,11 +233,20 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     mla = (2.0 * d * h * (dh + dr) + 2.0 * d * (r + dr)
            + 2.0 * r * h * 2 * dh + 2.0 * h * dh * d + 2.0 * d * h
            + 2.0 * s * h * (dh + dr) + 2.0 * s * h * dh)
+
+    def gqa(heads, window):  # q and o, k and v, the gate; scores and values
+        return (4.0 * d * heads * dh + 4.0 * d * hkv * dh + 2.0 * d * heads
+                + 4.0 * heads * dh * band_pairs_per_token(s, window))
+
+    mixers = sum(
+        kda if kind == "kda" else mla if kind == "mla"
+        else gqa(heads, cfg.attention_window if kind == "gqa_window" else 0)
+        for kind, heads in zip(kinds, layer_heads(cfg)))
     expert = 6.0 * d * cfg.moe_mlp_dim
     held = cfg.experts_held or cfg.num_experts
     moe = (2.0 * d * cfg.num_experts + expert
            + expert * cfg.expert_top_k * held / max(cfg.num_experts, 1))
-    return (n_kda * kda + n_latent * mla + n_dense * 6.0 * d * cfg.mlp_dim
+    return (mixers + n_dense * 6.0 * d * cfg.mlp_dim
             + n_moe * moe + 2.0 * d * cfg.vocab_size)
 
 

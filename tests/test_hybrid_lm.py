@@ -198,15 +198,22 @@ def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
         >= float(metrics["moe_rows_mean"]) > 0
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """16 experts over 4 chips, 4 a chip: the routed parts that the four
-    shares compute, plus the shared expert ONCE, are the whole layer as a
-    plain loop over all 16 experts computes it."""
-    E, held, D, F, N = 16, 4, 16, 24, 48
+@pytest.mark.parametrize("E,held,score,kw", [
+    (16, 4, "sigmoid", dict(top_k=4, n_groups=4, topk_groups=2)),
+    (256, 8, "softmax", dict(top_k=10, n_groups=1, topk_groups=1)),
+], ids=["sigmoid-16-over-4-chips", "softmax-256-over-32-chips"])
+def test_the_shares_add_up_to_the_uncut_layer(E, held, score, kw):
+    """16 experts over 4 chips, 4 a chip, under the sigmoid rule with its
+    groups and bias; 256 experts over 32 chips, 8 a chip, 10 a token, under
+    the softmax rule with neither: the routed parts that all the shares
+    compute, plus the shared expert ONCE, are the whole layer as a plain
+    loop over all the experts computes it."""
+    D, F, N = 16, 24, 48
     ks = jax.random.split(jax.random.PRNGKey(7), 9)
     x = jax.random.normal(ks[0], (1, N, D))
-    router = {"kernel": 0.5 * jax.random.normal(ks[1], (D, E)),
-              "bias": 0.05 * jax.random.normal(ks[2], (E,))}
+    router = {"kernel": 0.5 * jax.random.normal(ks[1], (D, E))}
+    if score == "sigmoid":
+        router["bias"] = 0.05 * jax.random.normal(ks[2], (E,))
     full = {n: 0.3 * jax.random.normal(k, shape) for n, k, shape in (
         ("gate_proj", ks[3], (E, D, F)), ("up_proj", ks[4], (E, D, F)),
         ("down_proj", ks[5], (E, F, D)))}
@@ -214,11 +221,12 @@ def test_the_shares_add_up_to_the_uncut_layer():
               for n, k, shape in (("gate_proj", ks[6], (D, F)),
                                   ("up_proj", ks[7], (D, F)),
                                   ("down_proj", ks[8], (F, D)))}
-    kw = dict(num_experts=E, top_k=4, n_groups=4, topk_groups=2,
-              routed_scale=2.5, held=held)
+    kw = dict(num_experts=E, routed_scale=2.5, held=held, score=score, **kw)
     # the uncut layer, by hand
-    s = jax.nn.sigmoid(x[0] @ router["kernel"])
-    ids, w = moe.group_limited_topk(s, router["bias"], _spec(**kw))
+    logits = x[0] @ router["kernel"]
+    s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, -1)
+    ids, w = moe.group_limited_topk(s, router.get("bias"), _spec(**kw))
     dense_w = jnp.zeros((N, E)).at[jnp.arange(N)[:, None], ids].set(w)
     shared_out = _swiglu(x[0], *(shared[n]["kernel"] for n in (
         "gate_proj", "up_proj", "down_proj")))

@@ -1,0 +1,433 @@
+"""models/hybrid.py's grouped-query kinds (window and full attention mixed
+in one decoder, per-layer heads, a per-head gate), llama.py's YaRN tables
+and partial rotation, and ops/moe.py's softmax router, against plain
+``jax.numpy`` formulas, numbers worked by hand and the plain reference the
+benchmark keeps (benchmark/references/laguna_s_lm_ep32.py, which imports
+nothing of the program); and the other hybrid preset, whose parameter tree
+and lowered step this work must not move."""
+
+import hashlib
+import importlib.util
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_distributed_train_tpu.config import get_preset
+from pytorch_distributed_train_tpu.models import hybrid
+from pytorch_distributed_train_tpu.models.llama import (
+    apply_rope,
+    rope_frequencies,
+)
+from pytorch_distributed_train_tpu.models.registry import build_model
+from pytorch_distributed_train_tpu.ops import moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+F32 = jnp.float32
+YARN = dict(scaling=128.0, scaling_type="yarn", original_max_len=8192,
+            beta_fast=32.0, beta_slow=1.0,
+            attention_factor=1.4852030263919618)
+
+
+@pytest.fixture(autouse=True)
+def _exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """(configuration file, its Reference at the rehearsal's sizes, the
+    program's config at the same sizes)."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    with open(os.path.join(BENCH, "configs", "laguna_s_lm_ep32.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "laguna_reference", os.path.join(BENCH, "references",
+                                         config["reference"] + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    cfg = get_preset(config["preset"])
+    cfg.apply_overrides(config["rehearsal_overrides"])
+    return config, mod.Reference(config, rehearsal=True), cfg
+
+
+def _close(a, b, tol=2e-5):
+    scale = float(jnp.max(jnp.abs(b))) + 1e-30
+    assert float(jnp.max(jnp.abs(a - b))) < tol * scale
+
+
+# ------------------------------------------------ the rotation, by hand
+
+def test_yarn_frequencies_against_numbers_worked_by_hand():
+    """The published numbers on the 64 rotated dims: theta 5e5, factor 128
+    from 8192, beta 32 / 1. Correction dims 64 ln(8192 / (b 2 pi)) /
+    (2 ln 5e5): 9.04 at b = 32 (floor 9), 17.49 at b = 1 (ceiling 18).
+    Pairs 0-9 keep f_i = 5e5^(-i/32), pairs 18-31 take f_i / 128, between
+    them the ramp (i - 9) / 9 mixes the two."""
+    assert math.floor(64 * math.log(8192 / (32 * 2 * math.pi))
+                      / (2 * math.log(5e5))) == 9
+    assert math.ceil(64 * math.log(8192 / (2 * math.pi))
+                     / (2 * math.log(5e5))) == 18
+    cos, sin = rope_frequencies(64, 4, 5e5, **YARN)
+    assert cos.shape == sin.shape == (4, 32)
+    af = 1.4852030263919618
+    # position 0: cos = the attention factor, sin = 0
+    np.testing.assert_allclose(np.asarray(cos[0]), af, rtol=1e-6)
+    assert not np.any(np.asarray(sin[0]))
+    # position 1 holds each pair's frequency: angle = inv_freq
+    angle = np.arctan2(np.asarray(sin[1]), np.asarray(cos[1]))
+    # pair 0: f = 1 kept; pair 9: the last kept, 5e5^(-9/32) = 0.0249554
+    # pair 13: ramp 4/9: f = 5e5^(-13/32) = 0.00483942;
+    #   f/128 * 4/9 + f * 5/9 = 0.00270537
+    # pair 18: the first divided, 5e5^(-18/32) / 128 = 4.86541e-6
+    # pair 31: 5e5^(-31/32) / 128 = 2.35458e-8
+    for pair, want in ((0, 1.0), (9, 0.0249554), (13, 0.00270537),
+                       (18, 4.86541e-6), (31, 2.35458e-8)):
+        assert angle[pair] == pytest.approx(want, rel=2e-5), pair
+    # cos^2 + sin^2 is the attention factor squared at every position
+    np.testing.assert_allclose(np.asarray(cos ** 2 + sin ** 2), af * af,
+                               rtol=1e-5)
+    # the factor the config states is YaRN's own 0.1 ln(128) + 1
+    derived = rope_frequencies(64, 4, 5e5, **{**YARN, "attention_factor": 0.0})
+    np.testing.assert_allclose(np.asarray(derived[0]), np.asarray(cos),
+                               rtol=1e-6)
+    # the recipes that were there read what they read
+    plain = rope_frequencies(64, 4, 5e5)
+    assert float(plain[0][1, 13]) == pytest.approx(math.cos(0.00483942),
+                                                   rel=1e-6)
+    with pytest.raises(ValueError, match="yarn"):
+        rope_frequencies(64, 4, 5e5, 2.0, "nope")
+    with pytest.raises(ValueError, match="rope_original_max_len"):
+        rope_frequencies(64, 4, 5e5, 128.0, "yarn")
+
+
+def test_partial_rotation_rotates_the_first_half_and_passes_the_rest():
+    cos, sin = rope_frequencies(8, 6, 1e4)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 6, 2, 16))
+    y = apply_rope(x, cos, sin)
+    assert y.shape == x.shape
+    np.testing.assert_array_equal(np.asarray(y[..., 8:]),
+                                  np.asarray(x[..., 8:]))  # the other half
+    # split halves of the 8 rotated dims: pair (j, j + 4), angle t f_j
+    t, j = 5, 2
+    f = 1e4 ** (-2 * j / 8)
+    a, b = float(x[0, t, 1, j]), float(x[0, t, 1, j + 4])
+    assert float(y[0, t, 1, j]) == pytest.approx(
+        a * math.cos(t * f) - b * math.sin(t * f), abs=1e-6)
+    assert float(y[0, t, 1, j + 4]) == pytest.approx(
+        b * math.cos(t * f) + a * math.sin(t * f), abs=1e-6)
+    # a whole head still rotates whole
+    whole = apply_rope(x[..., :8], cos, sin)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(y[..., :8]))
+
+
+# ------------------------------------------- the mixer, both kinds
+
+def _plain_gqa(p, x, heads, kv_heads, window, cos, sin, rotated):
+    """The layer's equations with explicit loops over heads and an explicit
+    mask: x (S, d) -> (S, d)."""
+    S, dh = x.shape[0], p["q_proj"]["kernel"].shape[-1]
+    i = jnp.arange(S)
+    keep = i[:, None] >= i[None, :]
+    if window:
+        keep = keep & (i[:, None] - i[None, :] < window)
+
+    def rot(t):  # (S, dh)
+        a, b = t[:, :rotated // 2], t[:, rotated // 2:rotated]
+        return jnp.concatenate(
+            [a * cos - b * sin, b * cos + a * sin, t[:, rotated:]], -1)
+
+    gate = jax.nn.sigmoid(x @ p["g_proj"]["kernel"])
+    out = 0.0
+    for h in range(heads):
+        g = h // (heads // kv_heads)      # the KV head this query head reads
+        q = rot(x @ p["q_proj"]["kernel"][:, h])
+        k = rot(x @ p["k_proj"]["kernel"][:, g])
+        v = x @ p["v_proj"]["kernel"][:, g]
+        w = jax.nn.softmax(jnp.where(keep, q @ k.T / math.sqrt(dh),
+                                     -jnp.inf), -1)
+        out = out + (gate[:, h:h + 1] * (w @ v)) @ p["o_proj"]["kernel"][h]
+    return out
+
+
+@pytest.mark.parametrize("kind,heads", [("gqa_window", 72), ("gqa_full", 48)])
+def test_mixer_matches_the_plain_formula_at_the_published_heads(kind, heads):
+    """72 heads over 8 (nine query heads a KV head) with the window of 512
+    and plain rope over the whole head; 48 over 8 (six a KV head), causal,
+    YaRN on half the head. S 640 holds the window's edge."""
+    S, d, dh, window = 640, 32, 16, 512 if kind == "gqa_window" else 0
+    rotation = hybrid.Rotation(dh, 1e4) if window else hybrid.Rotation(
+        dh // 2, 5e5, 128.0, "yarn", 8192, 32.0, 1.0, 1.4852030263919618)
+    mixer = hybrid.GQAMixer(heads, 8, dh, window, rotation, F32, F32,
+                            attn_impl="xla")
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, S, d))
+    p = mixer.init(jax.random.PRNGKey(2), x)["params"]
+    p = jax.tree.map(lambda w: 10.0 * w, p)   # scores and gates far from flat
+    assert {k: v["kernel"].shape for k, v in p.items()} == {
+        "q_proj": (d, heads, dh), "k_proj": (d, 8, dh), "v_proj": (d, 8, dh),
+        "g_proj": (d, heads), "o_proj": (heads, dh, d)}
+    got = mixer.apply({"params": p}, x)[0]
+    cos, sin = rotation.tables(S)
+    want = _plain_gqa(p, x[0], heads, 8, window, cos, sin, rotation.width)
+    _close(got, want, tol=1e-4)
+
+
+def test_the_windows_edge_is_at_511_and_512():
+    """Key j reaches query j + 511 and not query j + 512: the output's
+    derivative with respect to the normed input at position j."""
+    S, d, dh = 640, 16, 8
+    mixer = hybrid.GQAMixer(4, 2, dh, 512, hybrid.Rotation(dh, 1e4), F32,
+                            F32, attn_impl="xla")
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, S, d))
+    p = mixer.init(jax.random.PRNGKey(4), x)["params"]
+    j = 37
+
+    def reaches(i):
+        sens = jax.grad(lambda x: jnp.sum(
+            mixer.apply({"params": p}, x)[0, i] ** 2))(x)
+        return float(jnp.max(jnp.abs(sens[0, j])))
+
+    assert reaches(j + 511) > 1e-9
+    assert reaches(j + 512) == 0.0
+    assert reaches(j - 1) == 0.0   # and no query sees a later key
+
+
+def test_a_query_head_reads_its_own_kv_head():
+    """Query head h of 72 reads KV head h // 9: zero one KV head's values
+    and exactly its nine query heads' outputs vanish."""
+    S, d, dh = 16, 72, 8   # (d = the heads: o_proj can keep them apart)
+    mixer = hybrid.GQAMixer(72, 8, dh, 0, hybrid.Rotation(dh, 1e4), F32, F32,
+                            attn_impl="xla")
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, S, d))
+    p = mixer.init(jax.random.PRNGKey(6), x)["params"]
+    p["v_proj"]["kernel"] = p["v_proj"]["kernel"].at[:, 3].set(0.0)
+    # read each head's contribution through an o_proj that keeps heads apart
+    p["o_proj"]["kernel"] = jnp.zeros((72, dh, 72)).at[
+        jnp.arange(72), 0, jnp.arange(72)].set(1.0)
+    y = mixer.apply({"params": p}, x)[0]          # (S, 72): dim 0 of a head
+    dead = np.flatnonzero(~np.any(np.asarray(y) != 0.0, axis=0))
+    np.testing.assert_array_equal(dead, np.arange(27, 36))
+
+
+# ------------------------------------------------------------- the router
+
+def test_softmax_router_has_no_bias_and_matches_the_reference(bench):
+    _, ref, cfg = bench
+    m = cfg.model
+    spec = moe.HeldExpertsSpec(
+        num_experts=m.num_experts, top_k=m.expert_top_k, routed_scale=2.5,
+        score="softmax", held=m.experts_held)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(7))
+    x = jax.random.normal(k1, (96, m.hidden_size))
+    router = moe._Router(m.num_experts, "softmax")
+    p = router.init(k2, x)["params"]
+    assert set(p) == {"kernel"}                      # no bias leaf
+    p = {"kernel": 15.0 * p["kernel"]}
+    scores, bias = router.apply({"params": p}, x)
+    assert bias is None
+    _close(jnp.sum(scores, -1), jnp.ones((96,)))
+    ids, w = moe.group_limited_topk(scores, bias, spec)
+    _close(jnp.sum(w, -1), jnp.full((96,), 2.5))     # over the chosen ten
+    np.testing.assert_array_equal(                   # one group: plain top-k
+        np.sort(np.asarray(ids), -1),
+        np.sort(np.asarray(jax.lax.top_k(scores, m.expert_top_k)[1]), -1))
+    dense = jnp.zeros((96, m.num_experts)).at[
+        jnp.arange(96)[:, None], ids].set(w)
+    _close(dense[:, :m.experts_held], ref._route(p, x))
+    with pytest.raises(ValueError, match="router score"):
+        moe._Router(8, "tanh").init(k2, x)
+
+
+# ------------------------------------------------------ the whole model
+
+def test_model_logits_match_the_reference_on_its_seeded_weights(bench):
+    _, ref, cfg = bench
+    model = build_model(cfg.model, cfg.precision)
+    params = ref.init_variables(17)["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(18), (2, 128), 0,
+                             cfg.model.vocab_size)
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
+    sig = lambda t: [(jax.tree_util.keystr(k), v.shape, str(v.dtype))  # noqa: E731
+                     for k, v in jax.tree_util.tree_flatten_with_path(t)[0]]
+    assert sig(shapes) == sig(params)  # names and shapes are the interface
+    got = model.apply({"params": params}, ids, train=False)
+    want = jnp.stack([ref._logits(params, ids[b], lambda t: t)[0]
+                      for b in range(2)])
+    _close(got, want)
+    # the layer pattern: full with the dense FFN, window and full with experts
+    assert [sorted(set(params[f"layer{i}"]) - {"input_norm", "post_attn_norm"})
+            for i in range(3)] == [["gqa", "mlp"], ["moe", "swa"],
+                                   ["gqa", "moe"]]
+    assert "bias" not in params["layer1"]["moe"]["router"]
+
+
+def test_the_references_layer_by_layer_sweep_is_the_whole_models_gradient(
+        bench):
+    """``follow`` takes the backward pass a layer at a time from the host,
+    with programs shared by the layers of one kind (three kinds); the same
+    model in one piece under ``jax.grad`` gives the same loss and the same
+    gradient, leaf by leaf."""
+    _, ref, cfg = bench
+    assert len({ref.kind(i) for i in range(ref.L)}) == 3
+    params = ref.init_variables(23)["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(24), (2, 64), 0,
+                             cfg.model.vocab_size)
+
+    def loss(p):
+        total = 0.0
+        for row in ids:
+            logp = jax.nn.log_softmax(
+                ref._logits(p, row, lambda t: t)[0][:-1], -1)
+            total -= jnp.sum(jnp.take_along_axis(logp, row[1:, None], -1))
+        return total
+
+    want_loss, want = jax.value_and_grad(loss)(params)
+    got_loss, got, chosen = ref._sweep("float32", params, ids, True)
+    assert abs(float(got_loss) - float(want_loss)) < 1e-4 * float(want_loss)
+    assert chosen.shape == (2, 2, 64, 4)  # routed layers, rows, S, held
+    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
+                      jax.tree_util.tree_flatten_with_path(t)[0]}
+    got, want = flat(got), flat(want)
+    assert set(got) == set(want)
+    for leaf, w in want.items():
+        _close(got[leaf], w, tol=1e-4)
+
+
+def test_preset_counts_decay_mask_flops_and_partition_rules():
+    from pytorch_distributed_train_tpu.optim import decay_mask_fn
+    from pytorch_distributed_train_tpu.parallel.partition import (
+        P,
+        rules_for_model,
+    )
+    from pytorch_distributed_train_tpu.utils import flops
+
+    cfg = get_preset("laguna_s_lm_ep32")
+    model = build_model(cfg.model, cfg.precision)
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)},
+        jnp.zeros((1, 64), jnp.int32), train=False)["params"])
+    count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
+    assert count == 811_017_216  # ISSUE 30's table: 811.0 M, 12.98 GB
+    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
+    flat = {jax.tree_util.keystr(k): v for k, v in
+            jax.tree_util.tree_flatten_with_path(mask)[0]}
+    for leaf, decayed in flat.items():
+        plain = leaf.endswith("['kernel']") or leaf.endswith("['embedding']")
+        assert decayed == plain, leaf
+    # a token trained: 3 x forward, the band's pairs only. By hand, forward:
+    # full mixer 4*3072*48*128 + 4*3072*8*128 + 2*3072*48 + 4*48*128*4096.5
+    #   = 189 050 880; window mixer (72 heads, 496.03 pairs a token)
+    #   = 144 557 184; dense FFN 6*3072*12288; an expert layer 2*3072*256 +
+    #   6*3072*1024 * (1 + 10*8/256); the head 2*3072*12544
+    assert flops.band_pairs_per_token(8192, 512) == pytest.approx(496.03125)
+    assert flops.band_pairs_per_token(8192) == 4096.5
+    per_token = flops.train_flops_per_item(cfg.model, cfg.data.seq_len)
+    by_hand = 3 * (2 * 189050880 + 3 * 144557184 + 6 * 3072 * 12288
+                   + 4 * (2 * 3072 * 256 + 6 * 3072 * 1024 * (1 + 80 / 256))
+                   + 2 * 3072 * 12544)
+    assert per_token == pytest.approx(by_hand, rel=1e-9)
+    assert 3.6e9 < per_token < 3.7e9
+    specs = rules_for_model("hybrid_lm").tree_specs(shapes)
+    assert specs["layer1"]["swa"]["q_proj"]["kernel"] \
+        == P("fsdp", "tensor", None)
+    assert specs["layer4"]["gqa"]["k_proj"]["kernel"] \
+        == P("fsdp", "tensor", None)
+    assert specs["layer0"]["gqa"]["o_proj"]["kernel"] \
+        == P("tensor", None, "fsdp")
+    assert specs["layer1"]["swa"]["g_proj"]["kernel"] == P()
+    assert specs["layer1"]["moe"]["router"]["kernel"] == P()
+    assert specs["layer1"]["moe"]["experts"]["down_proj"]["kernel"] \
+        == P("expert", "tensor", "fsdp")
+
+
+def test_layer_kinds_and_heads_are_checked_and_derived():
+    cfg = get_preset("laguna_s_lm_ep32").model
+    assert hybrid.layer_kinds(cfg) == (
+        "gqa_full", "gqa_window", "gqa_window", "gqa_window", "gqa_full")
+    assert hybrid.layer_heads(cfg) == (48, 72, 72, 72, 48)
+    # the per-layer lists survive the config's JSON round trip as tuples
+    from pytorch_distributed_train_tpu.config import TrainConfig
+
+    whole = get_preset("laguna_s_lm_ep32")
+    again = TrainConfig.from_dict(json.loads(whole.to_json()))
+    assert again.model.layer_kinds == cfg.layer_kinds
+    assert again.model.layer_heads == cfg.layer_heads
+    cfg.layer_heads = ("4", "6", "4", "6", "4")   # an override's strings
+    assert hybrid.layer_heads(cfg) == (4, 6, 4, 6, 4)
+    cfg.layer_kinds = ("gqa_full", "conv")
+    with pytest.raises(ValueError, match="layer_kinds"):
+        hybrid.layer_kinds(cfg)
+    cfg.layer_kinds, cfg.layer_heads = (), (48,)
+    with pytest.raises(ValueError, match="layer_heads"):
+        hybrid.layer_heads(cfg)
+    # the other hybrid preset says "the last of each group": five then one
+    ling = get_preset("ling3_flash_lm_ep64").model
+    assert hybrid.layer_kinds(ling) == ("kda",) * 5 + ("mla",)
+    assert hybrid.layer_heads(ling) == (32,) * 6
+    # a window kind without a window is refused when the model is built
+    bad = get_preset("laguna_s_lm_ep32")
+    bad.model.attention_window = 0
+    with pytest.raises(ValueError, match="attention_window"):
+        build_model(bad.model, bad.precision)
+
+
+# ------------------- the hybrid preset this work shares its decoder with
+
+# sha256 of json.dumps([(leaf path, shape, dtype), ...]) of the preset's
+# parameter tree, and of the lowered StableHLO of its train step at the
+# benchmark configuration's rehearsal sizes in bfloat16, both taken on the
+# PARENT of the PR that gave `hybrid.py` a kind a layer, `_Router` a score
+# rule and `rope_frequencies` YaRN (PR 30; commit 89103c9). A PR that
+# means to change that model's step replaces them (the lines below print
+# how); one that does not and fails here has moved a cell it shares code
+# with.
+LING3_TREE = "d7a1ed3b5f0c92b2433b404a13d0135278b17a28b15770f912e66d9870df799d"
+LING3_STEP = "30a291fcec6f1b0061670bb6888f3cf833db0d30fa3d9768609a0ec626b7809e"
+
+
+def test_the_other_hybrid_presets_tree_and_lowered_step_are_the_parents():
+    from pytorch_distributed_train_tpu import losses, steps
+    from pytorch_distributed_train_tpu.optim import make_optimizer
+    from pytorch_distributed_train_tpu.train_state import TrainState
+
+    cfg = get_preset("ling3_flash_lm_ep64")
+    model = build_model(cfg.model, cfg.precision)
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 64), jnp.int32),
+        train=False)["params"])
+    sig = [(jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
+           for k, v in jax.tree_util.tree_flatten_with_path(shapes)[0]]
+    assert len(sig) == 132
+    tree = hashlib.sha256(json.dumps(sig).encode()).hexdigest()
+    assert tree == LING3_TREE, f"parameter tree moved: sha256 {tree}"
+
+    with open(os.path.join(BENCH, "configs",
+                           "ling3_flash_lm_ep64.json")) as f:
+        cfg.apply_overrides(json.load(f)["rehearsal_overrides"])
+    cfg.apply_overrides(["precision.compute_dtype=bfloat16"])
+    model = build_model(cfg.model, cfg.precision)
+    tx, _ = make_optimizer(cfg.optim, 10, 0)
+    ids = jnp.zeros((2, 128), jnp.int32)
+
+    def init(rng):
+        params = model.init({"params": rng}, ids, train=False)["params"]
+        return TrainState.create(params=params, tx=tx, batch_stats={},
+                                 dynamic_scale=None, ema=False, swa=False)
+
+    step = steps.make_train_step(model, losses.get_loss_fn(cfg.loss), tx)
+    with jax.default_matmul_precision("default"):  # as a run lowers it
+        text = jax.jit(step).lower(
+            jax.eval_shape(init, jax.random.PRNGKey(0)),
+            {"input_ids": jax.ShapeDtypeStruct((2, 128), jnp.int32)},
+            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
+    got = hashlib.sha256(text.encode()).hexdigest()
+    assert got == LING3_STEP, f"lowered step moved: sha256 {got}"

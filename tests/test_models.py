@@ -254,6 +254,10 @@ def test_rope_ntk_scaling_preserves_high_frequencies():
     import pytest
 
     with pytest.raises(ValueError, match="rope_scaling_type"):
+        rope_frequencies(D, S, theta, k, "cubic")
+    # "yarn" is a recipe since PR 30 (tests/test_laguna_lm.py): it asks for
+    # the pre-training length it stretches from
+    with pytest.raises(ValueError, match="rope_original_max_len"):
         rope_frequencies(D, S, theta, k, "yarn")
 
 

@@ -107,12 +107,16 @@ def _attn_loss(q, k, v, **kw):
 # (id, B, S, H, Hkv, D, window). gpt2_small is the preset's attention
 # shape at the benchmark cells' batch a chip; the rest are the
 # long-sequence shapes (llama-style heads of 128, GQA 4:1, Mistral-style
-# window).
+# window) and the window/full cell's own.
 FLASH_SHAPES = [
     ("gpt2_small", 16, 1024, 12, 12, 64, 0),
     ("s2048_d128", 2, 2048, 8, 8, 128, 0),
     ("s2048_d128_gqa", 2, 2048, 8, 2, 128, 0),
     ("s2048_d128_window512", 2, 2048, 8, 8, 128, 512),
+    # the window/full attention cell's two kinds (preset laguna_s_lm_ep32):
+    # 72 query heads over 8 KV heads inside a window of 512, 48 over 8 causal
+    ("s8192_d128_gqa72_window512", 1, 8192, 72, 8, 128, 512),
+    ("s8192_d128_gqa48", 1, 8192, 48, 8, 128, 0),
 ]
 
 
