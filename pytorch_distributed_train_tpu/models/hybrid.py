@@ -83,6 +83,7 @@ class KDAMixer(nn.Module):
     rms_norm_eps: float
     dtype: jnp.dtype
     param_dtype: jnp.dtype
+    cp: ContextParallelConfig | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -129,7 +130,7 @@ class KDAMixer(nn.Module):
             name="beta_proj")(x).astype(f32))
         o = kda_ops.kda_chunked(
             q, k, v, g, beta, chunk=min(kda_ops.DEFAULT_CHUNK, x.shape[1]),
-            lower_bound=self.gate_lower_bound)
+            lower_bound=self.gate_lower_bound, cp=self.cp)
         o = RMSNorm(self.rms_norm_eps, name="o_norm")(o.astype(f32))
         o = o * _head_gate(x, H, self.dtype, self.param_dtype)
         return nn.DenseGeneral(
@@ -297,7 +298,7 @@ class HybridBlock(nn.Module):
             mixed = KDAMixer(
                 self.num_heads, self.head_dim, self.conv_kernel_size,
                 self.kda_gate_lower_bound, self.rms_norm_eps, self.dtype,
-                self.param_dtype, name="kda")(h)
+                self.param_dtype, cp=self.cp, name="kda")(h)
         x = x + mixed
         h = RMSNorm(self.rms_norm_eps, name="post_attn_norm")(x)
         if self.moe is None:

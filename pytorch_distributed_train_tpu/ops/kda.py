@@ -41,11 +41,30 @@ at six passes (``HIGHEST``) these small batched products were two thirds
 of what the core costs the TPU's compiler (17.8 s an instance against 9.8,
 sandbox compile, PR 26; the step holds twenty instances).
 
-Memory. The sequence is walked in segments of ``segment_chunks`` chunks
-under ``jax.checkpoint``: the backward pass keeps one state a segment and
-recomputes a segment's chunk quantities and chunk states when it gets
-there, so no per-token state and no whole-sequence (C, C) table is ever
-kept.
+Two paths, one algorithm, :func:`kda_recurrent` the ground truth of both.
+On a TPU, at head widths that are multiples of 128 and a length of whole
+tiles, :func:`kda_chunked` takes the Pallas kernel pair of
+ops/kda_kernel.py (``unsupported`` says why not, from what a call can see;
+no option): a tile's tables are formed and consumed in VMEM, one launch a
+layer and direction. Everywhere else (the CPU tests, the rehearsals, small
+heads) it is the XLA scan below. The kernels keep the numbers above with
+two differences of form: Mosaic has no ``Precision.HIGH``, so the
+inverse's float32 operands are split into bfloat16 high and low parts by
+hand (the same three passes), and the sums of g are products with a 0/1
+triangle over g split three ways (exact products, float32 sums). Their
+chunk is their own (``KERNEL_CHUNK``; a grid step walks a tile of 128
+tokens): ``[kda] ... impl=pallas|xla`` says once a shape which path ran,
+with the chunk in use, and utils/flops.py counts with it
+(:func:`chunk_in_use`).
+
+Memory. XLA path: the sequence is walked in segments of ``segment_chunks``
+chunks under ``jax.checkpoint``: the backward pass keeps one state a
+segment and recomputes a segment's chunk quantities and chunk states when
+it gets there, so no per-token state and no whole-sequence (C, C) table is
+ever kept. Kernel path: the forward kernel leaves the float32 state each
+tile of 128 tokens starts from (268 MB a layer at (2, 8192, 32, 128)); the
+backward kernel recomputes a tile's tables and re-walks its chunks from
+that state, so nothing else is saved beside the inputs.
 """
 
 from __future__ import annotations
@@ -56,6 +75,8 @@ import sys
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_train_tpu.ops import attention
+
 BLOCK = 16  # rows that share a reference point; a masked pair of one block
 # still multiplies exp(+8 |lower_bound|) twice, so BLOCK * |lower_bound| < 88
 
@@ -63,6 +84,10 @@ BLOCK = 16  # rows that share a reference point; a masked pair of one block
 # 32 against 1319 at 64 (PERF.md section 6, PR 26)
 DEFAULT_CHUNK = 32
 DEFAULT_SEGMENT_CHUNKS = 4
+# The kernels' own (ops/kda_kernel.py): tokens a chunk and heads a grid
+# step, read through the hybrid cell's whole step (PERF.md section 6, PR 31)
+KERNEL_CHUNK = 64
+KERNEL_HEADS = 8
 SCOPE = "kda_chunk"  # jax.named_scope round the core
 _MASKED = -1e30  # exp() of it is 0, with a zero derivative
 _SOLVE = jax.lax.Precision.HIGH  # the inverse's float32 products
@@ -70,16 +95,51 @@ _SOLVE = jax.lax.Precision.HIGH  # the inverse's float32 products
 _logged: set[tuple] = set()
 
 
-def log_plan(S: int, chunk: int, heads: int, d_k: int, d_v: int) -> None:
-    """Say once a shape, at trace time, how the sequence is cut (stderr,
-    like the attention dispatch's line): a run's log then shows the scan's
-    length and the state's size."""
-    key = (S, chunk, heads, d_k, d_v)
+def _interpret() -> bool:
+    """Mosaic on a TPU; elsewhere the interpreter, which only a test that
+    steers the gate below ever reaches."""
+    return not attention._on_tpu()
+
+
+def unsupported(S: int, d_k: int, d_v: int, dtype=jnp.bfloat16,
+                cp=None) -> str | None:
+    """Why the kernels of ops/kda_kernel.py do not take this core, or None
+    when they do: from what a call can see, no option."""
+    if not attention._on_tpu():
+        return "the backend is not a TPU"
+    from pytorch_distributed_train_tpu.ops.kda_kernel import TILE
+
+    if d_k % 128 or d_v % 128:
+        return f"d_k={d_k} d_v={d_v}: not multiples of 128"
+    if S % TILE:
+        return f"S={S} is not whole tiles of {TILE}"
+    if dtype not in (jnp.bfloat16, jnp.float32):
+        return f"operands in {jnp.dtype(dtype).name}"
+    if cp is not None and cp.active:
+        return "mesh: the sequence is sharded"
+    return None
+
+
+def chunk_in_use(S: int, d_k: int, d_v: int) -> int:
+    """Tokens a chunk of the path :func:`kda_chunked` takes at its
+    defaults for this shape (utils/flops.py counts the tables with it)."""
+    if unsupported(S, d_k, d_v) is None:
+        return KERNEL_CHUNK
+    return min(DEFAULT_CHUNK, S)
+
+
+def log_plan(S: int, chunk: int, heads: int, d_k: int, d_v: int,
+             impl: str) -> None:
+    """Say once a shape, at trace time, how the sequence is cut and what
+    runs it (stderr, like the attention dispatch's line): ``impl=pallas``
+    with the kernels' tile, or ``impl=xla`` with the scan's length and the
+    reason."""
+    key = (S, chunk, heads, d_k, d_v, impl)
     if key in _logged:
         return
     _logged.add(key)
     print(f"[kda] S={S} chunk={chunk} chunks={S // chunk} heads={heads} "
-          f"d_k={d_k} d_v={d_v} state_dtype=float32", file=sys.stderr,
+          f"d_k={d_k} d_v={d_v} state_dtype=float32 {impl}", file=sys.stderr,
           flush=True)
 
 
@@ -195,12 +255,18 @@ def _segment(state, xs, *, chunk, dtype):
 
 def kda_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
                 segment_chunks: int = DEFAULT_SEGMENT_CHUNKS,
-                lower_bound: float = -5.0):
+                lower_bound: float = -5.0, cp=None):
     """The chunked form (module docstring). q, k: (B, S, H, d_k), v:
     (B, S, H, d_v), their dtype is the products' operand dtype; g:
     (B, S, H, d_k) float32 log-decay in [lower_bound, 0]; beta: (B, S, H).
     S must be whole chunks and ``chunk`` whole blocks of 16. Returns o in
-    q's dtype; the state and every sum of g stay float32."""
+    q's dtype; the state and every sum of g stay float32.
+
+    On a TPU, at head widths and a length its tiles fit, the kernel pair of
+    ops/kda_kernel.py takes the core at its own chunk (``chunk`` and
+    ``segment_chunks`` cut the XLA scan alone); ``cp`` is the mesh's axes
+    (ops/attention.py) or None: batch and heads are independent, so under
+    a mesh the kernels run a device on its own block."""
     B, S, H, dk = q.shape
     if BLOCK * abs(lower_bound) >= 88.0:
         raise ValueError(
@@ -210,10 +276,19 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
         raise ValueError(
             f"kda: chunk {chunk} must be a multiple of {BLOCK} and divide "
             f"the sequence length {S}")
+    why = unsupported(S, dk, v.shape[-1], q.dtype, cp)
+    if why is None:
+        return _kda_kernels(q, k, v, g, beta, cp)
+    log_plan(S, chunk, H, dk, v.shape[-1], f"impl=xla reason={why}")
+    return _kda_scan(q, k, v, g, beta, chunk, segment_chunks)
+
+
+def _kda_scan(q, k, v, g, beta, chunk, segment_chunks):
+    """The XLA path: a scan over segments of whole chunks."""
+    B, S, H, dk = q.shape
     n_chunks = S // chunk
     seg = math.gcd(n_chunks, max(segment_chunks, 1)) * chunk
     n_seg = S // seg
-    log_plan(S, chunk, H, dk, v.shape[-1])
     g = g.astype(jnp.float32)
 
     def segments(x):  # (B, S, ...) -> (n_seg, B, seg, ...)
@@ -230,3 +305,34 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
                             tuple(segments(x) for x in (q, k, v, g, beta)))
         o = jnp.moveaxis(o, 0, 1).reshape(B, S, H, -1)
     return o.astype(q.dtype)
+
+
+def _kda_kernels(q, k, v, g, beta, cp):
+    """The kernel pair; under a sharded mesh inside a manual region over
+    the axes that shard batch and heads (GSPMD cannot partition a Mosaic
+    call: ops/attention.py ``_sharded_flash``)."""
+    from pytorch_distributed_train_tpu.ops import kda_kernel
+
+    def local(q, k, v, g, beta):
+        H = q.shape[2]
+        hb = next(n for n in range(KERNEL_HEADS, 0, -1) if H % n == 0)
+        log_plan(q.shape[1], KERNEL_CHUNK, H, q.shape[3], v.shape[3],
+                 f"impl=pallas tile={kda_kernel.TILE} chunks_per_step="
+                 f"{kda_kernel.TILE // KERNEL_CHUNK} heads_per_step={hb}")
+        with jax.named_scope(SCOPE):
+            return kda_kernel.kda_pallas(
+                q, k, v, g, beta,
+                kda_kernel.Plan(KERNEL_CHUNK, hb, _interpret()))
+
+    if cp is None or cp.mesh.size == 1:
+        return local(q, k, v, g, beta)
+    from jax.sharding import PartitionSpec as P
+
+    from pytorch_distributed_train_tpu.ops.cp_common import qkv_spec
+    from pytorch_distributed_train_tpu.utils.compat import shard_map
+
+    spec = qkv_spec(q, k, cp.mesh, context_axis=None,
+                    batch_axes=cp.batch_axes, tensor_axis=cp.tensor_axis)
+    return shard_map(local, mesh=cp.mesh,
+                     in_specs=(spec,) * 4 + (P(*spec[:3]),), out_specs=spec,
+                     check_vma=False)(q, k, v, g, beta)

@@ -216,12 +216,12 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
         layer_heads,
         layer_kinds,
     )
-    from pytorch_distributed_train_tpu.ops.kda import DEFAULT_CHUNK
+    from pytorch_distributed_train_tpu.ops.kda import chunk_in_use
 
     s = seq or cfg.max_seq_len
     d, h = cfg.hidden_size, cfg.num_heads
     dh = cfg.head_dim or d // h
-    dr, r, c = cfg.rope_head_dim, cfg.kv_lora_rank, min(DEFAULT_CHUNK, s)
+    dr, r, c = cfg.rope_head_dim, cfg.kv_lora_rank, chunk_in_use(s, dh, dh)
     hkv = cfg.num_kv_heads or h
     kinds = layer_kinds(cfg)
     n_dense = min(cfg.first_dense_layers, cfg.num_layers)

@@ -1,18 +1,38 @@
 """ops/kda.py: the chunked gated delta rule against the token-by-token
 recurrence, outputs and every gradient, at each chunk size the model may
-use and over several segments; and the guards on its shapes."""
+use and over several segments; and the guards on its shapes. The same for
+the kernel pair of ops/kda_kernel.py, which the dispatch takes on a TPU:
+here in interpret mode, reached by steering the gate as a test of the
+head's kernels does (tests/test_lm_head_loss.py)."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from pytorch_distributed_train_tpu.ops import kda
+from pytorch_distributed_train_tpu.ops import attention, kda
 
 
 @pytest.fixture(autouse=True)
 def _exact_products():
     with jax.default_matmul_precision("highest"):
         yield
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """The dispatch sees a TPU; the kernels run interpreted."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "_interpret", lambda: True)
+    monkeypatch.setattr(kda, "_logged", set())
+
+    def kernels(chunk=kda.KERNEL_CHUNK, heads=kda.KERNEL_HEADS):
+        monkeypatch.setattr(kda, "KERNEL_CHUNK", chunk)
+        monkeypatch.setattr(kda, "KERNEL_HEADS", heads)
+
+    return kernels
+
+
+KERNEL = dict(dk=128, dv=128)  # head widths the kernels take
 
 
 def _inputs(seed, B=2, S=192, H=2, dk=16, dv=8, spread=3.0):
@@ -28,11 +48,22 @@ def _inputs(seed, B=2, S=192, H=2, dk=16, dv=8, spread=3.0):
     return (q, k, v, g, beta), jax.random.normal(ks[5], (B, S, H, dv))
 
 
-@pytest.mark.parametrize("chunk", [16, 32, 64])
-def test_chunked_equals_the_recurrence_outputs_and_all_gradients(chunk):
-    args, w = _inputs(chunk)
-    # 192 tokens: 12, 6 or 3 chunks, walked two chunks a segment or one
-    run = lambda *a: kda.kda_chunked(*a, chunk=chunk, segment_chunks=2)  # noqa: E731
+@pytest.mark.parametrize("impl,chunk,heads", [
+    ("xla", 16, 0), ("xla", 32, 0), ("xla", 64, 0),
+    # the kernel pair: two tiles of 128 tokens, 4 or 2 chunks a tile, one
+    # head a grid step or both in step
+    ("pallas", 32, 1), ("pallas", 64, 2),
+])
+def test_chunked_equals_the_recurrence_outputs_and_all_gradients(
+        impl, chunk, heads, request):
+    if impl == "pallas":
+        request.getfixturevalue("as_on_a_tpu")(chunk, heads)
+        args, w = _inputs(chunk, S=256, **KERNEL)
+        run = kda.kda_chunked
+    else:
+        args, w = _inputs(chunk)
+        # 192 tokens: 12, 6 or 3 chunks, walked two chunks a segment or one
+        run = lambda *a: kda.kda_chunked(*a, chunk=chunk, segment_chunks=2)  # noqa: E731
     want, got = kda.kda_recurrent(*args), run(*args)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-6
     grads = lambda f: jax.grad(  # noqa: E731
@@ -43,8 +74,12 @@ def test_chunked_equals_the_recurrence_outputs_and_all_gradients(chunk):
             < 2e-5 * float(jnp.max(jnp.abs(a))), name
 
 
-def test_a_decay_at_the_bound_for_a_whole_chunk_stays_finite():
-    (q, k, v, g, beta), _ = _inputs(7, S=128)
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_decay_at_the_bound_for_a_whole_chunk_stays_finite(impl, request):
+    if impl == "pallas":  # a chunk is the whole tile: 128 x 5 = 640
+        request.getfixturevalue("as_on_a_tpu")(128)
+    (q, k, v, g, beta), _ = _inputs(7, S=128,
+                                    **(KERNEL if impl == "pallas" else {}))
     g = jnp.full_like(g, -5.0)  # 64 x 5 = 320: exp(320) is not a float32
     out = kda.kda_chunked(q, k, v, g, beta, chunk=64)
     assert bool(jnp.all(jnp.isfinite(out)))
@@ -54,11 +89,17 @@ def test_a_decay_at_the_bound_for_a_whole_chunk_stays_finite():
     assert bool(jnp.all(jnp.isfinite(dg)))
 
 
-def test_the_state_carries_across_segments_and_bf16_operands_keep_f32_state():
-    (q, k, v, g, beta), _ = _inputs(5, S=256, spread=1.0)
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_state_carries_across_segments_and_bf16_operands_keep_f32_state(
+        impl, request):
+    if impl == "pallas":  # across grid steps: two tiles of 128 tokens
+        request.getfixturevalue("as_on_a_tpu")()
+    (q, k, v, g, beta), _ = _inputs(5, S=256, spread=1.0,
+                                    **(KERNEL if impl == "pallas" else {}))
     g = g * 0.02  # slow decays: a token still sees the first segment
     one = kda.kda_chunked(q, k, v, g, beta, chunk=32, segment_chunks=8)
-    many = kda.kda_chunked(q, k, v, g, beta, chunk=32, segment_chunks=1)
+    many = kda.kda_chunked(q, k, v, g, beta, chunk=32, segment_chunks=1) \
+        if impl == "xla" else kda.kda_recurrent(q, k, v, g, beta)
     assert float(jnp.max(jnp.abs(one - many))) < 1e-6
     cut = kda.kda_chunked(q[:, 128:], k[:, 128:], v[:, 128:], g[:, 128:],
                           beta[:, 128:], chunk=32)
@@ -79,3 +120,68 @@ def test_shapes_and_bounds_the_algorithm_cannot_take_are_refused(kwargs,
     (q, k, v, g, beta), _ = _inputs(1, S=48)
     with pytest.raises(ValueError, match=match):
         kda.kda_chunked(q, k, v, g, beta, **kwargs)
+
+
+@pytest.mark.parametrize("shape,on_tpu,reason", [
+    (dict(S=256, **KERNEL), False, "the backend is not a TPU"),
+    (dict(S=256), True, "d_k=16 d_v=8: not multiples of 128"),
+    (dict(S=192, **KERNEL), True, "S=192 is not whole tiles of 128"),
+    (dict(S=256, **KERNEL), True, None),
+])
+def test_the_dispatch_says_once_a_shape_what_took_the_core(
+        shape, on_tpu, reason, request, capfd):
+    """``[kda] ... impl=pallas`` with the kernels' tile where they take the
+    core, ``impl=xla reason=...`` where they do not; one line a shape."""
+    if on_tpu:
+        request.getfixturevalue("as_on_a_tpu")()
+    else:
+        request.getfixturevalue("monkeypatch").setattr(kda, "_logged", set())
+    (q, k, v, g, beta), _ = _inputs(3, B=1, H=1, **shape)
+    want = kda.kda_recurrent(q, k, v, g, beta)
+    for _ in range(2):
+        out = kda.kda_chunked(q, k, v, g, beta, chunk=32)
+        assert float(jnp.max(jnp.abs(out - want))) < 2e-6
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("[kda]")]
+    assert len(lines) == 1, lines
+    assert f"S={shape['S']} " in lines[0] and "state_dtype=float32" in lines[0]
+    if reason is None:
+        assert (f"chunk={kda.KERNEL_CHUNK} " in lines[0]
+                and lines[0].endswith(
+                    f"impl=pallas tile=128 chunks_per_step="
+                    f"{128 // kda.KERNEL_CHUNK} heads_per_step=1")), lines
+        assert kda.chunk_in_use(shape["S"], 128, 128) == kda.KERNEL_CHUNK
+    else:
+        assert lines[0].endswith(f"chunk=32 chunks={shape['S'] // 32} "
+                                 f"heads=1 d_k={q.shape[-1]} "
+                                 f"d_v={v.shape[-1]} state_dtype=float32 "
+                                 f"impl=xla reason={reason}"), lines
+        assert kda.chunk_in_use(shape["S"], q.shape[-1], v.shape[-1]) == 32
+
+
+def test_the_kernels_run_a_device_on_its_own_block_under_a_mesh(
+        devices8, as_on_a_tpu):
+    """GSPMD cannot partition a Mosaic call, and batch and heads are
+    independent: under a sharded mesh the pair runs in a manual region over
+    the batch and tensor axes (as the flash kernel does); with the sequence
+    sharded it leaves the core to the scan."""
+    from pytorch_distributed_train_tpu.config import MeshConfig
+    from pytorch_distributed_train_tpu.parallel.mesh import build_mesh
+
+    as_on_a_tpu()
+    cp = attention.ContextParallelConfig(
+        mesh=build_mesh(MeshConfig(data=2, tensor=2), devices8[:4]))
+    args, w = _inputs(11, B=2, S=128, H=2, **KERNEL)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * w)  # noqa: E731
+    run = lambda *a: kda.kda_chunked(*a, cp=cp)  # noqa: E731
+    assert "shard_map" in str(jax.make_jaxpr(run)(*args))
+    with cp.mesh:
+        got = jax.jit(jax.value_and_grad(loss(run), argnums=(0, 3)))(*args)
+    want = jax.value_and_grad(loss(kda.kda_recurrent), argnums=(0, 3))(*args)
+    assert abs(float(got[0] - want[0])) < 1e-4 * abs(float(want[0]))
+    for a, b in zip(got[1], want[1]):
+        assert float(jnp.max(jnp.abs(a - b))) < 2e-5 * float(jnp.max(jnp.abs(b)))
+    ring = attention.ContextParallelConfig(
+        mesh=build_mesh(MeshConfig(data=2, context=2), devices8[:4]))
+    assert kda.unsupported(128, 128, 128, jnp.bfloat16, ring) \
+        == "mesh: the sequence is sharded"

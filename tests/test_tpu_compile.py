@@ -173,6 +173,31 @@ def test_kda_core_compiles_at_the_hybrid_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+def test_kda_kernel_pair_compiles_at_the_hybrid_cells_shape(one_chip,
+                                                            monkeypatch):
+    """The same entry where the dispatch sees a TPU: ops/kda_kernel.py's
+    pair, forward and backward, one Mosaic call each under its own name,
+    no scan left, and the saved states (one float32 state a tile and head)
+    inside the memory the cell leaves."""
+    from pytorch_distributed_train_tpu.ops import attention as attention_lib
+    from pytorch_distributed_train_tpu.ops import kda
+
+    monkeypatch.setattr(attention_lib, "_on_tpu", lambda: True)
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    x = sds(2, 8192, 32, 128)
+    g, beta = sds(2, 8192, 32, 128, dtype=jnp.float32), \
+        sds(2, 8192, 32, dtype=jnp.float32)
+    loss = lambda *a: kda.kda_chunked(*a).astype(jnp.float32).sum()  # noqa: E731
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        x, x, x, g, beta)
+    names = _custom_calls(compiled.as_text())
+    assert sorted(n.split(".")[0] for n in names if "kda" in n) == \
+        ["%kda_bwd", "%kda_fwd"], names
+    assert " while(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 @pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("D,Hkv", [(64, 12), (128, 4)],
                          ids=["d64_mha", "d128_gqa"])
@@ -228,15 +253,9 @@ def test_ring_attention_compiles_on_four_chips(topo, direction):
     assert "collective-permute" in text
 
 
-def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
-    """The one-chip GPT-2 small step, lowered and compiled for the
-    described chip at the benchmark cell's batch: 12 layers x (forward,
-    dQ, dK/dV) = 36 instructions whose trace names (`%attn.N custom-call`)
-    match the configuration's `flash_kernel_pattern`. The benchmark's
-    `flash_attn_ms_per_step` finds the kernel by that name alone, so a
-    `name=` on a `pallas_call` or a renamed module would zero the metric.
-    The cell's own settings (loss causal_lm_xent, no option) land on the
-    head's kernels: `_assert_head_rides_its_products`."""
+def _lowered_step(config, one_chip, monkeypatch, overrides=()):
+    """A benchmark configuration's one-chip training step, lowered for the
+    described chip, with the configuration's file and the run's config."""
     import json
 
     from pytorch_distributed_train_tpu import losses as losses_lib
@@ -248,14 +267,14 @@ def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
     from pytorch_distributed_train_tpu.train_state import TrainState
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "gpt2_small.json"),
+    with open(os.path.join(root, "benchmark", "configs", f"{config}.json"),
               encoding="utf-8") as f:
         bench = json.load(f)
     # the dispatch asks the RUNTIME backend (the CPU here): steer it in
     # the test, as the described-chip guide says, not through an option
     monkeypatch.setattr(attention_lib, "_on_tpu", lambda: True)
     cfg = get_preset(bench["preset"])
-    cfg.apply_overrides(list(bench["overrides"]) + ["data.batch_size=16"])
+    cfg.apply_overrides(list(bench["overrides"]) + list(overrides))
     model = build_model(cfg.model, cfg.precision)
     tx, _ = make_optimizer(cfg.optim, cfg.total_steps, 0)
     dummy = steps_lib.dummy_inputs(cfg.loss, cfg.model, cfg.data)
@@ -277,13 +296,49 @@ def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
         (cfg.data.batch_size, cfg.data.seq_len), jnp.int32,
         sharding=one_chip)}
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    text = jax.jit(step, donate_argnums=(0,)).lower(
-        described(jax.eval_shape(init, jax.random.PRNGKey(0))), batch,
-        rng).compile().as_text()
+    lowered = jax.jit(step, donate_argnums=(0,)).lower(
+        described(jax.eval_shape(init, jax.random.PRNGKey(0))), batch, rng)
+    return lowered, bench, cfg
+
+
+def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
+    """The one-chip GPT-2 small step, lowered and compiled for the
+    described chip at the benchmark cell's batch: 12 layers x (forward,
+    dQ, dK/dV) = 36 instructions whose trace names (`%attn.N custom-call`)
+    match the configuration's `flash_kernel_pattern`. The benchmark's
+    `flash_attn_ms_per_step` finds the kernel by that name alone, so a
+    `name=` on a `pallas_call` or a renamed module would zero the metric.
+    The cell's own settings (loss causal_lm_xent, no option) land on the
+    head's kernels: `_assert_head_rides_its_products`."""
+    lowered, bench, cfg = _lowered_step(
+        "gpt2_small", one_chip, monkeypatch, ["data.batch_size=16"])
     assert 3 * bench["n_layer"] == 36
     _assert_head_rides_its_products(
-        text, bench["flash_kernel_pattern"], 36,
+        lowered.compile().as_text(), bench["flash_kernel_pattern"], 36,
         cfg.data.batch_size * cfg.data.seq_len, cfg.model.vocab_size)
+
+
+def test_hybrid_step_holds_the_kda_kernels_by_name(one_chip, monkeypatch):
+    """The hybrid cell's step at 2 x 8192 tokens, lowered (its compile is
+    two minutes here): five KDA layers, each the forward kernel twice
+    (`model.remat` runs a block's forward again) and the backward kernel
+    once, by the names their device operations carry (`%kda_fwd.N
+    custom-call`, which the configuration's `flash_kernel_pattern` must
+    NOT match: counted into `flash_attn_ms_per_step` they would move
+    `mla_attn_roofline`), and no `while` loop left under the core's
+    scope."""
+    lowered, bench, _ = _lowered_step(
+        "ling3_flash_lm_ep64", one_chip, monkeypatch,
+        ["data.batch_size=2", "data.seq_len=8192"])
+    text = lowered.as_text(debug_info=True)
+    kernels = re.findall(r'kernel_name = "(\w+)"', text)
+    assert (kernels.count("kda_fwd"), kernels.count("kda_bwd")) == (10, 5), \
+        kernels
+    for name in ("kda_fwd", "kda_bwd"):
+        assert not re.search(bench["flash_kernel_pattern"],
+                             f"%{name}.1 custom-call")
+    loops = sorted(set(re.findall(r'"[^"]*kda_chunk[^"]*/while"', text)))
+    assert not loops, loops
 
 
 def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
