@@ -73,6 +73,16 @@ class ModelConfig:
     rope_beta_slow: float = 1.0
     rope_attention_factor: float = 0.0
     rms_norm_eps: float = 1e-5
+    # Looped decoder (model name "llama"; models/llama.py's docstring): the
+    # stack of num_layers blocks applied loop_steps times over the same
+    # weights, each block with a norm after its two sublayers as well as
+    # before (sandwich_norm: four scales a layer), a head and a scalar exit
+    # gate after every pass; the loss (loss="looped_lm_xent") is the
+    # expectation of the exits' cross-entropies under the gates' exit
+    # distribution, less loop_entropy_beta x its entropy.
+    loop_steps: int = 1
+    sandwich_norm: bool = False
+    loop_entropy_beta: float = 0.05
     # T5 family (models/t5.py): decoder stack depth (0 → = num_layers) and
     # the bucketed relative-position-bias geometry.
     decoder_layers: int = 0
@@ -1223,6 +1233,38 @@ def _laguna_s_lm_ep32() -> TrainConfig:
     return c
 
 
+def _ouro_2_6b_lm_l8() -> TrainConfig:
+    """One pipeline stage of Ouro-2.6B (ByteDance,
+    https://huggingface.co/ByteDance/Ouro-2.6B config.json; arXiv:2510.25741):
+    every width as published (hidden 2048, 16 heads over 16 KV heads of 128,
+    SwiGLU of 5632, the whole vocabulary of 49152 with an untied head, rope
+    theta 1e6, RMSNorm eps 1e-6), `total_ut_steps` 4 passes over the same
+    weights, a sandwich of four norms a layer, a head and an exit gate at
+    every pass; 8 of the 48 layers. 612 438 017 parameters, 9.80 GB with
+    AdamW's float32 state (benchmark/configs/ouro_2_6b_lm_l8.json says what
+    was assumed)."""
+    c = TrainConfig(preset="ouro_2_6b_lm_l8")
+    c.model = ModelConfig(
+        name="llama", hidden_size=2048, num_layers=8, num_heads=16,
+        num_kv_heads=16, mlp_dim=5632, vocab_size=49152, max_seq_len=4096,
+        rope_theta=1e6, rms_norm_eps=1e-6, remat=True,
+        loop_steps=4, sandwich_norm=True, loop_entropy_beta=0.05,
+    )
+    # 4096 synthetic sequences of 4096 tokens: 4096 steps an epoch
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=1, seq_len=4096,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        decay_exclude=r"scale$,bias$",  # matrices (the gate's too) and embedding decay
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    c.total_steps = 500000
+    c.loss = "looped_lm_xent"
+    return c
+
+
 def _t5_small() -> TrainConfig:
     """T5-small seq2seq pretrain (model-zoo extension beyond the BASELINE
     matrix). HF-layout-compatible via interop's 't5' mapping
@@ -1261,6 +1303,7 @@ _PRESETS = {
     "mixtral_8x7b": _mixtral_8x7b,
     "ling3_flash_lm_ep64": _ling3_flash_lm_ep64,
     "laguna_s_lm_ep32": _laguna_s_lm_ep32,
+    "ouro_2_6b_lm_l8": _ouro_2_6b_lm_l8,
 }
 
 

@@ -95,6 +95,77 @@ def causal_lm_xent(logits, batch, *_):
     return loss, {"perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
 
 
+def exit_distribution(gates):
+    """gates (T, ...) -> (p, log p), float32, over the T exits: ``lam_t =
+    sigmoid(g_t)``; ``S_0 = 1``, ``S_t = S_{t-1} (1 - lam_t)``; ``p_t =
+    lam_t S_{t-1}`` for t < T and ``p_T = S_{T-1}``: the last exit takes
+    what is left (``g_T`` is unused), so ``sum_t p_t = 1``. Taken in logs:
+    ``log S_t`` is a running sum of ``log(1 - lam) = log_sigmoid(-g)``."""
+    g = gates.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)        # log S_1..S_T
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    logp = jnp.concatenate(
+        [jax.nn.log_sigmoid(g[:-1]) + before[:-1], before[-1:]])
+    return jnp.exp(logp), logp
+
+
+def looped_lm_xent(exits, batch, *_):
+    """The looped decoder's next-token loss (models/llama.py ``LoopExits``;
+    the stage-I objective of arXiv:2510.25741 under a uniform prior over
+    exits): ``ce_{t,i}`` is exit t's cross-entropy at position i against
+    token i + 1, ``p_{.,i}`` the exit distribution the model's gates give
+    (:func:`exit_distribution`), and
+
+        loss = mean_i [ sum_t p_{t,i} ce_{t,i} - beta H(p_{.,i}) ],
+        H = -sum_t p_t log p_t.
+
+    No stop-gradient: the gate learns from ``ce`` and from ``H``, every
+    other leaf from the T cross-entropies through its T uses. The shift,
+    ``loss_mask`` and the mean are ``causal_lm_xent``'s; each exit's
+    per-token loss comes from the head's kernels where they take the
+    shapes (ops/lm_head.py), its weight ``p_t`` the per-row cotangent of
+    their backward pass, and from the logits path elsewhere."""
+    from pytorch_distributed_train_tpu.models.llama import LoopExits
+    from pytorch_distributed_train_tpu.ops import lm_head
+
+    if not isinstance(exits, LoopExits):
+        raise TypeError(
+            "looped_lm_xent reads a looped decoder's exits "
+            f"(model.loop_steps > 1), not {type(exits).__name__}")
+    ids = batch["input_ids"]
+    pad = ((0, 0), (0, 1))  # all B*S rows: the shift is a weight of 0
+    targets = jnp.pad(ids[:, 1:], pad)
+    weights = jnp.pad(batch.get(
+        "loss_mask", jnp.ones_like(ids, jnp.float32))[:, 1:].astype(
+            jnp.float32), pad)
+    denom = jnp.maximum(weights.sum(), 1.0)
+    mean = lambda per_tok: (per_tok * weights).sum(  # noqa: E731
+        axis=(-2, -1)) / denom
+
+    def exit_xent(x):
+        head = HeadOperands(x, exits.table, exits.cp)
+        with jax.named_scope("exit_head"):
+            if lm_head.unsupported(head) is None:
+                return head_token_xent(head, targets)
+            return optax.softmax_cross_entropy_with_integer_labels(
+                lm_head.logits(head), targets)
+
+    # all T exits' float32 logits are kept for the backward pass (3.0 GiB
+    # at the drawn cell's size): one exit's at a time, by checkpointing
+    # this call, costs a fourth product an exit, 23.6 ms of a 516.6 ms step
+    # (PERF.md section 6, PR 35), and the step fits without it
+    ce = jnp.stack([exit_xent(x) for x in exits.x])  # (T, B, S)
+    p, logp = exit_distribution(exits.gates)
+    entropy = -jnp.sum(jnp.where(p > 0, p * logp, 0.0), axis=0)
+    loss = mean(jnp.sum(p * ce, axis=0) - exits.beta * entropy)
+    share, exit_ce = mean(p), mean(ce)
+    aux = {"exit_entropy": mean(entropy)}
+    for t in range(ce.shape[0]):
+        aux[f"exit_share_t{t + 1}"] = share[t]
+        aux[f"exit_ce_t{t + 1}"] = exit_ce[t]
+    return loss, aux
+
+
 def seq2seq_xent(logits, batch, *_):
     """Encoder-decoder LM loss (t5). batch: {'input_ids' (B,Se),
     'decoder_input_ids' (B,Sd), 'labels' (B,Sd)}; optional
@@ -350,6 +421,7 @@ LOSSES = {
     "softmax_xent": softmax_xent,
     "mlm_xent": mlm_xent,
     "causal_lm_xent": causal_lm_xent,
+    "looped_lm_xent": looped_lm_xent,
     "seq2seq_xent": seq2seq_xent,
     "fused_causal_lm_xent": fused_causal_lm_xent,
 }

@@ -16,13 +16,33 @@ TPU-first notes:
   HBM-for-FLOPs trade (SURVEY "jax.checkpoint / rematerialisation").
 - Causal masking happens inside the attention core; no materialised (S,S)
   mask tensor at the model level.
+
+The looped decoder (``loop_steps`` T > 1 with ``sandwich_norm``; preset
+``ouro_2_6b_lm_l8``: Ouro-2.6B, arXiv:2510.25741 section 3, as
+benchmark/configs/ouro_2_6b_lm_l8.json states and assumes it). ONE stack of
+L blocks, applied T times over the same weights:
+
+- layer l, four norm scales: ``a = x + N2_l(Attn_l(N1_l(x)))``,
+  ``y = a + N4_l(FFN_l(N3_l(a)))`` (N1 ``input_norm``, N2 ``attn_out_norm``,
+  N3 ``post_attn_norm``, N4 ``mlp_out_norm``);
+- the loop: ``h_0 = E[ids]``; for t = 1..T: ``u = h_{t-1}``; for l = 1..L:
+  ``u = Layer_l(u)``; ``h_t = N_f(u)`` (one ``final_norm``; its OUTPUT is
+  what pass t + 1 starts from); exit t reads ``h_t``: ``logits_t = W_head
+  h_t`` and the gate ``g_t = w_g . h_t + b_g``, one float32 scalar a token;
+- exit distribution and loss: losses.py ``looped_lm_xent``, which takes
+  the ``LoopExits`` this model returns in place of logits.
+
+The tree holds L layers' leaves: a weight's gradient is the sum over its T
+uses, and ``remat`` checkpoints each of the T x L block applications.
 """
 
 from __future__ import annotations
 
 import math
+import sys
 from functools import partial
 
+import flax
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -43,6 +63,18 @@ class RMSNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
         y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
         return (y * scale).astype(dtype)
+
+
+@flax.struct.dataclass
+class LoopExits:
+    """What the looped decoder hands its loss in place of logits: the T
+    exits' head operands and gate rows (losses.py ``looped_lm_xent``)."""
+
+    x: jax.Array      # (T, B, S, C): h_1..h_T, the final norm's outputs
+    table: jax.Array  # (V, C): the untied head, as ops/lm_head.py reads it
+    gates: jax.Array  # (T, B, S) float32: g_t, before the sigmoid
+    cp: object = flax.struct.field(pytree_node=False)    # the mesh's axes
+    beta: float = flax.struct.field(pytree_node=False)   # entropy's weight
 
 
 def rope_frequencies(head_dim: int, max_seq_len: int, theta: float,
@@ -476,12 +508,17 @@ class LlamaBlock(nn.Module):
     paged: bool = False
     page_size: int = 0
     paged_blocks: int = 0
+    # A norm AFTER each sublayer too, on what it adds to the residual
+    # stream (four scales a layer; the module docstring's N2 and N4).
+    sandwich_norm: bool = False
 
     @nn.compact
     def __call__(self, x, segments=None, positions=None,
                  block_tables=None):
+        after = (lambda name, y: RMSNorm(self.rms_norm_eps, name=name)(y)) \
+            if self.sandwich_norm else (lambda name, y: y)
         h = RMSNorm(self.rms_norm_eps, name="input_norm")(x)
-        x = x + LlamaAttention(
+        x = x + after("attn_out_norm", LlamaAttention(
             self.num_heads, self.num_kv_heads, self.rope_theta,
             self.rope_scaling, self.max_seq_len, self.dtype,
             self.param_dtype, rope_scaling_type=self.rope_scaling_type,
@@ -493,7 +530,7 @@ class LlamaBlock(nn.Module):
             paged_blocks=self.paged_blocks,
             name="attn",
         )(h, segments=segments, positions=positions,
-          block_tables=block_tables)
+          block_tables=block_tables))
         h = RMSNorm(self.rms_norm_eps, name="post_attn_norm")(x)
         if self.moe is not None:
             from pytorch_distributed_train_tpu.ops.moe import MoeMLP
@@ -503,12 +540,13 @@ class LlamaBlock(nn.Module):
         else:
             mlp = LlamaMLP(self.mlp_dim, self.dtype, self.param_dtype,
                            quant=self.quant, name="mlp")
-        x = x + mlp(h)
+        x = x + after("mlp_out_norm", mlp(h))
         return x
 
 
 class LlamaForCausalLM(nn.Module):
-    """Input: input_ids (B, S). Output: (B, S, vocab) fp32 logits."""
+    """Input: input_ids (B, S). Output: (B, S, vocab) fp32 logits; with
+    ``loop_steps`` > 1 a ``LoopExits`` (the module docstring)."""
 
     vocab_size: int
     hidden_size: int = 4096
@@ -563,6 +601,13 @@ class LlamaForCausalLM(nn.Module):
     # regions — CP without it replicates seq outside the shard_map regions;
     # SP (Megatron SequenceParallel) IS this constraint.
     act: "object | None" = None
+    # The looped decoder (the module docstring): the stack applied
+    # loop_steps times over the same weights, a norm after each sublayer,
+    # an exit (head and gate) at every pass; loop_entropy_beta weighs the
+    # exit distribution's entropy in the loss.
+    loop_steps: int = 1
+    sandwich_norm: bool = False
+    loop_entropy_beta: float = 0.05
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, loss_mask=None,
@@ -592,28 +637,46 @@ class LlamaForCausalLM(nn.Module):
         from pytorch_distributed_train_tpu.models.remat import remat_block
 
         block_cls = remat_block(LlamaBlock, self.remat, self.remat_policy)
-        for i in range(self.num_layers):
-            moe = (self.moe if self.moe is not None
-                   and self.moe.active_for_layer(i) else None)
-            x = block_cls(
-                self.num_heads, self.num_kv_heads, self.mlp_dim,
-                self.rope_theta, self.rope_scaling, self.max_seq_len,
-                self.rms_norm_eps, self.dtype, self.param_dtype,
-                rope_scaling_type=self.rope_scaling_type,
-                cp=self.cp, moe=moe,
-                attn_impl=self.attn_impl, window=self.attention_window,
-                quant=self.quant_training,
-                kv_cache_dtype=self.kv_cache_dtype, decode=self.decode,
-                decode_multi=self.decode_multi, decode_rows=self.decode_rows,
-                paged=self.paged, page_size=self.page_size,
-                paged_blocks=self.paged_blocks,
-                name=f"layer{i}",
-            )(x, segments=segments, positions=positions,
-              block_tables=block_tables)
-            if self.act is not None:
-                x = self.act.constrain(x)
 
-        x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
+        def make_stack(parent):
+            """The L blocks and the final norm, created under ``parent``;
+            returns the function that applies them once."""
+            blocks = []
+            for i in range(self.num_layers):
+                moe = (self.moe if self.moe is not None
+                       and self.moe.active_for_layer(i) else None)
+                blocks.append(block_cls(
+                    self.num_heads, self.num_kv_heads, self.mlp_dim,
+                    self.rope_theta, self.rope_scaling, self.max_seq_len,
+                    self.rms_norm_eps, self.dtype, self.param_dtype,
+                    rope_scaling_type=self.rope_scaling_type,
+                    cp=self.cp, moe=moe,
+                    attn_impl=self.attn_impl, window=self.attention_window,
+                    quant=self.quant_training,
+                    kv_cache_dtype=self.kv_cache_dtype, decode=self.decode,
+                    decode_multi=self.decode_multi,
+                    decode_rows=self.decode_rows,
+                    paged=self.paged, page_size=self.page_size,
+                    paged_blocks=self.paged_blocks,
+                    sandwich_norm=self.sandwich_norm,
+                    name=f"layer{i}", parent=parent,
+                ))
+            final_norm = RMSNorm(self.rms_norm_eps, name="final_norm",
+                                 parent=parent)
+
+            def stack(x):
+                for block in blocks:
+                    x = block(x, segments=segments, positions=positions,
+                              block_tables=block_tables)
+                    if self.act is not None:
+                        x = self.act.constrain(x)
+                return final_norm(x)
+
+            return stack
+
+        if self.loop_steps > 1:
+            return self._loop(make_stack, x)
+        x = make_stack(self)(x)
         # Head matmul in the compute dtype with fp32 accumulation: bf16
         # operands hit the MXU at full rate while preferred_element_type
         # keeps the (B,S,V) logits fp32 without an intermediate bf16
@@ -641,9 +704,60 @@ class LlamaForCausalLM(nn.Module):
         logits = head(x)
         return logits.astype(jnp.float32)
 
+    def _loop(self, make_stack, x) -> LoopExits:
+        """``loop_steps`` passes of the stack over the same weights, an
+        exit after each: the blocks are created once (in the scanned
+        pass's body, under this module's own scope), so the tree holds one
+        stack's leaves and each leaf's gradient sums over its uses."""
+        if self.decode or self.fused_loss or self.moe is not None:
+            # a cache entry would be one a (pass, layer) pair: the serving
+            # path cannot run the loop yet (ROADMAP)
+            raise ValueError(
+                "model.loop_steps > 1 is a training-path feature of the "
+                "dense decoder: no decode cache, fused_lm_loss or experts")
+
+        # a scan over the passes, the weights broadcast: against the passes
+        # unrolled it compiled in 54 s for 155 and ran the step in 516.6 ms
+        # for 538.7 (PERF.md section 6, PR 35)
+        def one_pass(mdl, x, _):
+            with jax.named_scope("loop_pass"):
+                x = make_stack(mdl)(x)
+            return x, x
+
+        x, hs = nn.scan(one_pass, variable_broadcast="params",
+                        split_rngs={"params": False},
+                        length=self.loop_steps)(self, x, None)
+        gates = nn.Dense(1, dtype=jnp.float32, param_dtype=self.param_dtype,
+                         kernel_init=nn.initializers.normal(0.02),
+                         name="exit_gate")(hs)[..., 0]
+        # the untied head's (C, V) kernel, viewed as the (V, C) table the
+        # head's kernels read (ops/lm_head.py); the tiny call creates the
+        # leaf at the standard path and is dead code (as the fused head's)
+        head = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02),
+                        name="lm_head")
+        _ = head(x[:, :1])
+        table = jnp.asarray(head.variables["params"]["kernel"],
+                            self.dtype).T
+        return LoopExits(hs, table, gates, self.cp, self.loop_entropy_beta)
+
+
+_loop_logged: set[tuple] = set()
+
 
 def llama(cfg, dtype, param_dtype, cp=None, act=None) -> LlamaForCausalLM:
     resolve_kv_dtype(getattr(cfg, "kv_cache_dtype", ""), dtype)  # validate NOW
+    passes = getattr(cfg, "loop_steps", 1)
+    beta = getattr(cfg, "loop_entropy_beta", 0.05)
+    sandwich = getattr(cfg, "sandwich_norm", False)
+    said = (passes, cfg.num_layers, beta, sandwich)
+    if passes > 1 and said not in _loop_logged:  # once a layout, on stderr
+        _loop_logged.add(said)
+        print(f"[loop] passes={passes} layers={cfg.num_layers} "
+              f"applications={passes * cfg.num_layers} exits={passes} "
+              f"beta={beta:g} impl=scan sandwich={int(sandwich)}",
+              file=sys.stderr, flush=True)
     moe = None
     if getattr(cfg, "num_experts", 0) > 1:
         from pytorch_distributed_train_tpu.ops.moe import MoeSpec
@@ -680,6 +794,9 @@ def llama(cfg, dtype, param_dtype, cp=None, act=None) -> LlamaForCausalLM:
         rms_norm_eps=cfg.rms_norm_eps,
         remat=cfg.remat,
         remat_policy=getattr(cfg, "remat_policy", "full"),
+        loop_steps=passes,
+        sandwich_norm=sandwich,
+        loop_entropy_beta=beta,
         dtype=dtype,
         param_dtype=param_dtype,
     )

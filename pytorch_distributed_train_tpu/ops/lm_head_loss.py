@@ -97,6 +97,19 @@ def tile_sizes(rows: int, vocab: int, *, tile_n: int | None = None,
 _VMEM_LIMIT = 64 << 20
 
 
+def _vmem_limit(tiles: Tiles, width: int, itemsize: int) -> int:
+    """The limit a kernel asks for: 64 MiB where the backward step's blocks
+    (the larger of the two kernels') leave it a fifth of room, as at width
+    768 (39 MiB), else their size and a quarter more: at width 2048 the
+    accumulator and the gradient's tile alone are 32 MiB, the step 64 MiB,
+    and the compiler refused it by 16 KiB."""
+    tn, tv, _ = tiles
+    need = (2 * tv * tn * (4 + itemsize)      # logits in, dl out, x2 buffers
+            + 2 * (tn + tv) * width * itemsize  # x in, dW out, x2 buffers
+            + 4 * tv * width)                   # the float32 accumulator
+    return _VMEM_LIMIT if 5 * need <= 4 * _VMEM_LIMIT else need + need // 4
+
+
 def _row_ids(shape, first):
     return jax.lax.broadcasted_iota(jnp.int32, shape, 0) + first
 
@@ -225,7 +238,7 @@ def _bwd(lt, lse, labels, g, x, w_dtype, tiles: Tiles, interpret: bool):
         scratch_shapes=[pltpu.VMEM((tv, C), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=_vmem_limit(tiles, C, x.dtype.itemsize)),
         name="lm_head_bwd",
         interpret=interpret,
     )(lt, lse, labels, g, x)

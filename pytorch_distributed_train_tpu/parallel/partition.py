@@ -207,8 +207,11 @@ def llama_rules() -> list[tuple[str, PartitionSpec]]:
         (r"down_proj/kernel$", P("tensor", "fsdp")),
         # Final LM head
         (r"lm_head/kernel$", P("fsdp", "tensor")),
-        # Norm scales replicate
-        (r"(input_norm|post_attn_norm|final_norm)/scale$", P()),
+        # Norm scales replicate (four a layer under sandwich_norm), and the
+        # looped decoder's exit gate (d x 1 and a bias)
+        (r"(input_norm|attn_out_norm|post_attn_norm|mlp_out_norm|final_norm)"
+         r"/scale$", P()),
+        (r"exit_gate/(kernel|bias)$", P()),
         (r".*", P()),
     ]
 

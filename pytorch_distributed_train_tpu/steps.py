@@ -30,6 +30,7 @@ from pytorch_distributed_train_tpu.config import (  # noqa: F401
     LATENCY_HIDING_XLA_FLAGS,
     ensure_latency_hiding_flags,
 )
+from pytorch_distributed_train_tpu.models.llama import LoopExits
 from pytorch_distributed_train_tpu.ops import lm_head
 from pytorch_distributed_train_tpu.train_state import TrainState
 
@@ -260,6 +261,14 @@ def make_train_step(model, loss_fn: Callable, tx,
                 if why is not None:
                     with jax.named_scope("forward"):
                         logits = lm_head.logits(logits)
+            elif isinstance(logits, LoopExits):
+                # the looped decoder's exits: its loss takes each exit's
+                # head through the same kernels, or the logits path
+                exit_head = lm_head.HeadOperands(logits.x[0], logits.table,
+                                                 logits.cp)
+                resolved["head_loss"] = lm_head.log_resolution(
+                    exit_head, lm_head.unsupported(exit_head))
+                resolved["loop"] = f"scan x{logits.x.shape[0]}"
             elif (keeps_logits and getattr(logits, "ndim", 0) == 3
                   and "input_ids" in batch):
                 resolved["head_loss"] = lm_head.log_resolution(

@@ -154,6 +154,17 @@ class Trainer:
                 f"set together (got fused_lm_loss={fused_model}, "
                 f"loss={cfg.loss!r}): the fused model returns CE sums, not "
                 "logits, so no other loss can consume its output")
+        # the model object says whether it loops (models/llama.py), as it
+        # says whether it has a tied leaf (tied_shards, below)
+        looped_model = getattr(self.model, "loop_steps", 1) > 1
+        if looped_model != (cfg.loss == "looped_lm_xent"):
+            raise ValueError(
+                "a model with loop_steps > 1 and loss='looped_lm_xent' "
+                "must be set together (got model "
+                f"{type(self.model).__name__} with loop_steps="
+                f"{getattr(self.model, 'loop_steps', 1)}, "
+                f"loss={cfg.loss!r}): the looped decoder returns its exits, "
+                "not logits, and no other loss reads them")
         if fused_model and cfg.model.name not in ("llama", "gpt2"):
             raise ValueError(
                 f"fused_lm_loss is implemented for llama/gpt2, not "
@@ -394,6 +405,17 @@ class Trainer:
               and hasattr(self.model, "tied_shards")):
             self.model = self.model.clone(
                 tied_shards=self.grad_reduce.batch_devices)
+        # A looped decoder uses every weight loop_steps times, and the
+        # partitioner would reduce each use. In pure data parallelism its
+        # step runs a replica's own program inside shard_map (the replica's
+        # kernels as on one chip) and reduces the whole gradient tree once.
+        # (The batch splits evenly over the replicas or no step runs: the
+        # loader's device_put refuses it first.)
+        replica_step = looped_model and self.grad_reduce.mode == "per_leaf"
+        if replica_step:
+            self.grad_reduce = self.grad_reduce._replace(
+                why="a looped decoder: a replica's step inside shard_map, "
+                    "the gradient tree reduced once")
         if jax.process_index() == 0:
             print(f"[parallel] grad all-reduce: {self.grad_reduce.mode} "
                   f"({self.grad_reduce.batch_devices} device(s) on "
@@ -423,8 +445,16 @@ class Trainer:
                 state_shape.params, max(cfg.train.grad_bucket_mb, 1),
                 self.batch_axes)
             reduce_metrics = steps_lib.metrics_reducer(self.batch_axes)
+        reduce_grads_accum = None
+        if replica_step:
+            reduce_grads_accum = steps_lib.monolithic_grad_reducer(
+                self.batch_axes)
+            reduce_metrics = steps_lib.metrics_reducer(self.batch_axes)
         train_step = steps_lib.make_train_step(
-            self.model, self.loss_fn, self.tx,
+            # inside shard_map a replica's model has no mesh to hand its
+            # kernels: it is the one-chip model
+            build_model(cfg.model, cfg.precision) if replica_step
+            else self.model, self.loss_fn, self.tx,
             ema_decay=cfg.optim.ema_decay,
             swa_start=getattr(cfg.optim, "swa_start_step", 0),
             swa_every=getattr(cfg.optim, "swa_every", 1), mixup=mixup,
@@ -437,16 +467,17 @@ class Trainer:
             grad_accum_steps=cfg.train.grad_accum_steps,
             fused_update=self.fused_update,
             reduce_grads=reduce_grads,
+            reduce_grads_accum=reduce_grads_accum,
             reduce_metrics=reduce_metrics)
         self._step_resolved = train_step.resolved
         if cfg.optim.offload_state:
             train_step = steps_lib.offload_opt_state(
                 train_step, opt_dev_sharding, self.state_sharding.opt_state)
-        if cfg.train.overlap_collectives:
+        if replica_step or cfg.train.overlap_collectives:
             self.train_step = steps_lib.jit_overlap_train_step(
                 train_step, self.mesh, self.state_sharding,
                 self.batch_axes)
-            if jax.process_index() == 0:
+            if cfg.train.overlap_collectives and jax.process_index() == 0:
                 print(f"[train] overlapped collectives: "
                       f"{len(self.grad_buckets)} grad bucket(s) x "
                       f"{cfg.train.grad_accum_steps} microbatch(es), "

@@ -175,15 +175,19 @@ def _attn_proj_flops(cfg) -> float:
 
 
 def llama_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
-    """models/llama.py: RMSNorm blocks, GQA, SwiGLU, untied head."""
+    """models/llama.py: RMSNorm blocks, GQA, SwiGLU, untied head. The
+    looped decoder (``loop_steps`` T > 1) applies its layers T times and
+    its head at each of the T exits: both count T times, whatever the
+    parameter count says (the exit gate's d products a pass are left out)."""
     s = seq or cfg.max_seq_len
     d, m = cfg.hidden_size, cfg.mlp_dim
+    passes = max(getattr(cfg, "loop_steps", 1), 1)
     per_layer = (
         _attn_proj_flops(cfg)
         + 4.0 * s * d       # QK^T + AV (un-masked convention)
         + 6.0 * d * m       # SwiGLU: gate + up + down
     )
-    return cfg.num_layers * per_layer + 2.0 * d * cfg.vocab_size
+    return passes * (cfg.num_layers * per_layer + 2.0 * d * cfg.vocab_size)
 
 
 def gpt2_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
@@ -387,11 +391,14 @@ def llama_param_count(cfg) -> float:
         d * d + d * d                 # q_proj + o_proj
         + 2 * d * (hkv * dh)          # k_proj + v_proj
         + 3 * d * m                   # SwiGLU gate/up/down
-        + 2 * d                       # two RMSNorm scales
+        # two RMSNorm scales, four under sandwich_norm
+        + (4 if getattr(cfg, "sandwich_norm", False) else 2) * d
     )
-    return (cfg.num_layers * per_layer
+    looped = getattr(cfg, "loop_steps", 1) > 1
+    return (cfg.num_layers * per_layer  # ONE stack, however often applied
             + 2 * cfg.vocab_size * d  # embedding + untied head
-            + d)                      # final norm
+            + d                       # final norm
+            + (d + 1 if looped else 0))  # the exit gate and its bias
 
 
 def decode_bytes_per_token(cfg, *, batch: int, avg_position: float,

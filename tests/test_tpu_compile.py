@@ -35,6 +35,8 @@ from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_train_tpu.ops import flash_attention as fa
 
+LOOP_FLASH_KERNELS = 4  # the looped step's, at depth 1: see its test
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -422,6 +424,108 @@ def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
         r"= (\w+\[[\d,]*\])[^\n]*? all-reduce\(", text[text.index("\nENTRY"):])
         if m.group(1).endswith(table)]
     assert reduced == ["f32" + table], reduced
+
+
+def _all_reduced(text, shape):
+    """How often an array of ``shape`` (``f32[2048,5632]``) is all-reduced
+    in a compiled program, alone or inside a tupled all-reduce."""
+    return sum(len(re.findall(re.escape(shape), m.group(1)))
+               for m in re.finditer(
+                   r"= ([^\n]*?) all-reduce(?:-start)?\(",
+                   text[text.index("\nENTRY"):]))
+
+
+def test_looped_step_holds_its_flash_and_head_kernels_by_name(one_chip,
+                                                              monkeypatch):
+    """The looped decoder's step at the cell's shape (1 x 4096, the whole
+    vocabulary, T 4 passes; depth cut to 1 for the suite's sake), compiled
+    for the described chip: the flash kernel's instructions under the names
+    `flash_kernel_pattern` finds, the head's two kernels once an exit under
+    the names `head_kernel_pattern` finds and the flash pattern does not
+    (at width 2048 the backward kernel asks for more VMEM than at 768:
+    ops/lm_head_loss.py `_vmem_limit`), and no elementwise pass over an
+    array of the logits' shape."""
+    lowered, bench, cfg = _lowered_step(
+        "ouro_2_6b_lm_l8", one_chip, monkeypatch, ["model.num_layers=1"])
+    text = lowered.compile().as_text()
+    names = _custom_calls(text)
+    flash = [n for n in names if re.search(bench["flash_kernel_pattern"], n)]
+    head = [n for n in names if re.search(bench["head_kernel_pattern"], n)]
+    # forward, the forward remat runs again, dQ, dK/dV: once a layer in
+    # the scanned pass's body, whatever the number of passes
+    assert len(flash) == LOOP_FLASH_KERNELS, flash
+    assert not set(flash) & set(head)
+    passes = cfg.model.loop_steps
+    assert sum("lm_head_fwd" in n for n in head) == passes, head
+    assert sum("lm_head_bwd" in n for n in head) == passes, head
+    rows, vocab = cfg.data.seq_len, cfg.model.vocab_size
+    logits = re.compile(rf"\[(\d+,\d+,{vocab}|{rows},{vocab}|{vocab},{rows})\]")
+    loops = [ln.strip()[:160] for ln in text.splitlines()
+             if "kind=kLoop" in ln and logits.search(ln)
+             and "calls=%bitcast_fusion" not in ln]
+    assert not loops, loops
+
+
+def test_dp4_looped_step_reduces_each_weight_once(topo, monkeypatch):
+    """The looped decoder under `data=4` (four sequences of 4096, depth cut
+    to 1), compiled for the described v5e:2x2 the way the trainer builds
+    it: steps.grad_reduce_plan sees pure data parallelism, so the step is
+    a replica's own program inside shard_map (its flash and head kernels
+    as on one chip, a chip on its own sequence) and the gradient tree is
+    reduced once: ONE all-reduce of a layer's (2048, 5632) projections and
+    of the (2048, 49152) head, in float32, where the partitioner alone
+    leaves one a use (four: tests/test_ouro_lm.py holds the same on CPU
+    devices, with the values)."""
+    from pytorch_distributed_train_tpu import losses as losses_lib
+    from pytorch_distributed_train_tpu import steps as steps_lib
+    from pytorch_distributed_train_tpu.config import get_preset
+    from pytorch_distributed_train_tpu.models.registry import build_model
+    from pytorch_distributed_train_tpu.ops import attention as attention_lib
+    from pytorch_distributed_train_tpu.optim import make_optimizer
+    from pytorch_distributed_train_tpu.parallel.mesh import build_mesh
+    from pytorch_distributed_train_tpu.parallel.partition import (
+        rules_for_model,
+    )
+    from pytorch_distributed_train_tpu.train_state import TrainState
+
+    monkeypatch.setattr(attention_lib, "_on_tpu", lambda: True)
+    cfg = get_preset("ouro_2_6b_lm_l8")
+    cfg.apply_overrides(["data.batch_size=4", "model.num_layers=1"])
+    mesh = build_mesh(cfg.mesh, devices=topo.devices)
+    batch_axes = tuple(cfg.mesh.batch_axes)
+    replica = build_model(cfg.model, cfg.precision)  # no mesh inside
+    tx, _ = make_optimizer(cfg.optim, cfg.total_steps, 0)
+    dummy = steps_lib.dummy_inputs(cfg.loss, cfg.model, cfg.data)
+
+    def init(rng):
+        params = replica.init({"params": rng}, *dummy, train=False)["params"]
+        return TrainState.create(params=params, tx=tx, batch_stats={},
+                                 dynamic_scale=None, ema=False, swa=False)
+
+    shape = jax.eval_shape(init, jax.random.PRNGKey(0))
+    sharding = steps_lib.state_shardings(
+        mesh, rules_for_model(cfg.model.name), shape)
+    plan = steps_lib.grad_reduce_plan(mesh, sharding, batch_axes)
+    assert (plan.mode, plan.batch_devices) == ("per_leaf", 4)
+    step = steps_lib.jit_overlap_train_step(
+        steps_lib.make_train_step(
+            replica, losses_lib.get_loss_fn(cfg.loss), tx,
+            reduce_grads_accum=steps_lib.monolithic_grad_reducer(batch_axes),
+            reduce_metrics=steps_lib.metrics_reducer(batch_axes)),
+        mesh, sharding, batch_axes)
+    state = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), shape, sharding)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (cfg.data.batch_size, cfg.data.seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P(batch_axes)))}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+    text = step.lower(state, batch, rng).compile().as_text()
+    names = _custom_calls(text)
+    assert sum("lm_head_fwd" in n for n in names) == cfg.model.loop_steps
+    # gate_proj and up_proj of the layer, each used four times: once each
+    assert _all_reduced(text, "f32[2048,5632]") == 2 * cfg.model.num_layers
+    assert _all_reduced(text, "f32[2048,49152]") == 1
 
 
 @pytest.mark.parametrize("bits", [8, 4])
