@@ -95,9 +95,12 @@ class ModelConfig:
     # Memory: rematerialise each transformer block's activations in backward
     remat: bool = False
     # What remat may keep resident (models/remat.py — the selective
-    # activation-checkpointing dial): "full" recomputes everything,
+    # activation-checkpointing dial): "full" recomputes the block,
     # "dots" keeps matmul outputs (XLA dots_saveable), "dots_no_batch"
-    # keeps only non-batch-dim matmuls.
+    # keeps only non-batch-dim matmuls. Every policy also keeps what a
+    # Pallas attention kernel handed back (its output and log-sum-exp:
+    # one (B, S, H x Dv) activation and a (B, H, S) fp32 row a layer),
+    # so the backward pass does not launch the kernel's forward again.
     remat_policy: str = "full"
     # Fused chunked LM-head loss (llama/gpt2): head matmul + CE computed per
     # sequence chunk under remat so (B,S,V) logits never materialize

@@ -51,6 +51,15 @@ def _resolve_default_impl() -> str:
     return _env_impl() or _default_impl
 
 
+# The checkpoint_name of what the Pallas kernel's forward hands back, its
+# output and log-sum-exp (flash_attention.py `_flash_fwd` tags them): a
+# remat policy that names it (models/remat.py: every one) keeps the two,
+# (B, S, H x Dv) in the input's dtype and (B, H, S) fp32 a call, and the
+# backward kernels read them; one that does not runs the forward kernel
+# again for them. Defined here and not beside the tag so that the policies'
+# module, which every training step imports, does not import Pallas.
+FLASH_RESIDUALS_NAME = "flash_out+lse"
+
 _resolutions_logged: set[tuple] = set()
 
 

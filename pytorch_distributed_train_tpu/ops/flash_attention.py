@@ -87,8 +87,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from pytorch_distributed_train_tpu.ops.attention import FLASH_RESIDUALS_NAME
 
 NEG_INF = -1e30
 
@@ -765,6 +768,10 @@ def _flash(q3, k3, v3, causal, scale, tiles, interpret, window):
 def _flash_fwd(q3, k3, v3, causal, scale, tiles, interpret, window):
     o, lse = _fwd(q3, k3, v3, causal=causal, scale=scale, tiles=tiles,
                   window=window, interpret=interpret)
+    # tagged HERE, inside the custom-vjp forward, so that lse, which is no
+    # output, can be kept too; the identity outside jax.checkpoint
+    o = checkpoint_name(o, FLASH_RESIDUALS_NAME)
+    lse = checkpoint_name(lse, FLASH_RESIDUALS_NAME)
     return o, (q3, k3, v3, o, lse)
 
 

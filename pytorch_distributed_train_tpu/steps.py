@@ -30,6 +30,7 @@ from pytorch_distributed_train_tpu.config import (  # noqa: F401
     LATENCY_HIDING_XLA_FLAGS,
     ensure_latency_hiding_flags,
 )
+from pytorch_distributed_train_tpu.models import remat
 from pytorch_distributed_train_tpu.models.llama import LoopExits
 from pytorch_distributed_train_tpu.ops import lm_head
 from pytorch_distributed_train_tpu.train_state import TrainState
@@ -214,7 +215,7 @@ def make_train_step(model, loss_fn: Callable, tx,
 
     model, keeps_logits = _head_loss_plan(model, loss_fn, teacher_fn)
     # what the trace resolved: the trainer's train.compile span carries it
-    resolved = {"head_loss": "none"}
+    resolved = {"head_loss": "none", "remat_keeps": "none"}
 
     def transform_batch(batch, dropout_rng):
         """Per-(micro)batch input transforms, same fold-in discipline
@@ -280,7 +281,11 @@ def make_train_step(model, loss_fn: Callable, tx,
             scaled = total * scale if scale is not None else total
             return scaled, (loss, aux, model_aux, new_stats)
 
-        return jax.grad(loss_for_grad, has_aux=True)(params)
+        remat.kept.clear()
+        grads_and_aux = jax.grad(loss_for_grad, has_aux=True)(params)
+        if remat.kept:  # by name, as that backward pass was traced
+            resolved["remat_keeps"] = "+".join(sorted(remat.kept))
+        return grads_and_aux
 
     def accum_grads(state, batch, dropout_rng, scale):
         """lax.scan over grad_accum_steps microbatches: grads (still

@@ -189,7 +189,7 @@ def test_gpt2s_training_step_takes_the_kernels_with_no_knob(as_on_a_tpu,
     err = capfd.readouterr().err
     assert ("[lm_head] impl=pallas rows=256 vocab=328 width=128 "
             "tiles=256x384 ragged_cols=328 logits=float32") in err
-    assert step.resolved == {"head_loss": "pallas"}
+    assert step.resolved == {"head_loss": "pallas", "remat_keeps": "none"}
 
     # the same step with the model's capability hidden: the logits path
     class NoOperands:
@@ -270,7 +270,8 @@ def test_what_needs_the_logits_still_gets_them(as_on_a_tpu, monkeypatch,
         step = steps_lib.make_train_step(model, loss_fn, tx,
                                          teacher_fn=teacher_fn)
         text = _traced(step, state, batch, rng)
-        assert step.resolved == {"head_loss": f"xla: {reason}"}
+        assert step.resolved == {"head_loss": f"xla: {reason}",
+                                 "remat_keeps": "none"}
     assert "pallas_call" not in text
     lines = [ln for ln in capfd.readouterr().err.splitlines()
              if ln.startswith("[lm_head]")]
@@ -298,4 +299,4 @@ def test_other_losses_steps_trace_what_they_traced(as_on_a_tpu, capfd):
     assert planned.head_operands and not model.head_operands and why is None
     assert capfd.readouterr().err == ""
     step = steps_lib.make_train_step(model, losses_lib.mlm_xent, None)
-    assert step.resolved == {"head_loss": "none"}
+    assert step.resolved == {"head_loss": "none", "remat_keeps": "none"}

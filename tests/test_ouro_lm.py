@@ -508,4 +508,5 @@ def test_fit_logs_the_nine_exit_gauges_the_loop_line_and_the_spans_word(
              if s.thread == main and s.name == "train.compile"]
     assert spans[-1].args["loop"] == "scan x3"
     assert spans[-1].args["head_loss"] == "xla: the backend is not a TPU"
+    assert spans[-1].args["remat_keeps"] == "none"  # no kernel on the CPU
     assert "loop_pass" in text and "exit_head" in text

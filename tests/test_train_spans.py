@@ -90,6 +90,16 @@ def test_the_compile_span_says_what_took_the_head_and_its_loss(run):
     assert all("head_loss" not in s.args for s in named(run, "train.step"))
 
 
+def test_the_compile_span_says_what_remat_keeps(run):
+    """`remat_keeps` rides the same span: the name under which a remat'd
+    block kept the flash kernel's output and log-sum-exp (models/remat.py),
+    `none` here, where no block is remat'd and no kernel runs
+    (tests/test_remat_policy.py holds the other value)."""
+    (compile_span,) = named(run, "train.compile")
+    assert compile_span.args["remat_keeps"] == "none"
+    assert all("remat_keeps" not in s.args for s in named(run, "train.step"))
+
+
 def test_cadenced_steps_carry_a_log_with_its_sync(run):
     logs = named(run, "train.log")
     assert [s.args["step"] for s in logs] == [4, 8, 9]  # cadence, horizon
