@@ -480,12 +480,18 @@ class ManagedProfiler:
 
     def _summarize(self, logdir: str) -> str:
         """Best-effort top-ops report over the fresh dump — the capture
-        is useful without it (the xplane proto needs the tsl protobuf)."""
+        is useful without it (the xplane proto needs the tsl protobuf).
+        Where the trainer mapped its compiled step (obs/step_program.py),
+        each top operation carries what the map says it is:
+        ``%fusion.2973 ... [backward/ffn]``."""
         try:
+            from pytorch_distributed_train_tpu.obs import step_program
             from pytorch_distributed_train_tpu.utils import xplane
 
+            program = step_program.latest()
             text = xplane.report(
-                logdir, top=getattr(self.cfg, "profile_top_ops", 5))
+                logdir, top=getattr(self.cfg, "profile_top_ops", 5),
+                describe=program.describe if program is not None else None)
         except Exception as e:
             text = (f"(xplane summary unavailable: "
                     f"{type(e).__name__}: {e})")

@@ -400,8 +400,12 @@ def make_train_step(model, loss_fn: Callable, tx,
             # elementwise — no host round-trip, no recompile; the step
             # counter advances either way
             skipped = state.replace(step=state.step + 1)
-            return jax.tree.map(lambda new, old: jnp.where(ok, new, old),
-                                stepped, skipped)
+            # XLA fuses the select into the update's last write and names
+            # the fusion by it: it is the optimizer's
+            with jax.named_scope("optimizer"):
+                return jax.tree.map(
+                    lambda new, old: jnp.where(ok, new, old),
+                    stepped, skipped)
 
         gated = numeric_guard or model_ok is not None
         if state.dynamic_scale is not None:

@@ -36,6 +36,7 @@ from pytorch_distributed_train_tpu.obs import memory as memory_lib
 from pytorch_distributed_train_tpu.obs import perf as perf_lib
 from pytorch_distributed_train_tpu.obs import profiler as profiler_lib
 from pytorch_distributed_train_tpu.obs import spans as spans_lib
+from pytorch_distributed_train_tpu.obs import step_program
 from pytorch_distributed_train_tpu.obs import tracing
 from pytorch_distributed_train_tpu.obs.goodput import GoodputTracker
 from pytorch_distributed_train_tpu.obs.registry import get_registry
@@ -487,6 +488,10 @@ class Trainer:
             self.train_step = steps_lib.jit_train_step(
                 train_step, self.mesh, self.state_sharding, self.batch_axes,
             )
+        # the jitted step under a name no wrapper replaces: a benchmark or
+        # a test puts its own callable at ``train_step``, and the program
+        # map (``_map_step_program``) needs the jit's ``.lower``
+        self._jit_train_step = self.train_step
         self.eval_step = steps_lib.jit_eval_step(
             steps_lib.make_eval_step(
                 self.model, self.eval_loss_fn,
@@ -1014,6 +1019,8 @@ class Trainer:
                             if is_first:  # what the trace resolved to
                                 dispatch.args.update(self._step_resolved)
                         self._stepped = True
+                        if is_first:
+                            self._map_step_program(batch, step)
                         if inflate_loss:
                             # step.loss_spike drill: corrupt the OBSERVED
                             # loss everywhere one observation is read —
@@ -1295,6 +1302,37 @@ class Trainer:
         except Exception as e:
             print(f"[perf-ledger] trainer append failed "
                   f"({type(e).__name__}: {e})", flush=True)
+
+    def _map_step_program(self, batch, step: int) -> None:
+        """After the first step: which scope made each instruction of the
+        compiled step (obs/step_program.py), inside a ``train.program_map``
+        span beside ``train.compile``. The jitted step, lowered again for
+        the live state and this batch on this thread, comes from JAX's
+        in-process caches (no trace, no lowering, no backend compile), so
+        the span costs one text dump and one pass over it. Best effort,
+        never an exception: a step that was not the jit's (a plain function
+        put at ``train_step``), or one whose lowering does compile (JAX's
+        own compile event lands inside the span: the program described
+        would not be the one that ran), leaves no map and a
+        ``program_map=none: <reason>`` attribute."""
+        with self.spans.span("train.program_map", step=step) as sp:
+            step_program.clear()
+            try:
+                compiled = self._jit_train_step.lower(
+                    self.state, batch, self.step_rng).compile()
+                if any(s.name == "jax.compile" and s.parent_seq == sp.seq
+                       for s in self.spans.events()):
+                    raise RuntimeError(
+                        "lowering the step again compiled it: the program "
+                        "described would not be the one that ran")
+                built = step_program.record(compiled, step)
+            except Exception as e:  # noqa: BLE001 - observability only
+                sp.args["program_map"] = f"none: {type(e).__name__}: {e}"
+                return
+            sp.args.update(instructions=built.instructions,
+                           fusions=built.fusions,
+                           mixed_fusions=len(built.mixed),
+                           text_mb=round(built.text_bytes / 1e6, 3))
 
     def _timed_batches(self, it):
         """Yield from the epoch iterator, accounting time blocked in its

@@ -154,8 +154,11 @@ def summarize_xspace(xs, device_only: bool = True) -> list[dict[str, Any]]:
     return out
 
 
-def report(logdir: str, top: int = 15) -> str:
-    """Human-readable top-ops report for the newest dump in ``logdir``."""
+def report(logdir: str, top: int = 15, describe=None) -> str:
+    """Human-readable top-ops report for the newest dump in ``logdir``.
+    ``describe(name) -> str`` may say what an operation is (the program's
+    map of its compiled step gives ``backward/ffn``): printed in brackets
+    after the name where it says anything."""
     files = find_xplane_files(logdir)
     if not files:
         return f"no *.xplane.pb files under {logdir}"
@@ -173,7 +176,9 @@ def report(logdir: str, top: int = 15) -> str:
             lines.append(f"    {ms:10.2f} ms  {pct:5.1f}%  {cls}")
         lines.append(f"  top {top} ops:")
         for name, ms, n in plane["ops"][:top]:
-            lines.append(f"    {ms:10.2f} ms  n={n:<6d} {name[:100]}")
+            what = describe(name) if describe is not None else ""
+            lines.append(f"    {ms:10.2f} ms  n={n:<6d} {name[:100]}"
+                         + (f"  [{what}]" if what else ""))
     return "\n".join(lines)
 
 
