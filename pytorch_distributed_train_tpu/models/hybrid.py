@@ -38,6 +38,7 @@ from pytorch_distributed_train_tpu.models.llama import (
     rope_frequencies,
 )
 from pytorch_distributed_train_tpu.ops import kda as kda_ops
+from pytorch_distributed_train_tpu.ops import kda_inputs
 from pytorch_distributed_train_tpu.ops.attention import (
     ContextParallelConfig,
     dot_product_attention,
@@ -52,13 +53,20 @@ KINDS = ("kda", "mla", "gqa_full", "gqa_window")
 _F32_OUT = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
 
 
-def causal_short_conv(x, weight):
-    """Depthwise causal convolution along the sequence: x (B, S, H, d),
-    weight (K, H, d); y_t = sum_j weight[j] x_{t-(K-1-j)}, zeros before the
-    start (weight[K-1] meets the current token)."""
-    K, S = weight.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0), (0, 0)))
-    return sum(padded[:, j:j + S] * weight[j] for j in range(K))
+def _heads_merged(dot_general=jax.lax.dot_general):
+    """``W x`` with the kernel's (H, d) as ONE dimension of the product,
+    split again after it. The same numbers; what changes is the layout the
+    TPU's compiler gives the result: a product onto (H, d) comes out with
+    the SEQUENCE on the lanes, ``[B, S, H, d]{1,3,2,0}``, and a product onto
+    (H d) row-major, which is what the kernels of ops/kda_inputs.py read
+    and write: no copy on either side of them (PERF.md section 6, PR 38)."""
+
+    def dot(lhs, rhs, dims, precision=None, preferred_element_type=None):
+        out = dot_general(lhs, rhs.reshape(rhs.shape[0], -1), dims,
+                          precision=precision)
+        return out.reshape(*lhs.shape[:-1], *rhs.shape[1:])
+
+    return dot
 
 
 def _head_gate(x, num_heads, dtype, param_dtype):
@@ -74,7 +82,9 @@ class KDAMixer(nn.Module):
     L2-normalised a head, q scaled by d^-1/2; log-decay
     g = lower_bound * sigmoid(exp(A_log) (W_a x + dt_bias)) a key channel;
     beta = sigmoid(W_beta x) a head; the delta-rule recurrence; RMSNorm over
-    each head's output, times a head-wise sigmoid gate; W_o. No rotary."""
+    each head's output, times a head-wise sigmoid gate; W_o. No rotary.
+    What shapes the projections into q, k, v, g is ops/kda_inputs.py: where
+    the core runs in its kernels a kernel pair on the same rows, else XLA."""
 
     num_heads: int
     head_dim: int
@@ -88,10 +98,14 @@ class KDAMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         H, d, f32 = self.num_heads, self.head_dim, jnp.float32
-        proj = lambda name, **kw: nn.DenseGeneral(  # noqa: E731
+        # where the shaping and the core run in their kernels (one gate for
+        # both), the projections hand them rows of whole heads
+        in_kernels = kda_ops.unsupported(x.shape[1], d, d, self.dtype,
+                                         self.cp) is None
+        proj = lambda name, dot=jax.lax.dot_general: nn.DenseGeneral(  # noqa: E731
             (H, d), axis=-1, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT, name=name,
-            **kw)(x)
+            dot_general=_heads_merged(dot) if in_kernels else dot)(x)
 
         taps = [self.param(name, _INIT, (self.conv_kernel_size, H, d),
                            self.param_dtype)
@@ -103,27 +117,10 @@ class KDAMixer(nn.Module):
             "dt_bias", lambda key, shape, dtype: jax.random.uniform(
                 key, shape, dtype, -5.0, -1.0), (H, d), f32)
 
-        @jax.checkpoint  # elementwise chains: recomputed, not kept
-        def shape_inputs(yq, yk, yv, a, taps, a_log, dt_bias):
-            def conv_silu(y, w):
-                return nn.silu(causal_short_conv(y.astype(f32),
-                                                 w.astype(f32)))
-
-            def unit(y):
-                return y * jax.lax.rsqrt(jnp.sum(y * y, -1, keepdims=True)
-                                         + 1e-6)
-
-            q = unit(conv_silu(yq, taps[0])) * d ** -0.5
-            k = unit(conv_silu(yk, taps[1]))
-            v = conv_silu(yv, taps[2])
-            g = self.gate_lower_bound * jax.nn.sigmoid(
-                jnp.exp(a_log)[:, None] * (a.astype(f32) + dt_bias))
-            return (q.astype(self.dtype), k.astype(self.dtype),
-                    v.astype(self.dtype), g)
-
-        q, k, v, g = shape_inputs(
+        q, k, v, g = kda_inputs.shape_inputs(
             proj("q_proj"), proj("k_proj"), proj("v_proj"),
-            proj("a_proj", dot_general=_F32_OUT), taps, a_log, dt_bias)
+            proj("a_proj", _F32_OUT), taps, a_log, dt_bias,
+            lower_bound=self.gate_lower_bound, cp=self.cp)
         beta = jax.nn.sigmoid(nn.Dense(
             H, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=_INIT, dot_general=_F32_OUT,

@@ -55,7 +55,10 @@ triangle over g split three ways (exact products, float32 sums). Their
 chunk is their own (``KERNEL_CHUNK``; a grid step walks a tile of 128
 tokens): ``[kda] ... impl=pallas|xla`` says once a shape which path ran,
 with the chunk in use, and utils/flops.py counts with it
-(:func:`chunk_in_use`).
+(:func:`chunk_in_use`). The same gate decides for what shapes the core's
+inputs (ops/kda_inputs.py: the convolution, SiLU, unit norm and decay
+gate between the projections and this core): a kernel pair on the rows the
+core's kernels read, so no layout copy stands between them, or XLA's chain.
 
 Memory. XLA path: the sequence is walked in segments of ``segment_chunks``
 chunks under ``jax.checkpoint``: the backward pass keeps one state a
@@ -128,19 +131,22 @@ def chunk_in_use(S: int, d_k: int, d_v: int) -> int:
     return min(DEFAULT_CHUNK, S)
 
 
-def log_plan(S: int, chunk: int, heads: int, d_k: int, d_v: int,
+def log_plan(S: int, chunk: int | None, heads: int, d_k: int, d_v: int,
              impl: str) -> None:
     """Say once a shape, at trace time, how the sequence is cut and what
     runs it (stderr, like the attention dispatch's line): ``impl=pallas``
     with the kernels' tile, or ``impl=xla`` with the scan's length and the
-    reason."""
+    reason. The input shaping (ops/kda_inputs.py) says ``inputs=pallas``
+    with its tile or ``inputs=xla`` with the reason on a line of its own,
+    which names no chunk."""
     key = (S, chunk, heads, d_k, d_v, impl)
     if key in _logged:
         return
     _logged.add(key)
-    print(f"[kda] S={S} chunk={chunk} chunks={S // chunk} heads={heads} "
-          f"d_k={d_k} d_v={d_v} state_dtype=float32 {impl}", file=sys.stderr,
-          flush=True)
+    cut = (f"chunk={chunk} chunks={S // chunk} heads={heads} d_k={d_k} "
+           f"d_v={d_v} state_dtype=float32" if chunk else
+           f"heads={heads} d={d_k}")
+    print(f"[kda] S={S} {cut} {impl}", file=sys.stderr, flush=True)
 
 
 def kda_recurrent(q, k, v, g, beta):
@@ -307,10 +313,28 @@ def _kda_scan(q, k, v, g, beta, chunk, segment_chunks):
     return o.astype(q.dtype)
 
 
+def on_own_block(local, cp, like, specs):
+    """``local`` as it runs a device: itself on one device, else inside a
+    manual region over the axes that shard batch and heads (GSPMD cannot
+    partition a Mosaic call: ops/attention.py ``_sharded_flash``).
+    ``like``: a (B, S, H, d) operand; ``specs(spec)`` gives the region's
+    (in_specs, out_specs) from that operand's spec."""
+    if cp is None or cp.mesh.size == 1:
+        return local
+    from pytorch_distributed_train_tpu.ops.cp_common import qkv_spec
+    from pytorch_distributed_train_tpu.utils.compat import shard_map
+
+    spec = qkv_spec(like, like, cp.mesh, context_axis=None,
+                    batch_axes=cp.batch_axes, tensor_axis=cp.tensor_axis)
+    in_specs, out_specs = specs(spec)
+    return shard_map(local, mesh=cp.mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
+
+
 def _kda_kernels(q, k, v, g, beta, cp):
-    """The kernel pair; under a sharded mesh inside a manual region over
-    the axes that shard batch and heads (GSPMD cannot partition a Mosaic
-    call: ops/attention.py ``_sharded_flash``)."""
+    """The kernel pair, a device on its own block of batch and heads."""
+    from jax.sharding import PartitionSpec as P
+
     from pytorch_distributed_train_tpu.ops import kda_kernel
 
     def local(q, k, v, g, beta):
@@ -324,15 +348,6 @@ def _kda_kernels(q, k, v, g, beta, cp):
                 q, k, v, g, beta,
                 kda_kernel.Plan(KERNEL_CHUNK, hb, _interpret()))
 
-    if cp is None or cp.mesh.size == 1:
-        return local(q, k, v, g, beta)
-    from jax.sharding import PartitionSpec as P
-
-    from pytorch_distributed_train_tpu.ops.cp_common import qkv_spec
-    from pytorch_distributed_train_tpu.utils.compat import shard_map
-
-    spec = qkv_spec(q, k, cp.mesh, context_axis=None,
-                    batch_axes=cp.batch_axes, tensor_axis=cp.tensor_axis)
-    return shard_map(local, mesh=cp.mesh,
-                     in_specs=(spec,) * 4 + (P(*spec[:3]),), out_specs=spec,
-                     check_vma=False)(q, k, v, g, beta)
+    return on_own_block(
+        local, cp, q,
+        lambda spec: ((spec,) * 4 + (P(*spec[:3]),), spec))(q, k, v, g, beta)

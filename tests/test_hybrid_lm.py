@@ -284,6 +284,86 @@ def test_kda_layer_matches_the_reference_token_by_token(bench):
     _close(got, want)
 
 
+def _wide_kda_layer(seed):
+    """A KDA layer at heads the kernels take (two heads of 128, a tile of
+    128 tokens), its parameters and an input."""
+    mixer = hybrid.KDAMixer(2, 128, 4, -5.0, 1e-6, F32, F32)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (2, 128, 64))
+    params = mixer.init({"params": jax.random.PRNGKey(seed + 1)},
+                        x)["params"]
+    return mixer, params, x
+
+
+def _steer_kda(monkeypatch):
+    """The dispatch sees a TPU, the kernels run interpreted (as
+    tests/test_kda.py and tests/test_kda_inputs.py steer it)."""
+    from pytorch_distributed_train_tpu.ops import attention, kda
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "_interpret", lambda: True)
+    monkeypatch.setattr(kda, "_logged", set())
+
+
+def test_kda_layer_in_its_kernels_is_the_layer_on_the_xla_path(
+        monkeypatch, capfd):
+    """Where the gate lets the kernels run, the shaping's pair feeds the
+    core's pair: the same outputs and the same gradient of every
+    parameter as the XLA chain and scan, and one ``[kda]`` line a shape
+    for each of the two, ``inputs=`` beside ``impl=``."""
+    from pytorch_distributed_train_tpu.ops import kda
+
+    mixer, params, x = _wide_kda_layer(31)
+    w = jax.random.normal(jax.random.PRNGKey(33), x.shape)
+    run = lambda p: mixer.apply({"params": p}, x)  # noqa: E731
+    both = jax.value_and_grad(lambda p: jnp.sum(run(p) * w))
+    monkeypatch.setattr(kda, "_logged", set())
+    capfd.readouterr()  # what building the layer said
+    want_out, (want_loss, want) = run(params), both(params)
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("[kda]")]
+    assert [ln.split(" reason=")[0].split()[-1] for ln in lines] \
+        == ["inputs=xla", "impl=xla"], lines
+    _steer_kda(monkeypatch)
+    got_out, (got_loss, got) = run(params), both(params)
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("[kda]")]
+    assert len(lines) == 2, lines
+    assert lines[0].endswith("inputs=pallas tile=128 heads_per_step=2")
+    assert " impl=pallas tile=128 " in lines[1]
+    _close(got_out, want_out)
+    assert abs(float(got_loss - want_loss)) < 2e-5 * abs(float(want_loss))
+    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
+                      jax.tree_util.tree_flatten_with_path(t)[0]}
+    got, want = flat(got), flat(want)
+    assert set(got) == set(want) and len(want) == 13
+    for leaf, value in want.items():
+        _close(got[leaf], value, tol=1e-4)
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_the_programs_map_names_the_shaping_on_both_paths(path, monkeypatch):
+    """``jax.named_scope("kda_inputs")`` stands round the shaping whichever
+    path runs, forward and backward: the map of a compiled step
+    (obs/step_program.py) holds instructions under it, which the
+    benchmark's ``kda_inputs_ms_per_step`` sums."""
+    from pytorch_distributed_train_tpu.obs import step_program
+
+    if path == "pallas":
+        _steer_kda(monkeypatch)
+    mixer, params, x = _wide_kda_layer(35)
+
+    def forward(p):
+        return jnp.sum(mixer.apply({"params": p}, x) ** 2)
+
+    text = jax.jit(jax.grad(forward)).lower(params).compile().as_text()
+    built = step_program.scope_map(text)
+    under = [op for op in built.scopes.values()
+             if "kda_inputs" in op.split("/")]
+    assert any("transpose(jvp(" in op for op in under), under[:5]
+    assert any("transpose(jvp(" not in op for op in under), under[:5]
+    assert all("/KDAMixer/" in op or "kda_inputs" in op for op in under)
+
+
 def test_model_logits_match_the_reference_on_its_seeded_weights(bench):
     _, ref, cfg = bench
     model = build_model(cfg.model, cfg.precision)

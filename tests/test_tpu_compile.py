@@ -200,6 +200,46 @@ def test_kda_kernel_pair_compiles_at_the_hybrid_cells_shape(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+def test_kda_mixer_in_its_kernels_moves_no_tensor_round_them(one_chip,
+                                                             monkeypatch):
+    """One KDA mixer at the hybrid cell's widths with its gradient, under
+    ``jax.checkpoint`` as ``model.remat`` runs it: the shaping's pair
+    (ops/kda_inputs.py) twice forward and once backward beside the core's,
+    the four projections' results row-major (a product onto (H d): onto
+    (H, d) the compiler puts the sequence on the lanes), and so no layout
+    copy under the shaping's scope, in front of the pair or behind it."""
+    from pytorch_distributed_train_tpu.models import hybrid
+    from pytorch_distributed_train_tpu.ops import attention as attention_lib
+
+    monkeypatch.setattr(attention_lib, "_on_tpu", lambda: True)
+    B, S, D = 2, 8192, 2560
+    mixer = hybrid.KDAMixer(32, 128, 4, -5.0, 1e-6, jnp.bfloat16,
+                            jnp.float32)
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip),
+        tree)
+    x = jax.ShapeDtypeStruct((B, S, D), jnp.bfloat16)
+    params = jax.eval_shape(lambda: mixer.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros(x.shape, x.dtype)))
+    layer = jax.checkpoint(lambda p, x: mixer.apply(p, x))
+    loss = lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32) ** 2)  # noqa: E731
+    text = _compile(jax.grad(loss, argnums=(0, 1)), described(params),
+                    described(x)).as_text()
+    names = [n.split(".")[0] for n in _custom_calls(text) if "kda" in n]
+    assert sorted(names) == ["%kda_bwd", "%kda_fwd", "%kda_fwd",
+                             "%kda_inputs_bwd", "%kda_inputs_fwd",
+                             "%kda_inputs_fwd"], names
+    moved = [ln.strip()[:200] for ln in text.splitlines()
+             if re.search(r"= \S+ (copy|transpose)\(", ln)
+             and re.search(r"/kda_inputs/", ln)]
+    assert not moved, moved
+    minor = [ln.strip()[:200] for ln in text.splitlines()
+             if re.search(r"_proj/dot_general", ln)
+             and re.search(r"= \w+\[2,8192,32,128\]\{1,3,2,0", ln)
+             and re.search(r"/[qkva]_proj/", ln)]
+    assert not minor, minor
+
+
 @pytest.mark.parametrize("direction", ["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("D,Hkv", [(64, 12), (128, 4)],
                          ids=["d64_mha", "d128_gqa"])
@@ -337,10 +377,13 @@ def test_hybrid_step_holds_the_kda_kernels_by_name(one_chip, monkeypatch):
     kernels = re.findall(r'kernel_name = "(\w+)"', text)
     assert (kernels.count("kda_fwd"), kernels.count("kda_bwd")) == (10, 5), \
         kernels
+    # and the shaping's pair in front of each (ops/kda_inputs.py)
+    assert (kernels.count("kda_inputs_fwd"),
+            kernels.count("kda_inputs_bwd")) == (10, 5), kernels
     flash = [kernels.count(k) for k in
              ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")]
     assert flash == [1, 1, 1], kernels
-    for name in ("kda_fwd", "kda_bwd"):
+    for name in ("kda_fwd", "kda_bwd", "kda_inputs_fwd", "kda_inputs_bwd"):
         assert not re.search(bench["flash_kernel_pattern"],
                              f"%{name}.1 custom-call")
     loops = sorted(set(re.findall(r'"[^"]*kda_chunk[^"]*/while"', text)))
