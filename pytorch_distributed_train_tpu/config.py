@@ -185,7 +185,19 @@ class ModelConfig:
     # under rope_scaling / rope_scaling_type over the first
     # partial_rotary_factor of each head; gqa_window sees the trailing
     # attention_window keys and is rotated by window_rope_theta, plain,
-    # over the whole head.
+    # over the whole head; partial_rotary_factor 0 is NO rotation (NoPE).
+    # A family's mixers differ in plain fields beyond their kinds
+    # (models/hybrid.py MixerVariants): kda_gate "bounded" (the log-decay
+    # kda_gate_lower_bound * sigmoid(.)) or "softplus" (-exp(A_log)
+    # softplus(.), unbounded below: ops/kda.py's second form);
+    # kda_beta_scale 1 or 2 (step sizes up to 2: a transition with a
+    # negative eigenvalue); kda_gate_rank r > 0 puts the decay gate and a
+    # channel-wise output gate through r features (two products);
+    # kda_out_gate / gqa_out_gate "head" (one scalar a head) or "channel".
+    # heads_held query/KDA heads from heads_held_first on live here (0 =
+    # all), with the KV heads the grouping gives them: one chip's share of
+    # a tensor-parallel group's mixers, as experts_held is of the experts;
+    # the held heads' projections, gates and rows of o_proj, a partial sum.
     head_dim: int = 0
     layer_kinds: tuple[str, ...] = ()
     layer_heads: tuple[int, ...] = ()
@@ -204,6 +216,13 @@ class ModelConfig:
     moe_routed_scale: float = 1.0
     experts_held: int = 0
     experts_held_first: int = 0
+    heads_held: int = 0
+    heads_held_first: int = 0
+    kda_gate: str = "bounded"
+    kda_beta_scale: float = 1.0
+    kda_gate_rank: int = 0
+    kda_out_gate: str = "head"
+    gqa_out_gate: str = "head"
     # Fused elementwise block epilogues (ops/fused_update.py; vit/bert):
     # the bias+GELU MLP epilogue and (post-LN bert) the residual-add+
     # LayerNorm epilogue compute as single tagged expressions XLA keeps
@@ -1236,6 +1255,52 @@ def _laguna_s_lm_ep32() -> TrainConfig:
     return c
 
 
+def _solar_open2_lm_ep40_tp8() -> TrainConfig:
+    """One chip's share of Solar-Open2-250B's language model (upstage,
+    https://huggingface.co/upstage/Solar-Open2-250B config.json): every
+    width as published (hidden 4096, heads of 128, experts and the shared
+    expert of 1280, a 320-wide softmax router, 8 a token, conv 4); layers
+    0-3 of the 48, one whole period: grouped-query attention with NO
+    rotation and a gate a channel, then three KDA layers whose decay gate
+    is the report's unbounded softplus, step sizes up to 2, gates through
+    128 features; every layer an expert layer (no leading dense one). Each
+    layer is shared by 40 chips: 8 of its 320 experts here (expert
+    parallelism over 40), 8 of its 64 query/KDA heads and 1 of its 8 KV
+    heads (tensor parallelism in groups of 8), an eighth of the 196608-row
+    vocabulary. 840.8 M parameters, 13.45 GB with AdamW's float32 state
+    (benchmark/configs/solar_open2_lm_ep40_tp8.json says what was
+    assumed)."""
+    c = TrainConfig(preset="solar_open2_lm_ep40_tp8")
+    c.model = ModelConfig(
+        name="hybrid_lm", hidden_size=4096, num_layers=4, num_heads=64,
+        num_kv_heads=8, head_dim=128, vocab_size=24576, max_seq_len=8192,
+        rms_norm_eps=1e-5, remat=True,
+        layer_kinds=("gqa_full", "kda", "kda", "kda"),
+        partial_rotary_factor=0.0,  # `use_rope: false`
+        heads_held=8, heads_held_first=0,
+        kda_gate="softplus", kda_beta_scale=2.0, kda_gate_rank=128,
+        kda_out_gate="channel", gqa_out_gate="channel", conv_kernel_size=4,
+        first_dense_layers=0, num_experts=320, expert_top_k=8,
+        moe_score="softmax", moe_routed_scale=1.0, moe_mlp_dim=1280,
+        experts_held=8, experts_held_first=0, expert_capacity_factor=4.0,
+    )
+    # 4096 synthetic sequences of 8192 tokens, as the other 8k presets
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=1, seq_len=8192,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        # decay the matrices and the embedding only: not the norms, the
+        # conv taps, the decay's A_log and dt_bias
+        decay_exclude=r"scale$,bias$,_conv$,A_log$",
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    c.total_steps = 500000
+    c.loss = "causal_lm_xent"
+    return c
+
+
 def _ouro_2_6b_lm_l8() -> TrainConfig:
     """One pipeline stage of Ouro-2.6B (ByteDance,
     https://huggingface.co/ByteDance/Ouro-2.6B config.json; arXiv:2510.25741):
@@ -1307,6 +1372,7 @@ _PRESETS = {
     "ling3_flash_lm_ep64": _ling3_flash_lm_ep64,
     "laguna_s_lm_ep32": _laguna_s_lm_ep32,
     "ouro_2_6b_lm_l8": _ouro_2_6b_lm_l8,
+    "solar_open2_lm_ep40_tp8": _solar_open2_lm_ep40_tp8,
 }
 
 

@@ -12,7 +12,17 @@ blocks, RMSNorm, untied head, no dropout, no auxiliary loss. Two families'
 language models are laid out so: Ling-3.0-flash (preset
 ``ling3_flash_lm_ep64``: groups of ``layer_group_size``, the last of each
 ``mla``, the others ``kda``) and Laguna-S (preset ``laguna_s_lm_ep32``:
-``layer_kinds`` a layer, one ``gqa_full`` to three ``gqa_window``); the
+``layer_kinds`` a layer, one ``gqa_full`` to three ``gqa_window``); a third,
+Solar-Open2 (preset ``solar_open2_lm_ep40_tp8``: one ``gqa_full`` with NO
+rotation and a gate a channel to three ``kda`` whose decay gate is the
+report's unbounded softplus, step sizes up to 2, low-rank gates), is ONE
+CHIP'S SHARE of its mixers too: ``heads_held`` query/KDA heads from
+``heads_held_first`` on live here (0 = all), with the KV heads the
+published grouping gives them, as one chip of a tensor-parallel group holds
+them. The held heads' projections, convolution taps, decay and gates, and
+the held ROWS of ``o_proj``: what the absent heads would add to a mixer's
+output is left out, that partial sum goes on (as the expert layer's does),
+and no code stands in for the absent chips or their all-reduce. The
 equations are written out beside each module and in
 ``benchmark/references/<preset>.py``, which share no code with this file.
 
@@ -77,14 +87,89 @@ def _head_gate(x, num_heads, dtype, param_dtype):
     return jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
 
 
+def _channel_gate(x, heads, head_dim, rank, dtype, param_dtype):
+    """sigmoid(W x) one value a CHANNEL, float32 (B, S, H, d); ``rank`` > 0:
+    W = W_up W_down through ``rank`` features (``gc_down`` every chip of a
+    tensor-parallel group computes alike, ``gc_proj`` carries the heads)."""
+    if rank:
+        x = nn.Dense(rank, use_bias=False, dtype=dtype,
+                     param_dtype=param_dtype, kernel_init=_INIT,
+                     name="gc_down")(x)
+    gate = nn.DenseGeneral((heads, head_dim), axis=-1, use_bias=False,
+                           dtype=dtype, param_dtype=param_dtype,
+                           kernel_init=_INIT, dot_general=_F32_OUT,
+                           name="gc_proj")(x)
+    return jax.nn.sigmoid(gate.astype(jnp.float32))
+
+
+def _out_gate(form, x, heads, head_dim, rank, dtype, param_dtype):
+    if form == "channel":
+        return _channel_gate(x, heads, head_dim, rank, dtype, param_dtype)
+    if form != "head":
+        raise ValueError(f"unknown output gate {form!r}; have head | channel")
+    return _head_gate(x, heads, dtype, param_dtype)
+
+
+def held_heads(num_heads: int, held: int, first: int) -> int:
+    """How many of a layer's ``num_heads`` live here (``held`` 0 = all)."""
+    if not held:
+        return num_heads
+    if not 0 <= first <= first + held <= num_heads:
+        raise ValueError(f"heads {first}..{first + held - 1} held of "
+                         f"{num_heads}")
+    return held
+
+
+def held_kv_heads(num_heads: int, num_kv_heads: int, held: int,
+                  first: int) -> int:
+    """The KV heads the published grouping (query head h reads KV head
+    h // (num_heads / num_kv_heads)) gives the held query heads: whole
+    groups, or part of one."""
+    if not held:
+        return num_kv_heads
+    group = num_heads // num_kv_heads
+    lo, hi = first // group, (first + held - 1) // group
+    if hi > lo and (first % group or held % group):
+        raise ValueError(
+            f"held heads {first}..{first + held - 1} straddle KV heads "
+            f"{lo}..{hi} of groups of {group}: a chip holds whole groups, "
+            f"or heads of one")
+    return hi - lo + 1
+
+
+def _softplus_gate_init(key, shape, dtype):
+    """dt_bias of the unbounded gate, the public fla layer's rule: the step
+    softplus(dt_bias) log-uniform in (1e-3, 0.1)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    dt = jnp.maximum(dt, 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 class KDAMixer(nn.Module):
     """q, k, v = W x through a causal depthwise conv and SiLU; q and k
-    L2-normalised a head, q scaled by d^-1/2; log-decay
-    g = lower_bound * sigmoid(exp(A_log) (W_a x + dt_bias)) a key channel;
-    beta = sigmoid(W_beta x) a head; the delta-rule recurrence; RMSNorm over
-    each head's output, times a head-wise sigmoid gate; W_o. No rotary.
+    L2-normalised a head, q scaled by d^-1/2; a log-decay g a key channel;
+    a step size beta a head; the delta-rule recurrence; RMSNorm over each
+    head's output, times a sigmoid gate; W_o. No rotary. The variants are
+    the families', plain fields:
+
+    * ``gate`` ``bounded``: g = lower_bound * sigmoid(exp(A_log) (a +
+      dt_bias)); ``softplus``: g = -exp(A_log) softplus(a + dt_bias),
+      unbounded below (the report's own). a = W_a x, or with ``gate_rank``
+      r > 0 the low-rank W_a2 (W_a1 x) (``a_down``, ``a_proj``).
+    * beta = ``beta_scale`` * sigmoid(W_beta x): 1, or 2 where the
+      transition may have a negative eigenvalue.
+    * ``out_gate`` ``head``: one scalar a head; ``channel``: one a channel,
+      through ``gate_rank`` like a.
+    * ``heads_held`` of the ``num_heads`` from ``heads_held_first`` on live
+      here (0 = all): every leaf with a head dimension carries the held
+      heads alone, ``o_proj`` their rows.
+
     What shapes the projections into q, k, v, g is ops/kda_inputs.py: where
-    the core runs in its kernels a kernel pair on the same rows, else XLA."""
+    the core runs in its kernels a kernel pair on the same rows, else XLA.
+    Returns (the mixer's output, stats): stats is None for a bounded gate
+    (its extremes are the gate's own) and for the unbounded one float32
+    (2,): the step's most negative one-token log-decay and largest beta."""
 
     num_heads: int
     head_dim: int
@@ -94,15 +179,27 @@ class KDAMixer(nn.Module):
     dtype: jnp.dtype
     param_dtype: jnp.dtype
     cp: ContextParallelConfig | None = None
+    gate: str = "bounded"
+    beta_scale: float = 1.0
+    gate_rank: int = 0
+    out_gate: str = "head"
+    heads_held: int = 0
+    heads_held_first: int = 0
 
     @nn.compact
     def __call__(self, x):
-        H, d, f32 = self.num_heads, self.head_dim, jnp.float32
+        d, f32 = self.head_dim, jnp.float32
+        H = held_heads(self.num_heads, self.heads_held, self.heads_held_first)
+        if self.gate not in ("bounded", "softplus"):
+            raise ValueError(f"unknown decay gate {self.gate!r}; have "
+                             "bounded | softplus")
+        unbounded = self.gate == "softplus"
+        lower_bound = None if unbounded else self.gate_lower_bound
         # where the shaping and the core run in their kernels (one gate for
         # both), the projections hand them rows of whole heads
         in_kernels = kda_ops.unsupported(x.shape[1], d, d, self.dtype,
                                          self.cp) is None
-        proj = lambda name, dot=jax.lax.dot_general: nn.DenseGeneral(  # noqa: E731
+        proj = lambda name, dot=jax.lax.dot_general, x=x: nn.DenseGeneral(  # noqa: E731
             (H, d), axis=-1, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT, name=name,
             dot_general=_heads_merged(dot) if in_kernels else dot)(x)
@@ -110,30 +207,49 @@ class KDAMixer(nn.Module):
         taps = [self.param(name, _INIT, (self.conv_kernel_size, H, d),
                            self.param_dtype)
                 for name in ("q_conv", "k_conv", "v_conv")]
-        # exp(A_log) = 1 and dt_bias in (-5, -1) start the channels at
-        # decays of exp(-0.03) to exp(-1.3) a token: memories of 1 to 30
-        a_log = self.param("A_log", nn.initializers.zeros, (H,), f32)
-        dt_bias = self.param(
-            "dt_bias", lambda key, shape, dtype: jax.random.uniform(
-                key, shape, dtype, -5.0, -1.0), (H, d), f32)
+        if unbounded:
+            # the public fla layer's rule: exp(A_log) uniform in (1, 16),
+            # the step softplus(dt_bias) log-uniform in (1e-3, 0.1): decays
+            # of exp(-0.001) to exp(-1.6) a token at the start
+            a_log = self.param(
+                "A_log", lambda key, shape, dtype: jnp.log(jax.random.uniform(
+                    key, shape, dtype, 1.0, 16.0)), (H,), f32)
+            dt_bias = self.param("dt_bias", _softplus_gate_init, (H, d), f32)
+        else:
+            # exp(A_log) = 1 and dt_bias in (-5, -1) start the channels at
+            # decays of exp(-0.03) to exp(-1.3) a token: memories of 1 to 30
+            a_log = self.param("A_log", nn.initializers.zeros, (H,), f32)
+            dt_bias = self.param(
+                "dt_bias", lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -5.0, -1.0), (H, d), f32)
+        a_in = x
+        if self.gate_rank:
+            a_in = nn.Dense(self.gate_rank, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, kernel_init=_INIT,
+                            name="a_down")(x)
 
         q, k, v, g = kda_inputs.shape_inputs(
             proj("q_proj"), proj("k_proj"), proj("v_proj"),
-            proj("a_proj", _F32_OUT), taps, a_log, dt_bias,
-            lower_bound=self.gate_lower_bound, cp=self.cp)
+            proj("a_proj", _F32_OUT, a_in), taps, a_log, dt_bias,
+            lower_bound=lower_bound, cp=self.cp)
         beta = jax.nn.sigmoid(nn.Dense(
             H, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=_INIT, dot_general=_F32_OUT,
             name="beta_proj")(x).astype(f32))
+        if self.beta_scale != 1.0:
+            beta = self.beta_scale * beta
         o = kda_ops.kda_chunked(
             q, k, v, g, beta, chunk=min(kda_ops.DEFAULT_CHUNK, x.shape[1]),
-            lower_bound=self.gate_lower_bound, cp=self.cp)
+            lower_bound=lower_bound, cp=self.cp)
         o = RMSNorm(self.rms_norm_eps, name="o_norm")(o.astype(f32))
-        o = o * _head_gate(x, H, self.dtype, self.param_dtype)
+        o = o * _out_gate(self.out_gate, x, H, d, self.gate_rank, self.dtype,
+                          self.param_dtype)
+        stats = jnp.stack([jnp.min(g), jnp.max(beta)]).astype(f32) \
+            if unbounded else None
         return nn.DenseGeneral(
             x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT,
-            name="o_proj")(o.astype(self.dtype))
+            name="o_proj")(o.astype(self.dtype)), stats
 
 
 class MLAMixer(nn.Module):
@@ -190,7 +306,7 @@ class MLAMixer(nn.Module):
 class Rotation:
     """One kind's rotary tables: ``rope_frequencies``' arguments, the
     rotated width first (below the head's: the first dims rotate, the
-    rest pass)."""
+    rest pass; 0: no rotation at all, and no tables)."""
 
     width: int
     theta: float
@@ -201,6 +317,14 @@ class Rotation:
     beta_slow: float = 1.0
     attention_factor: float = 0.0
 
+    def rotate(self, seq_len: int):
+        """The function that rotates a (B, S, H, d) tensor by its positions
+        0..S-1; width 0: the identity, and no tables are built."""
+        if not self.width:
+            return lambda x: x
+        cos, sin = self.tables(seq_len)
+        return lambda x: apply_rope(x, cos, sin)
+
     def tables(self, seq_len: int):
         return rope_frequencies(
             self.width, seq_len, self.theta, self.scaling, self.scaling_type,
@@ -210,12 +334,16 @@ class Rotation:
 
 
 class GQAMixer(nn.Module):
-    """Grouped-query softmax attention with a per-head gate: q = W_q x
+    """Grouped-query softmax attention with an output gate: q = W_q x
     (``num_heads`` x d), k, v = W_k x, W_v x (``num_kv_heads`` x d); query
     head h reads KV head h // (num_heads / num_kv_heads); q and k rotated
-    by ``rotation``; scores q k^T / sqrt(d), softmax in float32 over the
-    keys j <= i, and with ``window`` > 0 also i - j < window; each head's
-    output times sigmoid(W_g x)_h; W_o. No q/k norm, no bias."""
+    by ``rotation`` (width 0: no rotation); scores q k^T / sqrt(d), softmax
+    in float32 over the keys j <= i, and with ``window`` > 0 also
+    i - j < window; the output times sigmoid(W_g x), one value a head
+    (``out_gate`` ``head``) or a channel (``channel``: its own full-rank
+    projection); W_o. No q/k norm, no bias. ``heads_held`` query heads from
+    ``heads_held_first`` on live here (0 = all) with the KV heads the
+    grouping gives them (``held_kv_heads``), and their rows of ``o_proj``."""
 
     num_heads: int
     num_kv_heads: int
@@ -226,30 +354,55 @@ class GQAMixer(nn.Module):
     param_dtype: jnp.dtype
     cp: ContextParallelConfig | None = None
     attn_impl: str = "auto"
+    out_gate: str = "head"
+    heads_held: int = 0
+    heads_held_first: int = 0
 
     @nn.compact
     def __call__(self, x):
+        heads = held_heads(self.num_heads, self.heads_held,
+                           self.heads_held_first)
+        kv_heads = held_kv_heads(self.num_heads, self.num_kv_heads,
+                                 self.heads_held, self.heads_held_first)
         proj = lambda heads, name: nn.DenseGeneral(  # noqa: E731
             (heads, self.head_dim), axis=-1, use_bias=False,
             dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=_INIT, name=name)(x)
-        cos, sin = self.rotation.tables(x.shape[1])
+        rotate = self.rotation.rotate(x.shape[1])
         y = dot_product_attention(
-            apply_rope(proj(self.num_heads, "q_proj"), cos, sin),
-            apply_rope(proj(self.num_kv_heads, "k_proj"), cos, sin),
-            proj(self.num_kv_heads, "v_proj"), causal=True,
+            rotate(proj(heads, "q_proj")), rotate(proj(kv_heads, "k_proj")),
+            proj(kv_heads, "v_proj"), causal=True,
             window=self.window, cp=self.cp, impl=self.attn_impl)
-        y = y.astype(jnp.float32) * _head_gate(x, self.num_heads, self.dtype,
-                                               self.param_dtype)
+        y = y.astype(jnp.float32) * _out_gate(
+            self.out_gate, x, heads, self.head_dim, 0, self.dtype,
+            self.param_dtype)
         return nn.DenseGeneral(
             x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT,
             name="o_proj")(y.astype(self.dtype))
 
 
+@dataclasses.dataclass(frozen=True)
+class MixerVariants:
+    """What a family's mixers differ in, beyond their kinds (config fields
+    of the same names under ``model.``; the defaults are the first two
+    presets')."""
+
+    kda_gate: str = "bounded"        # | softplus: unbounded below
+    kda_beta_scale: float = 1.0      # 2: negative eigenvalues allowed
+    kda_gate_rank: int = 0           # 0: full-rank gates; r: through r
+    kda_out_gate: str = "head"       # | channel
+    gqa_out_gate: str = "head"       # | channel
+    heads_held: int = 0              # query/KDA heads held here, 0 = all
+    heads_held_first: int = 0
+
+
 class HybridBlock(nn.Module):
-    """x + mixer(norm x), then x + ffn(norm x). Returns (x, moe stats): the
-    expert layer's three row counts, zeros for a dense layer. The mixer's
+    """x + mixer(norm x), then x + ffn(norm x). Returns (x, moe stats, decay
+    stats): the expert layer's three row counts, zeros for a dense layer;
+    a KDA layer's extremes where its gate is unbounded (``KDAMixer``), else
+    None. ``variants`` are the mixers' plain fields (``MixerVariants``:
+    gate forms, the share of heads held here). The mixer's
     module carries its kind's name (``kda``, ``mla``, ``gqa`` for
     ``gqa_full``, ``swa`` for ``gqa_window``): a device trace names the
     attention kernel's events by it."""
@@ -274,15 +427,19 @@ class HybridBlock(nn.Module):
     num_kv_heads: int = 0
     window: int = 0
     rotation: Rotation | None = None   # the gqa kinds'
+    variants: MixerVariants = MixerVariants()
 
     @nn.compact
     def __call__(self, x):
         h = RMSNorm(self.rms_norm_eps, name="input_norm")(x)
+        var, decay_stats = self.variants, None
         if self.kind in ("gqa_full", "gqa_window"):
             mixed = GQAMixer(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 self.window, self.rotation, self.dtype, self.param_dtype,
                 cp=self.cp, attn_impl=self.attn_impl,
+                out_gate=var.gqa_out_gate, heads_held=var.heads_held,
+                heads_held_first=var.heads_held_first,
                 name="gqa" if self.kind == "gqa_full" else "swa")(h)
         elif self.kind == "mla":
             mixed = MLAMixer(
@@ -292,10 +449,13 @@ class HybridBlock(nn.Module):
                 attn_impl=self.attn_impl, name="mla")(h)
         else:
             assert self.kind == "kda", self.kind
-            mixed = KDAMixer(
+            mixed, decay_stats = KDAMixer(
                 self.num_heads, self.head_dim, self.conv_kernel_size,
                 self.kda_gate_lower_bound, self.rms_norm_eps, self.dtype,
-                self.param_dtype, cp=self.cp, name="kda")(h)
+                self.param_dtype, cp=self.cp, gate=var.kda_gate,
+                beta_scale=var.kda_beta_scale, gate_rank=var.kda_gate_rank,
+                out_gate=var.kda_out_gate, heads_held=var.heads_held,
+                heads_held_first=var.heads_held_first, name="kda")(h)
         x = x + mixed
         h = RMSNorm(self.rms_norm_eps, name="post_attn_norm")(x)
         if self.moe is None:
@@ -306,7 +466,7 @@ class HybridBlock(nn.Module):
             out, stats = HeldExpertsMLP(
                 self.moe, LlamaMLP, self.moe_mlp_dim, self.dtype,
                 self.param_dtype, name="moe")(h)
-        return x + out, stats
+        return x + out, stats, decay_stats
 
 
 class HybridLM(nn.Module):
@@ -315,7 +475,11 @@ class HybridLM(nn.Module):
     and of the mean held expert, sum of the pairs past the bound) into the
     ``step_metrics`` collection, which the train step reports; the pairs
     past the bound also as ``update_invalid``, the name by which the step
-    keeps its old state and reports ``update_skipped`` (steps.py)."""
+    keeps its old state and reports ``update_skipped`` (steps.py). Where
+    the KDA layers' decay gate is unbounded, also ``kda_log_decay_min`` (the
+    step's most negative one-token log-decay over those layers: how far
+    past a bounded gate's -5 the chunk core's unbounded form is asked to
+    go) and ``kda_beta_max``."""
 
     vocab_size: int
     hidden_size: int
@@ -344,6 +508,7 @@ class HybridLM(nn.Module):
     cp: ContextParallelConfig | None = None
     attn_impl: str = "auto"
     act: "object | None" = None
+    variants: MixerVariants = MixerVariants()
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, loss_mask=None):
@@ -356,11 +521,11 @@ class HybridLM(nn.Module):
             param_dtype=self.param_dtype,
             name="tok_embed")(input_ids).astype(self.dtype))
         block_cls = remat_block(HybridBlock, self.remat, self.remat_policy)
-        stats = []
+        stats, decay_stats = [], []
         for i, kind in enumerate(self.layer_kinds):
             moe = self.moe if i >= self.first_dense_layers else None
             windowed = kind == "gqa_window"
-            x, layer_stats = block_cls(
+            x, layer_stats, layer_decay = block_cls(
                 kind, moe, self.layer_heads[i], self.head_dim,
                 self.mlp_dim, self.moe_mlp_dim, self.rope_head_dim,
                 self.kv_lora_rank, self.rope_theta, self.max_seq_len,
@@ -370,18 +535,27 @@ class HybridLM(nn.Module):
                 num_kv_heads=self.num_kv_heads,
                 window=self.window if windowed else 0,
                 rotation=self.window_rotation if windowed
-                else self.full_rotation, name=f"layer{i}")(x)
+                else self.full_rotation, variants=self.variants,
+                name=f"layer{i}")(x)
             x = constrain(x)
             if moe is not None:
                 stats.append(layer_stats)
+            if layer_decay is not None:
+                decay_stats.append(layer_decay)
+        metrics = []
         if stats:
             fullest, mean, over = jnp.stack(stats).T
-            for name, value in (("moe_rows_fullest", jnp.mean(fullest)),
-                                ("moe_rows_mean", jnp.mean(mean)),
-                                ("moe_rows_over_bound", jnp.sum(over)),
-                                ("update_invalid", jnp.sum(over))):
-                self.sow("step_metrics", name, value,
-                         reduce_fn=lambda _, new: new, init_fn=lambda: 0.0)
+            metrics += [("moe_rows_fullest", jnp.mean(fullest)),
+                        ("moe_rows_mean", jnp.mean(mean)),
+                        ("moe_rows_over_bound", jnp.sum(over)),
+                        ("update_invalid", jnp.sum(over))]
+        if decay_stats:
+            lowest, largest = jnp.stack(decay_stats).T
+            metrics += [("kda_log_decay_min", jnp.min(lowest)),
+                        ("kda_beta_max", jnp.max(largest))]
+        for name, value in metrics:
+            self.sow("step_metrics", name, value,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: 0.0)
         x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
         with jax.named_scope("lm_head"):  # a phase of the step: steps.py
             logits = nn.Dense(
@@ -437,14 +611,33 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
     if "gqa_window" in kinds and cfg.attention_window <= 0:
         raise ValueError("a gqa_window layer needs "
                          "model.attention_window > 0")
-    said = (kinds, heads, kv_heads, cfg.attention_window)
+    variants = MixerVariants(
+        kda_gate=cfg.kda_gate, kda_beta_scale=cfg.kda_beta_scale,
+        kda_gate_rank=cfg.kda_gate_rank, kda_out_gate=cfg.kda_out_gate,
+        gqa_out_gate=cfg.gqa_out_gate, heads_held=cfg.heads_held,
+        heads_held_first=cfg.heads_held_first)
+    share = ""
+    if cfg.heads_held:
+        if "mla" in kinds:
+            raise ValueError("model.heads_held: a latent (mla) layer has no "
+                             "share of heads yet")
+        # every layer's share is well formed, and the line names the first's
+        held = [(held_heads(h, cfg.heads_held, cfg.heads_held_first),
+                 held_kv_heads(h, kv_heads, cfg.heads_held,
+                               cfg.heads_held_first)
+                 if kind != "kda" else 0)
+                for kind, h in zip(kinds, heads)]
+        kv_held = max(kv for _, kv in held)
+        share = (f" heads_held={held[0][0]}/{heads[0]}"
+                 + (f" kv_held={kv_held}/{kv_heads}" if kv_held else ""))
+    said = (kinds, heads, kv_heads, cfg.attention_window, variants)
     if said not in _built_logged:  # once a layout, on stderr
         _built_logged.add(said)
         print(f"[hybrid] layers={len(kinds)} kinds={','.join(kinds)} "
               f"heads={','.join(map(str, heads))} kv_heads={kv_heads} "
               f"window={cfg.attention_window} "
-              f"dense_layers={cfg.first_dense_layers}", file=sys.stderr,
-              flush=True)
+              f"dense_layers={cfg.first_dense_layers}{share}",
+              file=sys.stderr, flush=True)
     return HybridLM(
         cp=cp, act=act, moe=moe,
         attn_impl=getattr(cfg, "attention_impl", "auto"),
@@ -459,6 +652,7 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
         rms_norm_eps=cfg.rms_norm_eps,
         # the grouped-query kinds' (no other kind reads them)
         num_kv_heads=kv_heads, window=cfg.attention_window,
+        variants=variants,
         full_rotation=Rotation(
             int(head_dim * cfg.partial_rotary_factor), cfg.rope_theta,
             cfg.rope_scaling, cfg.rope_scaling_type,

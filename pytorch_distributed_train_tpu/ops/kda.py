@@ -3,7 +3,8 @@ Linear report arXiv:2510.26692): the recurrent mixer's core, chunked.
 
 A head keeps a state S in R^{d_k x d_v}, S_0 = 0, and for each token t with
 a query q_t and key k_t in R^{d_k}, a value v_t in R^{d_v}, a log-decay
-g_t in [lower_bound, 0]^{d_k} and a step size beta_t in [0, 1]:
+g_t in (-inf, 0]^{d_k} and a step size beta_t in [0, 2] (past 1 the factor
+I - beta k k^T has a negative eigenvalue: the state may flip along k):
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
@@ -25,13 +26,31 @@ only the three products with S run in sequence, one step a chunk.
 
 Numbers. The state, g and its sums are float32. exp(G_t - G_j) is never
 formed from exp(G_t) and exp(-G_j) over a whole chunk (32 x 5 overflows):
-rows are taken in blocks of 16 tokens, each against its own reference
-point R (the sum at the block's middle), so that queries carry
-exp(G_t - R) and keys exp(R - G_j), both within exp(+-8 |lower_bound|)
-inside the block and the keys' smaller before it: the bound on g is what
-makes the product form safe, and exp(+-40) times a small component stays
-a normal float32 (a reference at the block's start reached exp(-80), and
-the small components of q went denormal).
+rows are taken in blocks of 16 tokens (``BLOCK``), and the decay gate's FORM
+says how a block's factors are made (``lower_bound`` of
+:func:`kda_chunked`; the mixer hands over its gate's bound, or None):
+
+* bounded, g in [lower_bound, 0] (the gate ``lower_bound * sigmoid(.)``):
+  each block against its own reference point R (the sum at the block's
+  middle), so that queries carry exp(G_t - R) and keys exp(R - G_j), both
+  within exp(+-8 |lower_bound|) inside the block and the keys' smaller
+  before it. THE BOUND IS WHAT MAKES THIS PRODUCT FORM SAFE: it relies on
+  ``BLOCK * |lower_bound| < 88``, and exp(+-40) times a small component
+  stays a normal float32 (a reference at the block's start reached
+  exp(-80), and the small components of q went denormal). Every pair of a
+  chunk then comes out of one matrix product a block.
+* unbounded, g in (-inf, 0] (``lower_bound=None``: the report's own gate
+  ``-exp(A_log) softplus(.)``, where one token can decay a channel by
+  exp(-30)): no bound to lean on, so no factor above 1 is ever formed.
+  Pairs of DIFFERENT blocks take the reference at the query block's first
+  row: exp(G_t - R) <= 1 and exp(R - G_j) <= 1, a product that underflows
+  only where the true factor exp(G_t - G_j) does. Pairs INSIDE a block
+  form G_t - G_j by subtraction before the exponential, 16 x 16 pairs a
+  channel, summed over the channels in float32 (no matrix product: the
+  price of the missing bound). Nothing here relies on any bound on g.
+
+Everything else of a chunk (exp(G), exp(G_C - G), exp(G_C)) is at most 1 in
+both forms. beta enters as a plain factor, so [0, 2] needs nothing more.
 (I + A)^-1 is the finite Neumann product (I - A)(I + A^2)(I + A^4)...,
 exact because A is strictly lower triangular. Its products and the one
 with the right-hand sides take float32 operands in three bfloat16 passes
@@ -80,8 +99,12 @@ import jax.numpy as jnp
 
 from pytorch_distributed_train_tpu.ops import attention
 
-BLOCK = 16  # rows that share a reference point; a masked pair of one block
-# still multiplies exp(+8 |lower_bound|) twice, so BLOCK * |lower_bound| < 88
+BLOCK = 16  # rows that share a reference point. Bounded gate: the point is
+# the block's middle, and a masked pair of one block still multiplies
+# exp(+8 |lower_bound|) twice, so that form needs BLOCK * |lower_bound| < 88
+# (kda_chunked refuses a bound past it). Unbounded gate: the point is the
+# block's first row, every factor is <= 1, the block's own pairs are taken
+# by subtraction, and no bound is needed.
 
 # tokens a chunk: the hybrid cell's whole step on the v5e reads 1263 ms at
 # 32 against 1319 at 64 (PERF.md section 6, PR 26)
@@ -182,23 +205,26 @@ def _unit_lower_inverse(a):
     return out
 
 
-def _chunk_tables(q, k, v, g, beta, dtype):
-    """What a chunk needs that no state enters. Inputs (..., C, d) with the
-    chunk's tokens on the second-to-last axis; g float32. Returns
-    (qg, bmat, w, u0, kend, gend): Q exp G, B, W, U0, K exp(G_C - G) and
-    exp(G_C)."""
+def _pair_tables(q, k, G, dtype, bounded):
+    """``table(rows)``: sum_c rows_tc k_jc exp(G_tc - G_jc) for every pair
+    of a chunk's tokens that the causal masks keep, (..., C, C) float32;
+    what lies past the diagonal is for the caller to mask. The two forms of
+    the module docstring."""
     f32 = jnp.float32
     C = q.shape[-2]
     n = C // BLOCK
-    G = jnp.cumsum(g, axis=-2)  # inclusive, (..., C, dk)
-    # reference point of each block of rows: the sum at its middle
-    R = G[..., BLOCK // 2 - 1::BLOCK, :]  # (..., n, dk)
+    # reference point of each block of rows: the sum at its middle (a
+    # bounded gate), at its first row (an unbounded one)
+    R = G[..., (BLOCK // 2 - 1 if bounded else 0)::BLOCK, :]  # (..., n, dk)
     row_ref = jnp.repeat(R, BLOCK, axis=-2)  # (..., C, dk), of a row's block
-    row_decay = jnp.exp(G - row_ref)  # within exp(+-BLOCK/2 |lower_bound|)
+    # bounded: within exp(+-BLOCK/2 |lower_bound|); unbounded: <= 1
+    row_decay = jnp.exp(G - row_ref)
     # keys against each block's reference: (..., n, C, dk); keys after the
-    # block are out of its causal reach and masked before the exp
+    # block (unbounded: and the block's own) are out of its reach and
+    # masked before the exp
     block_of = jnp.arange(C) // BLOCK
-    reach = block_of[None, :] <= jnp.arange(n)[:, None]  # (n, C)
+    reach = block_of[None, :] <= jnp.arange(n)[:, None] if bounded \
+        else block_of[None, :] < jnp.arange(n)[:, None]  # (n, C)
     expo = R[..., :, None, :] - G[..., None, :, :]
     key_decay = jnp.exp(jnp.where(reach[..., None], expo, _MASKED))
     keys = (k.astype(f32)[..., None, :, :] * key_decay).astype(dtype)
@@ -210,6 +236,39 @@ def _chunk_tables(q, k, v, g, beta, dtype):
                        preferred_element_type=f32)
         return t.reshape(*t.shape[:-3], C, C)
 
+    if bounded:
+        return table
+
+    # a block's own pairs: G_t - G_j by subtraction, never a factor above 1
+    blocks = lambda x: x.astype(f32).reshape(  # noqa: E731
+        *x.shape[:-2], n, BLOCK, x.shape[-1])
+    Gb = blocks(G)
+    lower = jnp.tril(jnp.ones((BLOCK, BLOCK), bool))
+    inside = jnp.exp(jnp.where(
+        lower[..., None], Gb[..., :, None, :] - Gb[..., None, :, :],
+        _MASKED))  # (..., n, i, j, dk)
+    own_keys = blocks(k)[..., None, :, :] * inside
+    eye = jnp.eye(n, dtype=f32)
+
+    def with_own_block(rows):
+        # (a sum, not a product the backend may round to bfloat16 passes)
+        own = jnp.sum(blocks(rows)[..., :, None, :] * own_keys, -1)
+        # block n's (i, j) to row n BLOCK + i, column n BLOCK + j
+        own = own[..., :, :, None, :] * eye[:, None, :, None]
+        return table(rows) + own.reshape(*own.shape[:-4], C, C)
+
+    return with_own_block
+
+
+def _chunk_tables(q, k, v, g, beta, dtype, bounded=True):
+    """What a chunk needs that no state enters. Inputs (..., C, d) with the
+    chunk's tokens on the second-to-last axis; g float32. Returns
+    (qg, bmat, w, u0, kend, gend): Q exp G, B, W, U0, K exp(G_C - G) and
+    exp(G_C)."""
+    f32 = jnp.float32
+    C = q.shape[-2]
+    G = jnp.cumsum(g, axis=-2)  # inclusive, (..., C, dk)
+    table = _pair_tables(q, k, G, dtype, bounded)
     tri = jnp.tril(jnp.ones((C, C), bool))
     bmat = jnp.where(tri, table(q), 0.0)
     a = jnp.where(jnp.tril(tri, -1), table(k), 0.0) * beta[..., None]
@@ -225,7 +284,7 @@ def _chunk_tables(q, k, v, g, beta, dtype):
             kend.astype(dtype), jnp.exp(G[..., -1, :]))
 
 
-def _segment(state, xs, *, chunk, dtype):
+def _segment(state, xs, *, chunk, dtype, bounded=True):
     """One segment of whole chunks from ``state`` (B, H, dk, dv) float32.
     xs: q, k, v, g (B, L, H, d) and beta (B, L, H). Returns (state', o)."""
     f32 = jnp.float32
@@ -239,7 +298,7 @@ def _segment(state, xs, *, chunk, dtype):
 
     tables = _chunk_tables(chunks(q), chunks(k), chunks(v),
                            chunks(g).astype(f32),
-                           chunks(beta).astype(f32), dtype)
+                           chunks(beta).astype(f32), dtype, bounded)
 
     def step(s, t):
         qg, bmat, w, u0, kend, gend = t
@@ -259,12 +318,21 @@ def _segment(state, xs, *, chunk, dtype):
     return state, o
 
 
+def _form_said(bounded: bool) -> str:
+    """The ``[kda]`` line's last field: only the second form is named."""
+    return "" if bounded else " gate=unbounded"
+
+
 def kda_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
                 segment_chunks: int = DEFAULT_SEGMENT_CHUNKS,
-                lower_bound: float = -5.0, cp=None):
+                lower_bound: float | None = -5.0, cp=None):
     """The chunked form (module docstring). q, k: (B, S, H, d_k), v:
     (B, S, H, d_v), their dtype is the products' operand dtype; g:
-    (B, S, H, d_k) float32 log-decay in [lower_bound, 0]; beta: (B, S, H).
+    (B, S, H, d_k) float32 log-decay; beta: (B, S, H) in [0, 2].
+    ``lower_bound`` is the decay gate's FORM, which the mixer knows from
+    its gate: a number where g lies in [lower_bound, 0] (the blocks' product
+    form leans on it), None where g is unbounded below (no factor above 1
+    is formed; module docstring).
     S must be whole chunks and ``chunk`` whole blocks of 16. Returns o in
     q's dtype; the state and every sum of g stay float32.
 
@@ -274,22 +342,27 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     (ops/attention.py) or None: batch and heads are independent, so under
     a mesh the kernels run a device on its own block."""
     B, S, H, dk = q.shape
-    if BLOCK * abs(lower_bound) >= 88.0:
+    bounded = lower_bound is not None
+    if bounded and BLOCK * abs(lower_bound) >= 88.0:
+        # the bounded form's one reliance (module docstring); the
+        # unbounded form has none and takes any g <= 0
         raise ValueError(
             f"a log-decay as low as {lower_bound} overflows float32 over a "
-            f"block of {BLOCK} tokens")
+            f"block of {BLOCK} tokens in the bounded gate's product form; "
+            f"lower_bound=None takes an unbounded gate")
     if chunk % BLOCK or S % chunk:
         raise ValueError(
             f"kda: chunk {chunk} must be a multiple of {BLOCK} and divide "
             f"the sequence length {S}")
     why = unsupported(S, dk, v.shape[-1], q.dtype, cp)
     if why is None:
-        return _kda_kernels(q, k, v, g, beta, cp)
-    log_plan(S, chunk, H, dk, v.shape[-1], f"impl=xla reason={why}")
-    return _kda_scan(q, k, v, g, beta, chunk, segment_chunks)
+        return _kda_kernels(q, k, v, g, beta, cp, bounded)
+    log_plan(S, chunk, H, dk, v.shape[-1],
+             f"impl=xla reason={why}{_form_said(bounded)}")
+    return _kda_scan(q, k, v, g, beta, chunk, segment_chunks, bounded)
 
 
-def _kda_scan(q, k, v, g, beta, chunk, segment_chunks):
+def _kda_scan(q, k, v, g, beta, chunk, segment_chunks, bounded=True):
     """The XLA path: a scan over segments of whole chunks."""
     B, S, H, dk = q.shape
     n_chunks = S // chunk
@@ -301,7 +374,8 @@ def _kda_scan(q, k, v, g, beta, chunk, segment_chunks):
         return jnp.moveaxis(x.reshape(B, n_seg, seg, *x.shape[2:]), 1, 0)
 
     body = jax.checkpoint(
-        lambda s, xs: _segment(s, xs, chunk=chunk, dtype=q.dtype))
+        lambda s, xs: _segment(s, xs, chunk=chunk, dtype=q.dtype,
+                               bounded=bounded))
     state0 = jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32)
     # The core's name in every device operation's op_name, forward and (as
     # ``transpose(jvp(kda_chunk))``) backward: what a per-layer metric of
@@ -331,7 +405,7 @@ def on_own_block(local, cp, like, specs):
                      out_specs=out_specs, check_vma=False)
 
 
-def _kda_kernels(q, k, v, g, beta, cp):
+def _kda_kernels(q, k, v, g, beta, cp, bounded=True):
     """The kernel pair, a device on its own block of batch and heads."""
     from jax.sharding import PartitionSpec as P
 
@@ -342,11 +416,12 @@ def _kda_kernels(q, k, v, g, beta, cp):
         hb = next(n for n in range(KERNEL_HEADS, 0, -1) if H % n == 0)
         log_plan(q.shape[1], KERNEL_CHUNK, H, q.shape[3], v.shape[3],
                  f"impl=pallas tile={kda_kernel.TILE} chunks_per_step="
-                 f"{kda_kernel.TILE // KERNEL_CHUNK} heads_per_step={hb}")
+                 f"{kda_kernel.TILE // KERNEL_CHUNK} heads_per_step={hb}"
+                 f"{_form_said(bounded)}")
         with jax.named_scope(SCOPE):
             return kda_kernel.kda_pallas(
                 q, k, v, g, beta,
-                kda_kernel.Plan(KERNEL_CHUNK, hb, _interpret()))
+                kda_kernel.Plan(KERNEL_CHUNK, hb, _interpret(), bounded))
 
     return on_own_block(
         local, cp, q,

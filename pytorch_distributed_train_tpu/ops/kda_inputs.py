@@ -4,9 +4,13 @@ between the four projections and the chunk core of ops/kda.py.
     q = unit(silu(conv(y_q, w_q))) d^-1/2     unit(y) = y rsqrt(sum_d y^2 + 1e-6)
     k = unit(silu(conv(y_k, w_k)))            conv(y, w)_t = sum_j w[j] y_{t-(K-1-j)}
     v =      silu(conv(y_v, w_v))             (depthwise, causal, zeros before t = 0)
-    g = lower_bound sigmoid(exp(A_log) (a + dt_bias))
+    g = lower_bound sigmoid(exp(A_log) (a + dt_bias))      the bounded gate
+    g = -exp(A_log) softplus(a + dt_bias)                  the unbounded one
 
-all of it in float32 whatever the operands' dtype, one cast at the end.
+all of it in float32 whatever the operands' dtype, one cast at the end. The
+gate's form is the mixer's (``lower_bound`` a number, or None for the
+report's own softplus gate, g in (-inf, 0]: ops/kda.py's two forms); ``a``
+is whatever the mixer projected, full rank or two low-rank products.
 
 Two paths, one algorithm (the rule of ops/kda.py: ``kda.unsupported`` says
 why not, from what a call can see; no option, and ONE decision for the
@@ -96,8 +100,12 @@ def shape_inputs_xla(yq, yk, yv, a, taps, a_log, dt_bias, lower_bound):
     q = unit(conv_silu(yq, taps[0])) * d ** -0.5
     k = unit(conv_silu(yk, taps[1]))
     v = conv_silu(yv, taps[2])
-    g = lower_bound * jax.nn.sigmoid(
-        jnp.exp(a_log)[:, None] * (a.astype(_F32) + dt_bias))
+    if lower_bound is None:
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            a.astype(_F32) + dt_bias)
+    else:
+        g = lower_bound * jax.nn.sigmoid(
+            jnp.exp(a_log)[:, None] * (a.astype(_F32) + dt_bias))
     return q.astype(yq.dtype), k.astype(yq.dtype), v.astype(yq.dtype), g
 
 
@@ -149,11 +157,30 @@ def _unit(s):
     return jax.lax.rsqrt(jnp.sum(s * s, axis=1, keepdims=True) + _EPS)
 
 
+def _softplus(z):
+    """log(1 + exp z) as ``jax.nn.softplus`` computes it."""
+    return jnp.maximum(z, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(z)))
+
+
 def _gate(a, gate, lower_bound):
-    """sigmoid(A (a + dt_bias)) and its argument's factor; ``gate`` rows:
-    exp(A_log) a channel, dt_bias."""
+    """The log-decay g a channel; ``gate`` rows: A = exp(A_log) a channel,
+    dt_bias. A bounded gate: lower_bound sigmoid(A (a + dt_bias)); None:
+    -A softplus(a + dt_bias)."""
     shifted = a + gate[1:2]
-    return jax.nn.sigmoid(gate[0:1] * shifted), shifted
+    if lower_bound is None:
+        return -gate[0:1] * _softplus(shifted)
+    return lower_bound * jax.nn.sigmoid(gate[0:1] * shifted)
+
+
+def _gate_bwd(a, gate, lower_bound, dg):
+    """(da, dA a channel before the sum over rows) from g's cotangent."""
+    shifted = a + gate[1:2]
+    if lower_bound is None:
+        return dg * (-jax.nn.sigmoid(shifted)) * gate[0:1], \
+            dg * (-_softplus(shifted))
+    sig = jax.nn.sigmoid(gate[0:1] * shifted)
+    dz = dg * (lower_bound * sig * (1.0 - sig))
+    return dz * gate[0:1], dz * shifted
 
 
 # ================================================================= forward
@@ -183,8 +210,7 @@ def _fwd_kernel(yq_ref, yk_ref, yv_ref, a_ref, taps_ref, gate_ref,
             if scale is not None:
                 s = s * (_unit(s) * scale)
             o_ref[:, sl] = s.astype(o_ref.dtype)
-        sig, _ = _gate(a_ref[:, sl], gate_ref[:, sl], lower_bound)
-        g_ref[:, sl] = lower_bound * sig
+        g_ref[:, sl] = _gate(a_ref[:, sl], gate_ref[:, sl], lower_bound)
 
 
 def _wide(plan: Plan, d, tile_index):
@@ -293,12 +319,11 @@ def _bwd_kernel(yq_ref, yk_ref, yv_ref, a_ref, pq_ref, pk_ref, pv_ref,
             dx = _conv([_shift_up(dy, after, K - 1 - j) for j in range(K)],
                        w)
             dx_ref[:, sl] = dx.astype(dx_ref.dtype)
-        sig, shifted_a = _gate(a_ref[:, sl], gate_ref[:, sl], lower_bound)
-        dz = dg_ref[:, sl] * (lower_bound * sig * (1.0 - sig))
-        da = dz * gate_ref[0:1, sl]
+        da, d_gate = _gate_bwd(a_ref[:, sl], gate_ref[:, sl], lower_bound,
+                               dg_ref[:, sl])
         da_ref[:, sl] = da
         add(3 * K, da)
-        add(3 * K + 1, dz * shifted_a)
+        add(3 * K + 1, d_gate)
 
 
 @functools.partial(jax.jit, static_argnums=(8, 9), inline=True)
@@ -370,14 +395,15 @@ def kda_inputs(yq, yk, yv, a, taps, a_log, dt_bias, *, lower_bound,
                plan: Plan):
     """The kernel pair. yq, yk, yv: (B, S, H, d) in one dtype, bfloat16 or
     float32; a: (B, S, H, d) float32; taps: (3, K, H, d) (q's, k's, v's);
-    a_log: (H,); dt_bias: (H, d); S whole tiles of ``plan.tile``, d a
+    a_log: (H,); dt_bias: (H, d); ``lower_bound`` the gate's form (a
+    number, or None: softplus); S whole tiles of ``plan.tile``, d a
     multiple of 128, H whole head groups. Returns q, k, v in yq's dtype
     and g float32."""
     K = taps.shape[1]
     if not 1 <= K - 1 <= _HALO or 3 * K + 2 > _SMALL:
         raise ValueError(f"kda_inputs: a convolution of {K} taps")
     return _shaped(yq, yk, yv, a.astype(_F32), taps, a_log, dt_bias,
-                   float(lower_bound), plan)
+                   lower_bound, plan)
 
 
 def shape_inputs(yq, yk, yv, a, taps, a_log, dt_bias, *, lower_bound,
@@ -385,14 +411,16 @@ def shape_inputs(yq, yk, yv, a, taps, a_log, dt_bias, *, lower_bound,
     """What ``KDAMixer`` calls: the kernel pair where ``kda.unsupported``
     lets the core's kernels run (one decision for both), else
     :func:`shape_inputs_xla`. ``taps``: the three (K, H, d) arrays; ``cp``
-    the mesh's axes or None, as :func:`kda.kda_chunked` takes them."""
+    the mesh's axes or None, and ``lower_bound`` the gate's form (None:
+    unbounded), as :func:`kda.kda_chunked` takes them."""
     B, S, H, d = yq.shape
+    lower_bound = None if lower_bound is None else float(lower_bound)
     why = kda.unsupported(S, d, d, yq.dtype, cp)
     if why is not None:
         kda.log_plan(S, None, H, d, d, f"inputs=xla reason={why}")
         with jax.named_scope(SCOPE):
             return shape_inputs_xla(yq, yk, yv, a, tuple(taps), a_log,
-                                    dt_bias, float(lower_bound))
+                                    dt_bias, lower_bound)
     taps = jnp.stack(taps)
 
     def local(yq, yk, yv, a, taps, a_log, dt_bias):

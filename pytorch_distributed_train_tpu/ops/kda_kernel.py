@@ -24,7 +24,13 @@ out (I + a^(2^i)) = out + out p and p p, which share their right operand,
 so one product of the stacked rows [out; p] does both (11.7 -> 7.9 ms).
 
 Numbers are ops/kda.py's, rounding point for rounding point: float32 g,
-sums of g and state; the mid-block reference point for the decay factors;
+sums of g and state; the decay factors by the gate's form (``Plan.bounded``;
+ops/kda.py's module docstring): a bounded gate's blocks against their
+mid-block reference point, one product a block; an unbounded gate's against
+the block's FIRST row for the pairs of different blocks (every factor <= 1)
+and, for a block's own pairs, G_t - G_j by subtraction on the VPU, one
+offset t - j at a time over the whole tile (``_own_pairs``: rows rolled
+down by the offset meet their keys; 16 offsets, no product);
 operands of the tables' and the state's products in the callers' dtype
 with float32 accumulation; the inverse and the products with it at three
 bfloat16 passes over operands split into a high and a low part by hand
@@ -73,6 +79,7 @@ class Plan(NamedTuple):
     chunk: int      # tokens a chunk: a power of two, 32 <= chunk <= TILE
     heads: int      # heads a grid step
     interpret: bool
+    bounded: bool = True   # the decay gate's form (module docstring)
 
 
 # ------------------------------------------------------------ small products
@@ -195,23 +202,92 @@ class _Tables(NamedTuple):
     blocks: list        # a block of 16 rows: its decayed rows [q; k] and
     #                     keys as the tables' product took them, and the
     #                     two decay factors (the backward kernel's)
+    G: jax.Array        # (T, dk) the in-chunk sums of g
 
 
-def _block_decays(G, i, chunk):
+def _block_decays(G, i, chunk, bounded=True):
     """Row block i's decay factors: its rows against the block's reference
-    point (the sum at its middle), and every key of its causal reach
-    against the same point."""
+    point, and every key of its causal reach against the same point. A
+    bounded gate: the sum at the block's middle, the reach up to the
+    block's end. An unbounded one: the sum at its first row and the keys
+    BEFORE the block, both factors <= 1."""
     T = G.shape[0]
     lo, hi = i * BLOCK, (i + 1) * BLOCK
-    ref = G[lo + BLOCK // 2 - 1:lo + BLOCK // 2, :]
+    at = lo + BLOCK // 2 - 1 if bounded else lo
+    ref = G[at:at + 1, :]
     rowdec = jnp.exp(G[lo:hi, :] - ref)
     j = _iota((T, 1), 0)
-    reach = (j >= (lo // chunk) * chunk) & (j < hi)
+    reach = (j >= (lo // chunk) * chunk) & (j < (hi if bounded else lo))
     keydec = jnp.exp(jnp.where(reach, ref - G, _MASKED))
     return rowdec, keydec
 
 
-def _tables(q, k, v, g, beta, m: _Masks, *, chunk, dtype, exact):
+def _roll(x, shift):
+    """Rows rotated down by ``shift`` (static): out[t] = x[t - shift]."""
+    return pltpu.roll(x, shift % x.shape[0], 0)
+
+
+def _own_pair_factors(k, G, s):
+    """Offset s inside a block of 16 rows: row t meets key t - s where both
+    lie in one block (``live``). Returns (the key's row rolled to its
+    query's, exp(G_t - G_{t-s}) with 0 where not live: the difference is
+    taken BEFORE the exponential, and is <= 0)."""
+    if s == 0:
+        return k, None
+    live = (_iota((k.shape[0], 1), 0) & (BLOCK - 1)) >= s
+    return _roll(k, s), jnp.exp(jnp.where(live, G - _roll(G, s), _MASKED))
+
+
+def _on_offset(col, s):
+    """(T, T) bool: column = row - s; ``col`` = column - row."""
+    return col == -s
+
+
+def _own_pairs(q, k, G):
+    """An unbounded gate's in-block pairs of a tile: (tq, tk) (T, T)
+    float32, tq[t, j] = sum_c q_tc k_jc exp(G_tc - G_jc) for j <= t of t's
+    block, tk the same of k's rows for j < t, zeros elsewhere."""
+    T = q.shape[0]
+    col = _iota((T, T), 1) - _iota((T, T), 0)
+    tq = tk = jnp.zeros((T, T), _F32)
+    for s in range(BLOCK):
+        ks, e = _own_pair_factors(k, G, s)
+        w = ks if e is None else ks * e
+        tq = jnp.where(_on_offset(col, s),
+                       jnp.sum(q * w, axis=1, keepdims=True), tq)
+        if s:
+            tk = jnp.where(_on_offset(col, s),
+                           jnp.sum(k * w, axis=1, keepdims=True), tk)
+    return tq, tk
+
+
+def _own_pairs_bwd(q, k, G, dtq, dtk):
+    """The cotangents of q, k and G through :func:`_own_pairs`, from the
+    tables' (T, T) cotangents (already masked to the kept pairs)."""
+    T = q.shape[0]
+    col = _iota((T, T), 1) - _iota((T, T), 0)
+    dq, dk, dG = (jnp.zeros_like(q) for _ in range(3))
+    for s in range(BLOCK):
+        ks, e = _own_pair_factors(k, G, s)
+        pick = lambda d: jnp.sum(  # noqa: E731
+            jnp.where(_on_offset(col, s), d, 0.0), axis=1, keepdims=True)
+        cq = pick(dtq)
+        if s == 0:  # the diagonal: exp(0), q's table alone
+            dq, dk = dq + cq * k, dk + cq * q
+            continue
+        ck = pick(dtk)
+        w = ks * e
+        dq, dk = dq + cq * w, dk + ck * w
+        mix = cq * q + ck * k
+        # the key t - s: its cotangent goes s rows up (0 where not live)
+        dk = dk + _roll(mix * e, -s)
+        dge = mix * w
+        dG = dG + dge - _roll(dge, -s)
+    return dq, dk, dG
+
+
+def _tables(q, k, v, g, beta, m: _Masks, *, chunk, dtype, exact,
+            bounded=True):
     """q, k, g: (T, dk) float32; v: (T, dv) float32; beta: (T, 1). A
     generator (``_lockstep``) that returns the tile's ``_Tables``."""
     T = q.shape[0]
@@ -219,17 +295,25 @@ def _tables(q, k, v, g, beta, m: _Masks, *, chunk, dtype, exact):
     yield
     tq, tk, blocks = [], [], []
     for i in range(T // BLOCK):
-        rowdec, keydec = _block_decays(G, i, chunk)
+        rowdec, keydec = _block_decays(G, i, chunk, bounded)
         lo, hi = i * BLOCK, (i + 1) * BLOCK
         rows = jnp.concatenate(
             [q[lo:hi] * rowdec, k[lo:hi] * rowdec], 0).astype(dtype)
         keys = (k * keydec).astype(dtype)
-        t = _mm(rows, keys, _NT, exact)
+        if bounded or lo % chunk:
+            t = _mm(rows, keys, _NT, exact)
+        else:  # a chunk's first block: no key before it
+            t = jnp.zeros((2 * BLOCK, T), _F32)
         tq.append(t[:BLOCK])
         tk.append(t[BLOCK:])
         blocks.append((rows, keys, rowdec, keydec))
-    bmat = jnp.where(m.lower, jnp.concatenate(tq, 0), 0.0)
-    tk = jnp.where(m.strict, jnp.concatenate(tk, 0), 0.0)
+    tq, tk = jnp.concatenate(tq, 0), jnp.concatenate(tk, 0)
+    if not bounded:
+        yield
+        own_q, own_k = _own_pairs(q, k, G)
+        tq, tk = tq + own_q, tk + own_k
+    bmat = jnp.where(m.lower, tq, 0.0)
+    tk = jnp.where(m.strict, tk, 0.0)
     yield
     inv = yield from _unit_lower_inverse(tk * beta, m.eye, chunk, exact)
     gamma = jnp.exp(G)
@@ -242,7 +326,7 @@ def _tables(q, k, v, g, beta, m: _Masks, *, chunk, dtype, exact):
     g_end = jnp.concatenate(
         [jnp.broadcast_to(e, (chunk, e.shape[1])) for e in ends], 0)
     return _Tables(tk, bmat, inv, gamma, solw, u0, jnp.exp(g_end - G),
-                   [jnp.exp(e) for e in ends], blocks)
+                   [jnp.exp(e) for e in ends], blocks, G)
 
 
 def _walk(tb: _Tables, qg, kend, st, *, chunk, dtype, exact):
@@ -285,7 +369,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, s_ref, st_ref,
         v = v_ref[:, vl].astype(_F32)
         tb = yield from _tables(q, k, v, g_ref[:, kl], beta_ref[:, h:h + 1],
                                 masks, chunk=plan.chunk, dtype=dtype,
-                                exact=exact)
+                                exact=exact, bounded=plan.bounded)
         entries, u, o, st = yield from _walk(
             tb, (q * tb.gamma).astype(dtype), (k * tb.ke).astype(dtype),
             st_ref[h], chunk=plan.chunk, dtype=dtype, exact=exact)
@@ -345,14 +429,14 @@ def _fwd(q, k, v, g, beta, plan: Plan):
 # ================================================================ backward
 
 def _bwd_head(q, k, v, g, beta, do, st, dst, m: _Masks, *, chunk, dtype,
-              exact):
+              exact, bounded=True):
     """One head's tile: the cotangents of q, k, v, g (T, d), of beta
     (T, 1), and of the tile's entry state, from ``do`` (T, dv), the entry
     state ``st`` and the exit state's cotangent ``dst`` (dv, dk). A
     generator (``_lockstep``)."""
     T = q.shape[0]
     tb = yield from _tables(q, k, v, g, beta, m, chunk=chunk, dtype=dtype,
-                            exact=exact)
+                            exact=exact, bounded=bounded)
     qg, kend = (q * tb.gamma).astype(dtype), (k * tb.ke).astype(dtype)
     entries, u, _, _ = yield from _walk(tb, qg, kend, st, chunk=chunk,
                                         dtype=dtype, exact=exact)
@@ -415,8 +499,11 @@ def _bwd_head(q, k, v, g, beta, do, st, dst, m: _Masks, *, chunk, dtype,
     for i, (rows, keys, rowdec, keydec) in enumerate(tb.blocks):
         lo, hi = i * BLOCK, (i + 1) * BLOCK
         d = jnp.concatenate([dbm[lo:hi], dtk[lo:hi]], 0).astype(dtype)
-        drows = _mm(d, keys, _NN, exact)
-        dkeys = _mm(d, rows, _TN, exact) * keydec
+        if bounded or lo % chunk:
+            drows = _mm(d, keys, _NN, exact)
+            dkeys = _mm(d, rows, _TN, exact) * keydec
+        else:  # a chunk's first block met no key in the product
+            drows, dkeys = jnp.zeros((2 * BLOCK, q.shape[1]), _F32), 0.0
         dk_key = dk_key + dkeys
         dq_tab.append(drows[:BLOCK] * rowdec)
         dk_row.append(drows[BLOCK:] * rowdec)
@@ -426,9 +513,15 @@ def _bwd_head(q, k, v, g, beta, do, st, dst, m: _Masks, *, chunk, dtype,
                       keepdims=True), (BLOCK, q.shape[1])))
     dq_tab, dk_row, d_ref = (jnp.concatenate(x, 0)
                              for x in (dq_tab, dk_row, d_ref))
+    # the row a block's reference point was read from
+    ref_row = BLOCK // 2 - 1 if bounded else 0
     dG = dG + q * dq_tab + k * (dk_row - dk_key) \
-        + jnp.where((row & (BLOCK - 1)) == BLOCK // 2 - 1, d_ref, 0.0)
+        + jnp.where((row & (BLOCK - 1)) == ref_row, d_ref, 0.0)
     yield
+    if not bounded:  # the blocks' own pairs
+        dq_own, dk_own, dG_own = _own_pairs_bwd(q, k, tb.G, dbm, dtk)
+        dq_tab, dk_key, dG = dq_tab + dq_own, dk_key + dk_own, dG + dG_own
+        yield
     dg = _tri_sum(m.tri, dG, _TN)
     return dq + dq_tab, dk_ + dk_row + dk_key, dv_, dg, dbeta, dst
 
@@ -453,7 +546,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, do_ref,
             q_ref[:, kl].astype(_F32), k_ref[:, kl].astype(_F32),
             v_ref[:, vl].astype(_F32), g_ref[:, kl], beta_ref[:, h:h + 1],
             do_ref[:, vl].astype(_F32), s_ref[h], dst_ref[h], masks,
-            chunk=plan.chunk, dtype=dtype, exact=exact)
+            chunk=plan.chunk, dtype=dtype, exact=exact,
+            bounded=plan.bounded)
 
     lane = _iota(dbeta_ref.shape, 1)
     dbeta_all = jnp.zeros(dbeta_ref.shape, _F32)

@@ -268,13 +268,17 @@ def group_limited_topk(scores, bias, spec: HeldExpertsSpec):
     return ids.astype(jnp.int32), weights
 
 
-def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int):
+def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
+              slots: int = 0):
     """The (token, choice) pairs that fall on held experts, by expert and
     inside an expert by token: (token (rows,) int32, weight (rows,) float32
     of that pair, group_sizes (held,) int32 rows of each held expert inside
     the bound, counts (held,) int32 pairs on each before the bound, over:
     pairs past the bound). Rows past sum(group_sizes) belong to no expert
-    (token 0, weight 0).
+    (token 0, weight 0). ``slots`` > 0 (``rows`` = held x slots): every
+    held expert has ``slots`` rows of its own, expert e's from e x slots
+    on, the first group_sizes[e] of them real, and the bound is each
+    expert's own (``_ExpertBank``'s second form).
 
     A token chooses an expert at most once, so the pairs are the set
     entries of an (expert, token) table, and the r-th row is the r-th set
@@ -286,12 +290,25 @@ def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int):
     hit = ids[:, :, None] == mine                     # (N, k, held)
     on = jnp.any(hit, 1)                              # (N, held)
     counts = jnp.sum(on, 0)
-    ends = jnp.minimum(jnp.cumsum(counts), rows)
-    sizes = jnp.diff(ends, prepend=0)
-    over = jnp.sum(counts) - ends[-1]
-    upto = jnp.cumsum(on.T.reshape(-1).astype(jnp.int32))
-    at = jnp.searchsorted(upto, jnp.arange(1, rows + 1, dtype=jnp.int32))
-    real = at < upto.shape[0]                         # the r-th entry exists
+    entries = lambda: jnp.cumsum(  # noqa: E731 - set entries up to each
+        on.T.reshape(-1).astype(jnp.int32))
+    if slots:
+        sizes = jnp.minimum(counts, slots)
+        over = jnp.sum(counts - sizes)
+        slot = jnp.arange(slots, dtype=jnp.int32)
+        # expert e's p-th row is set entry (entries before e) + p + 1
+        rank = (jnp.cumsum(counts) - counts)[:, None] + slot + 1
+        upto = entries()
+        at = jnp.searchsorted(upto, rank.reshape(-1).astype(jnp.int32))
+        real = (slot < sizes[:, None]).reshape(-1)
+    else:
+        ends = jnp.minimum(jnp.cumsum(counts), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        over = jnp.sum(counts) - ends[-1]
+        upto = entries()
+        at = jnp.searchsorted(upto,
+                              jnp.arange(1, rows + 1, dtype=jnp.int32))
+        real = at < upto.shape[0]                     # the r-th entry exists
     token = jnp.where(real, at % N, 0).astype(jnp.int32)
     expert = jnp.where(real, at // N, 0)
     held_weight = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), 1)
@@ -300,12 +317,32 @@ def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int):
         over
 
 
+# An expert's matrix of this many elements or more takes the bank's second
+# form. On the v5e the grouped product walks 1640 rows of 8 groups through
+# 4096 x 1280 in 1.35 ms a call, a fifteenth of the MXU's peak, and by a
+# time that follows the routing (2.7 ms a step between two seeds: PERF.md
+# section 6, PR 39); the batched product over every expert's own slots does
+# four times the rows at a fixed time. Below it (2560 x 768, 3072 x 1024)
+# a call is 0.2 ms and the grouped product stays.
+PADDED_MIN_WEIGHT = 4 * 1024 * 1024
+
+
+def padded_slots(spec: HeldExpertsSpec, rows: int, d_model: int,
+                 mlp_dim: int) -> int:
+    """Rows of its own each held expert gets (``_ExpertBank``'s second
+    form), or 0 for the grouped product: decided from what the call sees."""
+    if d_model * mlp_dim < PADDED_MIN_WEIGHT:
+        return 0
+    return rows // spec.n_held
+
+
 _moe_logged: set[tuple] = set()
 
 
-def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int) -> None:
+def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int,
+              slots: int = 0) -> None:
     """Once a shape, at trace time, on stderr: what this chip holds."""
-    key = (spec, n_tokens)
+    key = (spec, n_tokens, slots)
     if key in _moe_logged:
         return
     _moe_logged.add(key)
@@ -313,7 +350,9 @@ def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int) -> None:
     print(f"[moe] experts={spec.num_experts} held={spec.n_held} "
           f"ids={spec.held_first}-{last} top_k={spec.top_k} "
           f"groups={spec.n_groups}/{spec.topk_groups} score={spec.score} "
-          f"tokens={n_tokens} row_bound={rows}", file=sys.stderr, flush=True)
+          f"tokens={n_tokens} row_bound={rows}"
+          + (f" bank=padded slots={slots} spill=grouped" if slots else ""),
+          file=sys.stderr, flush=True)
 
 
 class _Kernel(nn.Module):
@@ -328,34 +367,67 @@ class _Kernel(nn.Module):
                           self.param_dtype)
 
 
+def _grouped_bank(rows, sizes, kernels, dtype):
+    """Rows sorted by expert through three grouped products
+    (``jax.lax.ragged_dot``; on a TPU one kernel that walks the groups).
+    Rows past sum(sizes) belong to no group, and the TPU's kernel leaves
+    whatever the buffer held there, forward AND backward (a v5e returned
+    gradients 36 000 times too large, PERF.md PR 26): every such row is
+    zeroed on the way in, between the products and on the way out, so that
+    neither a value nor a cotangent passes through one. ``kernels``: three
+    thunks, (held, D, F) gate and up, (held, F, D) down."""
+    in_group = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+    held = lambda a: jnp.where(in_group, a, 0)  # noqa: E731
+    gdot = lambda a, w: held(jax.lax.ragged_dot(  # noqa: E731
+        held(a), w, sizes, preferred_element_type=jnp.float32))
+    gate = gdot(rows, kernels[0]())
+    up = gdot(rows, kernels[1]())
+    hidden = (nn.silu(gate) * up).astype(dtype)
+    return gdot(hidden, kernels[2]())
+
+
+def _padded_bank(rows, sizes, kernels, slots, dtype):
+    """Every held expert its own ``slots`` rows (``held_rows(..., slots)``)
+    through ONE batched product a projection: a fixed shape, so its time
+    does not follow the routing as the grouped product's does. Rows past an
+    expert's size are zeroed as above."""
+    n_held, D = kernels[0].shape[0], rows.shape[-1]
+    real = (jnp.arange(slots) < sizes[:, None])[..., None]
+    held = lambda a: jnp.where(real, a, 0)  # noqa: E731
+    bdot = lambda a, w: held(jnp.einsum(  # noqa: E731
+        "esd,edf->esf", held(a), w, preferred_element_type=jnp.float32))
+    x = rows.reshape(n_held, slots, D)
+    hidden = (nn.silu(bdot(x, kernels[0])) * bdot(x, kernels[1])
+              ).astype(dtype)
+    return bdot(hidden, kernels[2]).reshape(rows.shape[0], D)
+
+
 class _ExpertBank(nn.Module):
-    """The held experts' SwiGLU weights, stacked, applied to rows sorted by
-    expert: three grouped products (``jax.lax.ragged_dot``; on a TPU one
-    kernel that walks the groups, at the cost of one dense product over
-    the rows). Rows past sum(sizes) belong to no group, and the TPU's
-    kernel leaves whatever the buffer held there, forward AND backward (a
-    v5e returned gradients 36 000 times too large, PERF.md PR 26): every
-    such row is zeroed on the way in, between the products and on the way
-    out, so that neither a value nor a cotangent passes through one."""
+    """The held experts' SwiGLU weights, stacked: applied to rows sorted by
+    expert through the grouped product (``__call__``), or handed out
+    (``kernels``) to the two-form path of ``HeldExpertsMLP``."""
 
     held: int
+    d_model: int
     mlp_dim: int
     dtype: jnp.dtype
     param_dtype: jnp.dtype
 
-    @nn.compact
+    def setup(self):
+        D, F = self.d_model, self.mlp_dim
+        self.gate_proj = _Kernel((self.held, D, F), self.param_dtype)
+        self.up_proj = _Kernel((self.held, D, F), self.param_dtype)
+        self.down_proj = _Kernel((self.held, F, D), self.param_dtype)
+
+    def _thunks(self):
+        return tuple(lambda k=k: jnp.asarray(k(), self.dtype) for k in (
+            self.gate_proj, self.up_proj, self.down_proj))
+
     def __call__(self, rows, sizes):
-        D, F = rows.shape[-1], self.mlp_dim
-        kernel = lambda name, shape: jnp.asarray(_Kernel(  # noqa: E731
-            (self.held, *shape), self.param_dtype, name=name)(), self.dtype)
-        in_group = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
-        held = lambda a: jnp.where(in_group, a, 0)  # noqa: E731
-        gdot = lambda a, w: held(jax.lax.ragged_dot(  # noqa: E731
-            held(a), w, sizes, preferred_element_type=jnp.float32))
-        gate = gdot(rows, kernel("gate_proj", (D, F)))
-        up = gdot(rows, kernel("up_proj", (D, F)))
-        hidden = (nn.silu(gate) * up).astype(self.dtype)
-        return gdot(hidden, kernel("down_proj", (F, D)))
+        return _grouped_bank(rows, sizes, self._thunks(), self.dtype)
+
+    def kernels(self):
+        return tuple(k() for k in self._thunks())
 
 
 class _Router(nn.Module):
@@ -407,20 +479,41 @@ class HeldExpertsMLP(nn.Module):
         B, S, D = x.shape
         N, spec, F = B * S, self.spec, self.mlp_dim
         rows = spec.row_bound(N)
-        _log_plan(spec, N, rows)
+        slots = padded_slots(spec, rows, D, F)
+        _log_plan(spec, N, rows, slots)
         xf = x.reshape(N, D)
         scores, bias = _Router(spec.num_experts, spec.score,
                                name="router")(xf)
         ids, weights = group_limited_topk(scores, bias, spec)
         token, weight, sizes, counts, over = held_rows(ids, weights, spec,
                                                        rows)
+        bank = _ExpertBank(spec.n_held, D, F, self.dtype, self.param_dtype,
+                           name="experts")
 
-        out_rows = _ExpertBank(spec.n_held, F, self.dtype, self.param_dtype,
-                               name="experts")(
-            xf[token].astype(self.dtype), sizes)
-        # (rows past the held pairs belong to no expert: the bank zeroed them)
-        routed = jnp.zeros((N, D), jnp.float32).at[token].add(
-            out_rows * weight[:, None])
+        def routed_by(token, weight, sizes, product):
+            out_rows = product(xf[token].astype(self.dtype), sizes)
+            # (rows of no expert: the bank zeroed them)
+            return jnp.zeros((N, D), jnp.float32).at[token].add(
+                out_rows * weight[:, None])
+
+        if slots:
+            # every expert its own slots, one batched product; a step on
+            # which some expert has more pairs than slots (the first layer's
+            # counts swing 55-390 with the batch at a mean of 205, and one
+            # step in 1200 went past 820 on the v5e) takes the grouped
+            # product instead: the bound stays the layer's, as above
+            w = bank.kernels()
+            own = held_rows(ids, weights, spec, spec.n_held * slots, slots)
+            routed = jax.lax.cond(
+                own[4] > 0,
+                lambda: routed_by(token, weight, sizes, lambda r, n:
+                                  _grouped_bank(r, n, [lambda k=k: k
+                                                       for k in w],
+                                                self.dtype)),
+                lambda: routed_by(*own[:3], lambda r, n: _padded_bank(
+                    r, n, w, slots, self.dtype)))
+        else:
+            routed = routed_by(token, weight, sizes, bank)
         shared = self.mlp_module(self.mlp_dim, self.dtype, self.param_dtype,
                                  name="shared")(x)
         y = routed.reshape(B, S, D).astype(self.dtype) + shared

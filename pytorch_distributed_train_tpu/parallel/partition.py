@@ -264,8 +264,14 @@ def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
     the grouped-query kinds' modules are `gqa` and `swa`, their K and V
     kernels carry the KV heads);
     the held experts' stacked kernels put their leading dim on 'expert';
+    a gate a CHANNEL carries heads too (`gc_proj`, (D or r, H, d)), and
+    where it or the decay's `a_proj` goes through a low rank, the first
+    factor (`gc_down`, `a_down`: (D, r), what every chip of a `tensor` group
+    computes alike) replicates;
     the latent down-projections, the router, the conv taps, A_log, dt_bias,
-    the head gates and every norm replicate (small, or per-head vectors)."""
+    the head gates and every norm replicate (small, or per-head vectors).
+    A model told its share of heads (`model.heads_held`) is ONE chip's view
+    of `tensor`: its leaves are already that axis's shards."""
     return [
         (r"tok_embed/embedding$", P("fsdp", None)),
         (r"kda/(q_proj|k_proj|v_proj|a_proj)/kernel$",
@@ -273,6 +279,8 @@ def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
         (r"mla/(q_proj|kv_up)/kernel$", P("fsdp", "tensor", None)),
         (r"(gqa|swa)/(q_proj|k_proj|v_proj)/kernel$",
          P("fsdp", "tensor", None)),
+        (r"(kda|gqa|swa)/gc_proj/kernel$", P("fsdp", "tensor", None)),
+        (r"kda/(a_down|gc_down)/kernel$", P()),
         (r"(kda|mla|gqa|swa)/o_proj/kernel$", P("tensor", None, "fsdp")),
         (r"mla/(kv_down|k_rope_proj)/kernel$", P("fsdp", None)),
         (r"experts/(gate_proj|up_proj)/kernel$",

@@ -214,36 +214,58 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     kinds' scores and values over the pairs of their band alone (causal,
     and inside the window: the work done, at each layer's own heads), and
     the delta rule's chunk products (in-chunk tables and the three products
-    with the state). The Neumann inverse's small products are left out
-    (under 1 %)."""
+    with the state), all at the heads held here (``model.heads_held``) and
+    with a gate through ``kda_gate_rank`` counted as its two products. The
+    Neumann inverse's small products are left out (under 1 %)."""
     from pytorch_distributed_train_tpu.models.hybrid import (
+        held_heads,
+        held_kv_heads,
         layer_heads,
         layer_kinds,
     )
     from pytorch_distributed_train_tpu.ops.kda import chunk_in_use
 
     s = seq or cfg.max_seq_len
-    d, h = cfg.hidden_size, cfg.num_heads
-    dh = cfg.head_dim or d // h
+    d = cfg.hidden_size
+    dh = cfg.head_dim or d // cfg.num_heads
     dr, r, c = cfg.rope_head_dim, cfg.kv_lora_rank, chunk_in_use(s, dh, dh)
-    hkv = cfg.num_kv_heads or h
+    hkv = cfg.num_kv_heads or cfg.num_heads
     kinds = layer_kinds(cfg)
     n_dense = min(cfg.first_dense_layers, cfg.num_layers)
     n_moe = cfg.num_layers - n_dense if cfg.num_experts > 1 else 0
-    kda = (2.0 * d * h * dh * 5        # q, k, v, decay, output projections
-           + 2.0 * d * h * 2           # beta and the head gate
-           + 2.0 * h * c * dh * 3      # tables A, B and B U, a token
-           + 2.0 * h * dh * dh * 3)    # W S, (Q exp G) S, the state's update
-    mla = (2.0 * d * h * (dh + dr) + 2.0 * d * (r + dr)
-           + 2.0 * r * h * 2 * dh + 2.0 * h * dh * d + 2.0 * d * h
-           + 2.0 * s * h * (dh + dr) + 2.0 * s * h * dh)
+    # the heads held HERE (model.heads_held; 0 = all of a layer's)
+    here = lambda heads: held_heads(  # noqa: E731
+        heads, cfg.heads_held, cfg.heads_held_first)
+
+    def channels(h):  # d -> (h, dh): full rank, or through kda_gate_rank
+        rank = cfg.kda_gate_rank
+        return 2.0 * d * rank + 2.0 * rank * h * dh if rank \
+            else 2.0 * d * h * dh
+
+    def kda(heads):
+        h = here(heads)
+        gate = channels(h) if cfg.kda_out_gate == "channel" else 2.0 * d * h
+        return (2.0 * d * h * dh * 4    # q, k, v, output projections
+                + channels(h)           # the decay's
+                + 2.0 * d * h + gate    # beta and the output gate
+                + 2.0 * h * c * dh * 3  # tables A, B and B U, a token
+                + 2.0 * h * dh * dh * 3)  # W S, (Q exp G) S, the update
+
+    def mla(heads):
+        h = heads
+        return (2.0 * d * h * (dh + dr) + 2.0 * d * (r + dr)
+                + 2.0 * r * h * 2 * dh + 2.0 * h * dh * d + 2.0 * d * h
+                + 2.0 * s * h * (dh + dr) + 2.0 * s * h * dh)
 
     def gqa(heads, window):  # q and o, k and v, the gate; scores and values
-        return (4.0 * d * heads * dh + 4.0 * d * hkv * dh + 2.0 * d * heads
-                + 4.0 * heads * dh * band_pairs_per_token(s, window))
+        h = here(heads)
+        kv = held_kv_heads(heads, hkv, cfg.heads_held, cfg.heads_held_first)
+        gate = 2.0 * d * h * (dh if cfg.gqa_out_gate == "channel" else 1)
+        return (4.0 * d * h * dh + 4.0 * d * kv * dh + gate
+                + 4.0 * h * dh * band_pairs_per_token(s, window))
 
     mixers = sum(
-        kda if kind == "kda" else mla if kind == "mla"
+        kda(heads) if kind == "kda" else mla(heads) if kind == "mla"
         else gqa(heads, cfg.attention_window if kind == "gqa_window" else 0)
         for kind, heads in zip(kinds, layer_heads(cfg)))
     expert = 6.0 * d * cfg.moe_mlp_dim

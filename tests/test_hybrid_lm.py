@@ -149,6 +149,64 @@ def test_pairs_past_the_row_bound_are_counted_never_silent():
     assert _spec(capacity_factor=1e9).row_bound(80) == 80 * 4
 
 
+@pytest.mark.parametrize("capacity,spills", [(4.0, False), (1.0, True)])
+def test_the_banks_padded_form_is_the_grouped_product_at_a_fixed_shape(
+        monkeypatch, capfd, capacity, spills):
+    """Every held expert its own slots and ONE batched product (what a wide
+    expert takes: ``padded_slots``) against the grouped product over the
+    packed rows: the layer's output, its stats and every gradient. A step
+    on which some expert has more pairs than slots takes the grouped product
+    (``spills``), so the bound stays the layer's."""
+    spec = _spec(score="softmax", n_groups=1, topk_groups=1, held_first=4,
+                 capacity_factor=capacity)
+    layer = _layer(spec)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 64, 16))
+    params = layer.init(jax.random.PRNGKey(7), x)["params"]
+    if spills:  # most tokens lean towards held expert 5
+        x = x + 40.0 * params["router"]["kernel"][:, 5]
+    w = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+
+    def both(p, x):
+        y, stats = layer.apply({"params": p}, x)
+        return jnp.sum(y * w), (y, stats)
+
+    run = jax.value_and_grad(both, argnums=(0, 1), has_aux=True)
+    rows = spec.row_bound(128)
+    with jax.default_matmul_precision("highest"):
+        (_, (y0, s0)), g0 = run(params, x)
+        monkeypatch.setattr(moe, "PADDED_MIN_WEIGHT", 1)
+        monkeypatch.setattr(moe, "_moe_logged", set())
+        capfd.readouterr()
+        (_, (y1, s1)), g1 = run(params, x)
+        scores = jax.nn.softmax(x.reshape(128, 16)
+                                @ params["router"]["kernel"], -1)
+        ids, wts = moe.group_limited_topk(scores, None, spec)
+        over = moe.held_rows(ids, wts, spec, rows, rows // 4)[4]
+    assert f" row_bound={rows} bank=padded slots={rows // 4} " \
+        "spill=grouped" in capfd.readouterr().err
+    assert (int(over) > 0) == spills
+    _close(y1, y0)
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        _close(b, a)
+
+
+def test_an_experts_own_slots_and_the_rule_that_picks_the_padded_bank():
+    spec = _spec(score="softmax", n_groups=1, topk_groups=1, held_first=4)
+    # 80 pairs on one expert, 6 slots an expert: 74 past ITS slots
+    ids = jnp.tile(jnp.array([[5, 9, 10, 11]]), (80, 1))
+    token, weight, sizes, counts, over = moe.held_rows(
+        ids, jnp.full((80, 4), 0.25), spec, 24, 6)
+    assert int(over) == 74
+    np.testing.assert_array_equal(np.asarray(sizes), [0, 6, 0, 0])
+    np.testing.assert_array_equal(np.asarray(token)[6:12], np.arange(6))
+    assert float(jnp.sum(weight)) == 6 * 0.25
+    # the rule, from what a call sees: the expert's matrix
+    assert moe.padded_slots(spec, 6560, 4096, 1280) == 6560 // 4
+    assert moe.padded_slots(spec, 6560, 2560, 768) == 0
+    assert moe.padded_slots(spec, 6560, 3072, 1024) == 0
+
+
 def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
     from pytorch_distributed_train_tpu import losses, steps
     from pytorch_distributed_train_tpu.optim import make_optimizer
@@ -279,7 +337,8 @@ def test_kda_layer_matches_the_reference_token_by_token(bench):
     mixer = hybrid.KDAMixer(m.num_heads, m.head_dim, m.conv_kernel_size,
                             m.kda_gate_lower_bound, m.rms_norm_eps, F32,
                             F32)
-    got = mixer.apply({"params": p}, x)
+    got, stats = mixer.apply({"params": p}, x)
+    assert stats is None  # a bounded gate's extremes are its own
     want = jnp.stack([ref._kda(p, x[b], lambda t: t) for b in range(2)])
     _close(got, want)
 
@@ -314,7 +373,7 @@ def test_kda_layer_in_its_kernels_is_the_layer_on_the_xla_path(
 
     mixer, params, x = _wide_kda_layer(31)
     w = jax.random.normal(jax.random.PRNGKey(33), x.shape)
-    run = lambda p: mixer.apply({"params": p}, x)  # noqa: E731
+    run = lambda p: mixer.apply({"params": p}, x)[0]  # noqa: E731
     both = jax.value_and_grad(lambda p: jnp.sum(run(p) * w))
     monkeypatch.setattr(kda, "_logged", set())
     capfd.readouterr()  # what building the layer said
@@ -353,7 +412,7 @@ def test_the_programs_map_names_the_shaping_on_both_paths(path, monkeypatch):
     mixer, params, x = _wide_kda_layer(35)
 
     def forward(p):
-        return jnp.sum(mixer.apply({"params": p}, x) ** 2)
+        return jnp.sum(mixer.apply({"params": p}, x)[0] ** 2)
 
     text = jax.jit(jax.grad(forward)).lower(params).compile().as_text()
     built = step_program.scope_map(text)

@@ -1,6 +1,8 @@
 """ops/kda.py: the chunked gated delta rule against the token-by-token
 recurrence, outputs and every gradient, at each chunk size the model may
-use and over several segments; and the guards on its shapes. The same for
+use and over several segments, in both forms of the decay gate (a bounded
+one's product form; an unbounded one's, with one-token decays down to
+exp(-40) and step sizes up to 2); and the guards on its shapes. The same for
 the kernel pair of ops/kda_kernel.py, which the dispatch takes on a TPU:
 here in interpret mode, reached by steering the gate as a test of the
 head's kernels does (tests/test_lm_head_loss.py)."""
@@ -35,43 +37,76 @@ def as_on_a_tpu(monkeypatch):
 KERNEL = dict(dk=128, dv=128)  # head widths the kernels take
 
 
-def _inputs(seed, B=2, S=192, H=2, dk=16, dv=8, spread=3.0):
+def _inputs(seed, B=2, S=192, H=2, dk=16, dv=8, spread=3.0, lowest=None):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = unit(jax.random.normal(ks[0], (B, S, H, dk))) * dk ** -0.5
     k = unit(jax.random.normal(ks[1], (B, S, H, dk)))
     v = jax.random.normal(ks[2], (B, S, H, dv))
-    # log-decays all the way down to the bound: the blocks' reference
-    # points are what keeps exp() inside float32 there
-    g = -5.0 * jax.nn.sigmoid(spread * jax.random.normal(ks[3], (B, S, H, dk)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    if lowest is None:
+        # log-decays all the way down to the bound: the blocks' reference
+        # points are what keeps exp() inside float32 there
+        g = -5.0 * jax.nn.sigmoid(
+            spread * jax.random.normal(ks[3], (B, S, H, dk)))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    else:
+        # an unbounded gate: one-token log-decays log-uniform from -1e-3
+        # down to ``lowest``, step sizes up to 2
+        g = -jnp.exp(jax.random.uniform(
+            ks[3], (B, S, H, dk), minval=jnp.log(1e-3),
+            maxval=jnp.log(-lowest)))
+        beta = 2.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[4], (B, S, H)))
     return (q, k, v, g, beta), jax.random.normal(ks[5], (B, S, H, dv))
 
 
-@pytest.mark.parametrize("impl,chunk,heads", [
-    ("xla", 16, 0), ("xla", 32, 0), ("xla", 64, 0),
+@pytest.mark.parametrize("impl,chunk,heads,lowest", [
+    ("xla", 16, 0, None), ("xla", 32, 0, None), ("xla", 64, 0, None),
     # the kernel pair: two tiles of 128 tokens, 4 or 2 chunks a tile, one
     # head a grid step or both in step
-    ("pallas", 32, 1), ("pallas", 64, 2),
+    ("pallas", 32, 1, None), ("pallas", 64, 2, None),
+    # the unbounded gate's form: decays down to exp(-40) a token (16 tokens
+    # of a block reach exp(-640)), beta up to 2
+    ("xla", 32, 0, -40.0), ("xla", 64, 0, -40.0), ("pallas", 64, 2, -40.0),
 ])
 def test_chunked_equals_the_recurrence_outputs_and_all_gradients(
-        impl, chunk, heads, request):
+        impl, chunk, heads, lowest, request):
+    form = dict(lower_bound=None) if lowest else {}
     if impl == "pallas":
         request.getfixturevalue("as_on_a_tpu")(chunk, heads)
-        args, w = _inputs(chunk, S=256, **KERNEL)
-        run = kda.kda_chunked
+        args, w = _inputs(chunk, S=256, lowest=lowest, **KERNEL)
+        run = lambda *a: kda.kda_chunked(*a, **form)  # noqa: E731
     else:
-        args, w = _inputs(chunk)
+        args, w = _inputs(chunk, lowest=lowest)
         # 192 tokens: 12, 6 or 3 chunks, walked two chunks a segment or one
-        run = lambda *a: kda.kda_chunked(*a, chunk=chunk, segment_chunks=2)  # noqa: E731
+        run = lambda *a: kda.kda_chunked(  # noqa: E731
+            *a, chunk=chunk, segment_chunks=2, **form)
+    if lowest:
+        assert float(jnp.min(args[3])) < -35.0 and float(jnp.max(args[4])) > 1.9
     want, got = kda.kda_recurrent(*args), run(*args)
-    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    assert bool(jnp.all(jnp.isfinite(got)))
+    # step sizes past 1 make the chunk's triangular system worse
+    # conditioned: three times the room, forward and backward
+    room = 3.0 if lowest else 1.0
+    assert float(jnp.max(jnp.abs(got - want))) < room * 2e-6
     grads = lambda f: jax.grad(  # noqa: E731
         lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("q k v g beta".split(), grads(kda.kda_recurrent),
                           grads(run)):
+        assert bool(jnp.all(jnp.isfinite(b))), name
         assert float(jnp.max(jnp.abs(a - b))) \
-            < 2e-5 * float(jnp.max(jnp.abs(a))), name
+            < room * 2e-5 * float(jnp.max(jnp.abs(a))), name
+
+
+def test_the_bounded_form_cannot_take_an_unbounded_gate():
+    """What the second form is for: under decays of exp(-40) a token the
+    bounded gate's product form multiplies exp(+280) by 0 inside a block
+    (its reference point sits 7 tokens past a key), and the result is not a
+    number; the same call told the gate's form is the recurrence's."""
+    args, _ = _inputs(9, B=1, S=64, lowest=-40.0)
+    bad = kda.kda_chunked(*args, chunk=32)
+    assert not bool(jnp.all(jnp.isfinite(bad)))
+    good = kda.kda_chunked(*args, chunk=32, lower_bound=None)
+    assert float(jnp.max(jnp.abs(good - kda.kda_recurrent(*args)))) < 6e-6
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -122,14 +157,18 @@ def test_shapes_and_bounds_the_algorithm_cannot_take_are_refused(kwargs,
         kda.kda_chunked(q, k, v, g, beta, **kwargs)
 
 
-@pytest.mark.parametrize("shape,on_tpu,reason", [
-    (dict(S=256, **KERNEL), False, "the backend is not a TPU"),
-    (dict(S=256), True, "d_k=16 d_v=8: not multiples of 128"),
-    (dict(S=192, **KERNEL), True, "S=192 is not whole tiles of 128"),
-    (dict(S=256, **KERNEL), True, None),
+@pytest.mark.parametrize("shape,on_tpu,reason,form", [
+    (dict(S=256, **KERNEL), False, "the backend is not a TPU", {}),
+    (dict(S=256), True, "d_k=16 d_v=8: not multiples of 128", {}),
+    (dict(S=192, **KERNEL), True, "S=192 is not whole tiles of 128", {}),
+    (dict(S=256, **KERNEL), True, None, {}),
+    # an unbounded gate's form: the same decision, the line says the form
+    (dict(S=256), True, "d_k=16 d_v=8: not multiples of 128",
+     dict(lower_bound=None)),
+    (dict(S=128, **KERNEL), True, None, dict(lower_bound=None)),
 ])
 def test_the_dispatch_says_once_a_shape_what_took_the_core(
-        shape, on_tpu, reason, request, capfd):
+        shape, on_tpu, reason, form, request, capfd):
     """``[kda] ... impl=pallas`` with the kernels' tile where they take the
     core, ``impl=xla reason=...`` where they do not; one line a shape."""
     if on_tpu:
@@ -139,23 +178,25 @@ def test_the_dispatch_says_once_a_shape_what_took_the_core(
     (q, k, v, g, beta), _ = _inputs(3, B=1, H=1, **shape)
     want = kda.kda_recurrent(q, k, v, g, beta)
     for _ in range(2):
-        out = kda.kda_chunked(q, k, v, g, beta, chunk=32)
+        out = kda.kda_chunked(q, k, v, g, beta, chunk=32, **form)
         assert float(jnp.max(jnp.abs(out - want))) < 2e-6
     lines = [ln for ln in capfd.readouterr().err.splitlines()
              if ln.startswith("[kda]")]
     assert len(lines) == 1, lines
     assert f"S={shape['S']} " in lines[0] and "state_dtype=float32" in lines[0]
+    gate = " gate=unbounded" if form else ""
     if reason is None:
         assert (f"chunk={kda.KERNEL_CHUNK} " in lines[0]
                 and lines[0].endswith(
                     f"impl=pallas tile=128 chunks_per_step="
-                    f"{128 // kda.KERNEL_CHUNK} heads_per_step=1")), lines
+                    f"{128 // kda.KERNEL_CHUNK} heads_per_step=1{gate}")), \
+            lines
         assert kda.chunk_in_use(shape["S"], 128, 128) == kda.KERNEL_CHUNK
     else:
         assert lines[0].endswith(f"chunk=32 chunks={shape['S'] // 32} "
                                  f"heads=1 d_k={q.shape[-1]} "
                                  f"d_v={v.shape[-1]} state_dtype=float32 "
-                                 f"impl=xla reason={reason}"), lines
+                                 f"impl=xla reason={reason}{gate}"), lines
         assert kda.chunk_in_use(shape["S"], q.shape[-1], v.shape[-1]) == 32
 
 

@@ -41,18 +41,18 @@ def _operands(seed, dtype=jnp.float32, B=2, S=384, H=4, d=128):
     return (*y, a, *taps, a_log, dt_bias), ks[7]
 
 
-def _shape(*ops, **kw):
+def _shape(*ops, lower_bound=LOWER, **kw):
     yq, yk, yv, a, wq, wk, wv, a_log, dt_bias = ops
     return kda_inputs.shape_inputs(yq, yk, yv, a, [wq, wk, wv], a_log,
-                                   dt_bias, lower_bound=LOWER, **kw)
+                                   dt_bias, lower_bound=lower_bound, **kw)
 
 
-def _outputs_and_gradients(ops, key):
+def _outputs_and_gradients(ops, key, lower_bound=LOWER):
     weights = [jax.random.normal(k, ops[0].shape)
                for k in jax.random.split(key, 4)]
 
     def weighted(*ops):
-        out = _shape(*ops)
+        out = _shape(*ops, lower_bound=lower_bound)
         return sum(jnp.sum(o.astype(jnp.float32) * w)
                    for o, w in zip(out, weights)), out
 
@@ -66,24 +66,29 @@ def both_paths():
     """{dtype name: (the XLA chain's, the kernel pair's)} outputs and
     gradients by name: three tiles of 128 tokens, so that the convolution's
     rows cross two boundaries in each direction; batch 2; two head groups
-    of two heads."""
+    of two heads. ``float32-softplus``: the unbounded gate's form (no
+    lower bound: g = -exp(A_log) softplus(a + dt_bias))."""
     found = {}
-    for name, dtype in (("float32", jnp.float32), ("bfloat16", jnp.bfloat16)):
+    for name, dtype, lower in (("float32", jnp.float32, LOWER),
+                               ("bfloat16", jnp.bfloat16, LOWER),
+                               ("float32-softplus", jnp.float32, None)):
         ops, key = _operands(1, dtype)
-        want = _outputs_and_gradients(ops, key)
+        want = _outputs_and_gradients(ops, key, lower)
         with pytest.MonkeyPatch.context() as patch:
             _steer(patch)
-            found[name] = (want, _outputs_and_gradients(ops, key))
+            found[name] = (want, _outputs_and_gradients(ops, key, lower))
     return found
 
 
 @pytest.mark.parametrize("name", OUTPUTS + OPERANDS)
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16",
+                                   "float32-softplus"])
 def test_the_pair_equals_the_xla_chain(both_paths, dtype, name):
     """Outputs and all nine gradients. float32 operands: to the order of
     the sums (the small gradients add 768 rows a channel, ``A_log``'s 128
     channels more); bfloat16: the casts' rounding points are the same, so
-    what differs is a last bit here and there of a bfloat16 result."""
+    what differs is a last bit here and there of a bfloat16 result. The
+    gate's two forms share everything but g and its three gradients."""
     want, got = (x[name] for x in both_paths[dtype])
     assert want.dtype == got.dtype and want.shape == got.shape
     want, got = want.astype(jnp.float32), got.astype(jnp.float32)
@@ -113,14 +118,20 @@ def test_the_first_tokens_see_zeros_in_every_batch_row_and_head_group(
     assert float(jnp.max(jnp.abs(v[:, 131:] - cut[:, 3:]))) < 1e-6
 
 
-def test_a_gate_at_its_bounds_stays_finite(as_on_a_tpu):
-    """A pre-activation far out on either side: g reaches lower_bound or 0
-    and every gradient is a number."""
+@pytest.mark.parametrize("lower", [LOWER, None])
+def test_a_gate_at_its_bounds_stays_finite(as_on_a_tpu, lower):
+    """A pre-activation far out on either side: g reaches lower_bound (the
+    unbounded gate: -exp(A_log) times the pre-activation itself) or 0 and
+    every gradient is a number."""
     ops, key = _operands(3, S=128, H=2)
     a = jnp.where(ops[3] > 0, 1e4, -1e4)
-    found = _outputs_and_gradients((*ops[:3], a, *ops[4:]), key)
+    found = _outputs_and_gradients((*ops[:3], a, *ops[4:]), key, lower)
     g = found["g"]
-    assert float(jnp.min(g)) == LOWER and float(jnp.max(g)) == 0.0
+    assert float(jnp.max(g)) == 0.0
+    if lower is None:  # exp(A_log) in (0.4, 2.5), softplus(1e4 - 5) ~ 1e4
+        assert -3e4 < float(jnp.min(g)) < -3e3
+    else:
+        assert float(jnp.min(g)) == lower
     for name, x in found.items():
         assert bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))), name
 

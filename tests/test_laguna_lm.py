@@ -396,26 +396,38 @@ def test_layer_kinds_and_heads_are_checked_and_derived():
 # another order (30a291fc... before).
 LING3_TREE = "d7a1ed3b5f0c92b2433b404a13d0135278b17a28b15770f912e66d9870df799d"
 LING3_STEP = "7ee85e93ad25e15854cfee40314ef2438147b2009dac612846433d8ccb139a28"
+# PR 39 told the mixers which HEADS they hold (`model.heads_held`, 0 = all)
+# and gave them their families' variants as plain fields (the decay gate's
+# form, beta's scale, gates a channel, no rotation); under the defaults both
+# presets' trees and steps are the parent's (commit 371bc2d), this one's
+# taken there the same way.
+LAGUNA_TREE = "c281f4f8f2f437f91105ea2fc5bba924ed8de2327ef833c27ef858f6cdb8d43a"
+LAGUNA_STEP = "3f9f8ef9438ea684dea67ce40227c005df10428e27a1e2388f152f510cb5fe25"
 
 
-def test_the_other_hybrid_presets_tree_and_lowered_step_are_the_parents():
+@pytest.mark.parametrize("preset,leaves,want_tree,want_step", [
+    ("ling3_flash_lm_ep64", 132, LING3_TREE, LING3_STEP),
+    ("laguna_s_lm_ep32", 69, LAGUNA_TREE, LAGUNA_STEP),
+])
+def test_the_other_hybrid_presets_tree_and_lowered_step_are_the_parents(
+        preset, leaves, want_tree, want_step):
     from pytorch_distributed_train_tpu import losses, steps
     from pytorch_distributed_train_tpu.optim import make_optimizer
     from pytorch_distributed_train_tpu.train_state import TrainState
 
-    cfg = get_preset("ling3_flash_lm_ep64")
+    cfg = get_preset(preset)
+    assert cfg.model.heads_held == 0 and cfg.model.kda_gate == "bounded"
     model = build_model(cfg.model, cfg.precision)
     shapes = jax.eval_shape(lambda: model.init(
         {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 64), jnp.int32),
         train=False)["params"])
     sig = [(jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
            for k, v in jax.tree_util.tree_flatten_with_path(shapes)[0]]
-    assert len(sig) == 132
+    assert len(sig) == leaves
     tree = hashlib.sha256(json.dumps(sig).encode()).hexdigest()
-    assert tree == LING3_TREE, f"parameter tree moved: sha256 {tree}"
+    assert tree == want_tree, f"parameter tree moved: sha256 {tree}"
 
-    with open(os.path.join(BENCH, "configs",
-                           "ling3_flash_lm_ep64.json")) as f:
+    with open(os.path.join(BENCH, "configs", preset + ".json")) as f:
         cfg.apply_overrides(json.load(f)["rehearsal_overrides"])
     cfg.apply_overrides(["precision.compute_dtype=bfloat16"])
     model = build_model(cfg.model, cfg.precision)
@@ -434,4 +446,4 @@ def test_the_other_hybrid_presets_tree_and_lowered_step_are_the_parents():
             {"input_ids": jax.ShapeDtypeStruct((2, 128), jnp.int32)},
             jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
     got = hashlib.sha256(text.encode()).hexdigest()
-    assert got == LING3_STEP, f"lowered step moved: sha256 {got}"
+    assert got == want_step, f"lowered step moved: sha256 {got}"

@@ -221,7 +221,7 @@ def test_kda_mixer_in_its_kernels_moves_no_tensor_round_them(one_chip,
     x = jax.ShapeDtypeStruct((B, S, D), jnp.bfloat16)
     params = jax.eval_shape(lambda: mixer.init(
         {"params": jax.random.PRNGKey(0)}, jnp.zeros(x.shape, x.dtype)))
-    layer = jax.checkpoint(lambda p, x: mixer.apply(p, x))
+    layer = jax.checkpoint(lambda p, x: mixer.apply(p, x)[0])
     loss = lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32) ** 2)  # noqa: E731
     text = _compile(jax.grad(loss, argnums=(0, 1)), described(params),
                     described(x)).as_text()
