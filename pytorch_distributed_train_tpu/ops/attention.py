@@ -64,22 +64,28 @@ _resolutions_logged: set[tuple] = set()
 
 
 def _log_resolution(impl: str, q, k, *, causal: bool, window: int,
-                    interpret: bool = False, plan=None) -> None:
+                    interpret: bool = False, plan=None, bwd=None) -> None:
     """Say once per distinct call signature, at trace time, which
     implementation the dispatch resolved to — a run's log then shows
     whether the Pallas kernel really took the call, and with ``plan``
     (flash_attention.tile_plan of the call) how many of a head's score
     tiles it enters and how many of those build a mask: ``tiles=3/4
-    masked=2``. On stderr: stdout is the product of the generation CLIs."""
+    masked=2``; with ``bwd`` (flash_attention.backward_plan of the call:
+    the function the kernel's backward itself asks) the backward's form
+    and the bytes it would keep in VMEM under a KV head: ``bwd=fused
+    resident=1.6MB``, or ``bwd=split`` past the budget. On stderr: stdout
+    is the product of the generation CLIs."""
     key = (impl, q.shape, k.shape[2], str(q.dtype), causal, window, interpret)
     if key in _resolutions_logged:
         return
     _resolutions_logged.add(key)
-    tiles = "" if plan is None else \
+    kernel = "" if plan is None else \
         f" tiles={plan.executed}/{plan.total} masked={plan.masked}"
+    if bwd is not None:
+        kernel += f" {bwd}"
     print(f"[attention] impl={impl} q={tuple(q.shape)} kv_heads={k.shape[2]} "
           f"dtype={q.dtype} causal={causal} window={window} "
-          f"interpret={interpret}{tiles}", file=sys.stderr, flush=True)
+          f"interpret={interpret}{kernel}", file=sys.stderr, flush=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -229,7 +235,8 @@ def dot_product_attention(
                 _log_resolution(
                     "pallas", q, k, causal=causal, window=window,
                     interpret=not on_tpu,
-                    plan=_fa.call_plan(q, k, causal=causal, window=window))
+                    plan=_fa.call_plan(q, k, causal=causal, window=window),
+                    bwd=_fa.call_backward_plan(q, k, v))
                 flash = functools.partial(
                     _fa.flash_attention, causal=causal, window=window,
                     interpret=not on_tpu)

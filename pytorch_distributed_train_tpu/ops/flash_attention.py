@@ -4,7 +4,11 @@ The TPU counterpart of the reference stack's fused attention kernels
 (torch SDPA/cuDNN flash path — SURVEY C23): never materialises the (S, S)
 score matrix in HBM. Forward streams KV through the MXU under a running
 max/sum (the flash-attention-2 formulation); backward recomputes P per
-tile from the saved logsumexp and produces dQ and dK/dV in two kernels.
+tile from the saved logsumexp and produces dQ, dK and dV in ONE kernel:
+a score tile's S^T, P^T and dS^T are built once and the three output
+products run from them, five products a tile (a dQ kernel beside a dK/dV
+kernel runs seven: both rebuild S^T and dP^T). The two-kernel backward
+lives on for calls past the fused kernel's VMEM budget alone.
 
 Layout. Inputs (B, S, H, D) are reshaped to (B·H, S, D). Inside a kernel
 the scores live TRANSPOSED, keys on sublanes and queries on lanes
@@ -19,11 +23,25 @@ row a head, not an (S, 1) column padded to 128 lanes. D must be
 
 Tiling is two-level (:func:`tile_sizes` is the one rule). The GRID stays
 coarse, (B·H, S/block_q, S/major): a grid step has fixed costs. A step
-holds ``major`` rows of its streamed side in VMEM (K and V in the forward
-and dQ kernels; Q, dO, lse and delta in the dK/dV kernel, whose step owns
-one (block_k, D) output tile); with one major block (S <= 2048) the
-resident block's index does not depend on the other index, so it is
-fetched once a head.
+holds ``major`` rows of K and V in VMEM; with one major block (S <= 2048)
+the resident block's index does not depend on the other index, so it is
+fetched once a head. The backward's grid is the forward's under a KV
+head with the head's rep query heads innermost, (B·Hkv, S/block_q,
+S/major, rep): the rep steps of a (Q tile, major block) pair share ONE
+fetch of K and V (at nine query heads a KV head and S 8192 the forward
+moves 4.8 GB of K and V a call, the backward 0.5), a Q tile's Q, dO and dQ
+are blocks of all rep heads, fetched and written once a tile, and dK and
+dV of the KV head's WHOLE sequence stay in float32 VMEM under every Q
+tile and query head that reads the head, S x (D + Dv) x 4 bytes whatever
+rep is (0.5 MB at GPT-2's shape, 10.5 MB at latent attention's 8192 x
+(192 + 128)), beside the output blocks they are written to at the head's
+last step: :func:`backward_plan` is the one rule, and the call asks the
+compiler for the scoped VMEM it needs (the v5e has 128 MiB). The other
+side resident, dQ under a dK/dV grid, would hold rep x S x D x 4: 37.7 MB
+at nine heads a KV head. Past the budget (16384 keys at D 128, or more
+than 16 query heads a KV head at 8192) a call keeps the two kernels, whose
+dK/dV step holds ``major`` rows of Q, dO, lse and delta and owns one
+(block_k, D) output tile.
 
 What is skipped and what is masked, full-sequence entry (positions implied
 by the grid). Which (block_q, block_k) score tiles a step enters follows
@@ -54,9 +72,10 @@ neither the result nor the time (PERF.md, PR 25).
 GQA is native (r4): K/V stay at Hkv heads in HBM; the batch-major head
 order makes q row b's KV row exactly b // rep (rep = H/Hkv), so sharing is
 a BlockSpec index_map, not a materialised repeat — K/V read bandwidth drops
-by rep. The dK/dV backward adds a rep grid axis that revisits each KV tile
-once per query head in its group (first visit zeroes the accumulators,
-last writes out).
+by rep. The backward's rep grid axis, innermost, walks the query heads
+of a KV head under one fetched K and V block and the head's resident dK
+and dV (the head's first step zeroes them, its last writes out); the
+two-kernel dK/dV revisits each KV tile once per query head.
 
 Two entry points:
 - :func:`flash_attention` — full self-attention, positions implied by the
@@ -71,7 +90,7 @@ Two entry points:
   a predicate on its positions' min/max and masked from the positions.
   Same algorithm, different bound. Its custom VJP folds the incoming lse
   cotangent into the flash2 ``delta`` term (ds = p∘(dp − (delta − dlse))),
-  so the same backward kernels serve both entry points.
+  so the same backward serves both entry points, fused or not.
 
 Enable/disable: dispatched from ops.attention.dot_product_attention; tests
 run interpret=True on CPU against the XLA reference implementation
@@ -112,10 +131,10 @@ DEFAULT_BLOCK_K_MAJOR = 2048
 _MAJOR_BYTES = 512 << 10
 
 class Tiles(NamedTuple):
-    block_q: int  # Q rows of a score tile (and of the fwd/dQ grid step)
-    block_k: int  # KV columns of a score tile (and of the dK/dV grid step)
-    major_q: int  # Q rows resident in a dK/dV grid step
-    major_k: int  # KV rows resident in a forward / dQ grid step
+    block_q: int  # Q rows of a score tile (and of the fwd/bwd grid step)
+    block_k: int  # KV columns of a score tile (and of a split dK/dV step)
+    major_q: int  # Q rows resident in a split dK/dV grid step
+    major_k: int  # KV rows resident in a forward / backward grid step
 
 
 class TilePlan(NamedTuple):
@@ -157,8 +176,9 @@ def tile_sizes(Sq: int, Sk: int, D: int, itemsize: int, *,
 def tile_plan(S: int, block_q: int, block_k: int, *, causal: bool,
               window: int = 0) -> TilePlan:
     """Score tiles of one head of the full-sequence entry: in the square,
-    entered, and masked. The same set in all three kernels (forward and dQ
-    sweep a Q tile's KV tiles, dK/dV a KV tile's Q tiles); static per call
+    entered, and masked. The same set in every kernel (forward and
+    backward sweep a Q tile's KV tiles, a split dK/dV a KV tile's Q tiles),
+    the backward entering each ONCE; static per call
     site, so it costs a step nothing. With d = row - col: a tile is entered
     unless every d < 0 (causal) or every d >= window; it is masked when
     some d < 0 or some d >= window."""
@@ -381,8 +401,9 @@ def _dispatch(update, *, major, unit, masks, needed_at, off, offsets,
 
 def _kv_dispatch(update, q_ref, k_ref, qpos_ref, kpos_ref, *, block_k, grid,
                  causal, window):
-    """Forward and dQ: the key segments of the resident K/V block that
-    this step's Q tile has to meet."""
+    """Forward, dQ and the fused backward (grid axes 1 and 2 count Q tiles
+    and major blocks in all three): the key segments of the resident K/V
+    block that this step's Q tile has to meet."""
     block_q, major = q_ref.shape[1], k_ref.shape[1]
     _dispatch(
         update, major=major, unit=block_k, masks=causal or bool(window),
@@ -551,6 +572,13 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
 # are plain products and dQ^T = K^T dS^T is transposed once on the way out,
 # where ``scale`` multiplies it too.
 
+def _p_ds_t(q, kb, vb, do, lse, delta, mask, qpos, kpos, **scoring):
+    """One segment's P^T and dS^T, (keys, queries) each, rebuilt from the
+    saved lse: what every backward kernel starts a score tile with."""
+    pt = jnp.exp(_scores_t(q, kb, mask, qpos, kpos, **scoring) - lse)
+    return pt, pt * (_dot(vb, do, _NT) - delta)
+
+
 def _bwd_dq_kernel(*refs, block_k, grid, direct, causal, scale, window,
                    has_pos):
     """Grid (BH, nq, n_major), as the forward: one (block_q, D) dQ tile."""
@@ -578,10 +606,10 @@ def _bwd_dq_kernel(*refs, block_k, grid, direct, causal, scale, window,
         for c0, c1, mask in segs:
             kb = k_ref[0, c0:c1, :]
             vb = v_ref[0, c0:c1, :]
-            st = _scores_t(q, kb, mask, qpos_ref[...] if has_pos else None,
-                           kpos_ref[c0:c1, :] if has_pos else None,
-                           scale=scale, **masking)
-            dst = jnp.exp(st - lse) * (_dot(vb, do, _NT) - delta)
+            _, dst = _p_ds_t(q, kb, vb, do, lse, delta, mask,
+                             qpos_ref[...] if has_pos else None,
+                             kpos_ref[c0:c1, :] if has_pos else None,
+                             scale=scale, **masking)
             parts.append(_dot(kb, dst, _TN))  # (D, block_q) = K^T dS^T
         acc = functools.reduce(operator.add, parts)
         if direct:
@@ -634,12 +662,12 @@ def _bwd_dkv_kernel(*refs, block_q, grid, rep, direct, causal, scale,
         for r0, r1, mask in segs:
             q = q_ref[0, r0:r1, :]
             do = do_ref[0, r0:r1, :]
-            st = _scores_t(  # (block_k, r1 - r0)
-                q, kb, mask, qpos_ref[:, r0:r1] if has_pos else None,
+            pt, dst = _p_ds_t(  # (block_k, r1 - r0) each
+                q, kb, vb, do, _safe(lse_ref[0, :, r0:r1]),
+                delta_ref[0, :, r0:r1], mask,
+                qpos_ref[:, r0:r1] if has_pos else None,
                 kpos_ref[...] if has_pos else None, scale=scale, **masking)
-            pt = jnp.exp(st - _safe(lse_ref[0, :, r0:r1]))
             dvs.append(_dot(pt, do, _NN))  # (block_k, D)
-            dst = pt * (_dot(vb, do, _NT) - delta_ref[0, :, r0:r1])
             dks.append(_dot(dst, q, _NN))  # (block_k, D)
         dk = functools.reduce(operator.add, dks)
         dv = functools.reduce(operator.add, dvs)
@@ -666,13 +694,126 @@ def _bwd_dkv_kernel(*refs, block_q, grid, rep, direct, causal, scale,
             finish(dk_acc[...], dv_acc[...])
 
 
+def _bwd_fused_kernel(*refs, block_k, grid, rep, direct, causal, scale,
+                      window, has_pos):
+    """Grid (B·Hkv, nq, n_major, rep): the forward's grid under a KV head,
+    the head's rep query heads innermost. A step meets ONE query head's
+    (block_q, D) Q and dO tile with the key segments it has to enter of the
+    resident (major, D) K and V, builds S^T, P^T and dS^T of each once and
+    runs the three output products from them. The rep steps of a (Q tile,
+    major block) pair share K and V (one fetch for all of them) and the
+    pair's offset, so they enter the same segments. Q, dO, lse, delta and
+    dQ are blocks of all rep heads' tile, fetched and written once a Q
+    tile; dQ is written by its step outright with one major block, else
+    summed over them in a (rep, D, block_q) scratch. dK and dV of the KV
+    head's WHOLE sequence stay in float32 VMEM, (n_major, major, D), under
+    every Q tile and query head that reads the head: its first step zeroes
+    them, every step adds its segments' rows, its last writes them out."""
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qpos_ref, kpos_ref,
+     (dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *state)) = \
+        _unpack(refs, 6, has_pos)
+    major = k_ref.shape[1]
+    masking = dict(causal=causal, window=window)
+    qi, kmi, r = (pl.program_id(a) for a in (1, 2, 3))
+    n_major = grid[1]
+
+    @pl.when((qi == 0) & (kmi == 0) & (r == 0))
+    def _zero():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if not direct:
+        dq_acc, = state
+
+        @pl.when(kmi == 0)
+        def _init():
+            dq_acc[r] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    def update(segs):
+        q = q_ref[r]
+        do = do_ref[r]
+        lse = _safe(lse_ref[r])  # lse, delta: (1, block_q)
+        delta = delta_ref[r]
+        parts = []
+        for c0, c1, mask in segs:
+            kb = k_ref[0, c0:c1, :]
+            vb = v_ref[0, c0:c1, :]
+            pt, dst = _p_ds_t(  # (c1 - c0, block_q) each
+                q, kb, vb, do, lse, delta, mask,
+                qpos_ref[...] if has_pos else None,
+                kpos_ref[c0:c1, :] if has_pos else None,
+                scale=scale, **masking)
+            dv_acc[kmi, c0:c1, :] += _dot(pt, do, _NN)  # (c1 - c0, Dv)
+            dk_acc[kmi, c0:c1, :] += _dot(dst, q, _NN)  # (c1 - c0, D)
+            parts.append(_dot(kb, dst, _TN))  # (D, block_q) = K^T dS^T
+        acc = functools.reduce(operator.add, parts)
+        if direct:
+            dq_ref[r] = (acc * scale).T.astype(dq_ref.dtype)
+        else:
+            dq_acc[r] += acc
+
+    _kv_dispatch(update, q_ref, k_ref, qpos_ref, kpos_ref, block_k=block_k,
+                 grid=grid, **masking)
+
+    if not direct:
+        @pl.when(kmi == n_major - 1)
+        def _fin_dq():
+            dq_ref[r] = (dq_acc[r] * scale).T.astype(dq_ref.dtype)
+
+    @pl.when((qi == grid[0] - 1) & (kmi == n_major - 1) & (r == rep - 1))
+    def _fin_dkv():
+        for m in range(n_major):
+            rows = slice(m * major, (m + 1) * major)
+            dk_ref[0, rows, :] = (dk_acc[m] * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_acc[m].astype(dv_ref.dtype)
+
+
+# What the fused kernel keeps in VMEM beyond a step's own blocks. A KV
+# head's dK and dV: float32 accumulators, Sk x (D + Dv) x 4 bytes, and the
+# output blocks they are written to, which Pallas buffers twice. A Q
+# tile's blocks for all rep query heads of the KV head: Q, dO and dQ twice
+# buffered and dQ's float32 sum over the major blocks. Taken where that
+# fits FUSED_RESIDENT_BYTES: 21 MB at latent attention's (8192, 192 + 128)
+# bf16, 16.8 + 9.4 MB at (8192, 128 + 128) with nine heads a KV head, 1.6 MB
+# at GPT-2's (1024, 64 + 64); 16384 keys at D 128 no longer fit, nor 8192
+# under more than 16 heads a KV head. The scoped limit a call asks for
+# is that and _STEP_VMEM for what a step streams and computes (K and V
+# twice buffered 2-2.5 MiB, a few (2048, 512) float32 score segments of 4
+# MiB each): the v5e has 128 MiB.
+FUSED_RESIDENT_BYTES = 32 << 20
+_STEP_VMEM = 32 << 20
+
+
+class BackwardPlan(NamedTuple):
+    fused: bool    # one kernel (dQ with dK/dV), else the dQ and dK/dV pair
+    resident: int  # bytes the fused kernel keeps in VMEM under a KV head
+
+    def __str__(self):
+        return (f"bwd={'fused' if self.fused else 'split'} "
+                f"resident={self.resident / 1e6:.1f}MB")
+
+
+def backward_plan(Sk: int, D: int, Dv: int, itemsize: int, *, rep: int = 1,
+                  block_q: int = DEFAULT_BLOCK_Q) -> BackwardPlan:
+    """Which backward a call gets, from what it can see: the fused kernel
+    where what it keeps under a KV head (dK and dV of the head's keys, a Q
+    tile of its ``rep`` query heads) fits FUSED_RESIDENT_BYTES of VMEM."""
+    resident = (Sk * (D + Dv) * (4 + 2 * itemsize)
+                + rep * block_q * (2 * (2 * D + Dv) * itemsize + 4 * D))
+    return BackwardPlan(resident <= FUSED_RESIDENT_BYTES, resident)
+
+
+def call_backward_plan(q, k, v) -> BackwardPlan:
+    """:func:`backward_plan` of a (B, S, H, D) call — what the dispatch's
+    resolution line prints."""
+    block_q = tile_sizes(q.shape[1], k.shape[1], q.shape[3],
+                         q.dtype.itemsize).block_q
+    return backward_plan(k.shape[1], k.shape[3], v.shape[3], k.dtype.itemsize,
+                         rep=q.shape[2] // k.shape[2], block_q=block_q)
+
+
 def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
          scale, tiles, window, interpret, dlse=None):
-    BH, Sq, D = q3.shape
-    Sk, Dv = k3.shape[1], v3.shape[2]
-    rep = BH // k3.shape[0]  # GQA group size (see _fwd); 1 = MHA
-    block_q, block_k, major_q, major_k = tiles
-    has_pos = q_pos is not None
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (BH, 1, Sq), a row a head as lse
     if dlse is not None:
@@ -680,7 +821,78 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
         # lse = logsumexp(s), d lse/d s = p, so ds gains +p·dlse — which
         # folds into the flash2 formula as delta' = delta − dlse.
         delta = delta - dlse
-    static = dict(causal=causal, scale=scale, window=window, has_pos=has_pos)
+    plan = backward_plan(k3.shape[1], k3.shape[2], v3.shape[2],
+                         k3.dtype.itemsize, rep=q3.shape[0] // k3.shape[0],
+                         block_q=tiles.block_q)
+    form = functools.partial(_bwd_fused, resident=plan.resident) \
+        if plan.fused else _bwd_split
+    return form([q3, k3, v3, do3, lse, delta], q_pos, kv_pos, tiles=tiles,
+                interpret=interpret,
+                static=dict(causal=causal, scale=scale, window=window,
+                            has_pos=q_pos is not None))
+
+
+def _bwd_fused(args, q_pos, kv_pos, *, tiles, interpret, static, resident):
+    q3, k3, v3 = args[:3]
+    BH, Sq, D = q3.shape
+    Sk, Dv = k3.shape[1], v3.shape[2]
+    rep = BH // k3.shape[0]  # GQA group size (see _fwd); 1 = MHA
+    block_q, block_k, _, major = tiles
+    grid = (Sq // block_q, Sk // major)
+    has_pos = static["has_pos"]
+    direct = grid[1] == 1 and not has_pos  # dQ: as the forward
+
+    def group(width):
+        # the tile of all rep query heads of KV row b: rows b·rep ... of
+        # the flattened heads, the inverse of the forward's b // rep map
+        return pl.BlockSpec((rep, block_q, width),
+                            lambda b, i, j, r: (b, i, 0))
+
+    row = pl.BlockSpec((rep, 1, block_q), lambda b, i, j, r: (b, 0, i))
+    in_specs = [
+        group(D),
+        pl.BlockSpec((1, major, D), lambda b, i, j, r: (b, j, 0)),
+        pl.BlockSpec((1, major, Dv), lambda b, i, j, r: (b, j, 0)),
+        group(Dv), row, row,
+    ]
+    if has_pos:
+        in_specs += [pl.BlockSpec((1, block_q), lambda b, i, j, r: (0, i)),
+                     pl.BlockSpec((major, 1), lambda b, i, j, r: (j, 0))]
+        args = args + [q_pos, kv_pos]
+    return pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, block_k=block_k, grid=grid,
+                          rep=rep, direct=direct, **static),
+        grid=(BH // rep, *grid, rep),
+        in_specs=in_specs,
+        out_specs=[
+            group(D),
+            pl.BlockSpec((1, Sk, D), lambda b, i, j, r: (b, 0, 0)),
+            pl.BlockSpec((1, Sk, Dv), lambda b, i, j, r: (b, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+            jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+            jax.ShapeDtypeStruct(v3.shape, v3.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((grid[1], major, D), jnp.float32),
+                        pltpu.VMEM((grid[1], major, Dv), jnp.float32)]
+        + ([] if direct else [pltpu.VMEM((rep, D, block_q), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=resident + _STEP_VMEM,
+        ),
+        interpret=interpret,
+    )(*args)
+
+
+def _bwd_split(args, q_pos, kv_pos, *, tiles, interpret, static):
+    q3, k3, v3 = args[:3]
+    BH, Sq, D = q3.shape
+    Sk, Dv = k3.shape[1], v3.shape[2]
+    rep = BH // k3.shape[0]
+    block_q, block_k, major_q, major_k = tiles
+    has_pos = static["has_pos"]
 
     dq_grid = (Sq // block_q, Sk // major_k)
     dq_direct = dq_grid[1] == 1 and not has_pos  # as the forward
@@ -692,7 +904,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
     ]
-    dq_args = [q3, k3, v3, do3, lse, delta]
+    dq_args = list(args)
     if has_pos:
         dq_in_specs += [pl.BlockSpec((1, block_q), lambda b, i, j: (0, i)),
                         pl.BlockSpec((major_k, 1), lambda b, i, j: (j, 0))]
@@ -724,7 +936,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, q_pos=None, kv_pos=None, *, causal,
         pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
         pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
     ]
-    dkv_args = [q3, k3, v3, do3, lse, delta]
+    dkv_args = list(args)
     if has_pos:
         dkv_in_specs += [
             pl.BlockSpec((1, major_q), lambda b, j, r, i: (0, i)),

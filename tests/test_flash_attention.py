@@ -35,6 +35,22 @@ def _xla(q, k, v, causal):
                           softmax_dtype=jnp.float32)
 
 
+def _record_backward_forms(monkeypatch):
+    """Which backward ``_bwd`` hands a call to, as it is traced."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    taken = []
+    for form in ("fused", "split"):
+        inner = getattr(fa, f"_bwd_{form}")
+
+        def recorder(*a, _inner=inner, _form=form, **kw):
+            taken.append(_form)
+            return _inner(*a, **kw)
+
+        monkeypatch.setattr(fa, f"_bwd_{form}", recorder)
+    return taken
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_xla(causal):
     q, k, v = _make_qkv()
@@ -162,16 +178,25 @@ def test_tiled_kernels_match_xla(name, S, H, Hkv, block_q, major, block_k,
                                    rtol=5e-3, err_msg=f"d{n} mismatch")
 
 
+@pytest.mark.parametrize("backward", ["fused", "split"])
 @pytest.mark.parametrize("window", [0, 100])
-def test_chunk_entry_rotated_positions_match_xla(window):
+def test_chunk_entry_rotated_positions_match_xla(monkeypatch, window,
+                                                 backward):
     """The ring's entry with positions as a zigzag ring hands them over:
     two half-chunks out of order on both sides, so score tiles are entered
     or skipped by their positions' min/max, and some rows see no key at
-    all: those return zeros and lse = NEG_INF."""
+    all: those return zeros and lse = NEG_INF. Its backward is the fused
+    kernel too (every chunk of a major block under its own predicate, dQ
+    in its scratch), and past the VMEM budget the two kernels."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
     from pytorch_distributed_train_tpu.ops.flash_attention import (
         NEG_INF,
         flash_attention_chunk,
     )
+
+    if backward == "split":
+        monkeypatch.setattr(fa, "FUSED_RESIDENT_BYTES", 0)
+    taken = _record_backward_forms(monkeypatch)
 
     S, half = 512, 256
     q, _, _ = _make_qkv(B=1, S=S, H=4, D=64, seed=41)
@@ -207,6 +232,7 @@ def test_chunk_entry_rotated_positions_match_xla(window):
     assert float(lse[:, :, valid].min()) > NEG_INF / 2
     gf = jax.grad(lambda *a: jnp.sum((flash(*a)[0] * rows) ** 2),
                   argnums=(0, 1, 2))(q, k, v)
+    assert taken == [backward]
     gr = jax.grad(lambda *a: jnp.sum((ref(*a) * rows) ** 2),
                   argnums=(0, 1, 2))(q, k, v)
     for a, b, n in zip(gf, gr, "qkv"):
@@ -270,6 +296,147 @@ def test_tile_plan_counts_and_tile_ranges():
                                   q0 + i * bq + bq - 1 - k0, causal, window)
                             for i in range(n)]
                     assert got == want, (bq, bk, causal, window, k0, q0)
+
+
+# ------------------------------------------------- the backward's two forms
+
+# (id, S, H, Hkv, D, Dv, causal, window, tiles): the fused kernel's cases.
+# tiles None is the rule's: at S 1024 ONE major block (the `direct` form:
+# dQ written by its step, no running state), at S 4096 two of 2048 (dQ in
+# its scratch across them, the accumulators' first index traced).
+BACKWARD_CASES = [
+    ("mha_direct_s1024", 1024, 2, 2, 64, 64, True, 0, None),
+    ("mha_noncausal", 512, 2, 2, 64, 64, False, 0, (128, 128, 256)),
+    ("gqa_rep2_d128", 512, 4, 2, 128, 128, True, 0, (128, 128, 256)),
+    ("gqa_rep3", 512, 6, 2, 64, 64, True, 0, (256, 128, 256)),
+    ("gqa_rep8_over_1_noncausal", 256, 8, 1, 64, 64, False, 0,
+     (128, 128, 128)),
+    ("window_200_gqa_rep2", 512, 4, 2, 64, 64, True, 200, (128, 128, 256)),
+    ("mla_192_128", 512, 2, 2, 192, 128, True, 0, (128, 128, 256)),
+    ("s4096_two_majors_of_the_rule", 4096, 1, 1, 64, 64, True, 0, None),
+]
+
+
+@pytest.mark.parametrize("form", ["fused", "split"])
+@pytest.mark.parametrize("name,S,H,Hkv,D,Dv,causal,window,tiles",
+                         BACKWARD_CASES, ids=[c[0] for c in BACKWARD_CASES])
+def test_backward_forms_match_xla(monkeypatch, name, S, H, Hkv, D, Dv,
+                                  causal, window, tiles, form):
+    """All three gradients against the XLA reference from the ONE fused
+    backward kernel, and from the two kernels a call past the VMEM budget
+    keeps (the budget set to nothing here: the rule's own function decides,
+    so the same call takes the other path)."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    if form == "split":
+        monkeypatch.setattr(fa, "FUSED_RESIDENT_BYTES", 0)
+    taken = _record_backward_forms(monkeypatch)
+    rng = np.random.default_rng(53)
+    mk = lambda h, d: jnp.asarray(  # noqa: E731
+        rng.standard_normal((1, S, h, d)) * 0.5, jnp.float32)
+    q, k, v, w = mk(H, D), mk(Hkv, D), mk(Hkv, Dv), mk(H, Dv)
+    rep = H // Hkv
+    bq, bk, major = tiles or (None, None, None)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=bq, block_k=bk, block_k_major=major,
+                               interpret=True)
+
+    def ref(q, k, v):
+        return _xla_attention(q, jnp.repeat(k, rep, axis=2),
+                              jnp.repeat(v, rep, axis=2), causal=causal,
+                              mask=None, softmax_dtype=jnp.float32,
+                              window=window)
+
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    assert taken == [form]
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, n in zip(gf, gr, "qkv"):
+        assert a.shape == b.shape, n
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg=f"d{n} mismatch")
+
+
+def test_backward_past_the_vmem_budget_keeps_two_kernels_and_the_gradients(
+        monkeypatch):
+    """The shape rule, not a knob: `backward_plan` is fused while a KV
+    head's dK and dV (float32 accumulators and the twice-buffered output
+    blocks) fit FUSED_RESIDENT_BYTES, every benchmark cell's shape does,
+    and the first shape past it takes the two kernels; at one call's shape
+    both forms return the same gradients, bfloat16 operands as the cells'."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    for Sk, D, Dv, rep in ((1024, 64, 64, 1), (4096, 128, 128, 1),
+                           (8192, 128, 128, 6), (8192, 128, 128, 9),
+                           (8192, 128, 128, 8), (8192, 192, 128, 1)):
+        plan = fa.backward_plan(Sk, D, Dv, 2, rep=rep)
+        held = Sk * (D + Dv) * 8  # dK, dV: float32 sums, two output blocks
+        tile = rep * 512 * (4 * (2 * D + Dv) + 4 * D)  # Q, dO, dQ; dQ's sum
+        assert plan.fused and plan.resident == held + tile, plan
+    for past in (fa.backward_plan(16384, 128, 128, 2),
+                 fa.backward_plan(8192, 128, 128, 2, rep=32)):
+        assert not past.fused and str(past).startswith("bwd=split resident=")
+    assert str(fa.backward_plan(1024, 64, 64, 2)) == \
+        "bwd=fused resident=1.6MB"
+
+    q, _, _ = _make_qkv(B=1, S=512, H=4, D=64, seed=61, dtype=jnp.bfloat16)
+    _, k, v = _make_qkv(B=1, S=512, H=2, D=64, seed=67, dtype=jnp.bfloat16)
+    grads = {}
+    for form, budget in (("fused", fa.FUSED_RESIDENT_BYTES),
+                         ("split", fa.backward_plan(
+                             512, 64, 64, 2, rep=2, block_q=128).resident
+                          - 1)):
+        monkeypatch.setattr(fa, "FUSED_RESIDENT_BYTES", budget)
+        taken = _record_backward_forms(monkeypatch)
+        grads[form] = jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, block_q=128, block_k=128, block_k_major=256,
+            interpret=True).astype(jnp.float32) ** 2), argnums=(0, 1, 2))(
+                q, k, v)
+        assert taken[-1:] == [form], taken
+    for a, b, n in zip(grads["fused"], grads["split"], "qkv"):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), atol=2e-2,
+            rtol=2e-2, err_msg=f"d{n}: fused against split")
+
+
+# (id, S, window, D): the cells' attention shapes under the tile rule
+FUSED_PLAN_CASES = [("gpt2_small", 1024, 0, 64), ("looped", 4096, 0, 128),
+                    ("full_8k", 8192, 0, 128), ("window_512", 8192, 512, 128),
+                    ("mla_8k", 8192, 0, 192)]
+
+
+@pytest.mark.parametrize("name,S,window,D", FUSED_PLAN_CASES,
+                         ids=[c[0] for c in FUSED_PLAN_CASES])
+def test_fused_backward_enters_the_tiles_of_tile_plan(monkeypatch, name, S,
+                                                      window, D):
+    """What the fused kernel's own trace hands the dispatch (one offset a
+    (Q tile, major block) pair of its grid, the segments of each): the
+    score tiles it enters and those it masks are `tile_plan`'s for the
+    call, and the forward's."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    calls = []
+    monkeypatch.setattr(
+        fa, "_static_dispatch",
+        lambda off, offsets, segments_of, update: calls.append(
+            [segments_of(o) for o in offsets]))
+    x = jax.ShapeDtypeStruct((1, S, 2, D), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda *a: flash_attention(
+        *a, causal=True, window=window, interpret=True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
+    assert len(calls) == 2  # the forward's and the ONE backward kernel's
+    block_q, block_k, _, _ = fa.tile_sizes(S, S, D, 2)
+    plan = fa.tile_plan(S, block_q, block_k, causal=True, window=window)
+    assert plan == fa.call_plan(x, x, causal=True, window=window)
+    for segs in calls:
+        entered = sum((c1 - c0) // block_k for s in segs for c0, c1, _ in s)
+        masked = sum((c1 - c0) // block_k for s in segs for c0, c1, m in s
+                     if m is not None)
+        assert (entered, masked) == (plan.executed, plan.masked)
 
 
 def test_chunk_entry_contract():
@@ -357,7 +524,8 @@ def test_dispatch_windowed_pallas_impl():
 def test_resolution_line_prints_the_tile_plan(capsys):
     """The dispatch's once-per-shape line says how many of a head's score
     tiles the kernel enters and masks, and they are tile_plan's for the
-    call's shape under the kernel's own tile rule."""
+    call's shape under the kernel's own tile rule; then the backward's
+    form, by the function the kernel's backward asks."""
     from pytorch_distributed_train_tpu.ops import attention as attn
     from pytorch_distributed_train_tpu.ops import flash_attention as fa
 
@@ -369,8 +537,65 @@ def test_resolution_line_prints_the_tile_plan(capsys):
     plan = fa.call_plan(q, q, causal=True)
     assert line.startswith("[attention] impl=pallas q=(1, 1024, 2, 64)")
     assert line.endswith(
-        f"tiles={plan.executed}/{plan.total} masked={plan.masked}")
+        f"tiles={plan.executed}/{plan.total} masked={plan.masked} "
+        f"{fa.backward_plan(1024, 64, 64, 2)}")
+    assert line.endswith(" bwd=fused resident=1.6MB")
     assert plan == (4, 3, 2)
+
+
+CELLS = ["gpt2s-1chip-b16", "ling3f-1chip-ep64-s8k", "lagunas-1chip-ep32-w512",
+         "ouro26b-1chip-ut4-s4k", "solar2-1chip-ep40-tp8"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_benchmark_cells_attention_resolves_to_the_fused_backward(
+        monkeypatch, capsys, cell):
+    """A benchmark cell's model traced at the cell's own batch and length
+    where the dispatch sees a TPU (`gpt2s-dp4-b64` is the first cell's model
+    at the same 16 sequences a chip): every attention shape it holds
+    prints ONE `[attention] impl=pallas` line, however many layers call it,
+    and each ends `bwd=fused resident=...MB`: the mechanism engages in
+    every cell, by the function the kernel's backward itself asks."""
+    import json
+    import os
+
+    from pytorch_distributed_train_tpu import steps as steps_lib
+    from pytorch_distributed_train_tpu.config import get_preset
+    from pytorch_distributed_train_tpu.models.registry import build_model
+    from pytorch_distributed_train_tpu.ops import attention as attn
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "workloads", f"{cell}.json"),
+              encoding="utf-8") as f:
+        workload = json.load(f)
+    with open(os.path.join(bench, "configs", f"{workload['config']}.json"),
+              encoding="utf-8") as f:
+        config = json.load(f)
+    cfg = get_preset(config["preset"])
+    cfg.apply_overrides(list(config["overrides"])
+                        + list(workload["overrides"]))
+    monkeypatch.setattr(attn, "_on_tpu", lambda: True)
+    attn._resolutions_logged.clear()
+    model = build_model(cfg.model, cfg.precision)
+    ids = jnp.zeros((cfg.data.batch_size, cfg.data.seq_len), jnp.int32)
+    capsys.readouterr()
+    jax.eval_shape(lambda rng: model.init({"params": rng}, ids, train=False),
+                   jax.random.PRNGKey(0))
+    lines = [ln for ln in capsys.readouterr().err.splitlines()
+             if ln.startswith("[attention] ")]
+    assert lines and all("impl=pallas" in ln for ln in lines), lines
+    assert len(set(lines)) == len(lines), lines  # once a shape
+    kinds = len(set(cfg.model.layer_kinds) & {"gqa_full", "gqa_window",
+                                              "mla"}) \
+        if getattr(cfg.model, "layer_kinds", None) else 1
+    assert len(lines) == kinds, lines
+    for ln in lines:
+        form, resident = ln.split()[-2:]
+        assert form == "bwd=fused", ln
+        assert resident.startswith("resident=") and resident.endswith("MB")
+        assert float(resident[9:-2]) * 1e6 <= fa.FUSED_RESIDENT_BYTES
 
 
 def test_dispatch_pallas_impl_covers_gqa_expansion():

@@ -6,7 +6,7 @@ trade (checked via compiled peak-memory ordering on CPU).
 
 What the flash kernel's forward hands back is kept under every policy
 (models/remat.py): the backward of a remat'd block that holds the kernel
-launches it three times (forward, dQ, dK/dV), not four.
+launches it twice (forward, the fused backward), not three times.
 """
 
 import numpy as np
@@ -100,11 +100,12 @@ def _launches(policy):
 
 @pytest.mark.parametrize("policy", sorted(POLICIES))
 def test_the_backward_of_a_remat_block_launches_no_second_forward(policy):
-    """Forward, dQ, dK/dV: THREE launches under every policy, and four
-    under the policy each was before it named the kernel's tag (the test
-    that fails if a later edit drops the tag or the name from a policy)."""
+    """Forward and the one fused backward: TWO launches under every policy,
+    and three under the policy each was before it named the kernel's tag
+    (the test that fails if a later edit drops the tag or the name from a
+    policy)."""
     remat.kept.clear()
-    assert _launches(POLICIES[policy]) == 3
+    assert _launches(POLICIES[policy]) == 2
     assert remat.kept == {fa.FLASH_RESIDUALS_NAME}
     without = {
         "full": None,
@@ -114,7 +115,7 @@ def test_the_backward_of_a_remat_block_launches_no_second_forward(policy):
         # keeps everything but its own names: nothing to take out
         "no_fused_epilogue": None,
     }[policy]
-    assert _launches(without) == 4
+    assert _launches(without) == 3
 
 
 @pytest.mark.parametrize("remat_on,keeps", [(True, "flash_out+lse"),

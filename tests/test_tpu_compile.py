@@ -3,14 +3,16 @@ widths — asked of the chip's own compiler, for a DESCRIBED v5e:2x2 with
 no chip attached (on-chip-measurement guide, section 2, rehearsal 3).
 
 What this guards: Mosaic refusals interpret mode cannot see (VMEM
-budgets, lane padding of D=64, block-shape alignment, the 4-axis
+budgets, the fused backward's raised limit with a KV head's dK and dV
+resident, lane padding of D=64, block-shape alignment, the 4-axis
 backward grid, (S, 1) i32 position refs, the per-offset specialisations
 and their static slices of the resident block) under the kernel's own
 tile rule at GPT-2 small's attention shape — the shape the benchmark's
-cells train — and at the S 2048 / D 128 causal, GQA and window shapes,
-plus the ring chunk kernel on a 4-device mesh and the W8/W4 GEMV
-kernels; that the one-chip GPT-2 step keeps its 36 kernel calls under
-the names the benchmark finds them by; and that the four-chip
+cells train — and at the S 2048 / D 128 causal, GQA and window shapes and
+every other cell's own, plus the ring chunk kernel on a 4-device mesh
+and the W8/W4 GEMV kernels; that the one-chip GPT-2 step keeps its 24
+kernel calls (a layer's forward and its one fused backward) under the
+names the benchmark finds them by; and that the four-chip
 data-parallel GPT-2 step all-reduces its tied table once. A compile that passes here
 is a compile, not a chip run.
 
@@ -35,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_train_tpu.ops import flash_attention as fa
 
-LOOP_FLASH_KERNELS = 3  # the looped step's, at depth 1: see its test
+LOOP_FLASH_KERNELS = 2  # the looped step's, at depth 1: see its test
 
 
 @pytest.fixture(scope="module")
@@ -119,6 +121,10 @@ FLASH_SHAPES = [
     # 72 query heads over 8 KV heads inside a window of 512, 48 over 8 causal
     ("s8192_d128_gqa72_window512", 1, 8192, 72, 8, 128, 512),
     ("s8192_d128_gqa48", 1, 8192, 48, 8, 128, 0),
+    # the looped cell's (preset ouro_2_6b_lm_l8) and the head-share cell's
+    # one NoPE layer at an eighth of its heads (solar_open2_lm_ep40_tp8)
+    ("s4096_d128_mha16", 1, 4096, 16, 16, 128, 0),
+    ("s8192_d128_gqa8_over_1", 1, 8192, 8, 1, 128, 0),
 ]
 
 
@@ -343,10 +349,11 @@ def _lowered_step(config, one_chip, monkeypatch, overrides=()):
     return lowered, bench, cfg
 
 
-def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
+def test_gpt2_step_keeps_its_24_flash_kernels_by_name(one_chip, monkeypatch):
     """The one-chip GPT-2 small step, lowered and compiled for the
     described chip at the benchmark cell's batch: 12 layers x (forward,
-    dQ, dK/dV) = 36 instructions whose trace names (`%attn.N custom-call`)
+    the fused backward: dQ with dK/dV) = 24 instructions (36 while the
+    backward was two kernels) whose trace names (`%attn.N custom-call`)
     match the configuration's `flash_kernel_pattern`. The benchmark's
     `flash_attn_ms_per_step` finds the kernel by that name alone, so a
     `name=` on a `pallas_call` or a renamed module would zero the metric.
@@ -354,9 +361,9 @@ def test_gpt2_step_keeps_its_36_flash_kernels_by_name(one_chip, monkeypatch):
     head's kernels: `_assert_head_rides_its_products`."""
     lowered, bench, cfg = _lowered_step(
         "gpt2_small", one_chip, monkeypatch, ["data.batch_size=16"])
-    assert 3 * bench["n_layer"] == 36
+    assert 2 * bench["n_layer"] == 24
     _assert_head_rides_its_products(
-        lowered.compile().as_text(), bench["flash_kernel_pattern"], 36,
+        lowered.compile().as_text(), bench["flash_kernel_pattern"], 24,
         cfg.data.batch_size * cfg.data.seq_len, cfg.model.vocab_size)
 
 
@@ -368,8 +375,11 @@ def test_hybrid_step_holds_the_kda_kernels_by_name(one_chip, monkeypatch):
     custom-call`, which the configuration's `flash_kernel_pattern` must
     NOT match: counted into `flash_attn_ms_per_step` they would move
     `mla_attn_roofline`), and no `while` loop left under the core's
-    scope. The MLA layer's flash kernel runs its forward ONCE beside dQ
-    and dK/dV: remat keeps what it handed back (models/remat.py)."""
+    scope. The MLA layer's flash kernel runs its forward ONCE beside its
+    one fused backward (dK and dV of a head's 8192 keys at 192 + 128 in
+    VMEM, under the kernel's own raised limit): remat keeps what the
+    forward handed back (models/remat.py), and neither kernel of the
+    two-kernel backward is lowered."""
     lowered, bench, _ = _lowered_step(
         "ling3_flash_lm_ep64", one_chip, monkeypatch,
         ["data.batch_size=2", "data.seq_len=8192"])
@@ -381,8 +391,9 @@ def test_hybrid_step_holds_the_kda_kernels_by_name(one_chip, monkeypatch):
     assert (kernels.count("kda_inputs_fwd"),
             kernels.count("kda_inputs_bwd")) == (10, 5), kernels
     flash = [kernels.count(k) for k in
-             ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")]
-    assert flash == [1, 1, 1], kernels
+             ("_fwd_kernel", "_bwd_fused_kernel", "_bwd_dq_kernel",
+              "_bwd_dkv_kernel")]
+    assert flash == [1, 1, 0, 0], kernels
     for name in ("kda_fwd", "kda_bwd", "kda_inputs_fwd", "kda_inputs_bwd"):
         assert not re.search(bench["flash_kernel_pattern"],
                              f"%{name}.1 custom-call")
@@ -400,7 +411,7 @@ def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
     in float32, where the partitioner alone leaves two (the head's
     contribution and the lookup's). One chip of the same topology plans
     per_use, and jit_train_step passes jax.jit no compile options either
-    way (the 36-kernel test above compiles that step). The head's kernels
+    way (the 24-kernel test above compiles that step). The head's kernels
     run inside a shard_map of their own, a chip on its view of the table:
     the view, and the one all-reduce, survive them."""
     import json
@@ -461,10 +472,11 @@ def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32,
                                sharding=NamedSharding(mesh, P()))
     text = step.lower(state, batch, rng).compile().as_text()
-    # the flash kernels under shard_map (three a layer), the head's two
-    # beside them under their own names, a chip's 16 sequences each
+    # the flash kernels under shard_map (two a layer: forward and the fused
+    # backward), the head's two beside them under their own names, a chip's
+    # 16 sequences each
     _assert_head_rides_its_products(
-        text, bench["flash_kernel_pattern"], 3 * cfg.model.num_layers,
+        text, bench["flash_kernel_pattern"], 2 * cfg.model.num_layers,
         cfg.data.batch_size // 4 * cfg.data.seq_len, cfg.model.vocab_size)
     table = f"[{cfg.model.vocab_size},{cfg.model.hidden_size}]"
     reduced = [m.group(1) for m in re.finditer(
@@ -498,9 +510,9 @@ def test_looped_step_holds_its_flash_and_head_kernels_by_name(one_chip,
     names = _custom_calls(text)
     flash = [n for n in names if re.search(bench["flash_kernel_pattern"], n)]
     head = [n for n in names if re.search(bench["head_kernel_pattern"], n)]
-    # forward, dQ, dK/dV (remat keeps what the forward handed back and does
-    # not run it again: models/remat.py): once a layer in the scanned
-    # pass's body, whatever the number of passes
+    # forward and the fused backward (remat keeps what the forward handed
+    # back and does not run it again: models/remat.py): once a layer in the
+    # scanned pass's body, whatever the number of passes
     assert len(flash) == LOOP_FLASH_KERNELS, flash
     assert not set(flash) & set(head)
     passes = cfg.model.loop_steps
