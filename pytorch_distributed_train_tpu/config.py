@@ -198,6 +198,19 @@ class ModelConfig:
     # all), with the KV heads the grouping gives them: one chip's share of
     # a tensor-parallel group's mixers, as experts_held is of the experts;
     # the held heads' projections, gates and rows of o_proj, a partial sum.
+    # The latent kind has two families' forms: mla_qk_norm (RMSNorm over a
+    # head's whole q and k), mla_out_gate "head" | "none", mla_rope
+    # "halves" (dims i and i + rope/2 rotate together) | "pairs" (2i and
+    # 2i+1); the defaults are the first preset's, False / "none" / "pairs"
+    # DeepSeek-V3's plain form. moe_shared_mlp_dim is the shared expert's
+    # own width (a family's n shared experts of width w are ONE SwiGLU of
+    # n x w; 0: the routed experts' moe_mlp_dim). moe_bias_rate > 0 moves
+    # a sigmoid router's selection bias after every optimizer step by the
+    # auxiliary-loss-free balancing rule, b_e += rate x sign(mean(c) - c_e)
+    # over the step's tokens c_e on each router output (ops/moe.py
+    # balance_routers; steps.py): outside the gradient, the clip, AdamW's
+    # moments and the decay, kept on a skipped step; 0 leaves the bias as
+    # drawn. A hyper-parameter of the model's recipe, like the learning rate.
     head_dim: int = 0
     layer_kinds: tuple[str, ...] = ()
     layer_heads: tuple[int, ...] = ()
@@ -223,6 +236,11 @@ class ModelConfig:
     kda_gate_rank: int = 0
     kda_out_gate: str = "head"
     gqa_out_gate: str = "head"
+    mla_qk_norm: bool = True
+    mla_out_gate: str = "head"
+    mla_rope: str = "halves"
+    moe_shared_mlp_dim: int = 0
+    moe_bias_rate: float = 0.0
     # Fused elementwise block epilogues (ops/fused_update.py; vit/bert):
     # the bias+GELU MLP epilogue and (post-LN bert) the residual-add+
     # LayerNorm epilogue compute as single tagged expressions XLA keeps
@@ -1301,6 +1319,55 @@ def _solar_open2_lm_ep40_tp8() -> TrainConfig:
     return c
 
 
+def _kanana2_lm_ep8() -> TrainConfig:
+    """One chip's share of Kanana-2-30B-A3B's language model (kakaocorp,
+    https://huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601
+    config.json, ``model_type: deepseek_v3``): every width as published
+    (hidden 2048, 32 heads, latent attention over a 512-wide latent with
+    128 plain + 64 rotated score dims and 128-deep values, a dense SwiGLU
+    of 6144, experts of 768, two shared experts = one SwiGLU of 1536, a
+    128-wide ungrouped sigmoid router, 6 a token, scale 2.448); layers 0-5
+    of the 48: the leading dense layer and five expert layers, EVERY layer
+    latent attention in DeepSeek-V3's plain form (no head norms, no gate,
+    the rotated dims in pairs (2i, 2i+1), theta 1e6). Each layer is shared
+    by 8 chips by expert parallelism: 16 of its 128 routed experts here,
+    an eighth of the 128256-row vocabulary, every head whole. The
+    selection bias moves by the family's balancing rule at 0.001 a step
+    (``noaux_tc``; arXiv:2412.19437 4.2). 687.5 M parameters, 11.0 GB with
+    AdamW's float32 state (benchmark/configs/kanana2_lm_ep8.json says what
+    was assumed)."""
+    c = TrainConfig(preset="kanana2_lm_ep8")
+    c.model = ModelConfig(
+        name="hybrid_lm", hidden_size=2048, num_layers=6, num_heads=32,
+        head_dim=128, mlp_dim=6144, vocab_size=16032, max_seq_len=8192,
+        rope_theta=1e6, rms_norm_eps=1e-6, remat=True,
+        layer_kinds=("mla",) * 6, kv_lora_rank=512, rope_head_dim=64,
+        mla_qk_norm=False, mla_out_gate="none", mla_rope="pairs",
+        first_dense_layers=1, num_experts=128, expert_top_k=6,
+        moe_groups=1, moe_topk_groups=1, moe_routed_scale=2.448,
+        moe_mlp_dim=768, moe_shared_mlp_dim=1536, moe_bias_rate=1e-3,
+        experts_held=16, experts_held_first=0, expert_capacity_factor=4.0,
+    )
+    # 4096 synthetic sequences of 8192 tokens, as the other 8k presets
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=2, seq_len=8192,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        # decay the matrices and the embedding only: not the norms, nor
+        # the router's bias (which the optimizer does not move at all)
+        decay_exclude=r"scale$,bias$",
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    # a step of 16384 tokens takes most of a second on one v5e: a log
+    # every twenty steps, as the first hybrid preset's
+    c.obs.log_every_steps = 20
+    c.total_steps = 500000
+    c.loss = "causal_lm_xent"
+    return c
+
+
 def _ouro_2_6b_lm_l8() -> TrainConfig:
     """One pipeline stage of Ouro-2.6B (ByteDance,
     https://huggingface.co/ByteDance/Ouro-2.6B config.json; arXiv:2510.25741):
@@ -1373,6 +1440,7 @@ _PRESETS = {
     "laguna_s_lm_ep32": _laguna_s_lm_ep32,
     "ouro_2_6b_lm_l8": _ouro_2_6b_lm_l8,
     "solar_open2_lm_ep40_tp8": _solar_open2_lm_ep40_tp8,
+    "kanana2_lm_ep8": _kanana2_lm_ep8,
 }
 
 

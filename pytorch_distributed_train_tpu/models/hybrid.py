@@ -22,9 +22,14 @@ published grouping gives them, as one chip of a tensor-parallel group holds
 them. The held heads' projections, convolution taps, decay and gates, and
 the held ROWS of ``o_proj``: what the absent heads would add to a mixer's
 output is left out, that partial sum goes on (as the expert layer's does),
-and no code stands in for the absent chips or their all-reduce. The
-equations are written out beside each module and in
-``benchmark/references/<preset>.py``, which share no code with this file.
+and no code stands in for the absent chips or their all-reduce. A fourth
+(preset ``kanana2_lm_ep8``) has EVERY layer ``mla`` in DeepSeek-V3's plain
+form (``MLAMixer``'s second: no head norms, no gate, the rotated dims in
+pairs), shared experts at a width of their own, and the selection bias's
+balancing update after each optimizer step (``ops/moe.py``
+``balance_routers``). The equations are written out beside each module and
+in ``benchmark/references/<preset>.py``, which share no code with this
+file.
 
 Only the training path exists: no cache, no decode (a latent entry, a
 recurrent state and a window's ring in one cache manager are ROADMAP
@@ -56,6 +61,8 @@ from pytorch_distributed_train_tpu.ops.attention import (
 from pytorch_distributed_train_tpu.ops.moe import (
     HeldExpertsMLP,
     HeldExpertsSpec,
+    balance_routers,
+    router_load_metrics,
 )
 
 _INIT = nn.initializers.normal(0.02)
@@ -255,10 +262,21 @@ class KDAMixer(nn.Module):
 class MLAMixer(nn.Module):
     """Latent attention, expanded (no query compression): q = W_q x, a head
     [nope | rope]; c = RMSNorm(W_dkv x); k_rope = W_kr x, one for all heads;
-    [k_nope | v] a head = W_ukv c; RMSNorm over each head's whole q and
-    whole k = [k_nope | k_rope] before the rotation; RoPE on the rope
-    parts; causal softmax attention with scores over sqrt(nope + rope); a
-    head-wise sigmoid gate; W_o."""
+    [k_nope | v] a head = W_ukv c; RoPE on the rope parts; causal softmax
+    attention with scores over sqrt(nope + rope); W_o. Two families' forms,
+    by plain fields:
+
+    * ``qk_norm`` True, ``out_gate`` ``head``, ``rope`` ``halves`` (the
+      first hybrid preset's): RMSNorm over each head's whole q and whole
+      k = [k_nope | k_rope] before the rotation, the rotated dims paired
+      (i, i + rope/2), the heads' outputs times a head-wise sigmoid gate.
+    * ``qk_norm`` False, ``out_gate`` ``none``, ``rope`` ``pairs``
+      (DeepSeek-V3's plain form): no norm over a head, no gate, the rotated
+      dims paired (2i, 2i+1), and k_rope rotated ONCE for all heads. The
+      pairs are brought side by side first (dims 0, 2, 4, ... then 1, 3,
+      5, ...) and rotated as halves, as the family's public code does it: q
+      and k are permuted alike, so the scores are those of the pairs
+      rotated in place."""
 
     num_heads: int
     head_dim: int        # nope part of q and k, and v
@@ -271,11 +289,19 @@ class MLAMixer(nn.Module):
     param_dtype: jnp.dtype
     cp: ContextParallelConfig | None = None
     attn_impl: str = "auto"
+    qk_norm: bool = True
+    out_gate: str = "head"   # | none
+    rope: str = "halves"     # | pairs
 
     @nn.compact
     def __call__(self, x):
         H, dn, dr = self.num_heads, self.head_dim, self.rope_head_dim
         B, S, _ = x.shape
+        if self.rope not in ("halves", "pairs") \
+                or self.out_gate not in ("head", "none"):
+            raise ValueError(
+                f"latent attention: rope {self.rope!r} (have halves | "
+                f"pairs), out_gate {self.out_gate!r} (have head | none)")
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT, name=name)
@@ -284,18 +310,37 @@ class MLAMixer(nn.Module):
         c = norm("kv_norm")(dense(self.kv_lora_rank, "kv_down")(x))
         k_rope = dense(dr, "k_rope_proj")(x)
         kv = dense((H, 2 * dn), "kv_up")(c)
-        k = jnp.concatenate(
-            [kv[..., :dn],
-             jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], -1)
-        q, k = norm("q_norm")(q), norm("k_norm")(k)
-        cos, sin = rope_frequencies(dr, self.max_seq_len, self.rope_theta)
-        rotate = lambda t: jnp.concatenate(  # noqa: E731
-            [t[..., :dn], apply_rope(t[..., dn:], cos, sin)], -1)
-        y = dot_product_attention(rotate(q), rotate(k), kv[..., dn:],
+        # (2i, 2i+1) side by side: the halves' rotation then turns the pairs
+        paired = (lambda t: jnp.concatenate(  # noqa: E731
+            [t[..., 0::2], t[..., 1::2]], -1)) \
+            if self.rope == "pairs" else (lambda t: t)
+
+        def rotation():
+            cos, sin = rope_frequencies(dr, self.max_seq_len,
+                                        self.rope_theta)
+            return lambda t: apply_rope(paired(t), cos, sin)
+
+        if self.qk_norm:
+            k = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], -1)
+            q, k = norm("q_norm")(q), norm("k_norm")(k)
+            turn = rotation()
+            rotate = lambda t: jnp.concatenate(  # noqa: E731
+                [t[..., :dn], turn(t[..., dn:])], -1)
+            q, k = rotate(q), rotate(k)
+        else:  # nothing stands between k_rope and its rotation: once
+            turn = rotation()
+            q = jnp.concatenate([q[..., :dn], turn(q[..., dn:])], -1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(
+                    turn(k_rope[:, :, None, :]), (B, S, H, dr))], -1)
+        y = dot_product_attention(q, k, kv[..., dn:],
                                   causal=True, cp=self.cp,
                                   impl=self.attn_impl)
-        y = y.astype(jnp.float32) * _head_gate(x, H, self.dtype,
-                                               self.param_dtype)
+        if self.out_gate == "head":
+            y = y.astype(jnp.float32) * _head_gate(x, H, self.dtype,
+                                                   self.param_dtype)
         return nn.DenseGeneral(
             x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT,
@@ -395,6 +440,16 @@ class MixerVariants:
     gqa_out_gate: str = "head"       # | channel
     heads_held: int = 0              # query/KDA heads held here, 0 = all
     heads_held_first: int = 0
+    mla_qk_norm: bool = True         # RMSNorm over a head's whole q and k
+    mla_out_gate: str = "head"       # | none
+    mla_rope: str = "halves"         # | pairs: (2i, 2i+1) rotate together
+
+    @property
+    def mla_form(self) -> str:
+        """The latent mixer's form, as the build line says it."""
+        parts = (["normed"] if self.mla_qk_norm else []) \
+            + (["gated"] if self.mla_out_gate != "none" else [])
+        return f"mla={'+'.join(parts) or 'plain'} rope={self.mla_rope}"
 
 
 class HybridBlock(nn.Module):
@@ -446,7 +501,8 @@ class HybridBlock(nn.Module):
                 self.num_heads, self.head_dim, self.rope_head_dim,
                 self.kv_lora_rank, self.rope_theta, self.max_seq_len,
                 self.rms_norm_eps, self.dtype, self.param_dtype, cp=self.cp,
-                attn_impl=self.attn_impl, name="mla")(h)
+                attn_impl=self.attn_impl, qk_norm=var.mla_qk_norm,
+                out_gate=var.mla_out_gate, rope=var.mla_rope, name="mla")(h)
         else:
             assert self.kind == "kda", self.kind
             mixed, decay_stats = KDAMixer(
@@ -479,7 +535,12 @@ class HybridLM(nn.Module):
     the KDA layers' decay gate is unbounded, also ``kda_log_decay_min`` (the
     step's most negative one-token log-decay over those layers: how far
     past a bounded gate's -5 the chunk core's unbounded form is asked to
-    go) and ``kda_beta_max``."""
+    go) and ``kda_beta_max``. Where the expert layers' selection bias has a
+    rate (``moe.bias_rate``), each of them sows its router's load over ALL
+    its outputs (the ``router_load`` collection, ``ops/moe.py``) and the
+    model offers the step the rule that moves the bias by it after the
+    optimizer (``balance_routers``) and its metrics (``router_metrics``:
+    ``moe_load_fullest``, ``moe_load_mean``, ``moe_bias_abs_max``)."""
 
     vocab_size: int
     hidden_size: int
@@ -565,6 +626,15 @@ class HybridLM(nn.Module):
         return logits.astype(jnp.float32)
 
 
+    def balance_routers(self, params, moved, load):
+        """The parameters after the step: ``moved`` (after the optimizer)
+        with every router's selection bias taken from ``params`` (before
+        it) and moved by the balancing rule on the step's ``load``."""
+        return balance_routers(params, moved, load, self.moe.bias_rate)
+
+    router_metrics = staticmethod(router_load_metrics)
+
+
 def layer_kinds(cfg) -> tuple[str, ...]:
     """Each layer's mixer kind: ``cfg.layer_kinds`` where it is given, else
     groups of ``layer_group_size`` whose last is ``mla``, the others
@@ -604,7 +674,9 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
             n_groups=cfg.moe_groups, topk_groups=cfg.moe_topk_groups,
             routed_scale=cfg.moe_routed_scale, score=cfg.moe_score,
             held_first=cfg.experts_held_first, held=cfg.experts_held,
-            capacity_factor=cfg.expert_capacity_factor)
+            capacity_factor=cfg.expert_capacity_factor,
+            shared_mlp_dim=cfg.moe_shared_mlp_dim,
+            bias_rate=cfg.moe_bias_rate)
     kinds, heads = layer_kinds(cfg), layer_heads(cfg)
     head_dim = cfg.head_dim or cfg.hidden_size // cfg.num_heads
     kv_heads = cfg.num_kv_heads or cfg.num_heads
@@ -615,7 +687,9 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
         kda_gate=cfg.kda_gate, kda_beta_scale=cfg.kda_beta_scale,
         kda_gate_rank=cfg.kda_gate_rank, kda_out_gate=cfg.kda_out_gate,
         gqa_out_gate=cfg.gqa_out_gate, heads_held=cfg.heads_held,
-        heads_held_first=cfg.heads_held_first)
+        heads_held_first=cfg.heads_held_first,
+        mla_qk_norm=cfg.mla_qk_norm, mla_out_gate=cfg.mla_out_gate,
+        mla_rope=cfg.mla_rope)
     share = ""
     if cfg.heads_held:
         if "mla" in kinds:
@@ -636,7 +710,8 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
         print(f"[hybrid] layers={len(kinds)} kinds={','.join(kinds)} "
               f"heads={','.join(map(str, heads))} kv_heads={kv_heads} "
               f"window={cfg.attention_window} "
-              f"dense_layers={cfg.first_dense_layers}{share}",
+              f"dense_layers={cfg.first_dense_layers}{share}"
+              + (f" {variants.mla_form}" if "mla" in kinds else ""),
               file=sys.stderr, flush=True)
     return HybridLM(
         cp=cp, act=act, moe=moe,

@@ -27,7 +27,10 @@ routes over ALL the layer's experts (sigmoid scores with a selection-only
 bias, or softmax scores with none; group-limited top-k, weights normalised
 over every chosen expert), is told which experts it holds, and computes
 their part of the result with one grouped product over the (token, choice)
-pairs that fall on them, by expert.
+pairs that fall on them, by expert, beside the shared expert(s) at a width
+of their own. Where the spec gives the selection bias a rate, the layer
+also counts the tokens on EVERY router output and sows them, and the train
+step moves the bias by them after the optimizer (:func:`balance_routers`).
 No token is dropped silently: pairs past its static row bound are counted,
 and the model hands the count to the train step as ``update_invalid``, so
 such a step keeps its old state and reports ``update_skipped``.
@@ -228,6 +231,13 @@ class HeldExpertsSpec:
     # rows of the grouped product = this x the pairs expected on the held
     # experts under uniform routing (tokens x top_k x held / num_experts)
     capacity_factor: float = 4.0
+    # the shared expert's width: ONE SwiGLU every chip computes alike (a
+    # family's n shared experts of width w are one of n x w); 0 -> the
+    # routed experts' width
+    shared_mlp_dim: int = 0
+    # the balancing update's rate (:func:`balance_routers`); 0 leaves the
+    # selection bias where it was drawn and the layer sows no counts
+    bias_rate: float = 0.0
 
     @property
     def n_held(self) -> int:
@@ -340,9 +350,9 @@ _moe_logged: set[tuple] = set()
 
 
 def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int,
-              slots: int = 0) -> None:
+              slots: int, shared: int) -> None:
     """Once a shape, at trace time, on stderr: what this chip holds."""
-    key = (spec, n_tokens, slots)
+    key = (spec, n_tokens, slots, shared)
     if key in _moe_logged:
         return
     _moe_logged.add(key)
@@ -351,7 +361,9 @@ def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int,
           f"ids={spec.held_first}-{last} top_k={spec.top_k} "
           f"groups={spec.n_groups}/{spec.topk_groups} score={spec.score} "
           f"tokens={n_tokens} row_bound={rows}"
-          + (f" bank=padded slots={slots} spill=grouped" if slots else ""),
+          + (f" bank=padded slots={slots} spill=grouped" if slots else "")
+          + f" shared={shared}"
+          + (f" bias_rate={spec.bias_rate:g}" if spec.bias_rate else ""),
           file=sys.stderr, flush=True)
 
 
@@ -433,10 +445,11 @@ class _ExpertBank(nn.Module):
 class _Router(nn.Module):
     """Scores over every expert of the layer, float32 throughout, by the
     spec's rule. ``sigmoid``: each expert's own, and the bias that only
-    selection sees (drawn at std 0.01; it gets no gradient and no decay,
-    so it stays as initialised: the balancing update that would move it is
-    not part of this program). ``softmax``: over all the experts, and no
-    bias (no such leaf)."""
+    selection sees (drawn at std 0.01; it gets no gradient, so the
+    optimizer leaves it as it is: what moves it is the balancing update,
+    :func:`balance_routers`, where the spec gives it a rate, and nothing
+    where the rate is 0). ``softmax``: over all the experts, and no bias
+    (no such leaf)."""
 
     num_experts: int
     score: str = "sigmoid"
@@ -460,8 +473,13 @@ class _Router(nn.Module):
 
 class HeldExpertsMLP(nn.Module):
     """(B, S, D) -> ((B, S, D), stats): the held experts' part of the
-    routed sum plus the shared expert. ``stats`` is float32 (3,): pairs on
-    the fullest held expert, on the mean one, and past the row bound.
+    routed sum plus the shared expert, ONE SwiGLU of ``spec.shared_mlp_dim``
+    (0: the routed experts' ``mlp_dim``), which every chip of the layer
+    computes alike. ``stats`` is float32 (3,): pairs on the fullest held
+    expert, on the mean one, and past the row bound. With
+    ``spec.bias_rate`` > 0 the tokens that chose each of ALL the router's
+    outputs, float32 (E,), are sown as ``counts`` into the ``router_load``
+    collection, for the step's balancing update.
 
     Param tree: router/{kernel (D, E), bias (E,): the sigmoid rule's};
     experts/<proj>/kernel
@@ -480,7 +498,8 @@ class HeldExpertsMLP(nn.Module):
         N, spec, F = B * S, self.spec, self.mlp_dim
         rows = spec.row_bound(N)
         slots = padded_slots(spec, rows, D, F)
-        _log_plan(spec, N, rows, slots)
+        shared_dim = spec.shared_mlp_dim or F
+        _log_plan(spec, N, rows, slots, shared_dim)
         xf = x.reshape(N, D)
         scores, bias = _Router(spec.num_experts, spec.score,
                                name="router")(xf)
@@ -514,9 +533,62 @@ class HeldExpertsMLP(nn.Module):
                     r, n, w, slots, self.dtype)))
         else:
             routed = routed_by(token, weight, sizes, bank)
-        shared = self.mlp_module(self.mlp_dim, self.dtype, self.param_dtype,
+        if spec.bias_rate:
+            if bias is None:
+                raise ValueError("moe bias_rate: a softmax router has no "
+                                 "selection bias to balance")
+            load = jnp.sum(ids[:, :, None] == jnp.arange(spec.num_experts),
+                           (0, 1), dtype=jnp.float32)
+            self.sow("router_load", "counts", load,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: 0.0)
+        shared = self.mlp_module(shared_dim, self.dtype, self.param_dtype,
                                  name="shared")(x)
         y = routed.reshape(B, S, D).astype(self.dtype) + shared
         stats = jnp.stack([jnp.max(counts), jnp.mean(counts), over]
                           ).astype(jnp.float32)
         return y, stats
+
+
+# --------------------------------------------- the selection bias's update
+
+def _is_router_bias(path) -> bool:
+    return tuple(getattr(k, "key", None) for k in path[-2:]) \
+        == ("router", "bias")
+
+
+def balance_routers(params, moved, load, rate: float):
+    """DeepSeek-V3's auxiliary-loss-free balancing (arXiv:2412.19437, 2.1.2
+    and 4.2): after the optimizer, every router whose layer sowed its
+    ``counts`` c (tokens of the step's whole batch that chose each output)
+    has its selection bias moved by b_e <- b_e + rate x sign(mean(c) - c_e).
+    ``params`` is the tree before the optimizer, ``moved`` the tree after
+    it, ``load`` the step's ``router_load`` collection (a layer's counts sit
+    where its ``router`` does). The bias is taken from ``params``: no
+    gradient reaches it, and whatever else an optimizer would do to it
+    (decay) is not part of its rule. Every other leaf is ``moved``'s."""
+
+    def move(path, old, new):
+        if not _is_router_bias(path):
+            return new
+        layer = load
+        for k in path[:-2]:  # the layer's counts sit beside its router
+            layer = layer[k.key]
+        counts = layer["counts"]
+        return old + rate * jnp.sign(jnp.mean(counts) - counts)
+
+    return jax.tree_util.tree_map_with_path(move, params, moved)
+
+
+def router_load_metrics(load, params) -> dict:
+    """The step's metrics of the balancing update: tokens on the fullest
+    and on the mean router output (each a mean over the layers that sowed)
+    and the largest |bias| of those layers in ``params``."""
+    counts = jax.tree_util.tree_leaves(load)
+    biases = [b for path, b in jax.tree_util.tree_flatten_with_path(params)[0]
+              if _is_router_bias(path)]
+    return {
+        "moe_load_fullest": jnp.mean(jnp.stack([jnp.max(c) for c in counts])),
+        "moe_load_mean": jnp.mean(jnp.stack([jnp.mean(c) for c in counts])),
+        "moe_bias_abs_max": jnp.max(jnp.stack(
+            [jnp.max(jnp.abs(b)) for b in biases])),
+    }

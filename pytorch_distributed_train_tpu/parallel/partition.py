@@ -264,6 +264,8 @@ def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
     the grouped-query kinds' modules are `gqa` and `swa`, their K and V
     kernels carry the KV heads);
     the held experts' stacked kernels put their leading dim on 'expert';
+    the shared expert is a dense SwiGLU at a width of its own
+    (`model.moe_shared_mlp_dim`: the plain FFN rows below carry it);
     a gate a CHANNEL carries heads too (`gc_proj`, (D or r, H, d)), and
     where it or the decay's `a_proj` goes through a low rank, the first
     factor (`gc_down`, `a_down`: (D, r), what every chip of a `tensor` group

@@ -90,17 +90,21 @@ def apply_model_metrics(model, params, batch_stats, batch, *, train: bool,
     metrics, and so the log and the registry. One name is the step's own:
     ``update_invalid`` (> 0: the model computed this step on less than its
     input, so the update must not be applied) is folded into the
-    skip-select and reported as ``update_skipped``."""
+    skip-select and reported as ``update_skipped``. A model's
+    ``router_load`` collection (ops/moe.py: the tokens on every output of a
+    router whose selection bias has a rate) rides along under that name:
+    arrays, not a metric; the step takes it out and moves the bias by it
+    after the optimizer."""
     variables: dict[str, Any] = {"params": params}
     # mutable must be False (not []) when there are no stats — flax returns a
     # (out, vars) tuple for ANY list, including an empty one.
     mutable: Any = False
     if train:
-        mutable = ["losses", "step_metrics"]
+        mutable = ["losses", "step_metrics", "router_load"]
     if batch_stats:
         variables["batch_stats"] = batch_stats
         if train:
-            mutable = ["batch_stats", "losses", "step_metrics"]
+            mutable = ["batch_stats"] + mutable
     rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
     kwargs = {}
     if "decoder_input_ids" in batch and "attention_mask" in batch:
@@ -122,8 +126,10 @@ def apply_model_metrics(model, params, batch_stats, batch, *, train: bool,
                 updated.get("losses", {}))),
             start=jnp.float32(0.0),
         )
-        return (logits, updated.get("batch_stats"), aux,
-                dict(updated.get("step_metrics", {})))
+        metrics = dict(updated.get("step_metrics", {}))
+        if "router_load" in updated:
+            metrics["router_load"] = updated["router_load"]
+        return logits, updated.get("batch_stats"), aux, metrics
     return out, None, jnp.float32(0.0), {}
 
 
@@ -335,17 +341,21 @@ def make_train_step(model, loss_fn: Callable, tx,
             (micro, jnp.arange(k, dtype=jnp.int32)))
         grads = jax.tree.map(lambda g: g / k, grad_acc)
         loss = jnp.mean(losses)
+        load = auxes.pop("router_load", None)
         aux = jax.tree.map(jnp.mean, auxes)
+        if load is not None:  # counts, not a mean: the microbatches' add up
+            aux["router_load"] = jax.tree.map(lambda c: jnp.sum(c, 0), load)
         model_aux = jnp.mean(model_auxes)
         new_stats = stats if state.batch_stats else None
         return grads, (loss, aux, model_aux, new_stats)
 
-    def apply_update(state, grads, new_stats, loss):
+    def apply_update(state, grads, new_stats, loss, after_update):
         with jax.named_scope("optimizer"):
             return state.apply_gradients(tx, grads, new_stats,
                                          ema_decay=ema_decay,
                                          swa_start=swa_start,
-                                         swa_every=swa_every, loss=loss)
+                                         swa_every=swa_every, loss=loss,
+                                         after_update=after_update)
 
     def train_step(state: TrainState, batch: dict, rng: jax.Array):
         # Per-step dropout key: fold the step counter into the base key —
@@ -371,6 +381,24 @@ def make_train_step(model, loss_fn: Callable, tx,
             # collective on the accumulated grads.
             with jax.named_scope("grad_reduce"):
                 grads = reduce_grads_accum(grads)
+        # ``router_load`` (a model whose routers' selection bias has a
+        # rate): the bias is state that a rule of the model's own moves,
+        # from the step's counts, once the optimizer is done: outside the
+        # gradient, the clip's norm, the moments and the decay, and inside
+        # the skip-select like the rest of the state. The counts are the
+        # global batch's: the partitioner's own reduction under GSPMD jit,
+        # summed over the shards inside shard_map.
+        after_update = after_metrics = None
+        if "router_load" in aux:
+            aux = dict(aux)
+            load = aux.pop("router_load")
+            if reduce_metrics is not None:
+                load = reduce_metrics.total(load)
+            resolved["router_bias_rate"] = model.moe.bias_rate
+            after_update = lambda old, new: model.balance_routers(  # noqa: E731
+                old, new, load)
+            after_metrics = lambda params: model.router_metrics(  # noqa: E731
+                load, params)
         if reduce_metrics is not None:
             # shard_map: loss/metrics are per-shard means — average
             # across the batch shards so every replica logs (and the
@@ -393,7 +421,8 @@ def make_train_step(model, loss_fn: Callable, tx,
                 state, grads, loss, aux, model_aux, new_stats,
                 fused_update=fused_update, numeric_guard=numeric_guard,
                 module_grad_norms=module_grad_norms,
-                model_health=model_health, model_ok=model_ok)
+                model_health=model_health, model_ok=model_ok,
+                after_update=after_update, after_metrics=after_metrics)
 
         def select(ok, stepped):
             # both branches are computed in-graph and the select is
@@ -422,7 +451,8 @@ def make_train_step(model, loss_fn: Callable, tx,
             if model_ok is not None:
                 finite &= model_ok
             new_state = select(finite,
-                               apply_update(state, grads, new_stats, loss))
+                               apply_update(state, grads, new_stats, loss,
+                                            after_update))
             # The scaler adjusts on GRAD overflow only (GradScaler
             # semantics): a non-finite loss with finite grads skips the
             # update above but must not shrink the loss scale.
@@ -443,15 +473,20 @@ def make_train_step(model, loss_fn: Callable, tx,
                 metrics_extra["grads_finite"] = finite
                 ok = finite if ok is None else finite & ok
             new_state = select(ok,
-                               apply_update(state, grads, new_stats, loss))
+                               apply_update(state, grads, new_stats, loss,
+                                            after_update))
             metrics_extra["update_skipped"] = 1.0 - ok.astype(jnp.float32)
         else:
-            new_state = apply_update(state, grads, new_stats, loss)
+            new_state = apply_update(state, grads, new_stats, loss,
+                                     after_update)
             metrics_extra = {}
 
         gnorm = optax_global_norm(grads)
         metrics = {"loss": loss, "grad_norm": gnorm, "aux_loss": model_aux,
                    **aux, **metrics_extra}
+        if after_metrics is not None:  # of the bias the step leaves behind
+            with jax.named_scope("optimizer"):
+                metrics.update(after_metrics(new_state.params))
         if model_health:
             # Training-dynamics pass on the ACTUAL applied update (the
             # skip-select is already folded into new_state.params);
@@ -497,7 +532,8 @@ def _head_loss_plan(model, loss_fn, teacher_fn):
 def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,
                          new_stats, *, fused_update, numeric_guard: bool,
                          module_grad_norms: bool,
-                         model_health: bool = False, model_ok=None):
+                         model_health: bool = False, model_ok=None,
+                         after_update=None, after_metrics=None):
     """Shared tail of train_step on the fused path: loss-scale unscale +
     finite gate + clip + optimizer update in ONE pass over the grad tree
     (ops/fused_update.py), instead of the chain's three passes plus the
@@ -533,6 +569,11 @@ def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,
     with jax.named_scope("optimizer"):
         new_params, new_opt_state, gnorm = fused_update(
             grads, state.opt_state, state.params, finite=finite)
+        if after_update is not None:  # the model's own rule, gated alike
+            moved = after_update(state.params, new_params)
+            new_params = moved if finite is None else jax.tree.map(
+                lambda new, old: jnp.where(finite, new, old),
+                moved, new_params)
     stats = state.batch_stats
     if new_stats is not None:
         # The chain path's skip branch keeps the OLD stats (the whole
@@ -550,6 +591,8 @@ def _fused_epilogue_step(state: TrainState, grads, loss, aux, model_aux,
         new_state = new_state.replace(dynamic_scale=new_dynamic_scale)
     metrics = {"loss": loss, "grad_norm": gnorm, "aux_loss": model_aux,
                **aux, **metrics_extra}
+    if after_metrics is not None:
+        metrics.update(after_metrics(new_params))
     if model_health:
         from pytorch_distributed_train_tpu.ops.model_health import (
             health_stats,
@@ -787,6 +830,8 @@ def metrics_reducer(axis_names):
     def reduce_fn(tree):
         return jax.lax.pmean(tree, axes)
 
+    # for what is a count and not a mean (a router's load)
+    reduce_fn.total = lambda tree: jax.lax.psum(tree, axes)
     return reduce_fn
 
 

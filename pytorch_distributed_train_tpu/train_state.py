@@ -87,7 +87,7 @@ class TrainState:
     def apply_gradients(self, tx: optax.GradientTransformation, grads,
                         new_batch_stats=None, ema_decay: float = 0.0,
                         swa_start: int = 0, swa_every: int = 1,
-                        loss=None):
+                        loss=None, after_update=None):
         # reduce_on_plateau in the chain REQUIRES value=; other chains
         # reject the kwarg. Detect the plateau state structurally (trace-
         # time pytree walk, zero runtime cost) so every caller that passes
@@ -99,6 +99,10 @@ class TrainState:
             updates, new_opt_state = tx.update(
                 grads, self.opt_state, self.params)
         new_params = optax.apply_updates(self.params, updates)
+        if after_update is not None:
+            # leaves a rule of the model's own moves, not the optimizer
+            # (steps.py): (params before, params after) -> params
+            new_params = after_update(self.params, new_params)
         ema = self.ema_params
         swa_count = self.swa_count
         if ema is not None and swa_start > 0:

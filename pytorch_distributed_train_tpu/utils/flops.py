@@ -253,8 +253,9 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
 
     def mla(heads):
         h = heads
+        gate = 2.0 * d * h if cfg.mla_out_gate == "head" else 0.0
         return (2.0 * d * h * (dh + dr) + 2.0 * d * (r + dr)
-                + 2.0 * r * h * 2 * dh + 2.0 * h * dh * d + 2.0 * d * h
+                + 2.0 * r * h * 2 * dh + 2.0 * h * dh * d + gate
                 + 2.0 * s * h * (dh + dr) + 2.0 * s * h * dh)
 
     def gqa(heads, window):  # q and o, k and v, the gate; scores and values
@@ -269,8 +270,9 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
         else gqa(heads, cfg.attention_window if kind == "gqa_window" else 0)
         for kind, heads in zip(kinds, layer_heads(cfg)))
     expert = 6.0 * d * cfg.moe_mlp_dim
+    shared = 6.0 * d * (cfg.moe_shared_mlp_dim or cfg.moe_mlp_dim)
     held = cfg.experts_held or cfg.num_experts
-    moe = (2.0 * d * cfg.num_experts + expert
+    moe = (2.0 * d * cfg.num_experts + shared
            + expert * cfg.expert_top_k * held / max(cfg.num_experts, 1))
     return (mixers + n_dense * 6.0 * d * cfg.mlp_dim
             + n_moe * moe + 2.0 * d * cfg.vocab_size)

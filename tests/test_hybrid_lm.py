@@ -259,14 +259,20 @@ def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
 @pytest.mark.parametrize("E,held,score,kw", [
     (16, 4, "sigmoid", dict(top_k=4, n_groups=4, topk_groups=2)),
     (256, 8, "softmax", dict(top_k=10, n_groups=1, topk_groups=1)),
-], ids=["sigmoid-16-over-4-chips", "softmax-256-over-32-chips"])
+    (128, 16, "sigmoid", dict(top_k=6, n_groups=1, topk_groups=1,
+                              shared_mlp_dim=48)),
+], ids=["sigmoid-16-over-4-chips", "softmax-256-over-32-chips",
+        "sigmoid-128-over-8-chips-shared-of-its-own-width"])
 def test_the_shares_add_up_to_the_uncut_layer(E, held, score, kw):
     """16 experts over 4 chips, 4 a chip, under the sigmoid rule with its
     groups and bias; 256 experts over 32 chips, 8 a chip, 10 a token, under
-    the softmax rule with neither: the routed parts that all the shares
-    compute, plus the shared expert ONCE, are the whole layer as a plain
-    loop over all the experts computes it."""
+    the softmax rule with neither; 128 experts over 8 chips, 16 a chip, 6 a
+    token of ONE group under the sigmoid rule, beside two shared experts
+    (one SwiGLU of twice the routed width): the routed parts that all the
+    shares compute, plus the shared expert ONCE, are the whole layer as a
+    plain loop over all the experts computes it."""
     D, F, N = 16, 24, 48
+    Fs = kw.get("shared_mlp_dim", F)
     ks = jax.random.split(jax.random.PRNGKey(7), 9)
     x = jax.random.normal(ks[0], (1, N, D))
     router = {"kernel": 0.5 * jax.random.normal(ks[1], (D, E))}
@@ -276,9 +282,9 @@ def test_the_shares_add_up_to_the_uncut_layer(E, held, score, kw):
         ("gate_proj", ks[3], (E, D, F)), ("up_proj", ks[4], (E, D, F)),
         ("down_proj", ks[5], (E, F, D)))}
     shared = {n: {"kernel": 0.3 * jax.random.normal(k, shape)}
-              for n, k, shape in (("gate_proj", ks[6], (D, F)),
-                                  ("up_proj", ks[7], (D, F)),
-                                  ("down_proj", ks[8], (F, D)))}
+              for n, k, shape in (("gate_proj", ks[6], (D, Fs)),
+                                  ("up_proj", ks[7], (D, Fs)),
+                                  ("down_proj", ks[8], (Fs, D)))}
     kw = dict(num_experts=E, routed_scale=2.5, held=held, score=score, **kw)
     # the uncut layer, by hand
     logits = x[0] @ router["kernel"]
