@@ -278,6 +278,41 @@ def group_limited_topk(scores, bias, spec: HeldExpertsSpec):
     return ids.astype(jnp.int32), weights
 
 
+# Flags are counted in blocks of this many: the TPU's lane width, so a block
+# is one row of a vector register's tile (a constant of the chip, no knob).
+_BLOCK = 128
+
+
+def _nth_set(flags, ranks):
+    """Where the ``ranks[r]``-th (from 1) set entry of ``flags`` (L,) bool
+    sits, (R,) int32; L where the rank is past the count. Two levels and no
+    dependent chain: the rank's block of 128 flags from the blocks' running
+    counts (one compare-and-reduce over (R, blocks)), that block's flags as
+    a row (a one-hot product, exact on 0/1), the lane from the running
+    count along the row (a product with a 128 x 128 triangle). A binary
+    search of the running counts is ceil(log2(L + 1)) passes of R scalar
+    gathers, each waiting for the one before: 19 x 0.35 ms a call in the
+    all-latent cell on a v5e (PERF.md section 6, PR 42)."""
+    L = flags.shape[0]
+    nb = -(-L // _BLOCK)
+    tbl = jnp.pad(flags, (0, nb * _BLOCK - L)).reshape(nb, _BLOCK)
+    in_block = jnp.sum(tbl, 1, dtype=jnp.int32)
+    upto = jnp.cumsum(in_block)
+    ranks = ranks.astype(jnp.int32)[:, None]
+    passed = upto < ranks                             # (R, nb) whole blocks
+    block = jnp.sum(passed, 1, dtype=jnp.int32)       # nb: past the count
+    before = jnp.sum(jnp.where(passed, in_block, 0), 1)
+    row = jnp.dot(jax.nn.one_hot(block, nb, dtype=jnp.bfloat16),
+                  tbl.astype(jnp.bfloat16),
+                  preferred_element_type=jnp.float32)  # (R, 128) of 0 / 1
+    lanes = jnp.arange(_BLOCK)
+    incl = jnp.dot(row.astype(jnp.bfloat16),
+                   (lanes[:, None] <= lanes).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    lane = jnp.sum(incl < (ranks - before[:, None]), 1, dtype=jnp.int32)
+    return jnp.minimum(block * _BLOCK + lane, L)
+
+
 def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
               slots: int = 0):
     """The (token, choice) pairs that fall on held experts, by expert and
@@ -292,39 +327,37 @@ def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
 
     A token chooses an expert at most once, so the pairs are the set
     entries of an (expert, token) table, and the r-th row is the r-th set
-    entry: a running count and a binary search, no sort (a stable argsort
-    of the 131072 pairs of the hybrid cell took the TPU compiler 14 s a
-    layer: sandbox compile, PR 26)."""
-    N = ids.shape[0]
-    mine = spec.held_first + jnp.arange(spec.n_held)
-    hit = ids[:, :, None] == mine                     # (N, k, held)
-    on = jnp.any(hit, 1)                              # (N, held)
-    counts = jnp.sum(on, 0)
-    entries = lambda: jnp.cumsum(  # noqa: E731 - set entries up to each
-        on.T.reshape(-1).astype(jnp.int32))
-    if slots:
-        sizes = jnp.minimum(counts, slots)
-        over = jnp.sum(counts - sizes)
-        slot = jnp.arange(slots, dtype=jnp.int32)
-        # expert e's p-th row is set entry (entries before e) + p + 1
-        rank = (jnp.cumsum(counts) - counts)[:, None] + slot + 1
-        upto = entries()
-        at = jnp.searchsorted(upto, rank.reshape(-1).astype(jnp.int32))
-        real = (slot < sizes[:, None]).reshape(-1)
-    else:
-        ends = jnp.minimum(jnp.cumsum(counts), rows)
-        sizes = jnp.diff(ends, prepend=0)
-        over = jnp.sum(counts) - ends[-1]
-        upto = entries()
-        at = jnp.searchsorted(upto,
-                              jnp.arange(1, rows + 1, dtype=jnp.int32))
-        real = at < upto.shape[0]                     # the r-th entry exists
-    token = jnp.where(real, at % N, 0).astype(jnp.int32)
-    expert = jnp.where(real, at // N, 0)
-    held_weight = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), 1)
-    weight = jnp.where(real, held_weight[token, expert], 0.0)
-    return token, weight, sizes.astype(jnp.int32), counts.astype(jnp.int32), \
-        over
+    entry (:func:`_nth_set`): block counts and two small products, no sort
+    (a stable argsort of the 131072 pairs of the hybrid cell took the TPU
+    compiler 14 s a layer: sandbox compile, PR 26) and no search. The
+    program's map names all of it ``held_rows``."""
+    with jax.named_scope("held_rows"):
+        N = ids.shape[0]
+        mine = spec.held_first + jnp.arange(spec.n_held)
+        hit = ids[:, :, None] == mine                 # (N, k, held)
+        on = jnp.any(hit, 1)                          # (N, held)
+        counts = jnp.sum(on, 0)
+        table = on.T.reshape(-1)                      # (expert, token)
+        if slots:
+            sizes = jnp.minimum(counts, slots)
+            over = jnp.sum(counts - sizes)
+            slot = jnp.arange(slots, dtype=jnp.int32)
+            # expert e's p-th row is set entry (entries before e) + p + 1
+            rank = (jnp.cumsum(counts) - counts)[:, None] + slot + 1
+            at = _nth_set(table, rank.reshape(-1))
+            real = (slot < sizes[:, None]).reshape(-1)
+        else:
+            ends = jnp.minimum(jnp.cumsum(counts), rows)
+            sizes = jnp.diff(ends, prepend=0)
+            over = jnp.sum(counts) - ends[-1]
+            at = _nth_set(table, jnp.arange(1, rows + 1, dtype=jnp.int32))
+            real = at < table.shape[0]            # the r-th entry exists
+        token = jnp.where(real, at % N, 0).astype(jnp.int32)
+        expert = jnp.where(real, at // N, 0)
+        held_weight = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), 1)
+        weight = jnp.where(real, held_weight[token, expert], 0.0)
+        return token, weight, sizes.astype(jnp.int32), \
+            counts.astype(jnp.int32), over
 
 
 # An expert's matrix of this many elements or more takes the bank's second

@@ -207,6 +207,95 @@ def test_an_experts_own_slots_and_the_rule_that_picks_the_padded_bank():
     assert moe.padded_slots(spec, 6560, 3072, 1024) == 0
 
 
+def _pairs_by_numpy(ids, wts, spec, rows, slots):
+    """``held_rows`` in plain NumPy: the pairs on held experts sorted by
+    (expert, token), packed up to ``rows`` or ``slots`` an expert."""
+    ids, wts, held = np.asarray(ids), np.asarray(wts), spec.n_held
+    pairs = sorted((int(e) - spec.held_first, t, float(wts[t, c]))
+                   for (t, c), e in np.ndenumerate(ids)
+                   if 0 <= e - spec.held_first < held)
+    counts = np.bincount([e for e, _, _ in pairs], minlength=held)
+    token, weight = np.zeros(rows, np.int32), np.zeros(rows, np.float32)
+    if slots:
+        sizes = np.minimum(counts, slots)
+        for e in range(held):
+            mine = [p for p in pairs if p[0] == e][:slots]
+            at = slice(e * slots, e * slots + len(mine))
+            token[at], weight[at] = [p[1] for p in mine], [p[2] for p in mine]
+    else:
+        ends = np.minimum(np.cumsum(counts), rows)
+        sizes = np.diff(ends, prepend=0)
+        kept = pairs[:rows]
+        token[:len(kept)] = [p[1] for p in kept]
+        weight[:len(kept)] = [p[2] for p in kept]
+    return token, weight, sizes, counts, counts.sum() - sizes.sum()
+
+
+@pytest.mark.parametrize("case", [
+    *(f"nth_set-fill{fill}-{order}" for fill in (0, 0.1, 0.9, 1)
+      for order in ("in_order", "shuffled")),
+    "held_rows-packed", "held_rows-packed-past_the_bound",
+    "held_rows-slots", "held_rows-slots-past_an_experts_own"])
+def test_the_rth_row_is_the_rth_set_entry_of_the_expert_token_table(case):
+    """``_nth_set`` against NumPy's ``flatnonzero`` (tables of several
+    blocks whose length is no multiple of 128, empty, sparse, dense and
+    full; ranks past the count give L), and ``held_rows`` through it against
+    the pairs sorted by (expert, token)."""
+    kind, *how = case.split("-")
+    rng = np.random.default_rng(42)
+    if kind == "nth_set":
+        L, fill = 700, float(how[0][4:])     # five blocks and 60 flags
+        flags = rng.random(L) < fill
+        ranks = np.arange(1, L + 41, dtype=np.int32)  # 40 or more past it
+        if how[1] == "shuffled":
+            ranks = rng.permutation(ranks)
+        set_at = np.flatnonzero(flags)
+        want = np.append(set_at, np.full(L + 40, L))[ranks - 1]
+        got = jax.jit(moe._nth_set)(flags, ranks)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), want)
+        return
+    past = len(how) == 2
+    spec = _spec(capacity_factor=0.5 if past else 4.0)
+    # four distinct experts of 32 a token, as a router's top-k gives them
+    ids = rng.permuted(np.tile(np.arange(32, dtype=np.int32), (200, 1)),
+                       axis=1)[:, :4]
+    wts = rng.random((200, 4), dtype=np.float32)
+    rows = spec.row_bound(200)           # 400, or 56: under the 90-odd pairs
+    slots = rows // 4 if how[0] == "slots" else 0
+    got = jax.jit(lambda i, w: moe.held_rows(i, w, spec, rows, slots))(
+        ids, wts)
+    want = _pairs_by_numpy(ids, wts, spec, rows, slots)
+    assert (int(want[4]) > 0) == past
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
+def test_held_rows_at_the_all_latent_cells_shape_holds_no_loop_and_no_sort():
+    """The jaxpr of ``held_rows`` at 16384 tokens x 6 choices, 16 of 128
+    experts held (abstract: nothing compiles or runs): no ``while`` /
+    ``scan`` (a binary search is 19 dependent passes of scalar gathers
+    there: PERF.md section 6, PR 42) and no ``sort``, in either form."""
+    spec = moe.HeldExpertsSpec(num_experts=128, top_k=6, held_first=16,
+                               held=16)
+    rows = spec.row_bound(16384)
+    assert rows == 49152
+
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    for slots in (0, rows // 16):
+        names = set(primitives(jax.make_jaxpr(
+            lambda i, w: moe.held_rows(i, w, spec, rows, slots))(
+                jax.ShapeDtypeStruct((16384, 6), jnp.int32),
+                jax.ShapeDtypeStruct((16384, 6), F32)).jaxpr))
+        assert "dot_general" in names
+        assert not names & {"while", "scan", "sort"}, names
+
+
 def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
     from pytorch_distributed_train_tpu import losses, steps
     from pytorch_distributed_train_tpu.optim import make_optimizer
