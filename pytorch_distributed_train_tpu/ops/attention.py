@@ -64,13 +64,18 @@ _resolutions_logged: set[tuple] = set()
 
 
 def _log_resolution(impl: str, q, k, *, causal: bool, window: int,
-                    interpret: bool = False, plan=None, bwd=None) -> None:
+                    interpret: bool = False, plan=None, fetch=None,
+                    bwd=None) -> None:
     """Say once per distinct call signature, at trace time, which
     implementation the dispatch resolved to — a run's log then shows
     whether the Pallas kernel really took the call, and with ``plan``
     (flash_attention.tile_plan of the call) how many of a head's score
     tiles it enters and how many of those build a mask: ``tiles=3/4
-    masked=2``; with ``bwd`` (flash_attention.backward_plan of the call:
+    masked=2``; with ``fetch`` (flash_attention.block_map's plan: the
+    index maps the kernels' calls themselves use) how many of a head's
+    grid steps enter a tile and how many blocks of K and V the head's walk
+    fetches, forward and again in the fused backward: ``steps=72/128
+    fetches=70``; with ``bwd`` (flash_attention.backward_plan of the call:
     the function the kernel's backward itself asks) the backward's form
     and the bytes it would keep in VMEM under a KV head: ``bwd=fused
     resident=1.6MB``, or ``bwd=split`` past the budget. On stderr: stdout
@@ -81,8 +86,9 @@ def _log_resolution(impl: str, q, k, *, causal: bool, window: int,
     _resolutions_logged.add(key)
     kernel = "" if plan is None else \
         f" tiles={plan.executed}/{plan.total} masked={plan.masked}"
-    if bwd is not None:
-        kernel += f" {bwd}"
+    for more in (fetch, bwd):
+        if more is not None:
+            kernel += f" {more}"
     print(f"[attention] impl={impl} q={tuple(q.shape)} kv_heads={k.shape[2]} "
           f"dtype={q.dtype} causal={causal} window={window} "
           f"interpret={interpret}{kernel}", file=sys.stderr, flush=True)
@@ -236,6 +242,8 @@ def dot_product_attention(
                     "pallas", q, k, causal=causal, window=window,
                     interpret=not on_tpu,
                     plan=_fa.call_plan(q, k, causal=causal, window=window),
+                    fetch=_fa.call_fetch_plan(q, k, causal=causal,
+                                              window=window),
                     bwd=_fa.call_backward_plan(q, k, v))
                 flash = functools.partial(
                     _fa.flash_attention, causal=causal, window=window,

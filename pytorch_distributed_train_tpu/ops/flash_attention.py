@@ -28,8 +28,8 @@ the resident block's index does not depend on the other index, so it is
 fetched once a head. The backward's grid is the forward's under a KV
 head with the head's rep query heads innermost, (B·Hkv, S/block_q,
 S/major, rep): the rep steps of a (Q tile, major block) pair share ONE
-fetch of K and V (at nine query heads a KV head and S 8192 the forward
-moves 4.8 GB of K and V a call, the backward 0.5), a Q tile's Q, dO and dQ
+fetch of K and V (at nine query heads a KV head the forward's grid asks
+for them nine times as often as the backward's), a Q tile's Q, dO and dQ
 are blocks of all rep heads, fetched and written once a tile, and dK and
 dV of the KV head's WHOLE sequence stay in float32 VMEM under every Q
 tile and query head that reads the head, S x (D + Dv) x 4 bytes whatever
@@ -57,9 +57,18 @@ masks 2. Sliding-window attention (``window`` > 0) therefore scales
 O(S·window) like the chunked XLA path. A step with one major block writes
 its output itself: no running state, no scratch, no init/finalize — on a
 v5e that state cost a third of the old forward (PERF.md, PR 25). Several
-major blocks (S > 2048) keep the running max/sum/accumulator in VMEM
-across them; a major block wholly above the diagonal runs nothing but is
-still fetched.
+major blocks (S > 2048; S > 1024 at D 192, where 512 KiB of K are 1365
+rows) keep the running max/sum/accumulator in VMEM across them. A step
+whose major block lies wholly above the diagonal or below the window's
+band runs nothing AND fetches nothing: K's and V's index maps give it the
+nearest block its Q tile does enter (:func:`block_map`, one rule for the
+forward, the fused backward and both kernels of the split one, held
+against the dispatch's own ranges as it is built), consecutive steps then
+share an index and Pallas skips the copy. :class:`FetchPlan` counts it:
+of a head's 128 grid steps at latent attention's (8192, D 192) 72 enter a
+tile and 70 blocks are fetched (2.9 GB of K and V a call where a block a
+step is 5.4); 64 / 40 / 36 at (8192, D 128), 64 / 19 / 4 under a window of
+512. The empty steps stay in the grid and keep a step's fixed cost.
 
 Precision: scores, softmax statistics, ``exp``, lse, delta and every
 accumulator are fp32 regardless of input dtype (matches ops.attention
@@ -323,6 +332,20 @@ def _segments(ranges, unit, mask_off):
         if x < y)
 
 
+def _kv_segments(off, major, block_q, block_k, causal, window):
+    """What a Q tile enters of a resident K/V block, ``off`` = the tile's
+    first row less the block's first column; empty: nothing."""
+    return _segments(_kv_ranges(off, major // block_k, block_q, block_k,
+                                causal, window), block_k, lambda c0: c0 - off)
+
+
+def _q_segments(off, major, block_q, block_k, causal, window):
+    """What a KV tile enters of a resident block of Q's side (the split
+    dK/dV), ``off`` = the tile's first column less the block's first row."""
+    return _segments(_q_ranges(off, major // block_q, block_q, block_k,
+                               causal, window), block_q, lambda r0: off - r0)
+
+
 def _static_dispatch(off, offsets, segments_of, update):
     """The full-seq entry: the step's offset is an expression of grid
     indices, and the grid can produce only ``offsets``. Neighbouring
@@ -412,9 +435,100 @@ def _kv_dispatch(update, q_ref, k_ref, qpos_ref, kpos_ref, *, block_k, grid,
         off=pl.program_id(1) * block_q - pl.program_id(2) * major,
         offsets=[i * block_q - j * major
                  for i in range(grid[0]) for j in range(grid[1])],
-        segments_of=lambda off: _segments(
-            _kv_ranges(off, major // block_k, block_q, block_k, causal,
-                       window), block_k, lambda c0: c0 - off))
+        segments_of=lambda off: _kv_segments(off, major, block_q, block_k,
+                                             causal, window))
+
+
+class FetchPlan(NamedTuple):
+    steps: int    # grid steps of one head: tiles x streamed major blocks
+    entered: int  # of those, the ones the dispatch enters
+    fetched: int  # major blocks the walk fetches: changes of the index
+
+    def __str__(self):
+        return f"steps={self.entered}/{self.steps} fetches={self.fetched}"
+
+
+class BlockMap:
+    """Which major block of its streamed side grid step (i, j) FETCHES: its
+    own, j, where the dispatch enters it, else the nearest one that tile i
+    enters, so the empty steps of a tile repeat an index and Pallas copies
+    nothing for them. The map is a closed form a traced grid index can go
+    through (an index map holds no table): tile i, ``tile`` positions from
+    i x tile on, meets the streamed positions from ``back`` before its
+    first to ``ahead`` past it (None: to the sequence's end), so j is
+    clamped into the major blocks that hold them. Built, it is held against
+    the dispatch itself, every step of the grid: ``segments_at(i, j)`` is
+    what the dispatch hands the step, empty where it reads nothing of its
+    block, so the steps that read a block and the steps given their own
+    cannot drift apart. With nothing to skip (no mask, one major block) the
+    map is j itself."""
+
+    def __init__(self, n_tiles, n_major, segments_at, *, tile, major, back,
+                 ahead):
+        self.shape = (n_tiles, n_major)
+        self.tile, self.major, self.back, self.ahead = tile, major, back, ahead
+        self.enters = [[bool(segments_at(i, j)) for j in range(n_major)]
+                       for i in range(n_tiles)]
+        self.identity = all(map(all, self.enters))
+        for i, row in enumerate(self.enters):
+            for j, entered in enumerate(row):
+                fetched = self(i, j)
+                assert fetched == j if entered else row[fetched], (i, j)
+
+    def __call__(self, i, j):
+        if self.identity:
+            return j
+        least, most, div = (max, min, operator.floordiv) \
+            if isinstance(i, int) else (jnp.maximum, jnp.minimum, jax.lax.div)
+        first = i * self.tile
+        if self.back is not None:
+            j = least(j, div(least(first - self.back, 0), self.major))
+        if self.ahead is not None:
+            j = most(j, div(first + self.ahead, self.major))
+        return j
+
+    def plan(self) -> FetchPlan:
+        n_tiles, n_major = self.shape
+        walk = [self(i, j) for i in range(n_tiles) for j in range(n_major)]
+        return FetchPlan(len(walk), sum(map(sum, self.enters)),
+                         1 + sum(a != b for a, b in zip(walk, walk[1:])))
+
+
+def block_map(Sq, Sk, tiles, *, causal, window, has_pos=False,
+              streams="kv") -> BlockMap:
+    """The one :class:`BlockMap` rule of all four calls. ``streams`` "kv":
+    the forward's grid (Q tiles x major blocks of K and V: forward, fused
+    backward, split dQ), a Q tile meeting the keys from ``window`` - 1
+    before its first row to its last row; "q": the split dK/dV's (KV tiles
+    x major blocks of Q, dO, lse and delta), a KV tile meeting the queries
+    from its first column to ``window`` - 1 past its last. Traced positions
+    (the ring's chunk entry) have no static range: every step is given its
+    own block."""
+    block_q, block_k, major_q, major_k = tiles
+    if streams == "kv":
+        shape, tile, major, segments = (
+            (Sq // block_q, Sk // major_k), block_q, major_k, _kv_segments)
+        back = window - 1 if window else None
+        ahead = tile - 1 if causal else None
+    else:
+        shape, tile, major, segments = (
+            (Sk // block_k, Sq // major_q), block_k, major_q, _q_segments)
+        back = 0 if causal else None
+        ahead = tile + window - 2 if window else None
+    static = (causal or bool(window)) and not has_pos
+    return BlockMap(*shape, lambda t, m: not static or segments(
+        t * tile - m * major, major, block_q, block_k, causal, window),
+        tile=tile, major=major, back=back, ahead=ahead)
+
+
+def call_fetch_plan(q, k, *, causal: bool, window: int = 0) -> FetchPlan:
+    """A head's grid in the forward and the fused backward of a
+    full-sequence call under the tile rule, and the K and V blocks its maps
+    fetch — what the dispatch's resolution line prints."""
+    S = q.shape[1]
+    tiles = tile_sizes(S, k.shape[1], q.shape[3], q.dtype.itemsize)
+    return block_map(S, k.shape[1], tiles, causal=causal,
+                     window=window).plan()
 
 
 def _unpack(refs, n_inputs, has_pos):
@@ -527,10 +641,14 @@ def _fwd(q3, k3, v3, q_pos=None, kv_pos=None, *, causal, scale, tiles,
         _fwd_kernel, block_k=block_k, grid=grid, direct=direct,
         causal=causal, scale=scale, window=window, has_pos=has_pos,
     )
+    fetched = block_map(Sq, Sk, tiles, causal=causal, window=window,
+                        has_pos=has_pos)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, major, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, major, Dv), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major, D),
+                     lambda b, i, j: (b // rep, fetched(i, j), 0)),
+        pl.BlockSpec((1, major, Dv),
+                     lambda b, i, j: (b // rep, fetched(i, j), 0)),
     ]
     args = [q3, k3, v3]
     if has_pos:
@@ -684,9 +802,8 @@ def _bwd_dkv_kernel(*refs, block_q, grid, rep, direct, causal, scale,
         off=ki * block_k - qmi * major,
         offsets=[j * block_k - i * major
                  for j in range(grid[0]) for i in range(grid[1])],
-        segments_of=lambda off: _segments(
-            _q_ranges(off, major // block_q, block_q, block_k, causal,
-                      window), block_q, lambda r0: off - r0))
+        segments_of=lambda off: _q_segments(off, major, block_q, block_k,
+                                            causal, window))
 
     if not direct:
         @pl.when((qmi == pl.num_programs(3) - 1) & (r == rep - 1))
@@ -849,10 +966,14 @@ def _bwd_fused(args, q_pos, kv_pos, *, tiles, interpret, static, resident):
                             lambda b, i, j, r: (b, i, 0))
 
     row = pl.BlockSpec((rep, 1, block_q), lambda b, i, j, r: (b, 0, i))
+    fetched = block_map(Sq, Sk, tiles, has_pos=has_pos,
+                        causal=static["causal"], window=static["window"])
     in_specs = [
         group(D),
-        pl.BlockSpec((1, major, D), lambda b, i, j, r: (b, j, 0)),
-        pl.BlockSpec((1, major, Dv), lambda b, i, j, r: (b, j, 0)),
+        pl.BlockSpec((1, major, D),
+                     lambda b, i, j, r: (b, fetched(i, j), 0)),
+        pl.BlockSpec((1, major, Dv),
+                     lambda b, i, j, r: (b, fetched(i, j), 0)),
         group(Dv), row, row,
     ]
     if has_pos:
@@ -896,10 +1017,15 @@ def _bwd_split(args, q_pos, kv_pos, *, tiles, interpret, static):
 
     dq_grid = (Sq // block_q, Sk // major_k)
     dq_direct = dq_grid[1] == 1 and not has_pos  # as the forward
+    skips = dict(causal=static["causal"], window=static["window"],
+                 has_pos=has_pos)
+    kv_of = block_map(Sq, Sk, tiles, **skips)
     dq_in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, major_k, D), lambda b, i, j: (b // rep, j, 0)),
-        pl.BlockSpec((1, major_k, Dv), lambda b, i, j: (b // rep, j, 0)),
+        pl.BlockSpec((1, major_k, D),
+                     lambda b, i, j: (b // rep, kv_of(i, j), 0)),
+        pl.BlockSpec((1, major_k, Dv),
+                     lambda b, i, j: (b // rep, kv_of(i, j), 0)),
         pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -928,13 +1054,17 @@ def _bwd_split(args, q_pos, kv_pos, *, tiles, interpret, static):
     # b·rep + r — the inverse of the forward's b // rep map.
     dkv_grid = (Sk // block_k, Sq // major_q)
     dkv_direct = dkv_grid[1] == 1 and rep == 1 and not has_pos
+    q_of = block_map(Sq, Sk, tiles, streams="q", **skips)
+    rows = pl.BlockSpec((1, 1, major_q),
+                        lambda b, j, r, i: (b * rep + r, 0, q_of(j, i)))
     dkv_in_specs = [
-        pl.BlockSpec((1, major_q, D), lambda b, j, r, i: (b * rep + r, i, 0)),
+        pl.BlockSpec((1, major_q, D),
+                     lambda b, j, r, i: (b * rep + r, q_of(j, i), 0)),
         pl.BlockSpec((1, block_k, D), lambda b, j, r, i: (b, j, 0)),
         pl.BlockSpec((1, block_k, Dv), lambda b, j, r, i: (b, j, 0)),
-        pl.BlockSpec((1, major_q, Dv), lambda b, j, r, i: (b * rep + r, i, 0)),
-        pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
-        pl.BlockSpec((1, 1, major_q), lambda b, j, r, i: (b * rep + r, 0, i)),
+        pl.BlockSpec((1, major_q, Dv),
+                     lambda b, j, r, i: (b * rep + r, q_of(j, i), 0)),
+        rows, rows,
     ]
     dkv_args = list(args)
     if has_pos:

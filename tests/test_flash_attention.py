@@ -2,6 +2,8 @@
 (SURVEY §5.2: "Pallas kernels → interpret=True mode vs XLA reference
 implementation in tests")."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -142,7 +144,28 @@ TILED_CASES = [
     ("s512_window_wider_than_major", 512, 2, 2, 128, 256, 128, True, 300),
     ("s512_noncausal", 512, 2, 1, 128, 256, 128, False, 0),
     ("s384_rule", 384, 2, 2, None, None, None, True, 0),
+    # four major blocks, so K's and V's index maps clamp (block_map): steps
+    # above the diagonal and, under a window, below the band fetch nothing
+    ("s1024_four_majors_causal_gqa", 1024, 2, 1, 128, 256, 128, True, 0),
+    ("s1024_four_majors_window300", 1024, 1, 1, 128, 256, 128, True, 300),
+    ("s1024_four_majors_window_alone", 1024, 1, 1, 256, 256, 128, False,
+     300),
 ]
+
+
+def _ref_attention(q, k, v, *, causal, window):
+    """The XLA reference, K and V expanded to Q's heads; a window with no
+    causal mask (every later key, ``window`` earlier ones: the kernels
+    take it, `_xla_attention` has no word for it) as an explicit mask."""
+    rep = q.shape[2] // k.shape[2]
+    mask = None
+    if window and not causal:
+        pos = jnp.arange(q.shape[1])
+        mask = (pos[:, None] - pos[None, :] < window)[None, None]
+    return _xla_attention(q, jnp.repeat(k, rep, axis=2),
+                          jnp.repeat(v, rep, axis=2), causal=causal,
+                          mask=mask, softmax_dtype=jnp.float32,
+                          window=window)
 
 
 @pytest.mark.parametrize("name,S,H,Hkv,block_q,major,block_k,causal,window",
@@ -153,18 +176,13 @@ def test_tiled_kernels_match_xla(name, S, H, Hkv, block_q, major, block_k,
     XLA reference (GQA: the reference expands K/V inside the loss)."""
     q, _, _ = _make_qkv(B=1, S=S, H=H, D=64, seed=31)
     _, k, v = _make_qkv(B=1, S=S, H=Hkv, D=64, seed=37)
-    rep = H // Hkv
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                block_k_major=major, interpret=True)
 
-    def ref(q, k, v):
-        return _xla_attention(q, jnp.repeat(k, rep, axis=2),
-                              jnp.repeat(v, rep, axis=2), causal=causal,
-                              mask=None, softmax_dtype=jnp.float32,
-                              window=window)
+    ref = functools.partial(_ref_attention, causal=causal, window=window)
 
     np.testing.assert_allclose(np.asarray(flash(q, k, v)),
                                np.asarray(ref(q, k, v)),
@@ -314,6 +332,16 @@ BACKWARD_CASES = [
     ("window_200_gqa_rep2", 512, 4, 2, 64, 64, True, 200, (128, 128, 256)),
     ("mla_192_128", 512, 2, 2, 192, 128, True, 0, (128, 128, 256)),
     ("s4096_two_majors_of_the_rule", 4096, 1, 1, 64, 64, True, 0, None),
+    # four major blocks on both sides (a split dK/dV streams Q's): the
+    # clamped index maps of all four calls, causal, window and both
+    ("four_majors_causal_gqa_rep2", 1024, 4, 2, 64, 64, True, 0,
+     (128, 128, 256)),
+    ("four_majors_window300", 1024, 2, 2, 64, 64, True, 300,
+     (128, 128, 256)),
+    ("four_majors_window_alone", 1024, 2, 2, 64, 64, False, 300,
+     (256, 128, 256)),
+    ("four_majors_mla_192_128", 1024, 1, 1, 192, 128, True, 0,
+     (256, 128, 256)),
 ]
 
 
@@ -335,7 +363,6 @@ def test_backward_forms_match_xla(monkeypatch, name, S, H, Hkv, D, Dv,
     mk = lambda h, d: jnp.asarray(  # noqa: E731
         rng.standard_normal((1, S, h, d)) * 0.5, jnp.float32)
     q, k, v, w = mk(H, D), mk(Hkv, D), mk(Hkv, Dv), mk(H, Dv)
-    rep = H // Hkv
     bq, bk, major = tiles or (None, None, None)
 
     def flash(q, k, v):
@@ -343,11 +370,7 @@ def test_backward_forms_match_xla(monkeypatch, name, S, H, Hkv, D, Dv,
                                block_q=bq, block_k=bk, block_k_major=major,
                                interpret=True)
 
-    def ref(q, k, v):
-        return _xla_attention(q, jnp.repeat(k, rep, axis=2),
-                              jnp.repeat(v, rep, axis=2), causal=causal,
-                              mask=None, softmax_dtype=jnp.float32,
-                              window=window)
+    ref = functools.partial(_ref_attention, causal=causal, window=window)
 
     gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
         q, k, v)
@@ -439,6 +462,86 @@ def test_fused_backward_enters_the_tiles_of_tile_plan(monkeypatch, name, S,
         assert (entered, masked) == (plan.executed, plan.masked)
 
 
+# (id, S, D, (block_q, block_k, major) or the rule's, causal, window,
+# FetchPlan or None where only the properties are asserted): the cells'
+# attention shapes (gpt2s one chip and dp4, looped, window/full's two
+# kinds and the head-share cell's NoPE layer, latent attention's) and
+# small ones: a window whose band starts past block 0, one alone, no mask.
+BLOCK_MAP_CASES = [
+    ("gpt2_small", 1024, 64, None, True, 0, (2, 2, 1)),
+    ("looped", 4096, 128, None, True, 0, (16, 12, 8)),
+    ("full_8k", 8192, 128, None, True, 0, (64, 40, 36)),
+    ("window_512", 8192, 128, None, True, 512, (64, 19, 4)),
+    ("mla_8k", 8192, 192, None, True, 0, (128, 72, 70)),
+    ("small_causal", 1024, 64, (128, 128, 256), True, 0, (32, 20, 18)),
+    ("small_window300", 1024, 64, (128, 128, 256), True, 300, None),
+    ("small_window_inside_a_major", 1024, 64, (128, 128, 256), True, 50,
+     None),
+    ("small_window_alone", 1024, 64, (256, 128, 256), False, 300, None),
+    ("small_tall_tiles", 1024, 64, (512, 128, 256), True, 200, None),
+    ("small_no_mask", 1024, 64, (128, 128, 256), False, 0, (32, 32, 32)),
+    ("small_one_major", 512, 64, (128, 128, 512), True, 100, (4, 4, 1)),
+]
+
+
+@pytest.mark.parametrize("streams", ["kv", "q"])
+@pytest.mark.parametrize("name,S,D,tiles,causal,window,want",
+                         BLOCK_MAP_CASES, ids=[c[0] for c in BLOCK_MAP_CASES])
+def test_block_map_fetches_what_the_grid_enters(name, S, D, tiles, causal,
+                                                window, want, streams):
+    """Each kernel's grid walked in plain Python through the index-map
+    helper its `pallas_call` uses (`block_map`: "kv" the forward, the fused
+    backward and the split dQ; "q" the split dK/dV, Q's side streamed under
+    a KV tile). (a) A step holding a score tile that `tile_plan`'s own rule
+    enters is given its own block; an empty step one its tile enters.
+    (b) The index changes along the walk are `FetchPlan.fetched`, and the
+    numbers PERF.md quotes. (c) One major block, or no mask: the identity.
+    And traced indices, as an index map gets them, give the walk's blocks."""
+    from pytorch_distributed_train_tpu.ops import flash_attention as fa
+
+    t = fa.tile_sizes(S, S, D, 2, **dict(zip(
+        ("block_q", "block_k", "block_k_major"), tiles or ())))
+    bmap = fa.block_map(S, S, t, causal=causal, window=window,
+                        streams=streams)
+    tile, major = (t.block_q, t.major_k) if streams == "kv" else \
+        (t.block_k, t.major_q)
+    n_tiles, n_major = S // tile, S // major
+    entered = set()  # steps holding a score tile of tile_plan's rule
+    for q0 in range(0, S, t.block_q):
+        for k0 in range(0, S, t.block_k):
+            d_max, d_min = q0 + t.block_q - 1 - k0, q0 - (k0 + t.block_k - 1)
+            if not ((causal and d_max < 0) or (window and d_min >= window)):
+                entered.add((q0 // tile, k0 // major) if streams == "kv"
+                            else (k0 // tile, q0 // major))
+    walk = [bmap(i, j) for i in range(n_tiles) for j in range(n_major)]
+    assert all(isinstance(b, int) and 0 <= b < n_major for b in walk)
+    for i in range(n_tiles):
+        for j in range(n_major):
+            if (i, j) in entered:
+                assert bmap(i, j) == j, (i, j)
+            else:
+                assert (i, bmap(i, j)) in entered, (i, j)
+    plan = bmap.plan()
+    changes = 1 + sum(a != b for a, b in zip(walk, walk[1:]))
+    assert plan == (n_tiles * n_major, len(entered), changes)
+    assert plan.entered <= plan.steps and plan.fetched <= plan.steps
+    if want is not None:
+        assert plan == want  # the same numbers on both sides: S x S
+    one_block_or_no_mask = n_major == 1 or not (causal or window)
+    assert bmap.identity == (len(entered) == n_tiles * n_major)
+    if one_block_or_no_mask:
+        assert bmap.identity
+    if bmap.identity:
+        assert walk == [j for _ in range(n_tiles) for j in range(n_major)]
+        marker = object()
+        assert bmap(0, marker) is marker  # the compiled map is j itself
+    ii, jj = jnp.meshgrid(jnp.arange(n_tiles), jnp.arange(n_major),
+                          indexing="ij")
+    traced = jax.jit(bmap)(ii, jj)
+    assert traced.dtype == jnp.int32
+    assert np.asarray(traced).ravel().tolist() == walk
+
+
 def test_chunk_entry_contract():
     """flash_attention_chunk: the ring inner kernel's (o, lse) contract —
     diagonal chunk == causal self-attention; all-future chunk returns
@@ -524,8 +627,12 @@ def test_dispatch_windowed_pallas_impl():
 def test_resolution_line_prints_the_tile_plan(capsys):
     """The dispatch's once-per-shape line says how many of a head's score
     tiles the kernel enters and masks, and they are tile_plan's for the
-    call's shape under the kernel's own tile rule; then the backward's
-    form, by the function the kernel's backward asks."""
+    call's shape under the kernel's own tile rule; then a head's grid steps
+    (entered / all) and the K and V blocks its index maps fetch, by the map
+    the calls use; then the backward's form, by the function the kernel's
+    backward asks. At latent attention's shape, eight major blocks a Q
+    tile, the line says 72 of 128 steps and 70 fetches for the parent's
+    128."""
     from pytorch_distributed_train_tpu.ops import attention as attn
     from pytorch_distributed_train_tpu.ops import flash_attention as fa
 
@@ -538,9 +645,18 @@ def test_resolution_line_prints_the_tile_plan(capsys):
     assert line.startswith("[attention] impl=pallas q=(1, 1024, 2, 64)")
     assert line.endswith(
         f"tiles={plan.executed}/{plan.total} masked={plan.masked} "
+        f"{fa.call_fetch_plan(q, q, causal=True)} "
         f"{fa.backward_plan(1024, 64, 64, 2)}")
-    assert line.endswith(" bwd=fused resident=1.6MB")
+    assert line.endswith(" steps=2/2 fetches=1 bwd=fused resident=1.6MB")
     assert plan == (4, 3, 2)
+
+    q, v = (jax.ShapeDtypeStruct((1, 8192, 2, d), jnp.bfloat16)
+            for d in (192, 128))
+    jax.eval_shape(lambda a, b: attn.dot_product_attention(
+        a, a, b, causal=True, impl="pallas"), q, v)
+    line = capsys.readouterr().err.strip().splitlines()[-1]
+    assert " tiles=136/256 masked=16 steps=72/128 fetches=70 bwd=fused " \
+        in line
 
 
 CELLS = ["gpt2s-1chip-b16", "ling3f-1chip-ep64-s8k", "lagunas-1chip-ep32-w512",

@@ -151,12 +151,15 @@ def test_flash_attention_compiles_at_latent_attentions_head_dims(one_chip,
     ling3f-1chip-ep64-s8k): 192-deep scores (128 plain + 64 rotated dims,
     blocks whose last dim is one and a half lane widths) beside 128-deep
     values, at (2, 8192, 32): V and O block specs carry V's own head
-    dim."""
+    dim. Eight major blocks of 1024 a Q tile (`_MAJOR_BYTES` at D 192), so
+    K's and V's index maps are the clamped ones, forward and fused
+    backward: 72 of a head's 128 steps enter a tile, 70 blocks fetched."""
     sds = lambda d: jax.ShapeDtypeStruct(  # noqa: E731
         (2, 8192, 32, d), jnp.bfloat16, sharding=one_chip)
     q, k, v = sds(192), sds(192), sds(128)
     assert fa.supported(q, k, v, causal=True, mask=None)
     assert fa.call_plan(q, k, causal=True) == fa.TilePlan(256, 136, 16)
+    assert fa.call_fetch_plan(q, k, causal=True) == fa.FetchPlan(128, 72, 70)
     loss = functools.partial(_attn_loss, causal=True)
     fn = loss if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
     out = jax.eval_shape(functools.partial(fa.flash_attention, causal=True),
