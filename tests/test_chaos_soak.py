@@ -11,6 +11,9 @@ import sys
 
 import pytest
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

@@ -10,6 +10,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -38,6 +40,7 @@ def test_fails_in_a_directory_that_holds_nothing_else(tmp_path):
     assert '"ok"' not in r.stdout
 
 
+@pytest.mark.usefixtures("compile_cache_off")
 def test_tiny_rehearsal_runs_every_phase_and_never_says_ok():
     r = _run("--tiny")
     assert r.returncode == 3, (r.stdout[-3000:], r.stderr[-3000:])
@@ -53,5 +56,6 @@ def test_tiny_rehearsal_runs_every_phase_and_never_says_ok():
     assert phases["train"]["resumed_at"] == 6
     assert phases["train"]["last_loss"] < phases["train"]["first_loss"]
     assert phases["export_serve"]["requests"] == 4
-    # the suite's children run with the compile cache off (conftest)
+    # this test's children run with the compile cache off (conftest's
+    # compile_cache_off), as every child of the suite did before PR 44
     assert phases["total"]["cache_dir"] is None

@@ -222,7 +222,10 @@ def test_sliding_window_matches_explicit_mask():
     ids = jnp.asarray(rng.integers(0, 64, (1, 20)), jnp.int32)
     variables = train_model.init({"params": jax.random.PRNGKey(0)}, ids,
                                  train=False)
-    logits_full = train_model.apply(variables, ids, train=False)
+    # (one jitted program a length: op by op, every op of the model
+    # compiles again at each of the six lengths)
+    forward = jax.jit(lambda v, x: train_model.apply(v, x, train=False))
+    logits_full = forward(variables, ids)
     model = build_decode_model(cfg, PrecisionConfig())
     out = generate(model, variables["params"], ids, 6)
     # greedy continuation from the full forward's last logits agrees
@@ -230,8 +233,7 @@ def test_sliding_window_matches_explicit_mask():
     assert int(out[0, 20]) == nxt_full
     # and every single-token windowed decode step matches teacher forcing
     for i in range(1, 6):
-        logits_i = train_model.apply(variables, out[:, : 20 + i],
-                                     train=False)
+        logits_i = forward(variables, out[:, : 20 + i])
         assert int(out[0, 20 + i]) == int(jnp.argmax(logits_i[0, -1])), i
 
     from pytorch_distributed_train_tpu.ops.attention import (
@@ -264,15 +266,16 @@ def test_gpt2_sliding_window_decode_matches_full_forward():
     ids = jnp.asarray(rng.integers(0, 64, (1, 20)), jnp.int32)
     variables = train_model.init({"params": jax.random.PRNGKey(0)}, ids,
                                  train=False)
-    logits_full = train_model.apply(variables, ids, train=False)
+    # (one jitted program a length, as the llama test above)
+    forward = jax.jit(lambda v, x: train_model.apply(v, x, train=False))
+    logits_full = forward(variables, ids)
     model = build_decode_model(cfg, PrecisionConfig())
     out = generate(model, variables["params"], ids, 4)
     assert int(out[0, 20]) == int(jnp.argmax(logits_full[0, -1]))
     # every SINGLE-TOKEN decode step (the windowed cache mask) must agree
     # with a teacher-forced full forward over the growing sequence
     for i in range(1, 4):
-        logits_i = train_model.apply(variables, out[:, : 20 + i],
-                                     train=False)
+        logits_i = forward(variables, out[:, : 20 + i])
         assert int(out[0, 20 + i]) == int(jnp.argmax(logits_i[0, -1])), i
     # windowed != unwindowed (the band actually changes the computation)
     import dataclasses

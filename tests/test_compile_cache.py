@@ -1,7 +1,8 @@
 """The one rule for where the persistent compile cache lives
 (utils/compile_cache.py): JAX_COMPILATION_CACHE_DIR decides when set and
 no code sets another directory; unset, one fixed directory inside the
-checkout; the suite itself runs with the cache off (conftest)."""
+checkout; the suite itself runs on a cache of the run's own, which a test
+switches off for itself and its children (conftest)."""
 
 import os
 import subprocess
@@ -16,9 +17,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture()
-def cache_on(monkeypatch):
-    """The suite runs with the cache off; these tests are about it being
-    on. Every later jax.config.update is recorded, none is applied."""
+def cache_on(monkeypatch, compile_cache_off):
+    """Out of the run's own cache first (``compile_cache_off``); these tests
+    are about the flag being on and WHERE the cache then lives. Every later
+    jax.config.update is recorded, none is applied."""
     jax.config.update("jax_enable_compilation_cache", True)
     updates = []
     monkeypatch.setattr(jax.config, "update",
@@ -28,7 +30,24 @@ def cache_on(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
 
 
+def test_the_suite_runs_on_a_cache_of_the_runs_own():
+    """A fresh directory the process that started the run made, taken from
+    the environment by its workers and children; every program goes in."""
+    import tempfile
+
+    where = os.environ[compile_cache.ENV_VAR]
+    assert os.path.dirname(where) == tempfile.gettempdir()
+    assert os.path.isdir(where)
+    assert jax.config.jax_enable_compilation_cache is True
+    assert jax.config.jax_compilation_cache_dir == where
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+    assert compile_cache.enable("/a/trainers/preference") == where
+
+
+@pytest.mark.usefixtures("compile_cache_off")
 def test_suite_runs_with_the_cache_off():
+    """Under ``compile_cache_off``, as the whole suite ran before PR 44."""
     assert jax.config.jax_enable_compilation_cache is False
     assert os.environ["JAX_ENABLE_COMPILATION_CACHE"] == "false"
     assert compile_cache.enable("/anywhere") is None

@@ -10,6 +10,8 @@ import json
 import os
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
@@ -45,12 +47,14 @@ def test_same_seed_same_losses(tmp_path):
     assert _train_losses(c) != la  # different seed diverges
 
 
+@pytest.mark.usefixtures("compile_cache_off")
 def test_compile_cache_knob(tmp_path, monkeypatch):
     import jax
 
     cache = f"{tmp_path}/xla_cache"
     prev = jax.config.jax_compilation_cache_dir
-    # the suite runs with the cache off (conftest); this test is about it
+    # (out of the run's own cache first: conftest's compile_cache_off);
+    # this test is about the knob reaching JAX
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     jax.config.update("jax_enable_compilation_cache", True)
     try:

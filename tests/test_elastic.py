@@ -8,7 +8,12 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from pytorch_distributed_train_tpu.elastic import ElasticAgent, LaunchConfig
+
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

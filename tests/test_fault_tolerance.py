@@ -15,6 +15,9 @@ import numpy as np
 import pytest
 from tiny import TINY
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CPU_ENV = {

@@ -17,6 +17,9 @@ from pytorch_distributed_train_tpu.faults import registry as fregistry
 from pytorch_distributed_train_tpu.faults.preemption import PreemptionHandler
 from pytorch_distributed_train_tpu.obs.registry import get_registry
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 
 @pytest.fixture(autouse=True)
 def _clean_schedule(monkeypatch):

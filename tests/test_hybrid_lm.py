@@ -4,54 +4,36 @@ which imports nothing of the program): the router, each mixer and the whole
 model on seeded weights at tiny sizes; no token dropped; the shares of an
 expert-parallel layer add up to the uncut layer."""
 
-import importlib.util
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from lm_family import close as _close
+from lm_family import exact_products  # noqa: F401 - autouse here
+from lm_family import (
+    decay_mask,
+    family,
+    logits_match_the_reference,
+    preset_tree,
+    rehearsal_cfg,
+    sweep_is_the_whole_models_gradient,
+    train_state,
+)
 
-from pytorch_distributed_train_tpu.config import get_preset
 from pytorch_distributed_train_tpu.models import hybrid
 from pytorch_distributed_train_tpu.models.llama import LlamaMLP
-from pytorch_distributed_train_tpu.models.registry import build_model
 from pytorch_distributed_train_tpu.ops import moe
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
+LING3 = "ling3_flash_lm_ep64"
 F32 = jnp.float32
-
-
-@pytest.fixture(autouse=True)
-def _exact_products():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 @pytest.fixture(scope="module")
 def bench():
     """(configuration file, its Reference at the rehearsal's sizes, the
     program's config at the same sizes)."""
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    with open(os.path.join(BENCH, "configs", "ling3_flash_lm_ep64.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "ling3_reference", os.path.join(BENCH, "references",
-                                        config["reference"] + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    cfg = get_preset(config["preset"])
-    cfg.apply_overrides(config["rehearsal_overrides"])
-    return config, mod.Reference(config, rehearsal=True), cfg
-
-
-def _close(a, b, tol=2e-5):
-    scale = float(jnp.max(jnp.abs(b))) + 1e-30
-    assert float(jnp.max(jnp.abs(a - b))) < tol * scale
+    fam = family(LING3)
+    return fam.config, fam.ref, fam.cfg
 
 
 def _spec(**kw):
@@ -298,26 +280,19 @@ def test_held_rows_at_the_all_latent_cells_shape_holds_no_loop_and_no_sort():
 
 def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
     from pytorch_distributed_train_tpu import losses, steps
-    from pytorch_distributed_train_tpu.optim import make_optimizer
-    from pytorch_distributed_train_tpu.train_state import TrainState
+    from pytorch_distributed_train_tpu.models.registry import build_model
 
-    cfg = get_preset("ling3_flash_lm_ep64")
-    with open(os.path.join(BENCH, "configs",
-                           "ling3_flash_lm_ep64.json")) as f:
-        cfg.apply_overrides(json.load(f)["rehearsal_overrides"])
     # one dense and one routed layer are all this contract needs: two
     # whole steps compile here, and a third layer is a third of each
-    cfg.apply_overrides(["model.num_layers=2", "model.layer_group_size=2"])
+    cfg = rehearsal_cfg(LING3, "model.num_layers=2",
+                        "model.layer_group_size=2")
     ids = jax.random.randint(jax.random.PRNGKey(4), (2, 32), 0, 256)
     # (a step inside its bound reporting 0 is the benchmark rehearsal's
     # `failed` 0: tests/benchmark/test_bench_rehearsal_ling3.py)
     cfg.model.expert_capacity_factor = 0.05
     model = build_model(cfg.model, cfg.precision)
-    tx, _ = make_optimizer(cfg.optim, 10, 0)
-    params = model.init({"params": jax.random.PRNGKey(5)}, ids,
-                        train=False)["params"]
-    state = TrainState.create(params=params, tx=tx, batch_stats={},
-                              dynamic_scale=None, ema=False, swa=False)
+    tx, state = train_state(cfg, jax.jit(lambda key: model.init(
+        {"params": key}, ids, train=False)["params"])(jax.random.PRNGKey(5)))
     step = steps.make_train_step(model, losses.get_loss_fn(cfg.loss), tx)
     new, metrics = jax.jit(step)(state, {"input_ids": ids},
                                  jax.random.PRNGKey(6))
@@ -518,83 +493,29 @@ def test_the_programs_map_names_the_shaping_on_both_paths(path, monkeypatch):
     assert all("/KDAMixer/" in op or "kda_inputs" in op for op in under)
 
 
-def test_model_logits_match_the_reference_on_its_seeded_weights(bench):
-    _, ref, cfg = bench
-    model = build_model(cfg.model, cfg.precision)
-    params = ref.init_variables(17)["params"]
-    ids = jax.random.randint(jax.random.PRNGKey(18), (2, 128), 0,
-                             cfg.model.vocab_size)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
-    sig = lambda t: [(jax.tree_util.keystr(k), v.shape, str(v.dtype))  # noqa: E731
-                     for k, v in jax.tree_util.tree_flatten_with_path(t)[0]]
-    assert sig(shapes) == sig(params)  # names and shapes are the interface
-    got = model.apply({"params": params}, ids, train=False)
-    want = jnp.stack([ref._logits(params, ids[b], lambda t: t)[0]
-                      for b in range(2)])
-    _close(got, want)
+def test_model_logits_match_the_reference_on_its_seeded_weights():
+    params = logits_match_the_reference(LING3)
     # the layer pattern: KDA, KDA, then latent attention; dense FFN first
     assert [("mla" in params[f"layer{i}"], "moe" in params[f"layer{i}"])
             for i in range(3)] == [(False, False), (False, True),
                                    (True, True)]
 
 
-def test_the_references_layer_by_layer_sweep_is_the_whole_models_gradient(
-        bench):
-    """``follow`` takes the backward pass a layer at a time from the host,
-    with programs shared by the layers of one kind; the same model in one
-    piece under ``jax.grad`` gives the same loss and the same gradient,
-    leaf by leaf (the router's bias gets none in either)."""
-    _, ref, cfg = bench
-    params = ref.init_variables(23)["params"]
-    ids = jax.random.randint(jax.random.PRNGKey(24), (2, 64), 0,
-                             cfg.model.vocab_size)
-
-    def loss(p):
-        total = 0.0
-        for row in ids:
-            logp = jax.nn.log_softmax(
-                ref._logits(p, row, lambda t: t)[0][:-1], -1)
-            total -= jnp.sum(jnp.take_along_axis(logp, row[1:, None], -1))
-        return total
-
-    want_loss, want = jax.value_and_grad(loss)(params)
-    got_loss, got, chosen = ref._sweep("float32", params, ids, True)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-4 * float(want_loss)
-    assert chosen.shape == (2, 2, 64, 4)  # routed layers, rows, S, held
-    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
-                      jax.tree_util.tree_flatten_with_path(t)[0]}
-    got, want = flat(got), flat(want)
-    assert set(got) == set(want)
-    for leaf, w in want.items():
-        if leaf.endswith("['router']['bias']"):
-            assert not np.any(np.asarray(got[leaf])) and not np.any(
-                np.asarray(w))
-        else:
-            _close(got[leaf], w, tol=1e-4)
+def test_the_references_layer_by_layer_sweep_is_the_whole_models_gradient():
+    # routed layers, rows, S, held
+    sweep_is_the_whole_models_gradient(LING3, chosen_shape=(2, 2, 64, 4))
 
 
 def test_preset_counts_decay_mask_flops_and_partition_rules():
-    from pytorch_distributed_train_tpu.optim import decay_mask_fn
     from pytorch_distributed_train_tpu.parallel.partition import (
         P,
         rules_for_model,
     )
     from pytorch_distributed_train_tpu.utils import flops
 
-    cfg = get_preset("ling3_flash_lm_ep64")
-    model = build_model(cfg.model, cfg.precision)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)},
-        jnp.zeros((1, 64), jnp.int32), train=False)["params"])
-    count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
+    cfg, _, shapes, count = preset_tree(LING3)
     assert count == 714_990_240  # ISSUE 26's table: 714.99 M, 11.44 GB
-    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
-    flat = {jax.tree_util.keystr(k): v for k, v in
-            jax.tree_util.tree_flatten_with_path(mask)[0]}
-    for leaf, decayed in flat.items():
-        plain = leaf.endswith("['kernel']") or leaf.endswith("['embedding']")
-        assert decayed == plain, leaf
+    flat = decay_mask(cfg, shapes)
     assert not flat["['layer1']['moe']['router']['bias']"]
     assert not flat["['layer0']['kda']['q_conv']"]
     assert not flat["['layer0']['kda']['A_log']"]

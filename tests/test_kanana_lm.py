@@ -9,49 +9,28 @@ to the model: the expert shares' routed parts, the shared experts counted
 once, add up to the uncut reference's layer."""
 
 import dataclasses
-import importlib.util
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from lm_family import PLAIN, decay_mask, family, preset_tree
+from lm_family import close as _close
+from lm_family import exact_products  # noqa: F401 - autouse here
+from lm_family import load as _load
+from lm_family import logits_and_gradients_match_the_reference
+from lm_family import train_state as _state
 
 from pytorch_distributed_train_tpu import losses, steps
-from pytorch_distributed_train_tpu.config import get_preset
 from pytorch_distributed_train_tpu.models import hybrid
 from pytorch_distributed_train_tpu.models.llama import LlamaMLP
 from pytorch_distributed_train_tpu.models.registry import build_model
 from pytorch_distributed_train_tpu.ops import moe
 from pytorch_distributed_train_tpu.optim import make_optimizer
-from pytorch_distributed_train_tpu.train_state import TrainState
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
+KANANA = "kanana2_lm_ep8"
 F32 = jnp.float32
-PLAIN = lambda t: t  # noqa: E731 - the reference's float32 rounder
 RATE = 1e-3
-
-
-@pytest.fixture(autouse=True)
-def _exact_products():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def _load():
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    with open(os.path.join(BENCH, "configs", "kanana2_lm_ep8.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "kanana_reference", os.path.join(BENCH, "references",
-                                         config["reference"] + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return config, mod
 
 
 @pytest.fixture(scope="module")
@@ -59,15 +38,8 @@ def bench():
     """(configuration file, its Reference at the rehearsal's sizes: 4 of 16
     experts, 3 a token, two shared experts of 32; the program's config at
     the same sizes)."""
-    config, mod = _load()
-    cfg = get_preset(config["preset"])
-    cfg.apply_overrides(config["rehearsal_overrides"])
-    return config, mod.Reference(config, rehearsal=True), cfg
-
-
-def _close(a, b, tol=2e-5):
-    scale = float(jnp.max(jnp.abs(b))) + 1e-30
-    assert float(jnp.max(jnp.abs(a - b))) < tol * scale
+    fam = family(KANANA)
+    return fam.config, fam.ref, fam.cfg
 
 
 def _mla(m, **kw):
@@ -77,12 +49,6 @@ def _mla(m, **kw):
         m.num_heads, m.head_dim, m.rope_head_dim, m.kv_lora_rank,
         m.rope_theta, m.max_seq_len, m.rms_norm_eps, F32, F32,
         **{**form, **kw})
-
-
-def _state(cfg, params, steps_total=10):
-    tx, _ = make_optimizer(cfg.optim, steps_total, 0)
-    return tx, TrainState.create(params=params, tx=tx, batch_stats={},
-                                 dynamic_scale=None, ema=False, swa=False)
 
 
 def _biases(params):
@@ -156,7 +122,7 @@ def test_the_expert_shares_add_up_to_the_uncut_references_layer(bench):
     of 4: latent attention (every head on every chip), then each share's
     routed part of the expert layer, the shared experts (one SwiGLU of
     twice the routed width) counted ONCE."""
-    config, mod = _load()
+    config, mod = _load(KANANA)
     whole = dict(config)
     whole["rehearsal"] = {**config["rehearsal"], "n_routed_experts": 16}
     ref = mod.Reference(whole, rehearsal=True)
@@ -201,51 +167,10 @@ def test_the_expert_shares_add_up_to_the_uncut_references_layer(bench):
 
 # ------------------------------------------------- the whole model, the step
 
-@pytest.fixture(scope="module")
-def built(bench):
-    """The program's model at the rehearsal's sizes, the reference's seeded
-    parameters, one batch."""
-    _, ref, cfg = bench
-    model = build_model(cfg.model, cfg.precision)
-    params = ref.init_variables(17)["params"]
-    ids = jax.random.randint(jax.random.PRNGKey(18), (2, 128), 0,
-                             cfg.model.vocab_size)
-    return model, params, ids
-
-
-def test_model_logits_and_gradients_match_the_reference(bench, built):
-    _, ref, cfg = bench
-    model, params, ids = built
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
-    sig = lambda t: [(jax.tree_util.keystr(k), v.shape, str(v.dtype))  # noqa: E731
-                     for k, v in jax.tree_util.tree_flatten_with_path(t)[0]]
-    assert sig(shapes) == sig(params)  # names and shapes are the interface
-
-    def loss(p):
-        logits, sown = model.apply(
-            {"params": p}, ids, train=True,
-            mutable=["step_metrics", "router_load"])
-        logp = jax.nn.log_softmax(logits[:, :-1], -1)
-        return -jnp.sum(jnp.take_along_axis(
-            logp, ids[:, 1:, None], -1)), (logits, sown)
-
-    (got_loss, (logits, sown)), got = jax.jit(jax.value_and_grad(
-        loss, has_aux=True))(params)
-    one_row = jax.jit(lambda row: ref._logits(params, row, PLAIN)[0])
-    _close(logits, jnp.stack([one_row(ids[b]) for b in range(2)]))
-    # the reference's layer-by-layer sweep: the loss and every gradient
-    want_loss, grads, chosen = ref._sweep("float32", params, ids, True)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-5 * float(want_loss)
-    assert chosen.shape == (2, 2, 128, 16)  # layers, rows, S, ALL outputs
-    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
-                      jax.tree_util.tree_flatten_with_path(t)[0]}
-    got, grads = flat(got), flat(grads)
-    assert set(got) == set(grads)
-    for leaf, w in grads.items():
-        _close(got[leaf], w, tol=2e-4)
-        if leaf.endswith("['router']['bias']"):  # no gradient reaches it
-            assert float(jnp.max(jnp.abs(got[leaf]))) == 0.0
+def test_model_logits_and_gradients_match_the_reference():
+    sown, chosen = logits_and_gradients_match_the_reference(
+        KANANA, mutable=["step_metrics", "router_load"],
+        chosen_shape=(2, 2, 128, 16))  # layers, rows, S, ALL outputs
     # the routers' load, a layer's counts where its `router` sits
     assert set(sown["step_metrics"]) == {
         "moe_rows_fullest", "moe_rows_mean", "moe_rows_over_bound",
@@ -256,13 +181,13 @@ def test_model_logits_and_gradients_match_the_reference(bench, built):
             np.asarray(jnp.sum(chosen[i], (0, 1))))
 
 
-def test_three_steps_with_the_bias_update_match_the_reference(bench, built):
+def test_three_steps_with_the_bias_update_match_the_reference(bench):
     """Three AdamW steps from the seeded weights through the program's own
     train step against the reference's ``follow``: each loss, every bias
     entry after every step (its sign from that step's counts), the other
     leaves' change; and the step's metrics of the update."""
     _, ref, cfg = bench
-    model, _, _ = built
+    model = family(KANANA).model
     batches = ref.make_batches(17, {"rehearsal_batch": 2, "seq_len": 128}, 3)
     want = ref.follow(17, batches)
     tx, state = _state(cfg, ref.init_variables(17)["params"])
@@ -399,7 +324,6 @@ def test_the_update_over_a_split_batch_is_the_whole_batchs(two_layers, path):
 # ------------------------------------------------------------- the preset
 
 def test_preset_builds_its_share_counts_flops_decay_mask_and_lines(capfd):
-    from pytorch_distributed_train_tpu.optim import decay_mask_fn
     from pytorch_distributed_train_tpu.parallel.partition import (
         P,
         rules_for_model,
@@ -408,11 +332,7 @@ def test_preset_builds_its_share_counts_flops_decay_mask_and_lines(capfd):
 
     hybrid._built_logged.clear()
     moe._moe_logged.clear()
-    cfg = get_preset("kanana2_lm_ep8")
-    model = build_model(cfg.model, cfg.precision)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)},
-        jnp.zeros((1, 64), jnp.int32), train=False)["params"])
+    cfg, _, shapes, count = preset_tree(KANANA)
     err = capfd.readouterr().err.splitlines()
     assert next(ln for ln in err if ln.startswith("[hybrid]")) == (
         "[hybrid] layers=6 kinds=mla,mla,mla,mla,mla,mla "
@@ -422,7 +342,6 @@ def test_preset_builds_its_share_counts_flops_decay_mask_and_lines(capfd):
         "[moe] experts=128 held=16 ids=0-15 top_k=6 groups=1/1 "
         "score=sigmoid tokens=64 row_bound=192 shared=1536 bias_rate=0.001")
     assert hybrid.MixerVariants().mla_form == "mla=normed+gated rope=halves"
-    count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
     # a mixer 26.35 M, the dense layer 64.10 M, an expert layer 111.55 M,
     # embedding and head 65.67 M: 11.0 GB at 16 B a parameter
     assert count == 687_502_976
@@ -430,12 +349,7 @@ def test_preset_builds_its_share_counts_flops_decay_mask_and_lines(capfd):
         == (2048, 1536)
     assert shapes["layer1"]["moe"]["experts"]["up_proj"]["kernel"].shape \
         == (16, 2048, 768)
-    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
-    flat = {jax.tree_util.keystr(k): v for k, v in
-            jax.tree_util.tree_flatten_with_path(mask)[0]}
-    for leaf, decayed in flat.items():
-        plain = leaf.endswith("['kernel']") or leaf.endswith("['embedding']")
-        assert decayed == plain, leaf
+    decay_mask(cfg, shapes)
     # what this chip computes a token, by hand: six latent mixers (q, the
     # latent and k_pe, [k_nope | v], o; the un-masked scores and values),
     # the dense FFN, five expert layers (router 128 wide, the shared

@@ -6,17 +6,24 @@ benchmark keeps (benchmark/references/laguna_s_lm_ep32.py, which imports
 nothing of the program); and the other hybrid preset, whose parameter tree
 and lowered step this work must not move."""
 
-import hashlib
-import importlib.util
 import json
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from lm_family import close as _close
+from lm_family import exact_products  # noqa: F401 - autouse here
+from lm_family import (
+    decay_mask,
+    family,
+    load,
+    logits_match_the_reference,
+    preset_tree,
+    sweep_is_the_whole_models_gradient,
+    tree_and_lowered_step_are_the_parents,
+)
 
 from pytorch_distributed_train_tpu.config import get_preset
 from pytorch_distributed_train_tpu.models import hybrid
@@ -27,41 +34,19 @@ from pytorch_distributed_train_tpu.models.llama import (
 from pytorch_distributed_train_tpu.models.registry import build_model
 from pytorch_distributed_train_tpu.ops import moe
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
+LAGUNA = "laguna_s_lm_ep32"
 F32 = jnp.float32
 YARN = dict(scaling=128.0, scaling_type="yarn", original_max_len=8192,
             beta_fast=32.0, beta_slow=1.0,
             attention_factor=1.4852030263919618)
 
 
-@pytest.fixture(autouse=True)
-def _exact_products():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
 @pytest.fixture(scope="module")
 def bench():
     """(configuration file, its Reference at the rehearsal's sizes, the
     program's config at the same sizes)."""
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    with open(os.path.join(BENCH, "configs", "laguna_s_lm_ep32.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "laguna_reference", os.path.join(BENCH, "references",
-                                         config["reference"] + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    cfg = get_preset(config["preset"])
-    cfg.apply_overrides(config["rehearsal_overrides"])
-    return config, mod.Reference(config, rehearsal=True), cfg
-
-
-def _close(a, b, tol=2e-5):
-    scale = float(jnp.max(jnp.abs(b))) + 1e-30
-    assert float(jnp.max(jnp.abs(a - b))) < tol * scale
+    fam = family(LAGUNA)
+    return fam.config, fam.ref, fam.cfg
 
 
 # ------------------------------------------------ the rotation, by hand
@@ -248,21 +233,8 @@ def test_softmax_router_has_no_bias_and_matches_the_reference(bench):
 
 # ------------------------------------------------------ the whole model
 
-def test_model_logits_match_the_reference_on_its_seeded_weights(bench):
-    _, ref, cfg = bench
-    model = build_model(cfg.model, cfg.precision)
-    params = ref.init_variables(17)["params"]
-    ids = jax.random.randint(jax.random.PRNGKey(18), (2, 128), 0,
-                             cfg.model.vocab_size)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
-    sig = lambda t: [(jax.tree_util.keystr(k), v.shape, str(v.dtype))  # noqa: E731
-                     for k, v in jax.tree_util.tree_flatten_with_path(t)[0]]
-    assert sig(shapes) == sig(params)  # names and shapes are the interface
-    got = model.apply({"params": params}, ids, train=False)
-    want = jnp.stack([ref._logits(params, ids[b], lambda t: t)[0]
-                      for b in range(2)])
-    _close(got, want)
+def test_model_logits_match_the_reference_on_its_seeded_weights():
+    params = logits_match_the_reference(LAGUNA)
     # the layer pattern: full with the dense FFN, window and full with experts
     assert [sorted(set(params[f"layer{i}"]) - {"input_norm", "post_attn_norm"})
             for i in range(3)] == [["gqa", "mlp"], ["moe", "swa"],
@@ -272,57 +244,23 @@ def test_model_logits_match_the_reference_on_its_seeded_weights(bench):
 
 def test_the_references_layer_by_layer_sweep_is_the_whole_models_gradient(
         bench):
-    """``follow`` takes the backward pass a layer at a time from the host,
-    with programs shared by the layers of one kind (three kinds); the same
-    model in one piece under ``jax.grad`` gives the same loss and the same
-    gradient, leaf by leaf."""
-    _, ref, cfg = bench
+    """Programs shared by the layers of one kind: three kinds here."""
+    _, ref, _ = bench
     assert len({ref.kind(i) for i in range(ref.L)}) == 3
-    params = ref.init_variables(23)["params"]
-    ids = jax.random.randint(jax.random.PRNGKey(24), (2, 64), 0,
-                             cfg.model.vocab_size)
-
-    def loss(p):
-        total = 0.0
-        for row in ids:
-            logp = jax.nn.log_softmax(
-                ref._logits(p, row, lambda t: t)[0][:-1], -1)
-            total -= jnp.sum(jnp.take_along_axis(logp, row[1:, None], -1))
-        return total
-
-    want_loss, want = jax.value_and_grad(loss)(params)
-    got_loss, got, chosen = ref._sweep("float32", params, ids, True)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-4 * float(want_loss)
-    assert chosen.shape == (2, 2, 64, 4)  # routed layers, rows, S, held
-    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
-                      jax.tree_util.tree_flatten_with_path(t)[0]}
-    got, want = flat(got), flat(want)
-    assert set(got) == set(want)
-    for leaf, w in want.items():
-        _close(got[leaf], w, tol=1e-4)
+    # routed layers, rows, S, held
+    sweep_is_the_whole_models_gradient(LAGUNA, chosen_shape=(2, 2, 64, 4))
 
 
 def test_preset_counts_decay_mask_flops_and_partition_rules():
-    from pytorch_distributed_train_tpu.optim import decay_mask_fn
     from pytorch_distributed_train_tpu.parallel.partition import (
         P,
         rules_for_model,
     )
     from pytorch_distributed_train_tpu.utils import flops
 
-    cfg = get_preset("laguna_s_lm_ep32")
-    model = build_model(cfg.model, cfg.precision)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)},
-        jnp.zeros((1, 64), jnp.int32), train=False)["params"])
-    count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
+    cfg, _, shapes, count = preset_tree(LAGUNA)
     assert count == 811_017_216  # ISSUE 30's table: 811.0 M, 12.98 GB
-    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
-    flat = {jax.tree_util.keystr(k): v for k, v in
-            jax.tree_util.tree_flatten_with_path(mask)[0]}
-    for leaf, decayed in flat.items():
-        plain = leaf.endswith("['kernel']") or leaf.endswith("['embedding']")
-        assert decayed == plain, leaf
+    decay_mask(cfg, shapes)
     # a token trained: 3 x forward, the band's pairs only. By hand, forward:
     # full mixer 4*3072*48*128 + 4*3072*8*128 + 2*3072*48 + 4*48*128*4096.5
     #   = 189 050 880; window mixer (72 heads, 496.03 pairs a token)
@@ -415,39 +353,11 @@ LAGUNA_STEP = "c0fcfc54e48377c78e2d9c47a811bb3c70cee17e23cc0bc6bfb0a3d38de79af3"
 ])
 def test_the_other_hybrid_presets_tree_and_lowered_step_are_the_parents(
         preset, leaves, want_tree, want_step):
-    from pytorch_distributed_train_tpu import losses, steps
-    from pytorch_distributed_train_tpu.optim import make_optimizer
-    from pytorch_distributed_train_tpu.train_state import TrainState
-
+    """The tree at the published sizes; the step at the benchmark
+    configuration's rehearsal sizes in bfloat16."""
     cfg = get_preset(preset)
     assert cfg.model.heads_held == 0 and cfg.model.kda_gate == "bounded"
-    model = build_model(cfg.model, cfg.precision)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 64), jnp.int32),
-        train=False)["params"])
-    sig = [(jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
-           for k, v in jax.tree_util.tree_flatten_with_path(shapes)[0]]
-    assert len(sig) == leaves
-    tree = hashlib.sha256(json.dumps(sig).encode()).hexdigest()
-    assert tree == want_tree, f"parameter tree moved: sha256 {tree}"
-
-    with open(os.path.join(BENCH, "configs", preset + ".json")) as f:
-        cfg.apply_overrides(json.load(f)["rehearsal_overrides"])
-    cfg.apply_overrides(["precision.compute_dtype=bfloat16"])
-    model = build_model(cfg.model, cfg.precision)
-    tx, _ = make_optimizer(cfg.optim, 10, 0)
-    ids = jnp.zeros((2, 128), jnp.int32)
-
-    def init(rng):
-        params = model.init({"params": rng}, ids, train=False)["params"]
-        return TrainState.create(params=params, tx=tx, batch_stats={},
-                                 dynamic_scale=None, ema=False, swa=False)
-
-    step = steps.make_train_step(model, losses.get_loss_fn(cfg.loss), tx)
-    with jax.default_matmul_precision("default"):  # as a run lowers it
-        text = jax.jit(step).lower(
-            jax.eval_shape(init, jax.random.PRNGKey(0)),
-            {"input_ids": jax.ShapeDtypeStruct((2, 128), jnp.int32)},
-            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
-    got = hashlib.sha256(text.encode()).hexdigest()
-    assert got == want_step, f"lowered step moved: sha256 {got}"
+    tree_and_lowered_step_are_the_parents(
+        cfg, [*load(preset)[0]["rehearsal_overrides"],
+              "precision.compute_dtype=bfloat16"],
+        leaves, want_tree, want_step)

@@ -12,12 +12,17 @@ P32 = PrecisionConfig()
 
 
 def _init_and_apply(model, *inputs, train=False):
-    rng = jax.random.PRNGKey(0)
-    variables = model.init({"params": rng}, *inputs, train=False)
-    mutable = ["batch_stats"] if "batch_stats" in variables else False
-    out = model.apply(variables, *inputs, train=train,
-                      rngs={"dropout": jax.random.PRNGKey(1)}, mutable=mutable)
-    return (out[0] if mutable else out), variables
+    """Init and one apply as ONE jitted program: op by op, every primitive
+    of every new shape compiles alone, which was most of this file's time."""
+    def run(rng):
+        variables = model.init({"params": rng}, *inputs, train=False)
+        mutable = ["batch_stats"] if "batch_stats" in variables else False
+        out = model.apply(variables, *inputs, train=train,
+                          rngs={"dropout": jax.random.PRNGKey(1)},
+                          mutable=mutable)
+        return (out[0] if mutable else out), variables
+
+    return jax.jit(run)(jax.random.PRNGKey(0))
 
 
 def test_registry_lists_all_families():

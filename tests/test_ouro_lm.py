@@ -6,61 +6,43 @@ over its uses; the loss's edges; remat; the head's kernels (interpreter)
 under per-token weights; the counts; and the guard that every other
 ``llama`` preset traces what the parent traced."""
 
-import hashlib
-import importlib.util
-import json
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from lm_family import close as _close
+from lm_family import (
+    decay_mask,
+    family,
+    preset_tree,
+    rehearsal_cfg,
+    tree_and_lowered_step_are_the_parents,
+)
 
-from pytorch_distributed_train_tpu import losses, steps
+from pytorch_distributed_train_tpu import losses
 from pytorch_distributed_train_tpu.config import get_preset
 from pytorch_distributed_train_tpu.models import llama
 from pytorch_distributed_train_tpu.models.registry import build_model
-from pytorch_distributed_train_tpu.optim import make_optimizer
-from pytorch_distributed_train_tpu.train_state import TrainState
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
+OURO = "ouro_2_6b_lm_l8"
 T, L, S = 3, 3, 128  # the rehearsal's: T differs from every other number
 
-
-def _exact_products():
-    return jax.default_matmul_precision("highest")
+# (not the autouse fixture of the other families' files: the trainers and
+# the lowered steps below run as a run lowers them)
+_exact_products = functools.partial(jax.default_matmul_precision, "highest")
 
 
 @pytest.fixture(scope="module")
 def bench():
     """(configuration file, Reference at the rehearsal's sizes, the program's
     model at the same sizes in float32, seeded variables, ids (2, S))."""
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    with open(os.path.join(BENCH, "configs", "ouro_2_6b_lm_l8.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "ouro_reference", os.path.join(BENCH, "references",
-                                       "ouro_2_6b_lm_l8.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    ref = mod.Reference(config, rehearsal=True)
-    assert (ref.T, ref.L) == (T, L)
-    cfg = get_preset(config["preset"])
-    cfg.apply_overrides(config["rehearsal_overrides"])
-    model = build_model(cfg.model, cfg.precision)
-    variables = ref.init_variables(7)
-    ids = jax.random.randint(jax.random.PRNGKey(3), (2, S), 0, ref.V)
-    return config, mod, ref, cfg, model, variables, ids
-
-
-def _close(a, b, tol=2e-5):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    scale = max(np.abs(b).max(), 1e-30)
-    assert np.abs(a - b).max() <= tol * scale, \
-        (np.abs(a - b).max() / scale, tol)
+    fam = family(OURO)
+    assert (fam.ref.T, fam.ref.L) == (T, L)
+    ids = jax.random.randint(jax.random.PRNGKey(3), (2, S), 0, fam.ref.V)
+    return (fam.config, fam.mod, fam.ref, fam.cfg, fam.model,
+            fam.ref.init_variables(7), ids)
 
 
 def _program_loss(model, params, ids, **kw):
@@ -87,8 +69,7 @@ def test_the_sandwich_alone_is_one_pass_of_the_reference(bench):
     """``sandwich_norm`` without the loop: plain logits from a tree with four
     scales a layer and no gate, the reference's FIRST exit."""
     config, _, ref, cfg, _, variables, ids = bench
-    one = get_preset(config["preset"])
-    one.apply_overrides(config["rehearsal_overrides"] + ["model.loop_steps=1"])
+    one = rehearsal_cfg(OURO, "model.loop_steps=1")
     model = build_model(one.model, one.precision)
     params = {k: v for k, v in variables["params"].items()
               if k != "exit_gate"}
@@ -297,18 +278,12 @@ def test_preset_counts_flops_decay_mask_and_partition_rules():
     causal half of the attention term, ISSUE 35's count); norm scales and
     the gate's bias do not decay, the gate's matrix does; the new leaves
     are replicated under the ``llama`` rules."""
-    from pytorch_distributed_train_tpu.optim import decay_mask_fn
     from pytorch_distributed_train_tpu.parallel.partition import (
         rules_for_model,
     )
     from pytorch_distributed_train_tpu.utils import flops
 
-    cfg = get_preset("ouro_2_6b_lm_l8")
-    model = build_model(cfg.model, cfg.precision)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 64), jnp.int32),
-        train=False)["params"])
-    count = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    cfg, _, shapes, count = preset_tree(OURO)
     assert count == 612438017 == flops.llama_param_count(cfg.model)
     assert set(shapes) == {"exit_gate", "final_norm", "lm_head",
                            "tok_embed"} | {f"layer{i}" for i in range(8)}
@@ -323,9 +298,7 @@ def test_preset_counts_flops_decay_mask_and_partition_rules():
     causal = 3 * (fwd - 4 * 8 * 2.0 * s * d)  # half the un-masked pairs
     assert 13.8e9 < causal < 14.0e9
 
-    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
-    flat = {jax.tree_util.keystr(k): v for k, v in
-            jax.tree_util.tree_flatten_with_path(mask)[0]}
+    flat = decay_mask(cfg, shapes)
     assert flat["['exit_gate']['kernel']"] and not flat["['exit_gate']['bias']"]
     assert not flat["['layer0']['attn_out_norm']['scale']"]
     assert not flat["['layer7']['mlp_out_norm']['scale']"]
@@ -373,33 +346,9 @@ def test_the_other_llama_presets_tree_and_lowered_step_are_the_parents(
     """The loop, the sandwich and the exits are fields with defaults on the
     one decoder: a preset that sets none of them builds the parent's tree
     and lowers the parent's training step, byte for byte."""
-    leaves, want_tree, want_step = PARENTS[preset]
     cfg = get_preset(preset.split(":")[0])
     cfg.apply_overrides(OVERRIDES[preset])
-    model = build_model(cfg.model, cfg.precision)
-    ids = jnp.zeros((2, 128), jnp.int32)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
-    sig = [(jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
-           for k, v in jax.tree_util.tree_flatten_with_path(shapes)[0]]
-    assert len(sig) == leaves
-    tree = hashlib.sha256(json.dumps(sig).encode()).hexdigest()
-    assert tree == want_tree, f"parameter tree moved: sha256 {tree}"
-    tx, _ = make_optimizer(cfg.optim, 10, 0)
-
-    def init(rng):
-        params = model.init({"params": rng}, ids, train=False)["params"]
-        return TrainState.create(params=params, tx=tx, batch_stats={},
-                                 dynamic_scale=None, ema=False, swa=False)
-
-    step = steps.make_train_step(model, losses.get_loss_fn(cfg.loss), tx)
-    with jax.default_matmul_precision("default"):  # as a run lowers it
-        text = jax.jit(step).lower(
-            jax.eval_shape(init, jax.random.PRNGKey(0)),
-            {"input_ids": jax.ShapeDtypeStruct((2, 128), jnp.int32)},
-            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
-    got = hashlib.sha256(text.encode()).hexdigest()
-    assert got == want_step, f"lowered step moved: sha256 {got}"
+    tree_and_lowered_step_are_the_parents(cfg, [], *PARENTS[preset])
 
 
 def test_replicas_reduce_each_looped_weight_once_and_train_as_one_device(

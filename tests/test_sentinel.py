@@ -35,6 +35,9 @@ from pytorch_distributed_train_tpu.sentinel.numeric import (
     scale_cooldown,
 )
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 CPU_ENV = {
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=1",

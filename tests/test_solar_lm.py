@@ -7,15 +7,14 @@ the program) on seeded weights at tiny sizes; and the share tied to the
 model: the head shares' mixer outputs add up to the whole mixer's, and with
 the expert shares (the shared expert counted once) to the uncut layer."""
 
-import importlib.util
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
+from lm_family import PLAIN, decay_mask, family, preset_tree
+from lm_family import close as _close
+from lm_family import exact_products  # noqa: F401 - autouse here
+from lm_family import load as _load
+from lm_family import logits_and_gradients_match_the_reference
 
 from pytorch_distributed_train_tpu.config import get_preset
 from pytorch_distributed_train_tpu.models import hybrid
@@ -23,30 +22,8 @@ from pytorch_distributed_train_tpu.models.llama import LlamaMLP
 from pytorch_distributed_train_tpu.models.registry import build_model
 from pytorch_distributed_train_tpu.ops import moe
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
+SOLAR = "solar_open2_lm_ep40_tp8"
 F32 = jnp.float32
-PLAIN = lambda t: t  # noqa: E731 - the reference's float32 rounder
-
-
-@pytest.fixture(autouse=True)
-def _exact_products():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def _load():
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    with open(os.path.join(BENCH, "configs",
-                           "solar_open2_lm_ep40_tp8.json")) as f:
-        config = json.load(f)
-    spec = importlib.util.spec_from_file_location(
-        "solar_reference", os.path.join(BENCH, "references",
-                                        config["reference"] + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return config, mod
 
 
 @pytest.fixture(scope="module")
@@ -54,10 +31,8 @@ def bench():
     """(configuration file, its Reference at the rehearsal's sizes: 4 of 8
     heads, 1 of 2 KV heads, 4 of 32 experts; the program's config at the
     same sizes)."""
-    config, mod = _load()
-    cfg = get_preset(config["preset"])
-    cfg.apply_overrides(config["rehearsal_overrides"])
-    return config, mod.Reference(config, rehearsal=True), cfg
+    fam = family(SOLAR)
+    return fam.config, fam.ref, fam.cfg
 
 
 @pytest.fixture(scope="module")
@@ -65,7 +40,7 @@ def uncut():
     """The same model WHOLE at the rehearsal's widths: 8 heads over 2 KV
     heads, all 32 experts held (the reference given the published counts'
     stand-ins), beside its parameters from a seed."""
-    config, mod = _load()
+    config, mod = _load(SOLAR)
     whole = dict(config)
     small = dict(config["rehearsal"])
     small.update(num_attention_heads=8, num_key_value_heads=2,
@@ -75,11 +50,6 @@ def uncut():
     whole["rehearsal"] = small
     ref = mod.Reference(whole, rehearsal=True)
     return ref, ref.init_variables(5)["params"]
-
-
-def _close(a, b, tol=2e-5):
-    scale = float(jnp.max(jnp.abs(b))) + 1e-30
-    assert float(jnp.max(jnp.abs(a - b))) < tol * scale
 
 
 def _kda(m, **kw):
@@ -275,39 +245,11 @@ def test_head_and_expert_shares_add_up_to_the_uncut_layer(bench, uncut,
 
 # ------------------------------------------------- the whole model, the preset
 
-def test_model_logits_and_gradients_match_the_reference(bench):
-    _, ref, cfg = bench
-    model = build_model(cfg.model, cfg.precision)
-    params = ref.init_variables(17)["params"]
-    ids = jax.random.randint(jax.random.PRNGKey(18), (2, 128), 0,
-                             cfg.model.vocab_size)
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, ids, train=False)["params"])
-    sig = lambda t: [(jax.tree_util.keystr(k), v.shape, str(v.dtype))  # noqa: E731
-                     for k, v in jax.tree_util.tree_flatten_with_path(t)[0]]
-    assert sig(shapes) == sig(params)  # names and shapes are the interface
-
-    def loss(p):
-        logits, sown = model.apply({"params": p}, ids, train=True,
-                                   mutable=["step_metrics"])
-        logp = jax.nn.log_softmax(logits[:, :-1], -1)
-        return -jnp.sum(jnp.take_along_axis(
-            logp, ids[:, 1:, None], -1)), (logits, sown["step_metrics"])
-
-    (got_loss, (logits, sown)), got = jax.value_and_grad(
-        loss, has_aux=True)(params)
-    one_row = jax.jit(lambda row: ref._logits(params, row, PLAIN)[0])
-    _close(logits, jnp.stack([one_row(ids[b]) for b in range(2)]))
-    # the reference's layer-by-layer sweep: the loss and every gradient
-    want_loss, grads, chosen = ref._sweep("float32", params, ids, True)
-    assert abs(float(got_loss) - float(want_loss)) < 1e-5 * float(want_loss)
-    assert chosen.shape == (2, 2, 128, 4)  # layers, rows, S, held experts
-    flat = lambda t: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
-                      jax.tree_util.tree_flatten_with_path(t)[0]}
-    got, grads = flat(got), flat(grads)
-    assert set(got) == set(grads)
-    for leaf, w in grads.items():
-        _close(got[leaf], w, tol=2e-4)
+def test_model_logits_and_gradients_match_the_reference():
+    sown, _ = logits_and_gradients_match_the_reference(
+        SOLAR, mutable=["step_metrics"],
+        chosen_shape=(2, 2, 128, 4))  # layers, rows, S, held experts
+    sown = sown["step_metrics"]
     # both counters of the unbounded gate, beside the expert layers' rows
     assert {"kda_log_decay_min", "kda_beta_max", "moe_rows_fullest",
             "update_invalid"} <= set(sown)
@@ -316,7 +258,6 @@ def test_model_logits_and_gradients_match_the_reference(bench):
 
 
 def test_preset_builds_its_share_counts_flops_decay_mask_and_rules(capfd):
-    from pytorch_distributed_train_tpu.optim import decay_mask_fn
     from pytorch_distributed_train_tpu.parallel.partition import (
         P,
         rules_for_model,
@@ -324,8 +265,7 @@ def test_preset_builds_its_share_counts_flops_decay_mask_and_rules(capfd):
     from pytorch_distributed_train_tpu.utils import flops
 
     hybrid._built_logged.clear()
-    cfg = get_preset("solar_open2_lm_ep40_tp8")
-    model = build_model(cfg.model, cfg.precision)
+    cfg, model, shapes, count = preset_tree(SOLAR)
     line = next(ln for ln in capfd.readouterr().err.splitlines()
                 if ln.startswith("[hybrid]"))
     assert line == ("[hybrid] layers=4 kinds=gqa_full,kda,kda,kda "
@@ -333,18 +273,9 @@ def test_preset_builds_its_share_counts_flops_decay_mask_and_rules(capfd):
                     "heads_held=8/64 kv_held=1/8")
     assert model.moe.num_experts == 320 and model.moe.n_held == 8
     assert model.first_dense_layers == 0 and model.full_rotation.width == 0
-    shapes = jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)},
-        jnp.zeros((1, 64), jnp.int32), train=False)["params"])
-    count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
     assert count == 840_871_320  # 13.45 GB at 16 B a parameter
     assert "mlp" not in shapes["layer0"] and "moe" in shapes["layer0"]
-    mask = decay_mask_fn(cfg.optim.decay_exclude)(shapes)
-    flat = {jax.tree_util.keystr(k): v for k, v in
-            jax.tree_util.tree_flatten_with_path(mask)[0]}
-    for leaf, decayed in flat.items():
-        plain = leaf.endswith("['kernel']") or leaf.endswith("['embedding']")
-        assert decayed == plain, leaf
+    decay_mask(cfg, shapes)
     # what this chip computes a token, by hand: the attention layer's held
     # heads (q, o, the channel gate 3 x 2 D 8 128; k, v 2 x 2 D 128; the
     # causal pairs), three KDA layers (q, k, v, o; two gates through 128;

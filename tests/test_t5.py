@@ -199,11 +199,14 @@ def test_seq2seq_decode_matches_teacher_forced(tied):
     src = jnp.asarray(rng.integers(0, V, (2, 10)), jnp.int32)
     n = 8
 
+    # (one jitted program a prefix length: op by op, every op of the model
+    # compiled again at each of the eight lengths)
+    forward = jax.jit(lambda p, s, t: model.apply(
+        {"params": p}, s, t, train=False))
     prefix = np.zeros((2, 1), np.int32)  # decoder_start_id = 0
     ref = []
     for _ in range(n):
-        logits = model.apply({"params": params}, src,
-                             jnp.asarray(prefix), train=False)
+        logits = forward(params, src, jnp.asarray(prefix))
         tok = np.asarray(jnp.argmax(logits[:, -1], -1), np.int32)
         ref.append(tok)
         prefix = np.concatenate([prefix, tok[:, None]], axis=1)

@@ -476,8 +476,13 @@ def test_e2e_spike_drill_journals_captures_and_reports(tmp_path, capfd):
     assert ("fault", "step.loss_spike") in names
     # both observed spikes journal an anomaly; the cooldown means only
     # the FIRST opens a capture
-    anomalies = [e for e in evs if e["category"] == "anomaly"]
-    assert [a["name"] for a in anomalies] == ["loss_spike", "loss_spike"]
+    # (a loaded worker may journal an ``input_stall_regression`` of its
+    # own between them: the drill's contract is its two spikes, in order)
+    anomalies = [e for e in evs if e["category"] == "anomaly"
+                 and e["name"] == "loss_spike"]
+    assert len(anomalies) == 2
+    assert anomalies[0]["step"] < anomalies[1]["step"]
+    assert anomalies[0]["ts"] <= anomalies[1]["ts"]
     # (2) an automatic capture opened and its journaled summary carries
     # the xplane top-ops report of the fake dump
     assert [c[0] for c in t.profiler.backend.calls] == ["start", "stop"]

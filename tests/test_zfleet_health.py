@@ -42,6 +42,9 @@ from pytorch_distributed_train_tpu.obs.collector import (  # noqa: E402
 from pytorch_distributed_train_tpu.obs.events import load_events  # noqa: E402
 from pytorch_distributed_train_tpu.obs.registry import get_registry  # noqa: E402
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 
 @pytest.fixture(autouse=True)
 def _clean_events():

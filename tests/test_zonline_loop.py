@@ -23,6 +23,9 @@ import urllib.request
 import jax.numpy as jnp
 import pytest
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

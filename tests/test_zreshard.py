@@ -48,6 +48,9 @@ from pytorch_distributed_train_tpu.parallel.partition import (
 from pytorch_distributed_train_tpu.sentinel import numeric as sentinel_numeric
 from pytorch_distributed_train_tpu.train_state import TrainState
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 

@@ -45,6 +45,9 @@ from pytorch_distributed_train_tpu.serving_plane.testing import (  # noqa: E402
     FakeTokenBatcher,
 )
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 
 @pytest.fixture(autouse=True)
 def _clean_planes():

@@ -45,6 +45,9 @@ from pytorch_distributed_train_tpu.obs.tsdb import (  # noqa: E402
     write_chunk,
 )
 
+# (ends processes abruptly: tests/conftest.py on the run's compile cache)
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 
 @pytest.fixture(autouse=True)
 def _clean_events():

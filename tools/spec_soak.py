@@ -21,6 +21,7 @@ Usage: python tools/spec_soak.py [--slots 16] [--k 4] [--ngram 3]
 
 import argparse
 import json
+import statistics
 import sys
 import time
 
@@ -41,6 +42,18 @@ def _mk_ctx(n: int, seed: int) -> list[int]:
         else:
             ctx.append(r.randrange(256))
     return ctx[:n]
+
+
+def _median_us_per_row(rounds: int, slots: int, one_round) -> float:
+    """Each round timed on its own and the MEDIAN round taken: on a busy
+    host a preemption lengthens one round of a few dozen microseconds
+    many times over, and a mean over the window reads it as scaling."""
+    times = []
+    for i in range(rounds):
+        t0 = time.perf_counter()
+        one_round(i)
+        times.append(time.perf_counter() - t0)
+    return statistics.median(times) * 1e6 / slots
 
 
 def main(argv=None) -> int:
@@ -66,28 +79,24 @@ def main(argv=None) -> int:
         ctxs = [_mk_ctx(n, s) for s in range(args.slots)]
         idxs = [_ngram_build(c, args.ngram) for c in ctxs]
 
-        t0 = time.perf_counter()
-        for _ in range(args.rounds):
+        def propose(_):
             for c, ix in zip(ctxs, idxs):
                 _ngram_propose(c, ix, args.ngram, args.k)
-        idx_us = (time.perf_counter() - t0) * 1e6 / (
-            args.rounds * args.slots)
 
         # amortized index maintenance: one commit per row per round
-        t0 = time.perf_counter()
-        for i in range(args.rounds):
+        def append(i):
             for c, ix in zip(ctxs, idxs):
                 _ngram_append(c, ix, i % 256, args.ngram)
-        app_us = (time.perf_counter() - t0) * 1e6 / (
-            args.rounds * args.slots)
 
-        scan_rounds = max(1, args.rounds // 10)  # rescan is slow; sample
-        t0 = time.perf_counter()
-        for _ in range(scan_rounds):
+        def rescan(_):
             for c in ctxs:
                 propose_from_context(c, args.k, args.ngram)
-        scan_us = (time.perf_counter() - t0) * 1e6 / (
-            scan_rounds * args.slots)
+
+        idx_us = _median_us_per_row(args.rounds, args.slots, propose)
+        app_us = _median_us_per_row(args.rounds, args.slots, append)
+        # rescan is slow; sample
+        scan_us = _median_us_per_row(max(1, args.rounds // 10), args.slots,
+                                     rescan)
         rows.append({"context": n, "index_us_per_row": round(idx_us, 2),
                      "append_us_per_row": round(app_us, 2),
                      "rescan_us_per_row": round(scan_us, 2)})
