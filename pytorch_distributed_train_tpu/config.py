@@ -177,8 +177,10 @@ class ModelConfig:
     # "sigmoid" (with the bias that only selection sees) or "softmax"
     # (over all the experts, no bias leaf).
     # layer_kinds names each layer's mixer where "the last of each group"
-    # does not say it: kda | mla | gqa_full | gqa_window, one a layer
-    # (empty: derived from layer_group_size). The two gqa kinds are
+    # does not say it: kda | mla | gqa_full | gqa_window | conv, one a
+    # layer (empty: derived from layer_group_size). conv is a gated short
+    # convolution (LFM2's: three products and an elementwise chain over
+    # conv_kernel_size taps a channel, no S x S term). The two gqa kinds are
     # grouped-query softmax attention over num_kv_heads KV heads with a
     # per-head sigmoid gate, layer_heads[i] query heads on layer i (empty:
     # num_heads everywhere): gqa_full is causal, rotated by rope_theta
@@ -193,7 +195,10 @@ class ModelConfig:
     # kda_beta_scale 1 or 2 (step sizes up to 2: a transition with a
     # negative eigenvalue); kda_gate_rank r > 0 puts the decay gate and a
     # channel-wise output gate through r features (two products);
-    # kda_out_gate / gqa_out_gate "head" (one scalar a head) or "channel".
+    # kda_out_gate / gqa_out_gate "head" (one scalar a head) or "channel";
+    # gqa_out_gate "none": no gate at all. gqa_qk_norm: an RMSNorm over
+    # each head's q and k before the rotation (two learned head_dim-vectors
+    # a layer, shared by the heads).
     # heads_held query/KDA heads from heads_held_first on live here (0 =
     # all), with the KV heads the grouping gives them: one chip's share of
     # a tensor-parallel group's mixers, as experts_held is of the experts;
@@ -204,7 +209,9 @@ class ModelConfig:
     # 2i+1); the defaults are the first preset's, False / "none" / "pairs"
     # DeepSeek-V3's plain form. moe_shared_mlp_dim is the shared expert's
     # own width (a family's n shared experts of width w are ONE SwiGLU of
-    # n x w; 0: the routed experts' moe_mlp_dim). moe_bias_rate > 0 moves
+    # n x w; 0: the routed experts' moe_mlp_dim; -1: the family has NO
+    # shared expert, no such subtree). tie_word_embeddings reads the head
+    # from the input table (no lm_head leaf). moe_bias_rate > 0 moves
     # a sigmoid router's selection bias after every optimizer step by the
     # auxiliary-loss-free balancing rule, b_e += rate x sign(mean(c) - c_e)
     # over the step's tokens c_e on each router output (ops/moe.py
@@ -236,6 +243,7 @@ class ModelConfig:
     kda_gate_rank: int = 0
     kda_out_gate: str = "head"
     gqa_out_gate: str = "head"
+    gqa_qk_norm: bool = False
     mla_qk_norm: bool = True
     mla_out_gate: str = "head"
     mla_rope: str = "halves"
@@ -1368,6 +1376,57 @@ def _kanana2_lm_ep8() -> TrainConfig:
     return c
 
 
+def _lfm2_8b_a1b_lm_ep4() -> TrainConfig:
+    """One chip's share of LFM2-8B-A1B's language model (LiquidAI,
+    https://huggingface.co/LiquidAI/LFM2-8B-A1B config.json, ``model_type:
+    lfm2_moe``): every width as published (hidden 2048, a gated short
+    convolution of 3 taps a channel, 32 query heads over 8 KV heads of 64
+    with an RMSNorm over each head's q and k and no output gate, a dense
+    SwiGLU of 7168, experts of 1792, a 32-wide ungrouped sigmoid router, 4
+    a token, NO shared expert, rope theta 1e6, eps 1e-5, the head tied to
+    the input table); layers 1-5 of the 24: one of the two leading dense
+    layers, then one whole period ``full_attention, conv, conv, conv`` of
+    expert layers. Each layer is shared by 4 chips by expert parallelism:
+    8 of its 32 routed experts here, a quarter of the 65536-row
+    vocabulary, every head and channel whole. The selection bias moves by
+    DeepSeek-V3's balancing rule at 0.001 a step. 507.8 M parameters,
+    8.13 GB with AdamW's float32 state
+    (benchmark/configs/lfm2_8b_a1b_lm_ep4.json says what was assumed)."""
+    c = TrainConfig(preset="lfm2_8b_a1b_lm_ep4")
+    c.model = ModelConfig(
+        name="hybrid_lm", hidden_size=2048, num_layers=5, num_heads=32,
+        num_kv_heads=8, head_dim=64, mlp_dim=7168, vocab_size=16384,
+        max_seq_len=8192, rope_theta=1e6, rms_norm_eps=1e-5, remat=True,
+        layer_kinds=("conv", "gqa_full", "conv", "conv", "conv"),
+        conv_kernel_size=3, gqa_qk_norm=True, gqa_out_gate="none",
+        tie_word_embeddings=True, first_dense_layers=1,
+        num_experts=32, expert_top_k=4, moe_groups=1, moe_topk_groups=1,
+        moe_routed_scale=1.0, moe_mlp_dim=1792, moe_shared_mlp_dim=-1,
+        moe_bias_rate=1e-3, experts_held=8, experts_held_first=0,
+        # a token meets ONE held expert on average (4 x 8 / 32), so 4.0
+        # would be the worst case itself (65536 rows): twice the mean's
+        # 16384 (the configuration file's `assumed` gives the readings)
+        expert_capacity_factor=2.0,
+    )
+    # 4096 synthetic sequences of 8192 tokens, as the other 8k presets
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=2, seq_len=8192,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        # decay the matrices and the table only: not the norms, the conv
+        # taps, nor the router's bias (which the optimizer does not move)
+        decay_exclude=r"scale$,bias$,taps$",
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    # (a step of 16384 tokens takes a third of a second on one v5e: the
+    # default log every fifty steps, as the presets of like steps)
+    c.total_steps = 500000
+    c.loss = "causal_lm_xent"
+    return c
+
+
 def _ouro_2_6b_lm_l8() -> TrainConfig:
     """One pipeline stage of Ouro-2.6B (ByteDance,
     https://huggingface.co/ByteDance/Ouro-2.6B config.json; arXiv:2510.25741):
@@ -1441,6 +1500,7 @@ _PRESETS = {
     "ouro_2_6b_lm_l8": _ouro_2_6b_lm_l8,
     "solar_open2_lm_ep40_tp8": _solar_open2_lm_ep40_tp8,
     "kanana2_lm_ep8": _kanana2_lm_ep8,
+    "lfm2_8b_a1b_lm_ep4": _lfm2_8b_a1b_lm_ep4,
 }
 
 

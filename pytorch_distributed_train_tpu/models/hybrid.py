@@ -5,11 +5,13 @@ Delta Attention (a gated delta rule with a per-channel decay,
 arXiv:2510.26692; ``ops/kda.py``), ``mla`` latent attention (DeepSeek-V2
 arXiv:2405.04434 section 2.1, expanded form), ``gqa_full`` and
 ``gqa_window`` grouped-query softmax attention whose query heads, window
-and rotation differ by kind. The first ``first_dense_layers`` layers carry
-a dense SwiGLU FFN, the rest one chip's share of a routed expert layer with
-a shared expert (``ops/moe.py`` ``HeldExpertsMLP``). Pre-norm residual
-blocks, RMSNorm, untied head, no dropout, no auxiliary loss. Two families'
-language models are laid out so: Ling-3.0-flash (preset
+and rotation differ by kind, ``conv`` a gated short convolution (LFM2's:
+``ConvMixer``). The first ``first_dense_layers`` layers carry a dense
+SwiGLU FFN, the rest one chip's share of a routed expert layer, with a
+shared expert where the family has one (``ops/moe.py``
+``HeldExpertsMLP``). Pre-norm residual blocks, RMSNorm, a head of its own
+or the input table's (``tie_word_embeddings``), no dropout, no auxiliary
+loss. Two families' language models are laid out so: Ling-3.0-flash (preset
 ``ling3_flash_lm_ep64``: groups of ``layer_group_size``, the last of each
 ``mla``, the others ``kda``) and Laguna-S (preset ``laguna_s_lm_ep32``:
 ``layer_kinds`` a layer, one ``gqa_full`` to three ``gqa_window``); a third,
@@ -27,13 +29,16 @@ and no code stands in for the absent chips or their all-reduce. A fourth
 form (``MLAMixer``'s second: no head norms, no gate, the rotated dims in
 pairs), shared experts at a width of their own, and the selection bias's
 balancing update after each optimizer step (``ops/moe.py``
-``balance_routers``). The equations are written out beside each module and
-in ``benchmark/references/<preset>.py``, which share no code with this
-file.
+``balance_routers``). A fifth (preset ``lfm2_8b_a1b_lm_ep4``) has the
+``conv`` mixer three layers in four, grouped-query attention with an
+RMSNorm over each head's q and k and NO output gate, a 32-wide sigmoid
+router with no shared expert beside it, and the head tied to the input
+table. The equations are written out beside each module and in
+``benchmark/references/<preset>.py``, which share no code with this file.
 
 Only the training path exists: no cache, no decode (a latent entry, a
-recurrent state and a window's ring in one cache manager are ROADMAP
-R2/R7's serving halves).
+recurrent state, a window's ring and a convolution's last tokens in one
+cache manager are ROADMAP R2/R7's serving halves).
 """
 
 from __future__ import annotations
@@ -66,7 +71,7 @@ from pytorch_distributed_train_tpu.ops.moe import (
 )
 
 _INIT = nn.initializers.normal(0.02)
-KINDS = ("kda", "mla", "gqa_full", "gqa_window")
+KINDS = ("kda", "mla", "gqa_full", "gqa_window", "conv")
 _F32_OUT = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
 
 
@@ -386,7 +391,10 @@ class GQAMixer(nn.Module):
     in float32 over the keys j <= i, and with ``window`` > 0 also
     i - j < window; the output times sigmoid(W_g x), one value a head
     (``out_gate`` ``head``) or a channel (``channel``: its own full-rank
-    projection); W_o. No q/k norm, no bias. ``heads_held`` query heads from
+    projection), or as it is (``none``); W_o. ``qk_norm``: an RMSNorm over
+    each head's q and each head's k before the rotation, one learned
+    vector for q and one for k (``q_norm``, ``k_norm``), shared by the
+    heads. No bias. ``heads_held`` query heads from
     ``heads_held_first`` on live here (0 = all) with the KV heads the
     grouping gives them (``held_kv_heads``), and their rows of ``o_proj``."""
 
@@ -399,9 +407,11 @@ class GQAMixer(nn.Module):
     param_dtype: jnp.dtype
     cp: ContextParallelConfig | None = None
     attn_impl: str = "auto"
-    out_gate: str = "head"
+    out_gate: str = "head"   # | channel | none
     heads_held: int = 0
     heads_held_first: int = 0
+    qk_norm: bool = False
+    rms_norm_eps: float = 1e-6   # the head norms'
 
     @nn.compact
     def __call__(self, x):
@@ -413,18 +423,61 @@ class GQAMixer(nn.Module):
             (heads, self.head_dim), axis=-1, use_bias=False,
             dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=_INIT, name=name)(x)
+        normed = (lambda t, name: RMSNorm(  # noqa: E731
+            self.rms_norm_eps, name=name)(t)) if self.qk_norm \
+            else (lambda t, name: t)
         rotate = self.rotation.rotate(x.shape[1])
         y = dot_product_attention(
-            rotate(proj(heads, "q_proj")), rotate(proj(kv_heads, "k_proj")),
+            rotate(normed(proj(heads, "q_proj"), "q_norm")),
+            rotate(normed(proj(kv_heads, "k_proj"), "k_norm")),
             proj(kv_heads, "v_proj"), causal=True,
             window=self.window, cp=self.cp, impl=self.attn_impl)
-        y = y.astype(jnp.float32) * _out_gate(
-            self.out_gate, x, heads, self.head_dim, 0, self.dtype,
-            self.param_dtype)
+        if self.out_gate != "none":
+            y = y.astype(jnp.float32) * _out_gate(
+                self.out_gate, x, heads, self.head_dim, 0, self.dtype,
+                self.param_dtype)
         return nn.DenseGeneral(
             x.shape[-1], axis=(-2, -1), use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=_INIT,
             name="o_proj")(y.astype(self.dtype))
+
+
+def short_conv(bcu, taps, dtype):
+    """The conv mixer's elementwise chain on [B | C | u] (.., S, 3D) and
+    the taps (K, D): z_t = B_t * u_t; c_t = sum_j w_j * z_{t-(K-1)+j}, z
+    zero before the sequence's start (depthwise, causal: w_{K-1} meets the
+    current token); C_t * c_t, as ``dtype``. In float32 between its
+    bfloat16 ends, under the scope ``short_conv``: what a kernel for it
+    would take the place of."""
+    with jax.named_scope("short_conv"):
+        (K, D), S, f32 = taps.shape, bcu.shape[-2], jnp.float32
+        b, c, u = (bcu[..., i * D:(i + 1) * D].astype(f32) for i in range(3))
+        z = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
+        mixed = sum(taps[j].astype(f32) * z[:, j:j + S] for j in range(K))
+        return (c * mixed).astype(dtype)
+
+
+class ConvMixer(nn.Module):
+    """LFM2's gated short convolution: [B | C | u] = W_in x (three D-wide
+    parts, in that order); the chain of ``short_conv`` over
+    ``kernel_size`` taps a channel (no sequence reads another's tokens);
+    y_t = W_out (C_t * c_t). No activation, no norm, no bias. Param tree:
+    in_proj/kernel (D, 3D), taps (K, D), out_proj/kernel (D, D)."""
+
+    kernel_size: int
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        D = x.shape[-1]
+        dense = lambda feats, name: nn.Dense(  # noqa: E731
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=_INIT, name=name)
+        taps = self.param("taps", _INIT, (self.kernel_size, D),
+                          self.param_dtype)
+        gated = short_conv(dense(3 * D, "in_proj")(x), taps, self.dtype)
+        return dense(D, "out_proj")(gated)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -437,7 +490,8 @@ class MixerVariants:
     kda_beta_scale: float = 1.0      # 2: negative eigenvalues allowed
     kda_gate_rank: int = 0           # 0: full-rank gates; r: through r
     kda_out_gate: str = "head"       # | channel
-    gqa_out_gate: str = "head"       # | channel
+    gqa_out_gate: str = "head"       # | channel | none
+    gqa_qk_norm: bool = False        # RMSNorm over each head's q and k
     heads_held: int = 0              # query/KDA heads held here, 0 = all
     heads_held_first: int = 0
     mla_qk_norm: bool = True         # RMSNorm over a head's whole q and k
@@ -458,7 +512,7 @@ class HybridBlock(nn.Module):
     a KDA layer's extremes where its gate is unbounded (``KDAMixer``), else
     None. ``variants`` are the mixers' plain fields (``MixerVariants``:
     gate forms, the share of heads held here). The mixer's
-    module carries its kind's name (``kda``, ``mla``, ``gqa`` for
+    module carries its kind's name (``kda``, ``mla``, ``conv``, ``gqa`` for
     ``gqa_full``, ``swa`` for ``gqa_window``): a device trace names the
     attention kernel's events by it."""
 
@@ -495,7 +549,11 @@ class HybridBlock(nn.Module):
                 cp=self.cp, attn_impl=self.attn_impl,
                 out_gate=var.gqa_out_gate, heads_held=var.heads_held,
                 heads_held_first=var.heads_held_first,
+                qk_norm=var.gqa_qk_norm, rms_norm_eps=self.rms_norm_eps,
                 name="gqa" if self.kind == "gqa_full" else "swa")(h)
+        elif self.kind == "conv":
+            mixed = ConvMixer(self.conv_kernel_size, self.dtype,
+                              self.param_dtype, name="conv")(h)
         elif self.kind == "mla":
             mixed = MLAMixer(
                 self.num_heads, self.head_dim, self.rope_head_dim,
@@ -526,7 +584,10 @@ class HybridBlock(nn.Module):
 
 
 class HybridLM(nn.Module):
-    """Input: (B, S) int ids. Output: (B, S, vocab) float32 logits. Sows
+    """Input: (B, S) int ids. Output: (B, S, vocab) float32 logits: a
+    head of its own, or with ``tie_word_embeddings`` the input table read
+    again (logits = h E^T; no ``lm_head`` leaf, and the table's gradient
+    is the sum of its two uses). Sows
     the expert layers' row counts (mean over those layers of the fullest
     and of the mean held expert, sum of the pairs past the bound) into the
     ``step_metrics`` collection, which the train step reports; the pairs
@@ -570,6 +631,7 @@ class HybridLM(nn.Module):
     attn_impl: str = "auto"
     act: "object | None" = None
     variants: MixerVariants = MixerVariants()
+    tie_word_embeddings: bool = False
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, loss_mask=None):
@@ -577,10 +639,10 @@ class HybridLM(nn.Module):
         from pytorch_distributed_train_tpu.models.remat import remat_block
 
         constrain = (lambda t: t) if self.act is None else self.act.constrain
-        x = constrain(nn.Embed(
+        embed = nn.Embed(
             self.vocab_size, self.hidden_size, embedding_init=_INIT,
-            param_dtype=self.param_dtype,
-            name="tok_embed")(input_ids).astype(self.dtype))
+            param_dtype=self.param_dtype, name="tok_embed")
+        x = constrain(embed(input_ids).astype(self.dtype))
         block_cls = remat_block(HybridBlock, self.remat, self.remat_policy)
         stats, decay_stats = [], []
         for i, kind in enumerate(self.layer_kinds):
@@ -619,10 +681,15 @@ class HybridLM(nn.Module):
                      reduce_fn=lambda _, new: new, init_fn=lambda: 0.0)
         x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
         with jax.named_scope("lm_head"):  # a phase of the step: steps.py
-            logits = nn.Dense(
-                self.vocab_size, use_bias=False, dtype=self.dtype,
-                param_dtype=self.param_dtype, dot_general=_F32_OUT,
-                kernel_init=_INIT, name="lm_head")(x)
+            if self.tie_word_embeddings:
+                logits = _F32_OUT(
+                    x, embed.embedding.astype(self.dtype),
+                    (((x.ndim - 1,), (1,)), ((), ())))
+            else:
+                logits = nn.Dense(
+                    self.vocab_size, use_bias=False, dtype=self.dtype,
+                    param_dtype=self.param_dtype, dot_general=_F32_OUT,
+                    kernel_init=_INIT, name="lm_head")(x)
         return logits.astype(jnp.float32)
 
 
@@ -686,15 +753,16 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
     variants = MixerVariants(
         kda_gate=cfg.kda_gate, kda_beta_scale=cfg.kda_beta_scale,
         kda_gate_rank=cfg.kda_gate_rank, kda_out_gate=cfg.kda_out_gate,
-        gqa_out_gate=cfg.gqa_out_gate, heads_held=cfg.heads_held,
-        heads_held_first=cfg.heads_held_first,
+        gqa_out_gate=cfg.gqa_out_gate, gqa_qk_norm=cfg.gqa_qk_norm,
+        heads_held=cfg.heads_held, heads_held_first=cfg.heads_held_first,
         mla_qk_norm=cfg.mla_qk_norm, mla_out_gate=cfg.mla_out_gate,
         mla_rope=cfg.mla_rope)
     share = ""
     if cfg.heads_held:
-        if "mla" in kinds:
+        if "mla" in kinds or "conv" in kinds:
             raise ValueError("model.heads_held: a latent (mla) layer has no "
-                             "share of heads yet")
+                             "share of heads yet, a conv layer no share of "
+                             "channels")
         # every layer's share is well formed, and the line names the first's
         held = [(held_heads(h, cfg.heads_held, cfg.heads_held_first),
                  held_kv_heads(h, kv_heads, cfg.heads_held,
@@ -704,14 +772,16 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
         kv_held = max(kv for _, kv in held)
         share = (f" heads_held={held[0][0]}/{heads[0]}"
                  + (f" kv_held={kv_held}/{kv_heads}" if kv_held else ""))
-    said = (kinds, heads, kv_heads, cfg.attention_window, variants)
+    tied = cfg.tie_word_embeddings
+    said = (kinds, heads, kv_heads, cfg.attention_window, variants, tied)
     if said not in _built_logged:  # once a layout, on stderr
         _built_logged.add(said)
         print(f"[hybrid] layers={len(kinds)} kinds={','.join(kinds)} "
               f"heads={','.join(map(str, heads))} kv_heads={kv_heads} "
               f"window={cfg.attention_window} "
               f"dense_layers={cfg.first_dense_layers}{share}"
-              + (f" {variants.mla_form}" if "mla" in kinds else ""),
+              + (f" {variants.mla_form}" if "mla" in kinds else "")
+              + (" head=tied" if tied else ""),
               file=sys.stderr, flush=True)
     return HybridLM(
         cp=cp, act=act, moe=moe,
@@ -727,7 +797,7 @@ def hybrid_lm(cfg, dtype, param_dtype, cp=None, act=None) -> HybridLM:
         rms_norm_eps=cfg.rms_norm_eps,
         # the grouped-query kinds' (no other kind reads them)
         num_kv_heads=kv_heads, window=cfg.attention_window,
-        variants=variants,
+        variants=variants, tie_word_embeddings=tied,
         full_rotation=Rotation(
             int(head_dim * cfg.partial_rotary_factor), cfg.rope_theta,
             cfg.rope_scaling, cfg.rope_scaling_type,

@@ -28,7 +28,7 @@ COMPONENTS = ("attention", "ffn", "experts", "norm", "embed", "other")
 MODEL_PHASES = ("forward", "backward", "recompute")
 
 # The module names models/gpt2.py, models/hybrid.py and models/llama.py give
-# their parts, as the four cells' compiled steps spell them (a trailing
+# their parts, as the cells' compiled steps spell them (a trailing
 # number dropped: ``ln_1`` -> ``ln_``). The FIRST segment of a path that the
 # table knows decides, so a mixer's inner norm (``kda/o_norm``,
 # ``mla/q_norm``) is the mixer's and GPT-2's ``attn/c_proj`` is not its
@@ -37,7 +37,7 @@ COMPONENT_OF = {
     # mixers whole: projections, rotation, gates, the kernel
     "attn": "attention",                     # gpt2.py, llama.py
     "kda": "attention", "mla": "attention",  # hybrid.py, a kind a layer
-    "gqa": "attention", "swa": "attention",
+    "gqa": "attention", "swa": "attention", "conv": "attention",
     # dense feed-forward blocks (GPT-2's has no module of its own)
     "c_fc": "ffn", "c_proj": "ffn", "mlp": "ffn",
     # expert layers whole: router, dispatch, grouped products, combine,

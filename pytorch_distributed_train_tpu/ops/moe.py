@@ -28,9 +28,10 @@ bias, or softmax scores with none; group-limited top-k, weights normalised
 over every chosen expert), is told which experts it holds, and computes
 their part of the result with one grouped product over the (token, choice)
 pairs that fall on them, by expert, beside the shared expert(s) at a width
-of their own. Where the spec gives the selection bias a rate, the layer
-also counts the tokens on EVERY router output and sows them, and the train
-step moves the bias by them after the optimizer (:func:`balance_routers`).
+of their own (or none, where the family has none). Where the spec gives
+the selection bias a rate, the layer also counts the tokens on EVERY router
+output and sows them, and the train step moves the bias by them after the
+optimizer (:func:`balance_routers`).
 No token is dropped silently: pairs past its static row bound are counted,
 and the model hands the count to the train step as ``update_invalid``, so
 such a step keeps its old state and reports ``update_skipped``.
@@ -233,7 +234,8 @@ class HeldExpertsSpec:
     capacity_factor: float = 4.0
     # the shared expert's width: ONE SwiGLU every chip computes alike (a
     # family's n shared experts of width w are one of n x w); 0 -> the
-    # routed experts' width
+    # routed experts' width; -1 -> the family has NO shared expert (no
+    # ``shared`` subtree, nothing added to the routed sum)
     shared_mlp_dim: int = 0
     # the balancing update's rate (:func:`balance_routers`); 0 leaves the
     # selection bias where it was drawn and the layer sows no counts
@@ -395,7 +397,7 @@ def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int,
           f"groups={spec.n_groups}/{spec.topk_groups} score={spec.score} "
           f"tokens={n_tokens} row_bound={rows}"
           + (f" bank=padded slots={slots} spill=grouped" if slots else "")
-          + f" shared={shared}"
+          + f" shared={'none' if shared is None else shared}"
           + (f" bias_rate={spec.bias_rate:g}" if spec.bias_rate else ""),
           file=sys.stderr, flush=True)
 
@@ -508,15 +510,17 @@ class HeldExpertsMLP(nn.Module):
     """(B, S, D) -> ((B, S, D), stats): the held experts' part of the
     routed sum plus the shared expert, ONE SwiGLU of ``spec.shared_mlp_dim``
     (0: the routed experts' ``mlp_dim``), which every chip of the layer
-    computes alike. ``stats`` is float32 (3,): pairs on the fullest held
-    expert, on the mean one, and past the row bound. With
+    computes alike; at -1 the routed part alone (the family has no shared
+    expert: no ``shared`` subtree). ``stats`` is
+    float32 (3,): pairs on the fullest held expert, on the mean one, and
+    past the row bound. With
     ``spec.bias_rate`` > 0 the tokens that chose each of ALL the router's
     outputs, float32 (E,), are sown as ``counts`` into the ``router_load``
     collection, for the step's balancing update.
 
     Param tree: router/{kernel (D, E), bias (E,): the sigmoid rule's};
     experts/<proj>/kernel
-    with a leading (held,) dim; shared/<proj>/kernel.
+    with a leading (held,) dim; shared/<proj>/kernel where there is one.
     """
 
     spec: HeldExpertsSpec
@@ -531,7 +535,8 @@ class HeldExpertsMLP(nn.Module):
         N, spec, F = B * S, self.spec, self.mlp_dim
         rows = spec.row_bound(N)
         slots = padded_slots(spec, rows, D, F)
-        shared_dim = spec.shared_mlp_dim or F
+        shared_dim = None if spec.shared_mlp_dim < 0 \
+            else spec.shared_mlp_dim or F
         _log_plan(spec, N, rows, slots, shared_dim)
         xf = x.reshape(N, D)
         scores, bias = _Router(spec.num_experts, spec.score,
@@ -574,9 +579,11 @@ class HeldExpertsMLP(nn.Module):
                            (0, 1), dtype=jnp.float32)
             self.sow("router_load", "counts", load,
                      reduce_fn=lambda _, new: new, init_fn=lambda: 0.0)
-        shared = self.mlp_module(shared_dim, self.dtype, self.param_dtype,
-                                 name="shared")(x)
-        y = routed.reshape(B, S, D).astype(self.dtype) + shared
+        shared = None if shared_dim is None else self.mlp_module(
+            shared_dim, self.dtype, self.param_dtype, name="shared")(x)
+        y = routed.reshape(B, S, D).astype(self.dtype)
+        if shared is not None:
+            y = y + shared
         stats = jnp.stack([jnp.max(counts), jnp.mean(counts), over]
                           ).astype(jnp.float32)
         return y, stats

@@ -270,6 +270,8 @@ def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
     where it or the decay's `a_proj` goes through a low rank, the first
     factor (`gc_down`, `a_down`: (D, r), what every chip of a `tensor` group
     computes alike) replicates;
+    the `conv` mixer's two projections put their rows on 'fsdp' alone (no
+    share of its channels over 'tensor' yet: ROADMAP Reach);
     the latent down-projections, the router, the conv taps, A_log, dt_bias,
     the head gates and every norm replicate (small, or per-head vectors).
     A model told its share of heads (`model.heads_held`) is ONE chip's view
@@ -285,6 +287,7 @@ def hybrid_rules() -> list[tuple[str, PartitionSpec]]:
         (r"kda/(a_down|gc_down)/kernel$", P()),
         (r"(kda|mla|gqa|swa)/o_proj/kernel$", P("tensor", None, "fsdp")),
         (r"mla/(kv_down|k_rope_proj)/kernel$", P("fsdp", None)),
+        (r"conv/(in_proj|out_proj)/kernel$", P("fsdp", None)),
         (r"experts/(gate_proj|up_proj)/kernel$",
          P("expert", "fsdp", "tensor")),
         (r"experts/down_proj/kernel$", P("expert", "tensor", "fsdp")),

@@ -212,7 +212,11 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     ones: top_k x held / num_experts), the latent layers' scores and values
     (un-masked convention, as the other rows here), the grouped-query
     kinds' scores and values over the pairs of their band alone (causal,
-    and inside the window: the work done, at each layer's own heads), and
+    and inside the window: the work done, at each layer's own heads), the
+    ``conv`` kind's two projections and its chain (a multiply-add a tap and
+    the two gates a channel; no term in the sequence's length), a shared
+    expert only where the family has one, the head as ONE product whether
+    its matrix is its own or the input table's, and
     the delta rule's chunk products (in-chunk tables and the three products
     with the state), all at the heads held here (``model.heads_held``) and
     with a gate through ``kda_gate_rank`` counted as its two products. The
@@ -261,16 +265,21 @@ def hybrid_fwd_flops_per_token(cfg, seq: int | None = None) -> float:
     def gqa(heads, window):  # q and o, k and v, the gate; scores and values
         h = here(heads)
         kv = held_kv_heads(heads, hkv, cfg.heads_held, cfg.heads_held_first)
-        gate = 2.0 * d * h * (dh if cfg.gqa_out_gate == "channel" else 1)
+        gate = 0.0 if cfg.gqa_out_gate == "none" else \
+            2.0 * d * h * (dh if cfg.gqa_out_gate == "channel" else 1)
         return (4.0 * d * h * dh + 4.0 * d * kv * dh + gate
                 + 4.0 * h * dh * band_pairs_per_token(s, window))
 
+    # in_proj (d -> 3d) and out_proj; B * u, the taps, C * a channel
+    conv = 8.0 * d * d + (2.0 * cfg.conv_kernel_size + 4.0) * d
     mixers = sum(
         kda(heads) if kind == "kda" else mla(heads) if kind == "mla"
+        else conv if kind == "conv"
         else gqa(heads, cfg.attention_window if kind == "gqa_window" else 0)
         for kind, heads in zip(kinds, layer_heads(cfg)))
     expert = 6.0 * d * cfg.moe_mlp_dim
-    shared = 6.0 * d * (cfg.moe_shared_mlp_dim or cfg.moe_mlp_dim)
+    shared = 0.0 if cfg.moe_shared_mlp_dim < 0 \
+        else 6.0 * d * (cfg.moe_shared_mlp_dim or cfg.moe_mlp_dim)
     held = cfg.experts_held or cfg.num_experts
     moe = (2.0 * d * cfg.num_experts + shared
            + expert * cfg.expert_top_k * held / max(cfg.num_experts, 1))

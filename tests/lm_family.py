@@ -55,6 +55,12 @@ def flat(tree):
             for k, v in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
+def router_biases(params):
+    """{expert layer: its router's selection bias, on the host}."""
+    return {k: np.asarray(v["moe"]["router"]["bias"])
+            for k, v in params.items() if "moe" in v}
+
+
 def signature(tree):
     return [(jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
             for k, v in jax.tree_util.tree_flatten_with_path(tree)[0]]

@@ -660,7 +660,8 @@ def test_resolution_line_prints_the_tile_plan(capsys):
 
 
 CELLS = ["gpt2s-1chip-b16", "ling3f-1chip-ep64-s8k", "lagunas-1chip-ep32-w512",
-         "ouro26b-1chip-ut4-s4k", "solar2-1chip-ep40-tp8"]
+         "ouro26b-1chip-ut4-s4k", "solar2-1chip-ep40-tp8",
+         "lfm2moe-1chip-ep4-s8k"]
 
 
 @pytest.mark.parametrize("cell", CELLS)

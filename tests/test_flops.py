@@ -91,6 +91,42 @@ class TestConventions:
         assert flops.fwd_flops_per_item(mha, 2048) - \
             flops.fwd_flops_per_item(gqa, 2048) == pytest.approx(delta)
 
+    def test_hybrid_counts_the_conv_mixer_no_shared_expert_one_head(self):
+        """The short-convolution preset at S = 8192, its pieces written out
+        (millions a token): the conv mixer's two projections (d -> 3d, d ->
+        d; no term in S), the one attention layer's causal pairs, a router
+        and ONE held expert a token (4 x 8 / 32) with no shared expert
+        beside it, the tied head once as a product."""
+        from pytorch_distributed_train_tpu.config import get_preset
+
+        cfg = get_preset("lfm2_8b_a1b_lm_ep4").model
+        d, s = 2048, 8192
+        conv = 8.0 * d * d + 10.0 * d
+        attention = 4.0 * d * 2048 + 4.0 * d * 512 + 4.0 * 2048 * (s + 1) / 2
+        routed = 2.0 * d * 32 + 6.0 * d * 1792
+        pieces = {"dense layer": conv + 6.0 * d * 7168,
+                  "attention expert layer": attention + routed,
+                  "conv expert layer": conv + routed,
+                  "head": 2.0 * d * 16384}
+        assert {k: round(v / 1e6, 1) for k, v in pieces.items()} == {
+            "dense layer": 121.7, "attention expert layer": 76.7,
+            "conv expert layer": 55.7, "head": 67.1}
+        total = sum(pieces.values()) + 2 * pieces["conv expert layer"]
+        assert flops.fwd_flops_per_item(cfg, s) == pytest.approx(total)
+        assert total == pytest.approx(432.6e6, rel=1e-3)
+        # the conv layers do not grow with the sequence; the one attention
+        # layer does, by 2 x heads x head_dim a token of context
+        assert flops.fwd_flops_per_item(cfg, 2 * s) - total \
+            == pytest.approx(2.0 * 2048 * s)
+        # a shared expert and a gate would each be counted where they exist
+        import dataclasses
+        assert flops.fwd_flops_per_item(dataclasses.replace(
+            cfg, moe_shared_mlp_dim=0), s) - total \
+            == pytest.approx(4 * 6.0 * d * 1792)
+        assert flops.fwd_flops_per_item(dataclasses.replace(
+            cfg, gqa_out_gate="head"), s) - total \
+            == pytest.approx(2.0 * d * 32)
+
     def test_seq_defaults_to_config_max(self):
         cfg = _llama_1b()
         assert flops.fwd_flops_per_item(cfg) == \

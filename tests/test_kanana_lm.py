@@ -19,6 +19,7 @@ from lm_family import close as _close
 from lm_family import exact_products  # noqa: F401 - autouse here
 from lm_family import load as _load
 from lm_family import logits_and_gradients_match_the_reference
+from lm_family import router_biases as _biases
 from lm_family import train_state as _state
 
 from pytorch_distributed_train_tpu import losses, steps
@@ -49,11 +50,6 @@ def _mla(m, **kw):
         m.num_heads, m.head_dim, m.rope_head_dim, m.kv_lora_rank,
         m.rope_theta, m.max_seq_len, m.rms_norm_eps, F32, F32,
         **{**form, **kw})
-
-
-def _biases(params):
-    return {k: np.asarray(v["moe"]["router"]["bias"])
-            for k, v in params.items() if "moe" in v}
 
 
 # ------------------------------------------------- the mixer's plain form

@@ -171,6 +171,18 @@ PATHS = [
      "rematted_computation/mul", "recompute", "attention"),
     (BWD + REMAT + "layer4/post_attn_norm/mul", "recompute", "norm"),
     (BWD + "HybridLM/tok_embed/jit(_take)/scatter-add", "backward", "embed"),
+    # the short-convolution decoder: `conv` is a mixer like the others, its
+    # chain's scope beneath it; the tied head is the head's phase whichever
+    # table it reads
+    (FWD + "HybridLM/layer2/conv/in_proj/dot_general", "forward",
+     "attention"),
+    (BWD + REMAT + "layer4/conv/short_conv/mul", "recompute", "attention"),
+    (BWD + "HybridLM/jvp(forward)/HybridLM/checkpoint/layer0/conv/short_conv/"
+     "pad", "backward", "attention"),
+    (FWD + "HybridLM/layer1/gqa/q_norm/rsqrt", "forward", "attention"),
+    (FWD + "HybridLM/lm_head/dot_general", "head_loss", "other"),
+    (BWD + "HybridLM/lm_head/transpose", "head_loss", "other"),
+    (BWD + "HybridLM/layer1/gqa/k_norm/mul", "backward", "attention"),
     (FWD + LOOP + "layer7/attn_out_norm/rsqrt", "forward", "norm"),
     (BWD + LOOP + "LlamaForCausalLM.one_pass/loop_pass/checkpoint/"
      "rematted_computation/layer2/mlp/gate_proj/dot_general",
@@ -353,7 +365,7 @@ def test_a_capture_summary_says_what_each_top_operation_is(tmp_path,
 
 # ------------------------------------- the four configurations' own steps
 CONFIGS = ("gpt2_small", "ling3_flash_lm_ep64", "laguna_s_lm_ep32",
-           "ouro_2_6b_lm_l8")
+           "ouro_2_6b_lm_l8", "lfm2_8b_a1b_lm_ep4")
 # What may stay outside every phase, as the four steps compile here: arguments
 # named by their place in the state, reducers' bodies (a bare primitive, under
 # `checkpoint/` inside a remat'd block, under the scanned pass's own name in
@@ -435,7 +447,34 @@ def test_every_scoped_instruction_of_a_cells_step_lies_in_a_phase(
     assert counts["other"] < 0.1 * len(built.scopes), counts
     for component in ("attention", "ffn", "norm", "embed"):
         assert components[component] > 0, components
-    experts = config in ("ling3_flash_lm_ep64", "laguna_s_lm_ep32")
+    experts = config in ("ling3_flash_lm_ep64", "laguna_s_lm_ep32",
+                         "lfm2_8b_a1b_lm_ep4")
     assert (components["experts"] > 0) == experts, components
     # under the model's phases little is left without a component
     assert components["other"] < 0.25 * sum(components.values()), components
+
+
+def test_nothing_of_a_conv_module_is_left_without_a_component(
+        rehearsal_maps):
+    """The short-convolution preset's map: every scoped instruction under a
+    `conv` module (both projections, the `short_conv` chain, in forward,
+    rerun forward and backward) is a mixer's, the component the other
+    mixers go to; the tied head's product sits in the head's phase; the
+    phases and components still partition the step."""
+    built = rehearsal_maps["lfm2_8b_a1b_lm_ep4"]
+    under = {name: op for name, op in built.scopes.items()
+             if "conv" in step_program._SEGMENTS.split(op)}
+    assert len(under) > 30
+    chain = [op for op in under.values()
+             if "short_conv" in step_program._SEGMENTS.split(op)]
+    assert chain and len(chain) < len(under)
+    phases = set()
+    for name in under:
+        phase, component, _ = built.place(name)
+        assert phase in step_program.MODEL_PHASES, under[name]
+        assert component == "attention", under[name]
+        phases.add(phase)
+    assert phases == set(step_program.MODEL_PHASES)
+    heads = [op for op in built.scopes.values()
+             if op.endswith("HybridLM/lm_head/dot_general")]
+    assert heads and all(classify(op)[0] == "head_loss" for op in heads)

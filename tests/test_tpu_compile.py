@@ -125,6 +125,9 @@ FLASH_SHAPES = [
     # one NoPE layer at an eighth of its heads (solar_open2_lm_ep40_tp8)
     ("s4096_d128_mha16", 1, 4096, 16, 16, 128, 0),
     ("s8192_d128_gqa8_over_1", 1, 8192, 8, 1, 128, 0),
+    # the short-convolution cell's one attention layer (preset
+    # lfm2_8b_a1b_lm_ep4): 32 query heads over 8 KV heads of 64, two rows
+    ("s8192_d64_gqa32_over_8_b2", 2, 8192, 32, 8, 64, 0),
 ]
 
 
