@@ -508,7 +508,8 @@ class MixerVariants:
 
 class HybridBlock(nn.Module):
     """x + mixer(norm x), then x + ffn(norm x). Returns (x, moe stats, decay
-    stats): the expert layer's three row counts, zeros for a dense layer;
+    stats): the expert layer's four numbers (``HeldExpertsMLP``), zeros for a
+    dense layer;
     a KDA layer's extremes where its gate is unbounded (``KDAMixer``), else
     None. ``variants`` are the mixers' plain fields (``MixerVariants``:
     gate forms, the share of heads held here). The mixer's
@@ -575,7 +576,7 @@ class HybridBlock(nn.Module):
         if self.moe is None:
             out = LlamaMLP(self.mlp_dim, self.dtype, self.param_dtype,
                            name="mlp")(h)
-            stats = jnp.zeros((3,), jnp.float32)
+            stats = jnp.zeros((4,), jnp.float32)
         else:
             out, stats = HeldExpertsMLP(
                 self.moe, LlamaMLP, self.moe_mlp_dim, self.dtype,
@@ -589,7 +590,8 @@ class HybridLM(nn.Module):
     again (logits = h E^T; no ``lm_head`` leaf, and the table's gradient
     is the sum of its two uses). Sows
     the expert layers' row counts (mean over those layers of the fullest
-    and of the mean held expert, sum of the pairs past the bound) into the
+    and of the mean held expert, sum of the pairs past the bound, mean of
+    the grouped product's ``moe_tile_visits_ratio``) into the
     ``step_metrics`` collection, which the train step reports; the pairs
     past the bound also as ``update_invalid``, the name by which the step
     keeps its old state and reports ``update_skipped`` (steps.py). Where
@@ -667,10 +669,11 @@ class HybridLM(nn.Module):
                 decay_stats.append(layer_decay)
         metrics = []
         if stats:
-            fullest, mean, over = jnp.stack(stats).T
+            fullest, mean, over, visits = jnp.stack(stats).T
             metrics += [("moe_rows_fullest", jnp.mean(fullest)),
                         ("moe_rows_mean", jnp.mean(mean)),
                         ("moe_rows_over_bound", jnp.sum(over)),
+                        ("moe_tile_visits_ratio", jnp.mean(visits)),
                         ("update_invalid", jnp.sum(over))]
         if decay_stats:
             lowest, largest = jnp.stack(decay_stats).T

@@ -47,6 +47,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_train_tpu.ops import attention, grouped_matmul
+
 
 @dataclasses.dataclass(frozen=True)
 class MoeSpec:
@@ -254,6 +256,11 @@ class HeldExpertsSpec:
                          * self.n_held / self.num_experts)
         return min(worst, -(-want // 8) * 8)
 
+    def mean_rows(self, n_tokens: int) -> int:
+        """The pairs a held expert has on average (static): what the
+        grouped product's row tile follows."""
+        return max(1, n_tokens * self.top_k // self.num_experts)
+
 
 def group_limited_topk(scores, bias, spec: HeldExpertsSpec):
     """DeepSeek-V3's rule (arXiv:2412.19437, 2.1.2) on float32 ``scores``
@@ -363,12 +370,16 @@ def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
 
 
 # An expert's matrix of this many elements or more takes the bank's second
-# form. On the v5e the grouped product walks 1640 rows of 8 groups through
-# 4096 x 1280 in 1.35 ms a call, a fifteenth of the MXU's peak, and by a
-# time that follows the routing (2.7 ms a step between two seeds: PERF.md
-# section 6, PR 39); the batched product over every expert's own slots does
-# four times the rows at a fixed time. Below it (2560 x 768, 3072 x 1024)
-# a call is 0.2 ms and the grouped product stays.
+# form. On the v5e XLA's grouped product (``jax.lax.ragged_dot``, the bank's
+# products until PR 46) walked 1640 rows of 8 groups through 4096 x 1280 in
+# 1.35 ms a call, a fifteenth of the MXU's peak, and by a time that followed
+# the routing (2.7 ms a step between two seeds: PERF.md section 6, PR 39);
+# the batched product over every expert's own slots does four times the
+# rows at a fixed time. Below it (2560 x 768, 3072 x 1024) a call was 0.2 ms
+# and the grouped product stayed. The kernels of ops/grouped_matmul.py read
+# 0.28-0.35 ms a call at 4096 x 1280 (PERF.md section 6, PR 46: kernels
+# alone, not through the cell): whether the second form still earns its
+# place there is ROADMAP S17 (e) and (f)'s to measure; the threshold stands.
 PADDED_MIN_WEIGHT = 4 * 1024 * 1024
 
 
@@ -385,18 +396,31 @@ _moe_logged: set[tuple] = set()
 
 
 def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int,
-              slots: int, shared: int) -> None:
-    """Once a shape, at trace time, on stderr: what this chip holds."""
-    key = (spec, n_tokens, slots, shared)
+              slots: int, shared: int, d_model: int, mlp_dim: int) -> None:
+    """Once a shape, at trace time, on stderr: what this chip holds, and
+    where the Pallas kernels take its grouped products
+    ``bank=grouped-kernel`` with the row kernel's tiles (rows x contraction
+    x columns, for gate / up and for down) and the most grid steps a pass
+    over the rows can take."""
+    key = (spec, n_tokens, slots, shared, d_model, mlp_dim)
     if key in _moe_logged:
         return
     _moe_logged.add(key)
+    grouped = "grouped"
+    if grouped_matmul.unsupported(d_model, mlp_dim) is None:
+        mean_rows = spec.mean_rows(n_tokens)
+        up = grouped_matmul.tile_sizes(d_model, mlp_dim, mean_rows)
+        down = grouped_matmul.tile_sizes(mlp_dim, d_model, mean_rows)
+        grouped = (f"grouped-kernel tiles={up.m}x{d_model}x{up.n},"
+                   f"{down.m}x{mlp_dim}x{down.n} "
+                   f"steps<={-(-rows // up.m) + spec.n_held - 1}")
     last = spec.held_first + spec.n_held - 1
     print(f"[moe] experts={spec.num_experts} held={spec.n_held} "
           f"ids={spec.held_first}-{last} top_k={spec.top_k} "
           f"groups={spec.n_groups}/{spec.topk_groups} score={spec.score} "
           f"tokens={n_tokens} row_bound={rows}"
-          + (f" bank=padded slots={slots} spill=grouped" if slots else "")
+          + (f" bank=padded slots={slots} spill={grouped}" if slots
+             else f" bank={grouped}" if grouped != "grouped" else "")
           + f" shared={'none' if shared is None else shared}"
           + (f" bias_rate={spec.bias_rate:g}" if spec.bias_rate else ""),
           file=sys.stderr, flush=True)
@@ -414,23 +438,45 @@ class _Kernel(nn.Module):
                           self.param_dtype)
 
 
-def _grouped_bank(rows, sizes, kernels, dtype):
-    """Rows sorted by expert through three grouped products
-    (``jax.lax.ragged_dot``; on a TPU one kernel that walks the groups).
-    Rows past sum(sizes) belong to no group, and the TPU's kernel leaves
-    whatever the buffer held there, forward AND backward (a v5e returned
-    gradients 36 000 times too large, PERF.md PR 26): every such row is
-    zeroed on the way in, between the products and on the way out, so that
-    neither a value nor a cotangent passes through one. ``kernels``: three
-    thunks, (held, D, F) gate and up, (held, F, D) down."""
+def _interpret() -> bool:
+    """Mosaic on a TPU; elsewhere the interpreter, which only a test that
+    steers ``grouped_matmul.unsupported``'s gate ever reaches."""
+    return not attention._on_tpu()
+
+
+def _grouped_bank(rows, sizes, kernels, dtype, mean_rows):
+    """Rows sorted by expert through three grouped products, under scope
+    ``grouped_product``: the Pallas kernels of ops/grouped_matmul.py, whose
+    row tile follows ``mean_rows`` (the pairs an expert has on average,
+    static). Rows past sum(sizes) belong to no group. The kernels read none
+    of them, forward or backward, and leave what the buffer held in the
+    results' rows there (a v5e's stale rows once gave gradients 36 000
+    times too large, PERF.md PR 26): the rows are zeroed ONCE on the way in
+    (its transpose keeps a stale ``d rows`` out of the scatter-add behind
+    ``xf[token]``) and the result once on the way out. Where the kernels do
+    not take the call (``grouped_matmul.unsupported``: no TPU, matrices
+    that are not whole tiles) the products are ``jax.lax.ragged_dot``, whose
+    TPU kernel reads and leaves stale rows: there the rows are zeroed
+    between the products too. ``kernels``: three thunks, (held, D, F) gate
+    and up, (held, F, D) down."""
     in_group = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
     held = lambda a: jnp.where(in_group, a, 0)  # noqa: E731
-    gdot = lambda a, w: held(jax.lax.ragged_dot(  # noqa: E731
-        held(a), w, sizes, preferred_element_type=jnp.float32))
-    gate = gdot(rows, kernels[0]())
-    up = gdot(rows, kernels[1]())
-    hidden = (nn.silu(gate) * up).astype(dtype)
-    return gdot(hidden, kernels[2]())
+    gate_w, up_w, down_w = (k() for k in kernels)  # (casts: not the scope's)
+    if grouped_matmul.unsupported(*gate_w.shape[1:]) is None:
+        between = lambda a: a  # noqa: E731
+        gdot = lambda a, w: grouped_matmul.grouped_matmul(  # noqa: E731
+            a, w, sizes, mean_rows=mean_rows, interpret=_interpret())
+    else:
+        between = held
+        gdot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+            a, w, sizes, preferred_element_type=jnp.float32)
+    rows = held(rows)
+    with jax.named_scope("grouped_product"):
+        gate, up = gdot(rows, gate_w), gdot(rows, up_w)
+    hidden = between((nn.silu(between(gate)) * between(up)).astype(dtype))
+    with jax.named_scope("grouped_product"):
+        out = gdot(hidden, down_w)
+    return held(out)
 
 
 def _padded_bank(rows, sizes, kernels, slots, dtype):
@@ -470,8 +516,9 @@ class _ExpertBank(nn.Module):
         return tuple(lambda k=k: jnp.asarray(k(), self.dtype) for k in (
             self.gate_proj, self.up_proj, self.down_proj))
 
-    def __call__(self, rows, sizes):
-        return _grouped_bank(rows, sizes, self._thunks(), self.dtype)
+    def __call__(self, rows, sizes, mean_rows):
+        return _grouped_bank(rows, sizes, self._thunks(), self.dtype,
+                             mean_rows)
 
     def kernels(self):
         return tuple(k() for k in self._thunks())
@@ -512,8 +559,10 @@ class HeldExpertsMLP(nn.Module):
     (0: the routed experts' ``mlp_dim``), which every chip of the layer
     computes alike; at -1 the routed part alone (the family has no shared
     expert: no ``shared`` subtree). ``stats`` is
-    float32 (3,): pairs on the fullest held expert, on the mean one, and
-    past the row bound. With
+    float32 (4,): pairs on the fullest held expert, on the mean one, and
+    past the row bound, and the grouped product's row-tile visits over the
+    whole tiles its real rows would fill (1.0: the experts' boundaries cost
+    no visit; ``grouped_matmul.tile_visits_ratio``). With
     ``spec.bias_rate`` > 0 the tokens that chose each of ALL the router's
     outputs, float32 (E,), are sown as ``counts`` into the ``router_load``
     collection, for the step's balancing update.
@@ -537,7 +586,8 @@ class HeldExpertsMLP(nn.Module):
         slots = padded_slots(spec, rows, D, F)
         shared_dim = None if spec.shared_mlp_dim < 0 \
             else spec.shared_mlp_dim or F
-        _log_plan(spec, N, rows, slots, shared_dim)
+        mean_rows = spec.mean_rows(N)
+        _log_plan(spec, N, rows, slots, shared_dim, D, F)
         xf = x.reshape(N, D)
         scores, bias = _Router(spec.num_experts, spec.score,
                                name="router")(xf)
@@ -566,11 +616,12 @@ class HeldExpertsMLP(nn.Module):
                 lambda: routed_by(token, weight, sizes, lambda r, n:
                                   _grouped_bank(r, n, [lambda k=k: k
                                                        for k in w],
-                                                self.dtype)),
+                                                self.dtype, mean_rows)),
                 lambda: routed_by(*own[:3], lambda r, n: _padded_bank(
                     r, n, w, slots, self.dtype)))
         else:
-            routed = routed_by(token, weight, sizes, bank)
+            routed = routed_by(token, weight, sizes, lambda r, n: bank(
+                r, n, mean_rows))
         if spec.bias_rate:
             if bias is None:
                 raise ValueError("moe bias_rate: a softmax router has no "
@@ -584,7 +635,9 @@ class HeldExpertsMLP(nn.Module):
         y = routed.reshape(B, S, D).astype(self.dtype)
         if shared is not None:
             y = y + shared
-        stats = jnp.stack([jnp.max(counts), jnp.mean(counts), over]
+        visits = grouped_matmul.tile_visits_ratio(
+            sizes, rows, grouped_matmul.tile_sizes(D, F, mean_rows).m)
+        stats = jnp.stack([jnp.max(counts), jnp.mean(counts), over, visits]
                           ).astype(jnp.float32)
         return y, stats
 

@@ -170,6 +170,7 @@ def test_model_logits_and_gradients_match_the_reference():
     # the routers' load, a layer's counts where its `router` sits
     assert set(sown["step_metrics"]) == {
         "moe_rows_fullest", "moe_rows_mean", "moe_rows_over_bound",
+        "moe_tile_visits_ratio",
         "update_invalid"}
     for i, layer in enumerate(("layer1", "layer2")):
         np.testing.assert_array_equal(

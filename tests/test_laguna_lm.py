@@ -336,15 +336,20 @@ def test_layer_kinds_and_heads_are_checked_and_derived():
 # set entry of the (expert, token) table from block counts and two small
 # products where it made a binary search, under scope `held_rows` (the same
 # integers come out; 7ee85e93... and 3f9f8ef9... before). The trees stand.
+# PR 46 MEANT to change both steps again: the expert bank's products sit
+# under scope `grouped_product` with the rows of no group zeroed five times
+# a pass where it was six (on a TPU the kernels of ops/grouped_matmul.py and
+# twice), and the layers' `stats` carry `moe_tile_visits_ratio` (c38c9dac...
+# and c0fcfc54... before). The trees stand.
 LING3_TREE = "d7a1ed3b5f0c92b2433b404a13d0135278b17a28b15770f912e66d9870df799d"
-LING3_STEP = "c38c9dac1647b9b3bb5920163caf9cc3c5b63282e86e672ffd0015f9a3baf7e8"
+LING3_STEP = "09084c6b5f023141f7dfbf376f59268fb9d70da580fda6c57cc022e36372cb82"
 # PR 39 told the mixers which HEADS they hold (`model.heads_held`, 0 = all)
 # and gave them their families' variants as plain fields (the decay gate's
 # form, beta's scale, gates a channel, no rotation); under the defaults both
 # presets' trees and steps are the parent's (commit 371bc2d), this one's
 # taken there the same way.
 LAGUNA_TREE = "c281f4f8f2f437f91105ea2fc5bba924ed8de2327ef833c27ef858f6cdb8d43a"
-LAGUNA_STEP = "c0fcfc54e48377c78e2d9c47a811bb3c70cee17e23cc0bc6bfb0a3d38de79af3"
+LAGUNA_STEP = "20b63bbd6bd9667621d4abdb353bd9637353d6116673f1711668b466d9947822"
 
 
 @pytest.mark.parametrize("preset,leaves,want_tree,want_step", [

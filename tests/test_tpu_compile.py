@@ -212,6 +212,44 @@ def test_kda_kernel_pair_compiles_at_the_hybrid_cells_shape(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+GROUPED_SHAPES = [  # rows of the bound, held experts, D, F, mean rows
+    ("lfm2moe", 32768, 8, 2048, 1792, 2048),
+    ("kanana2", 49152, 16, 2048, 768, 768),
+    ("lagunas", 10240, 8, 3072, 1024, 320),
+    ("ling3f", 8192, 8, 2560, 768, 256),
+    # the head-share cell's spill: a row bound that is no whole row tile
+    ("solar2", 6560, 8, 4096, 1280, 205),
+]
+
+
+@pytest.mark.parametrize("name,R,G,D,F,mean_rows", GROUPED_SHAPES,
+                         ids=[s[0] for s in GROUPED_SHAPES])
+def test_grouped_matmul_kernels_compile_at_the_expert_cells_shapes(
+        one_chip, name, R, G, D, F, mean_rows):
+    """ops/grouped_matmul.py under its own tile rule, forward and both
+    gradients, for gate / up's matrices and for down's: the row kernel
+    twice (the forward, ``d rows`` against the weights read transposed) and
+    the weights kernel once, each under its own name, the grid's steps a
+    number the program computes (a dynamic grid axis)."""
+    from pytorch_distributed_train_tpu.ops import grouped_matmul as gm
+
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    loss = lambda x, w, sizes: jnp.square(gm.grouped_matmul(  # noqa: E731
+        x, w, sizes, mean_rows=mean_rows)).sum()
+    for K, N in ((D, F), (F, D)):
+        compiled = _compile(jax.grad(loss, argnums=(0, 1)), sds(R, K),
+                            sds(G, K, N), sds(G, dtype=jnp.int32))
+        names = [n for n in _custom_calls(compiled.as_text())
+                 if "grouped" in n]
+        assert sorted("rows" in n for n in names) == [False, True, True] \
+            and all("grouped_matmul_" in n for n in names), names
+        # the float32 result, its cotangent's bfloat16 twin at most, and
+        # nothing else of the bound's size
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 1.2 * (4 + 2) * R * N + (64 << 20)
+
+
 def test_kda_mixer_in_its_kernels_moves_no_tensor_round_them(one_chip,
                                                              monkeypatch):
     """One KDA mixer at the hybrid cell's widths with its gradient, under
@@ -405,6 +443,51 @@ def test_hybrid_step_holds_the_kda_kernels_by_name(one_chip, monkeypatch):
                              f"%{name}.1 custom-call")
     loops = sorted(set(re.findall(r'"[^"]*kda_chunk[^"]*/while"', text)))
     assert not loops, loops
+
+
+def test_short_convolution_step_holds_its_grouped_kernels_by_name(
+        one_chip, monkeypatch):
+    """The short-convolution cell's step at 2 x 8192 tokens, lowered: four
+    expert layers, each the row kernel of ops/grouped_matmul.py three times
+    forward, three in the forward ``model.remat`` runs again and three for
+    ``d rows``, and the weights kernel three times, by the names their
+    device operations carry; no ``ragged_dot`` left; every kernel's
+    ``op_name`` under the scope `moe_grouped_ms_per_step` sums."""
+    lowered, bench, _ = _lowered_step(
+        "lfm2_8b_a1b_lm_ep4", one_chip, monkeypatch,
+        ["data.batch_size=2", "data.seq_len=8192"])
+    text = lowered.as_text(debug_info=True)
+    kernels = re.findall(r'kernel_name = "(\w+)"', text)
+    assert (kernels.count("grouped_matmul_rows"),
+            kernels.count("grouped_matmul_weights")) == (36, 12), kernels
+    assert "ragged_dot" not in text
+    for name in ("grouped_matmul_rows", "grouped_matmul_weights"):
+        assert not re.search(bench["flash_kernel_pattern"],
+                             f"%{name}.1 custom-call")
+    scoped = re.findall(
+        r'"([^"]*/grouped_matmul_(?:rows|weights)/pallas_call)"', text)
+    assert scoped and all("/moe/experts/grouped_product/" in s
+                          for s in scoped), scoped[:4]
+
+
+def test_head_share_step_compiles_inside_the_programs_memory(one_chip,
+                                                             monkeypatch):
+    """The head-share cell's step at 1 x 8192 tokens, compiled: its expert
+    bank keeps BOTH forms under a ``cond`` (the padded bank, and the grouped
+    kernels as the way out), and the program stood at 15.30 of the 15.75
+    GiB a program may use with ``ragged_dot`` there (sandbox compile, PR
+    39). The kernels' residuals are ``ragged_dot``'s, so it must still
+    fit: 15.01 GiB (sandbox compile, PR 46)."""
+    lowered, _, _ = _lowered_step(
+        "solar_open2_lm_ep40_tp8", one_chip, monkeypatch,
+        ["data.batch_size=1", "data.seq_len=8192"])
+    compiled = lowered.compile()
+    names = _custom_calls(compiled.as_text())
+    assert (sum("grouped_matmul_rows" in n for n in names),
+            sum("grouped_matmul_weights" in n for n in names)) == (36, 12)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        < 15.4 * 2 ** 30
 
 
 def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
