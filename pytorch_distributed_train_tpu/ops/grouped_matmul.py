@@ -98,7 +98,11 @@ def tile_sizes(K: int, N: int, mean_rows: int) -> Tiles:
     # 0.839, of 512 0.865 / 0.855 / 0.877, of 1024 1.003 / 1.005 / 1.580 (a
     # straddled tile is a whole tile's work a group); at ~768 rows a group
     # rows of 128 ran 0.361 / 0.376 / 0.396, of 256 0.366 / 0.381 / 0.407,
-    # of 512 0.406 / 0.423 / 0.446.
+    # of 512 0.406 / 0.423 / 0.446. At ~205 rows a group, read THROUGH the
+    # head-share cell's step (PERF.md section 6, PR 47): rows of 64 / 128 /
+    # 256 a median step of 225.12 / 224.22 / 224.56 ms, where two seeds of
+    # one tile stand 0.35 ms apart: a call there is bound by each group's
+    # weights read once and its launch, so the rule has no arm under 128.
     m = 256 if mean_rows >= 1024 else 128
     wn = _fit(N, 2048)
     return Tiles(m, _fit(N, _BLOCK // K), _fit(K, _BLOCK // N),

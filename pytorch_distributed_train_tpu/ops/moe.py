@@ -92,7 +92,7 @@ def topk_dispatch(gates: jnp.ndarray, top_k: int, capacity: int):
     # Renormalize the selected gates so combine weights sum to 1 per token.
     vals = vals / jnp.maximum(jnp.sum(vals, axis=-1, keepdims=True), 1e-9)
 
-    counts = jnp.zeros((E,), jnp.int32)  # slots used per expert so far
+    counts = jnp.zeros((E,), jnp.int32)  # places taken per expert so far
     dispatch = jnp.zeros((N, E, capacity), jnp.float32)
     combine = jnp.zeros((N, E, capacity), jnp.float32)
     for s in range(top_k):
@@ -138,7 +138,7 @@ def expert_choice_dispatch(gates: jnp.ndarray, capacity: int):
 
 def load_balance_loss(gates: jnp.ndarray, dispatch: jnp.ndarray) -> jnp.ndarray:
     """Switch-Transformer load-balance loss: E · Σ_e f_e · p_e, minimized at
-    uniform routing. f_e = fraction of dispatched slots on expert e (not
+    uniform routing. f_e = fraction of dispatched places on expert e (not
     differentiable), p_e = mean router prob (differentiable)."""
     E = gates.shape[1]
     f = jnp.mean(jnp.sum(dispatch, axis=2), axis=0)  # (E,) tokens kept per e / N
@@ -322,17 +322,13 @@ def _nth_set(flags, ranks):
     return jnp.minimum(block * _BLOCK + lane, L)
 
 
-def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
-              slots: int = 0):
+def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int):
     """The (token, choice) pairs that fall on held experts, by expert and
     inside an expert by token: (token (rows,) int32, weight (rows,) float32
     of that pair, group_sizes (held,) int32 rows of each held expert inside
     the bound, counts (held,) int32 pairs on each before the bound, over:
     pairs past the bound). Rows past sum(group_sizes) belong to no expert
-    (token 0, weight 0). ``slots`` > 0 (``rows`` = held x slots): every
-    held expert has ``slots`` rows of its own, expert e's from e x slots
-    on, the first group_sizes[e] of them real, and the bound is each
-    expert's own (``_ExpertBank``'s second form).
+    (token 0, weight 0): the one layout the bank's grouped products read.
 
     A token chooses an expert at most once, so the pairs are the set
     entries of an (expert, token) table, and the r-th row is the r-th set
@@ -347,20 +343,11 @@ def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
         on = jnp.any(hit, 1)                          # (N, held)
         counts = jnp.sum(on, 0)
         table = on.T.reshape(-1)                      # (expert, token)
-        if slots:
-            sizes = jnp.minimum(counts, slots)
-            over = jnp.sum(counts - sizes)
-            slot = jnp.arange(slots, dtype=jnp.int32)
-            # expert e's p-th row is set entry (entries before e) + p + 1
-            rank = (jnp.cumsum(counts) - counts)[:, None] + slot + 1
-            at = _nth_set(table, rank.reshape(-1))
-            real = (slot < sizes[:, None]).reshape(-1)
-        else:
-            ends = jnp.minimum(jnp.cumsum(counts), rows)
-            sizes = jnp.diff(ends, prepend=0)
-            over = jnp.sum(counts) - ends[-1]
-            at = _nth_set(table, jnp.arange(1, rows + 1, dtype=jnp.int32))
-            real = at < table.shape[0]            # the r-th entry exists
+        ends = jnp.minimum(jnp.cumsum(counts), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        over = jnp.sum(counts) - ends[-1]
+        at = _nth_set(table, jnp.arange(1, rows + 1, dtype=jnp.int32))
+        real = at < table.shape[0]                    # the r-th entry exists
         token = jnp.where(real, at % N, 0).astype(jnp.int32)
         expert = jnp.where(real, at // N, 0)
         held_weight = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), 1)
@@ -369,58 +356,33 @@ def held_rows(ids, weights, spec: HeldExpertsSpec, rows: int,
             counts.astype(jnp.int32), over
 
 
-# An expert's matrix of this many elements or more takes the bank's second
-# form. On the v5e XLA's grouped product (``jax.lax.ragged_dot``, the bank's
-# products until PR 46) walked 1640 rows of 8 groups through 4096 x 1280 in
-# 1.35 ms a call, a fifteenth of the MXU's peak, and by a time that followed
-# the routing (2.7 ms a step between two seeds: PERF.md section 6, PR 39);
-# the batched product over every expert's own slots does four times the
-# rows at a fixed time. Below it (2560 x 768, 3072 x 1024) a call was 0.2 ms
-# and the grouped product stayed. The kernels of ops/grouped_matmul.py read
-# 0.28-0.35 ms a call at 4096 x 1280 (PERF.md section 6, PR 46: kernels
-# alone, not through the cell): whether the second form still earns its
-# place there is ROADMAP S17 (e) and (f)'s to measure; the threshold stands.
-PADDED_MIN_WEIGHT = 4 * 1024 * 1024
-
-
-def padded_slots(spec: HeldExpertsSpec, rows: int, d_model: int,
-                 mlp_dim: int) -> int:
-    """Rows of its own each held expert gets (``_ExpertBank``'s second
-    form), or 0 for the grouped product: decided from what the call sees."""
-    if d_model * mlp_dim < PADDED_MIN_WEIGHT:
-        return 0
-    return rows // spec.n_held
-
-
 _moe_logged: set[tuple] = set()
 
 
 def _log_plan(spec: HeldExpertsSpec, n_tokens: int, rows: int,
-              slots: int, shared: int, d_model: int, mlp_dim: int) -> None:
+              shared: int, d_model: int, mlp_dim: int) -> None:
     """Once a shape, at trace time, on stderr: what this chip holds, and
     where the Pallas kernels take its grouped products
     ``bank=grouped-kernel`` with the row kernel's tiles (rows x contraction
     x columns, for gate / up and for down) and the most grid steps a pass
     over the rows can take."""
-    key = (spec, n_tokens, slots, shared, d_model, mlp_dim)
+    key = (spec, n_tokens, shared, d_model, mlp_dim)
     if key in _moe_logged:
         return
     _moe_logged.add(key)
-    grouped = "grouped"
+    bank = ""
     if grouped_matmul.unsupported(d_model, mlp_dim) is None:
         mean_rows = spec.mean_rows(n_tokens)
         up = grouped_matmul.tile_sizes(d_model, mlp_dim, mean_rows)
         down = grouped_matmul.tile_sizes(mlp_dim, d_model, mean_rows)
-        grouped = (f"grouped-kernel tiles={up.m}x{d_model}x{up.n},"
-                   f"{down.m}x{mlp_dim}x{down.n} "
-                   f"steps<={-(-rows // up.m) + spec.n_held - 1}")
+        bank = (f" bank=grouped-kernel tiles={up.m}x{d_model}x{up.n},"
+                f"{down.m}x{mlp_dim}x{down.n} "
+                f"steps<={-(-rows // up.m) + spec.n_held - 1}")
     last = spec.held_first + spec.n_held - 1
     print(f"[moe] experts={spec.num_experts} held={spec.n_held} "
           f"ids={spec.held_first}-{last} top_k={spec.top_k} "
           f"groups={spec.n_groups}/{spec.topk_groups} score={spec.score} "
-          f"tokens={n_tokens} row_bound={rows}"
-          + (f" bank=padded slots={slots} spill={grouped}" if slots
-             else f" bank={grouped}" if grouped != "grouped" else "")
+          f"tokens={n_tokens} row_bound={rows}{bank}"
           + f" shared={'none' if shared is None else shared}"
           + (f" bias_rate={spec.bias_rate:g}" if spec.bias_rate else ""),
           file=sys.stderr, flush=True)
@@ -479,26 +441,9 @@ def _grouped_bank(rows, sizes, kernels, dtype, mean_rows):
     return held(out)
 
 
-def _padded_bank(rows, sizes, kernels, slots, dtype):
-    """Every held expert its own ``slots`` rows (``held_rows(..., slots)``)
-    through ONE batched product a projection: a fixed shape, so its time
-    does not follow the routing as the grouped product's does. Rows past an
-    expert's size are zeroed as above."""
-    n_held, D = kernels[0].shape[0], rows.shape[-1]
-    real = (jnp.arange(slots) < sizes[:, None])[..., None]
-    held = lambda a: jnp.where(real, a, 0)  # noqa: E731
-    bdot = lambda a, w: held(jnp.einsum(  # noqa: E731
-        "esd,edf->esf", held(a), w, preferred_element_type=jnp.float32))
-    x = rows.reshape(n_held, slots, D)
-    hidden = (nn.silu(bdot(x, kernels[0])) * bdot(x, kernels[1])
-              ).astype(dtype)
-    return bdot(hidden, kernels[2]).reshape(rows.shape[0], D)
-
-
 class _ExpertBank(nn.Module):
-    """The held experts' SwiGLU weights, stacked: applied to rows sorted by
-    expert through the grouped product (``__call__``), or handed out
-    (``kernels``) to the two-form path of ``HeldExpertsMLP``."""
+    """The held experts' SwiGLU weights, stacked, applied to rows sorted by
+    expert through the grouped product."""
 
     held: int
     d_model: int
@@ -512,16 +457,10 @@ class _ExpertBank(nn.Module):
         self.up_proj = _Kernel((self.held, D, F), self.param_dtype)
         self.down_proj = _Kernel((self.held, F, D), self.param_dtype)
 
-    def _thunks(self):
-        return tuple(lambda k=k: jnp.asarray(k(), self.dtype) for k in (
-            self.gate_proj, self.up_proj, self.down_proj))
-
     def __call__(self, rows, sizes, mean_rows):
-        return _grouped_bank(rows, sizes, self._thunks(), self.dtype,
-                             mean_rows)
-
-    def kernels(self):
-        return tuple(k() for k in self._thunks())
+        kernels = tuple(lambda k=k: jnp.asarray(k(), self.dtype) for k in (
+            self.gate_proj, self.up_proj, self.down_proj))
+        return _grouped_bank(rows, sizes, kernels, self.dtype, mean_rows)
 
 
 class _Router(nn.Module):
@@ -583,45 +522,22 @@ class HeldExpertsMLP(nn.Module):
         B, S, D = x.shape
         N, spec, F = B * S, self.spec, self.mlp_dim
         rows = spec.row_bound(N)
-        slots = padded_slots(spec, rows, D, F)
         shared_dim = None if spec.shared_mlp_dim < 0 \
             else spec.shared_mlp_dim or F
         mean_rows = spec.mean_rows(N)
-        _log_plan(spec, N, rows, slots, shared_dim, D, F)
+        _log_plan(spec, N, rows, shared_dim, D, F)
         xf = x.reshape(N, D)
         scores, bias = _Router(spec.num_experts, spec.score,
                                name="router")(xf)
         ids, weights = group_limited_topk(scores, bias, spec)
         token, weight, sizes, counts, over = held_rows(ids, weights, spec,
                                                        rows)
-        bank = _ExpertBank(spec.n_held, D, F, self.dtype, self.param_dtype,
-                           name="experts")
-
-        def routed_by(token, weight, sizes, product):
-            out_rows = product(xf[token].astype(self.dtype), sizes)
-            # (rows of no expert: the bank zeroed them)
-            return jnp.zeros((N, D), jnp.float32).at[token].add(
-                out_rows * weight[:, None])
-
-        if slots:
-            # every expert its own slots, one batched product; a step on
-            # which some expert has more pairs than slots (the first layer's
-            # counts swing 55-390 with the batch at a mean of 205, and one
-            # step in 1200 went past 820 on the v5e) takes the grouped
-            # product instead: the bound stays the layer's, as above
-            w = bank.kernels()
-            own = held_rows(ids, weights, spec, spec.n_held * slots, slots)
-            routed = jax.lax.cond(
-                own[4] > 0,
-                lambda: routed_by(token, weight, sizes, lambda r, n:
-                                  _grouped_bank(r, n, [lambda k=k: k
-                                                       for k in w],
-                                                self.dtype, mean_rows)),
-                lambda: routed_by(*own[:3], lambda r, n: _padded_bank(
-                    r, n, w, slots, self.dtype)))
-        else:
-            routed = routed_by(token, weight, sizes, lambda r, n: bank(
-                r, n, mean_rows))
+        out_rows = _ExpertBank(spec.n_held, D, F, self.dtype,
+                               self.param_dtype, name="experts")(
+            xf[token].astype(self.dtype), sizes, mean_rows)
+        # (rows of no expert: the bank zeroed them)
+        routed = jnp.zeros((N, D), jnp.float32).at[token].add(
+            out_rows * weight[:, None])
         if spec.bias_rate:
             if bias is None:
                 raise ValueError("moe bias_rate: a softmax router has no "

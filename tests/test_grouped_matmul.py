@@ -29,6 +29,9 @@ CELLS = {
     "lagunas": (3072, 1024, 320),
     "ling3f": (2560, 768, 256),
 }
+# with the head-share cell, whose experts are too wide for the interpreter
+# at every routing: its own test below, at half its widths
+EXPERT_CELLS = {**CELLS, "solar2": (4096, 1280, 204)}
 ROUTINGS = {
     "on_tile_edges": [128, 128, 128, 128],  # and a fifth of the bound free
     "off_tile_edges": [100, 60, 200, 90],
@@ -41,12 +44,12 @@ ROUTINGS = {
 }
 
 
-def _operands(K, N, seed=0):
+def _operands(K, N, seed=0, rows=R, groups=G):
     kx, kw, kc = jax.random.split(jax.random.PRNGKey(seed), 3)
-    x = jax.random.normal(kx, (R, K), BF16)
-    w = (0.05 * jax.random.normal(kw, (G, K, N))).astype(BF16)
+    x = jax.random.normal(kx, (rows, K), BF16)
+    w = (0.05 * jax.random.normal(kw, (groups, K, N))).astype(BF16)
     # a cotangent the kernels' bfloat16 rounding leaves as it is
-    ct = jax.random.normal(kc, (R, N), BF16).astype(F32)
+    ct = jax.random.normal(kc, (rows, N), BF16).astype(F32)
     return x, w, ct
 
 
@@ -56,7 +59,7 @@ def _both(x, w, ct, sizes, mean_rows, kernel):
     row-shaped results. By the kernels, which get NaN in every row of no
     group, of the rows and of the cotangent, and must not read one; or by
     ``ragged_dot`` with those rows zeroed on both sides."""
-    in_group = (jnp.arange(R) < jnp.sum(sizes))[:, None]
+    in_group = (jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None]
     held = lambda a: jnp.where(in_group, a, 0)  # noqa: E731
     if kernel:
         dirty = lambda a: jnp.where(in_group, a, jnp.nan)  # noqa: E731
@@ -95,6 +98,37 @@ def test_values_and_both_gradients_match_ragged_dot(cell, routing):
     assert not np.asarray(got[2], np.float32)[empty].any()
 
 
+# The head-share cell's geometry at half its widths and a quarter of its row
+# bound: 4096 x 1280 is K : N = 16 : 5 in whole tiles of 128, eight held
+# experts of ~200 pairs a layer (one with none, one past a quarter of the
+# rows), a bound that is no whole row tile.
+WIDE_ROWS, WIDE_SIZES = 1640, [210, 0, 190, 420, 150, 230, 180, 205]
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """{by the kernels?: for gate / up's orientation and for down's, (out,
+    d rows, d weights)}."""
+    assert max(WIDE_SIZES) > WIDE_ROWS // 4
+    sizes = jnp.asarray(WIDE_SIZES, jnp.int32)
+    assert gm.tile_sizes(2048, 640, 204).m == 128
+    return {kernel: [_both(*_operands(K, N, rows=WIDE_ROWS, groups=8), sizes,
+                           204, kernel)
+                     for K, N in ((2048, 640), (640, 2048))]
+            for kernel in (True, False)}
+
+
+@pytest.mark.parametrize("what", ["out", "d_rows", "d_weights"])
+def test_the_head_share_cells_geometry_matches_ragged_dot(wide, what):
+    at = ("out", "d_rows", "d_weights").index(what)
+    for got, want, shape in zip(wide[True], wide[False], ("up", "down")):
+        assert got[at].shape == want[at].shape \
+            and got[at].dtype == want[at].dtype
+        _close(got[at], want[at], f"{what} of {shape} at the wide geometry")
+        if what == "d_weights":  # the expert of no pairs: zeros, written
+            assert not np.asarray(got[at], np.float32)[1].any()
+
+
 @pytest.mark.parametrize("m", [16, 128, 512])
 @pytest.mark.parametrize("routing", ROUTINGS)
 def test_the_grids_table_visits_each_tile_of_each_group_once(routing, m):
@@ -122,17 +156,7 @@ def test_the_grids_table_visits_each_tile_of_each_group_once(routing, m):
 
 
 def test_the_tile_rule_at_the_cells_shapes_and_what_it_refuses():
-    # a group's whole matrix a block wherever it has 4 Mi elements or fewer
-    assert gm.tile_sizes(2048, 1792, 2048) == gm.Tiles(256, 1792, 2048,
-                                                       2048, 1792)
-    assert gm.tile_sizes(1792, 2048, 2048).m == 256
-    assert gm.tile_sizes(2048, 768, 768) == gm.Tiles(128, 768, 2048, 2048,
-                                                     768)
-    assert gm.tile_sizes(3072, 1024, 320).m == 128
-    assert gm.tile_sizes(2560, 768, 256).m == 128
-    assert gm.tile_sizes(4096, 1280, 205) == gm.Tiles(128, 640, 2048, 2048,
-                                                      1280)
-    for D, F, mean_rows in (*CELLS.values(), (4096, 1280, 205)):
+    for D, F, mean_rows in EXPERT_CELLS.values():
         for K, N in ((D, F), (F, D)):
             t = gm.tile_sizes(K, N, mean_rows)
             assert N % t.n == K % t.back_n == K % t.wk == N % t.wn == 0
@@ -142,6 +166,37 @@ def test_the_tile_rule_at_the_cells_shapes_and_what_it_refuses():
     with pytest.raises(ValueError, match="do not fit"):
         gm.grouped_matmul(x, w, jnp.zeros((G,), jnp.int32), mean_rows=64,
                           tiles=gm.Tiles(128, 96, 128, 128, 128))
+
+
+# The rule at every expert cell's shape, for gate / up and for down, pinned
+# whole: a cell's program is its tiles, so a new arm of the rule may move
+# only shapes no cell has (a group's whole matrix is a block wherever it has
+# 4 Mi elements or fewer).
+TILES_AT_THE_CELLS = {
+    "lfm2moe": (gm.Tiles(256, 1792, 2048, 2048, 1792),
+                gm.Tiles(256, 2048, 1792, 1792, 2048)),
+    "kanana2": (gm.Tiles(128, 768, 2048, 2048, 768),
+                gm.Tiles(128, 2048, 768, 768, 2048)),
+    "lagunas": (gm.Tiles(128, 1024, 3072, 3072, 1024),
+                gm.Tiles(128, 3072, 1024, 1024, 1536)),
+    "ling3f": (gm.Tiles(128, 768, 2560, 2560, 768),
+               gm.Tiles(128, 2560, 768, 768, 1280)),
+    "solar2": (gm.Tiles(128, 640, 2048, 2048, 1280),
+               gm.Tiles(128, 2048, 640, 1280, 2048)),
+}
+
+
+@pytest.mark.parametrize("cell", TILES_AT_THE_CELLS)
+def test_every_width_takes_the_kernels_under_the_tiles_it_had(monkeypatch,
+                                                              cell):
+    from pytorch_distributed_train_tpu.ops import attention
+
+    D, F, mean_rows = EXPERT_CELLS[cell]
+    up, down = TILES_AT_THE_CELLS[cell]
+    assert gm.tile_sizes(D, F, mean_rows) == up
+    assert gm.tile_sizes(F, D, mean_rows) == down
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert gm.unsupported(D, F) is None and gm.unsupported(F, D) is None
 
 
 # ------------------------------------------------- through the bank itself

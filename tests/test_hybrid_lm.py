@@ -133,93 +133,118 @@ def test_pairs_past_the_row_bound_are_counted_never_silent():
     assert _spec(capacity_factor=1e9).row_bound(80) == 80 * 4
 
 
-@pytest.mark.parametrize("capacity,spills", [(4.0, False), (1.0, True)])
-def test_the_banks_padded_form_is_the_grouped_product_at_a_fixed_shape(
-        monkeypatch, capfd, capacity, spills):
-    """Every held expert its own slots and ONE batched product (what a wide
-    expert takes: ``padded_slots``) against the grouped product over the
-    packed rows: the layer's output, its stats and every gradient. A step
-    on which some expert has more pairs than slots takes the grouped product
-    (``spills``), so the bound stays the layer's."""
-    spec = _spec(score="softmax", n_groups=1, topk_groups=1, held_first=4,
-                 capacity_factor=capacity)
-    layer = _layer(spec)
-    x = jax.random.normal(jax.random.PRNGKey(6), (2, 64, 16))
+@pytest.mark.parametrize("routing", ["balanced", "skewed_onto_one_expert"])
+def test_the_layer_at_a_wide_expert_is_the_plain_sum_of_its_held_experts(
+        monkeypatch, capfd, routing):
+    """The bank has ONE form at every width: the layer with matrices of whole
+    tiles of 128, its grouped products in the Pallas kernels (interpreted,
+    as on a TPU), against every held expert applied to EVERY token and
+    summed under the router's weights, no grouped product anywhere: the
+    output and every gradient. ``skewed``: most tokens lean towards one held
+    expert, which gets more pairs than ``row_bound / held`` and stays inside
+    the layer's bound (the bound is the layer's, not an expert's): nothing
+    is dropped, nothing skipped."""
+    from pytorch_distributed_train_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "unsupported", lambda K, N: None)
+    monkeypatch.setattr(moe, "_moe_logged", set())
+    spec = _spec(score="softmax", n_groups=1, topk_groups=1, held_first=4)
+    D, F, N = 128, 256, 128
+    layer = moe.HeldExpertsMLP(spec, LlamaMLP, F, F32, F32)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, N // 2, D))
     params = layer.init(jax.random.PRNGKey(7), x)["params"]
-    if spills:  # most tokens lean towards held expert 5
+    if routing != "balanced":
         x = x + 40.0 * params["router"]["kernel"][:, 5]
     w = jax.random.normal(jax.random.PRNGKey(8), x.shape)
 
-    def both(p, x):
+    def by_the_layer(p, x):
         y, stats = layer.apply({"params": p}, x)
         return jnp.sum(y * w), (y, stats)
 
-    run = jax.value_and_grad(both, argnums=(0, 1), has_aux=True)
-    rows = spec.row_bound(128)
-    with jax.default_matmul_precision("highest"):
-        (_, (y0, s0)), g0 = run(params, x)
-        monkeypatch.setattr(moe, "PADDED_MIN_WEIGHT", 1)
-        monkeypatch.setattr(moe, "_moe_logged", set())
-        capfd.readouterr()
-        (_, (y1, s1)), g1 = run(params, x)
-        scores = jax.nn.softmax(x.reshape(128, 16)
-                                @ params["router"]["kernel"], -1)
-        ids, wts = moe.group_limited_topk(scores, None, spec)
-        over = moe.held_rows(ids, wts, spec, rows, rows // 4)[4]
-    assert f" row_bound={rows} bank=padded slots={rows // 4} " \
-        "spill=grouped" in capfd.readouterr().err
-    assert (int(over) > 0) == spills
-    _close(y1, y0)
-    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
-    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
-        _close(b, a)
+    def by_every_expert_on_every_token(p, x):
+        xf = x.reshape(N, D)
+        ids, wts = moe.group_limited_topk(
+            jax.nn.softmax(xf @ p["router"]["kernel"], -1), None, spec)
+        e, s = ({k: v["kernel"] for k, v in p[part].items()}
+                for part in ("experts", "shared"))
+        y = _swiglu(xf, s["gate_proj"], s["up_proj"], s["down_proj"])
+        for held in range(spec.n_held):
+            share = jnp.sum(jnp.where(ids == spec.held_first + held, wts, 0),
+                            1)
+            y = y + share[:, None] * _swiglu(
+                xf, e["gate_proj"][held], e["up_proj"][held],
+                e["down_proj"][held])
+        return jnp.sum(y.reshape(x.shape) * w), y.reshape(x.shape)
+
+    (_, (y, stats)), got = jax.value_and_grad(
+        by_the_layer, argnums=(0, 1), has_aux=True)(params, x)
+    rows = spec.row_bound(N)
+    assert f" row_bound={rows} bank=grouped-kernel tiles=128x{D}x{F}," \
+        f"128x{F}x{D} " in capfd.readouterr().err
+    (_, want_y), want = jax.value_and_grad(
+        by_every_expert_on_every_token, argnums=(0, 1), has_aux=True)(
+            params, x)
+    fullest, _, over, _ = np.asarray(stats)
+    assert over == 0
+    assert (fullest > rows // spec.n_held) == (routing != "balanced")
+    _close(y, want_y)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        assert np.abs(np.asarray(b)).max() > 0, path
+        _close(a, b)
 
 
-def test_an_experts_own_slots_and_the_rule_that_picks_the_padded_bank():
-    spec = _spec(score="softmax", n_groups=1, topk_groups=1, held_first=4)
-    # 80 pairs on one expert, 6 slots an expert: 74 past ITS slots
-    ids = jnp.tile(jnp.array([[5, 9, 10, 11]]), (80, 1))
-    token, weight, sizes, counts, over = moe.held_rows(
-        ids, jnp.full((80, 4), 0.25), spec, 24, 6)
-    assert int(over) == 74
-    np.testing.assert_array_equal(np.asarray(sizes), [0, 6, 0, 0])
-    np.testing.assert_array_equal(np.asarray(token)[6:12], np.arange(6))
-    assert float(jnp.sum(weight)) == 6 * 0.25
-    # the rule, from what a call sees: the expert's matrix
-    assert moe.padded_slots(spec, 6560, 4096, 1280) == 6560 // 4
-    assert moe.padded_slots(spec, 6560, 2560, 768) == 0
-    assert moe.padded_slots(spec, 6560, 3072, 1024) == 0
+def test_the_head_share_cells_layer_holds_no_cond_and_one_held_rows():
+    """The jaxpr of ``HeldExpertsMLP`` at the head-share cell's shape (8192
+    tokens, 8 of 320 experts held, 4096 x 1280; abstract: nothing compiles
+    or runs): no ``cond`` (a second form of the bank behind one kept both
+    branches' residuals, 3 GiB of the step's program: PERF.md section 6,
+    PR 47), ONE ``held_rows`` (its two products once) and the bank's three
+    grouped products."""
+    spec = moe.HeldExpertsSpec(num_experts=320, top_k=8, score="softmax",
+                               held_first=0, held=8)
+    assert spec.row_bound(8192) == 6560 and spec.mean_rows(8192) == 204
+    layer = moe.HeldExpertsMLP(spec, LlamaMLP, 1280, jnp.bfloat16, F32)
+    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    found = list(eqns(jax.make_jaxpr(layer.apply)(params, x).jaxpr))
+    names = [e.primitive.name for e in found]
+    assert "cond" not in names and "while" not in names, set(names)
+    assert names.count("ragged_dot_general") \
+        + names.count("ragged_dot") == 3, set(names)
+    in_held_rows = [e.primitive.name for e in found
+                    if "held_rows" in str(e.source_info.name_stack)]
+    assert in_held_rows.count("dot_general") == 2, in_held_rows
 
 
-def _pairs_by_numpy(ids, wts, spec, rows, slots):
+def _pairs_by_numpy(ids, wts, spec, rows):
     """``held_rows`` in plain NumPy: the pairs on held experts sorted by
-    (expert, token), packed up to ``rows`` or ``slots`` an expert."""
+    (expert, token), packed up to ``rows``."""
     ids, wts, held = np.asarray(ids), np.asarray(wts), spec.n_held
     pairs = sorted((int(e) - spec.held_first, t, float(wts[t, c]))
                    for (t, c), e in np.ndenumerate(ids)
                    if 0 <= e - spec.held_first < held)
     counts = np.bincount([e for e, _, _ in pairs], minlength=held)
     token, weight = np.zeros(rows, np.int32), np.zeros(rows, np.float32)
-    if slots:
-        sizes = np.minimum(counts, slots)
-        for e in range(held):
-            mine = [p for p in pairs if p[0] == e][:slots]
-            at = slice(e * slots, e * slots + len(mine))
-            token[at], weight[at] = [p[1] for p in mine], [p[2] for p in mine]
-    else:
-        ends = np.minimum(np.cumsum(counts), rows)
-        sizes = np.diff(ends, prepend=0)
-        kept = pairs[:rows]
-        token[:len(kept)] = [p[1] for p in kept]
-        weight[:len(kept)] = [p[2] for p in kept]
+    ends = np.minimum(np.cumsum(counts), rows)
+    sizes = np.diff(ends, prepend=0)
+    kept = pairs[:rows]
+    token[:len(kept)] = [p[1] for p in kept]
+    weight[:len(kept)] = [p[2] for p in kept]
     return token, weight, sizes, counts, counts.sum() - sizes.sum()
 
 
 @pytest.mark.parametrize("case", [
     *(f"nth_set-fill{fill}-{order}" for fill in (0, 0.1, 0.9, 1)
       for order in ("in_order", "shuffled")),
-    "held_rows-packed", "held_rows-packed-past_the_bound",
-    "held_rows-slots", "held_rows-slots-past_an_experts_own"])
+    "held_rows-packed", "held_rows-packed-past_the_bound"])
 def test_the_rth_row_is_the_rth_set_entry_of_the_expert_token_table(case):
     """``_nth_set`` against NumPy's ``flatnonzero`` (tables of several
     blocks whose length is no multiple of 128, empty, sparse, dense and
@@ -246,10 +271,8 @@ def test_the_rth_row_is_the_rth_set_entry_of_the_expert_token_table(case):
                        axis=1)[:, :4]
     wts = rng.random((200, 4), dtype=np.float32)
     rows = spec.row_bound(200)           # 400, or 56: under the 90-odd pairs
-    slots = rows // 4 if how[0] == "slots" else 0
-    got = jax.jit(lambda i, w: moe.held_rows(i, w, spec, rows, slots))(
-        ids, wts)
-    want = _pairs_by_numpy(ids, wts, spec, rows, slots)
+    got = jax.jit(lambda i, w: moe.held_rows(i, w, spec, rows))(ids, wts)
+    want = _pairs_by_numpy(ids, wts, spec, rows)
     assert (int(want[4]) > 0) == past
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w)
@@ -259,7 +282,7 @@ def test_held_rows_at_the_all_latent_cells_shape_holds_no_loop_and_no_sort():
     """The jaxpr of ``held_rows`` at 16384 tokens x 6 choices, 16 of 128
     experts held (abstract: nothing compiles or runs): no ``while`` /
     ``scan`` (a binary search is 19 dependent passes of scalar gathers
-    there: PERF.md section 6, PR 42) and no ``sort``, in either form."""
+    there: PERF.md section 6, PR 42) and no ``sort``."""
     spec = moe.HeldExpertsSpec(num_experts=128, top_k=6, held_first=16,
                                held=16)
     rows = spec.row_bound(16384)
@@ -271,13 +294,12 @@ def test_held_rows_at_the_all_latent_cells_shape_holds_no_loop_and_no_sort():
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from primitives(sub)
 
-    for slots in (0, rows // 16):
-        names = set(primitives(jax.make_jaxpr(
-            lambda i, w: moe.held_rows(i, w, spec, rows, slots))(
-                jax.ShapeDtypeStruct((16384, 6), jnp.int32),
-                jax.ShapeDtypeStruct((16384, 6), F32)).jaxpr))
-        assert "dot_general" in names
-        assert not names & {"while", "scan", "sort"}, names
+    names = set(primitives(jax.make_jaxpr(
+        lambda i, w: moe.held_rows(i, w, spec, rows))(
+            jax.ShapeDtypeStruct((16384, 6), jnp.int32),
+            jax.ShapeDtypeStruct((16384, 6), F32)).jaxpr))
+    assert "dot_general" in names
+    assert not names & {"while", "scan", "sort"}, names
 
 
 def test_an_overflowing_step_keeps_its_state_and_reports_update_skipped():
