@@ -1427,6 +1427,66 @@ def _lfm2_8b_a1b_lm_ep4() -> TrainConfig:
     return c
 
 
+def _mellum2_12b_a2_5b_lm_ep4() -> TrainConfig:
+    """One chip's share of Mellum2-12B-A2.5B's language model (JetBrains,
+    https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct config.json,
+    ``model_type: mellum``) in a long-context stage at 16384 tokens: every
+    width as published (hidden 2304, 32 query heads over 4 KV heads of 128
+    with an RMSNorm over each head's q and k and no output gate, a window
+    of 1024 on the sliding layers, experts of 896, a 64-wide softmax
+    router, 8 a token renormalised over the eight, NO shared expert and no
+    dense layer, eps 1e-6, an untied head); layers 0-3 of the 28: one
+    whole period ``sliding, sliding, sliding, full``. The sliding layers
+    turn by plain rope at theta 5e5, the full layer by YaRN at factor 16
+    from 8192 original positions over the whole head, so half of a
+    sequence's positions lie past the original length. Each layer is shared
+    by 4 chips by expert parallelism: 16 of its 64 routed experts here, a
+    quarter of the 98304-row vocabulary, every head whole. 595.2 M
+    parameters, 9.52 GB with AdamW's float32 state
+    (benchmark/configs/mellum2_12b_a2_5b_lm_ep4.json says what was
+    assumed)."""
+    c = TrainConfig(preset="mellum2_12b_a2_5b_lm_ep4")
+    c.model = ModelConfig(
+        name="hybrid_lm", hidden_size=2304, num_layers=4, num_heads=32,
+        num_kv_heads=4, head_dim=128, vocab_size=24576, max_seq_len=16384,
+        mlp_dim=7168,  # published; every layer is sparse, so none reads it
+        rms_norm_eps=1e-6, remat=True,
+        layer_kinds=("gqa_window", "gqa_window", "gqa_window", "gqa_full"),
+        attention_window=1024, rope_theta=5e5, window_rope_theta=5e5,
+        rope_scaling=16.0, rope_scaling_type="yarn",
+        rope_original_max_len=8192, rope_beta_fast=32.0, rope_beta_slow=1.0,
+        rope_attention_factor=1.2772588722239782, partial_rotary_factor=1.0,
+        gqa_qk_norm=True, gqa_out_gate="none", first_dense_layers=0,
+        num_experts=64, expert_top_k=8, moe_score="softmax",
+        moe_routed_scale=1.0, moe_mlp_dim=896, moe_shared_mlp_dim=-1,
+        moe_bias_rate=0.0, experts_held=16, experts_held_first=0,
+        # a token meets TWO held experts on average (8 x 16 / 64), so 4.0
+        # would be the worst case itself (8 N rows). 1.25 holds for weights
+        # that route EVENLY, as a trained model's entering a long-context
+        # stage do (read on the chip: the configuration file's `assumed`).
+        # It does NOT hold from models/hybrid.py's own flat N(0, 0.02) init
+        # at this length: the routers collapse behind the attention mixers
+        # and 5-25 % of the steps pass the bound and are skipped, at 1.5
+        # too, at 2.0 still one step in 200 (PERF.md section 6, PR 48;
+        # ROADMAP R15). From scratch, raise it with
+        # --set model.expert_capacity_factor=... (4.0 cannot be passed)
+        expert_capacity_factor=1.25,
+    )
+    # 4096 synthetic sequences of 16384 tokens: 4096 steps an epoch
+    c.data = DataConfig(dataset="synthetic_lm", batch_size=1, seq_len=16384,
+                        synthetic_size=4096)
+    c.optim = OptimConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1, beta2=0.95,
+        schedule="cosine", warmup_steps=2000, grad_clip_norm=1.0,
+        decay_exclude=r"scale$",  # the matrices and the embedding decay
+    )
+    c.precision = PrecisionConfig(compute_dtype="bfloat16")
+    c.mesh = MeshConfig(data=-1)
+    c.total_steps = 500000
+    c.loss = "causal_lm_xent"
+    return c
+
+
 def _ouro_2_6b_lm_l8() -> TrainConfig:
     """One pipeline stage of Ouro-2.6B (ByteDance,
     https://huggingface.co/ByteDance/Ouro-2.6B config.json; arXiv:2510.25741):
@@ -1501,6 +1561,7 @@ _PRESETS = {
     "solar_open2_lm_ep40_tp8": _solar_open2_lm_ep40_tp8,
     "kanana2_lm_ep8": _kanana2_lm_ep8,
     "lfm2_8b_a1b_lm_ep4": _lfm2_8b_a1b_lm_ep4,
+    "mellum2_12b_a2_5b_lm_ep4": _mellum2_12b_a2_5b_lm_ep4,
 }
 
 

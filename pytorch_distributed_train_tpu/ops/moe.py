@@ -532,12 +532,15 @@ class HeldExpertsMLP(nn.Module):
         ids, weights = group_limited_topk(scores, bias, spec)
         token, weight, sizes, counts, over = held_rows(ids, weights, spec,
                                                        rows)
+        with jax.named_scope("held_gather"):
+            in_rows = xf[token].astype(self.dtype)
         out_rows = _ExpertBank(spec.n_held, D, F, self.dtype,
                                self.param_dtype, name="experts")(
-            xf[token].astype(self.dtype), sizes, mean_rows)
+            in_rows, sizes, mean_rows)
         # (rows of no expert: the bank zeroed them)
-        routed = jnp.zeros((N, D), jnp.float32).at[token].add(
-            out_rows * weight[:, None])
+        with jax.named_scope("held_combine"):
+            routed = jnp.zeros((N, D), jnp.float32).at[token].add(
+                out_rows * weight[:, None])
         if spec.bias_rate:
             if bias is None:
                 raise ValueError("moe bias_rate: a softmax router has no "

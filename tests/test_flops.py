@@ -127,6 +127,41 @@ class TestConventions:
             cfg, gqa_out_gate="head"), s) - total \
             == pytest.approx(2.0 * d * 32)
 
+    def test_hybrid_counts_a_16k_band_two_held_experts_no_dense_layer(self):
+        """The 16k window/full preset at S = 16384, its pieces written out
+        (millions a token): a mixer's four projections at 32 / 4 heads of
+        128 with no gate, the window layers' band (992.03 pairs a token: a
+        sixteenth of the sequence) against the full layer's causal half
+        (8192.5), a router and TWO held experts a token (8 x 16 / 64) with
+        no shared expert and no dense layer, the untied head."""
+        from pytorch_distributed_train_tpu.config import get_preset
+
+        cfg = get_preset("mellum2_12b_a2_5b_lm_ep4").model
+        d, s = 2304, 16384
+        projections = 4.0 * d * 4096 + 4.0 * d * 512
+        band = (1024 * 1025 / 2 + (s - 1024) * 1024) / s
+        assert flops.band_pairs_per_token(s, 1024) == band == 992.03125
+        routed = 2.0 * d * 64 + 2 * 6.0 * d * 896
+        pieces = {"window layer": projections + 4.0 * 4096 * band + routed,
+                  "full layer": projections + 4.0 * 4096 * (s + 1) / 2
+                  + routed,
+                  "head": 2.0 * d * 24576}
+        assert {k: round(v / 1e6, 1) for k, v in pieces.items()} == {
+            "window layer": 83.8, "full layer": 201.8, "head": 113.2}
+        total = 3 * pieces["window layer"] + pieces["full layer"] \
+            + pieces["head"]
+        assert flops.fwd_flops_per_item(cfg, s) == pytest.approx(total)
+        assert total == pytest.approx(566.37e6, rel=1e-4)
+        # doubling the sequence grows the full layer alone: the band is
+        # already inside the window
+        assert flops.fwd_flops_per_item(cfg, 2 * s) - total \
+            == pytest.approx(2.0 * 4096 * s + 3 * 4.0 * 4096 * (
+                flops.band_pairs_per_token(2 * s, 1024) - band))
+        # the mlp_dim the preset carries (published, unread) counts nowhere
+        import dataclasses
+        assert flops.fwd_flops_per_item(dataclasses.replace(
+            cfg, mlp_dim=1), s) == pytest.approx(total)
+
     def test_seq_defaults_to_config_max(self):
         cfg = _llama_1b()
         assert flops.fwd_flops_per_item(cfg) == \

@@ -183,6 +183,17 @@ PATHS = [
     (FWD + "HybridLM/lm_head/dot_general", "head_loss", "other"),
     (BWD + "HybridLM/lm_head/transpose", "head_loss", "other"),
     (BWD + "HybridLM/layer1/gqa/k_norm/mul", "backward", "attention"),
+    # the expert layer's gather and combine under scopes of their own, as
+    # `held_rows` and `grouped_product` are: the experts' component in every
+    # phase (the gather's transpose is a scatter-add, the combine's a gather)
+    (FWD + "HybridLM/layer0/moe/held_gather/gather", "forward", "experts"),
+    (FWD + "HybridLM/layer0/moe/held_combine/scatter-add", "forward",
+     "experts"),
+    (BWD + REMAT + "layer2/moe/held_gather/gather", "recompute", "experts"),
+    (BWD + "HybridLM/jvp(forward)/HybridLM/checkpoint/layer2/moe/"
+     "held_gather/scatter-add", "backward", "experts"),
+    (BWD + "HybridLM/jvp(forward)/HybridLM/checkpoint/layer3/moe/"
+     "held_combine/gather", "backward", "experts"),
     (FWD + LOOP + "layer7/attn_out_norm/rsqrt", "forward", "norm"),
     (BWD + LOOP + "LlamaForCausalLM.one_pass/loop_pass/checkpoint/"
      "rematted_computation/layer2/mlp/gate_proj/dot_general",
@@ -365,7 +376,11 @@ def test_a_capture_summary_says_what_each_top_operation_is(tmp_path,
 
 # ------------------------------------- the four configurations' own steps
 CONFIGS = ("gpt2_small", "ling3_flash_lm_ep64", "laguna_s_lm_ep32",
-           "ouro_2_6b_lm_l8", "lfm2_8b_a1b_lm_ep4")
+           "ouro_2_6b_lm_l8", "lfm2_8b_a1b_lm_ep4",
+           "mellum2_12b_a2_5b_lm_ep4")
+EXPERT_CONFIGS = ("ling3_flash_lm_ep64", "laguna_s_lm_ep32",
+                  "lfm2_8b_a1b_lm_ep4", "mellum2_12b_a2_5b_lm_ep4")
+ALL_SPARSE = ("mellum2_12b_a2_5b_lm_ep4",)  # no layer with a dense FFN
 # What may stay outside every phase, as the four steps compile here: arguments
 # named by their place in the state, reducers' bodies (a bare primitive, under
 # `checkpoint/` inside a remat'd block, under the scanned pass's own name in
@@ -445,13 +460,40 @@ def test_every_scoped_instruction_of_a_cells_step_lies_in_a_phase(
         assert counts[phase] > 50, (phase, counts)
     assert (counts["recompute"] > 50) == remat, counts
     assert counts["other"] < 0.1 * len(built.scopes), counts
-    for component in ("attention", "ffn", "norm", "embed"):
+    for component in ("attention", "norm", "embed"):
         assert components[component] > 0, components
-    experts = config in ("ling3_flash_lm_ep64", "laguna_s_lm_ep32",
-                         "lfm2_8b_a1b_lm_ep4")
-    assert (components["experts"] > 0) == experts, components
+    assert (components["ffn"] > 0) == (config not in ALL_SPARSE), components
+    assert (components["experts"] > 0) == (config in EXPERT_CONFIGS), \
+        components
     # under the model's phases little is left without a component
     assert components["other"] < 0.25 * sum(components.values()), components
+
+
+@pytest.mark.parametrize("scope", ["held_gather", "held_combine"])
+def test_an_expert_layers_gather_and_combine_have_scopes_in_every_phase(
+        rehearsal_maps, scope):
+    """The 16k window/full preset's map: the rows read out of the tokens
+    (`held_gather`) and the weighted results added back (`held_combine`)
+    sit under scopes of their own beneath every layer's `moe`, in the
+    forward, the backward and (the gather) the forward run again, all of it
+    the experts' component; `held_rows` and `grouped_product` beside them, so the map
+    parts the expert layer's four costs."""
+    built = rehearsal_maps["mellum2_12b_a2_5b_lm_ep4"]
+    under = {name: op for name, op in built.scopes.items()
+             if scope in step_program._SEGMENTS.split(op)}
+    assert len(under) >= 6  # three layers, two phases or three
+    phases = set()
+    for name, op in under.items():
+        assert "moe" in step_program._SEGMENTS.split(op), op
+        phase, component, _ = built.place(name)
+        assert component == "experts", op
+        phases.add(phase)
+    # (the combine is linear in its rows: the backward needs no rerun of it)
+    assert phases == set(step_program.MODEL_PHASES) - (
+        {"recompute"} if scope == "held_combine" else set())
+    for other in ("held_rows", "grouped_product"):
+        assert any(other in step_program._SEGMENTS.split(op)
+                   for op in built.scopes.values()), other
 
 
 def test_nothing_of_a_conv_module_is_left_without_a_component(

@@ -128,6 +128,11 @@ FLASH_SHAPES = [
     # the short-convolution cell's one attention layer (preset
     # lfm2_8b_a1b_lm_ep4): 32 query heads over 8 KV heads of 64, two rows
     ("s8192_d64_gqa32_over_8_b2", 2, 8192, 32, 8, 64, 0),
+    # the 16k window/full cell's two kinds (preset mellum2_12b_a2_5b_lm_ep4):
+    # 32 query heads over 4 KV heads of 128 at 16384 keys, where the
+    # backward is the SPLIT pair (`backward_plan`: 41.9 MB would be resident)
+    ("s16384_d128_gqa32_over_4_window1024", 1, 16384, 32, 4, 128, 1024),
+    ("s16384_d128_gqa32_over_4", 1, 16384, 32, 4, 128, 0),
 ]
 
 
@@ -219,6 +224,8 @@ GROUPED_SHAPES = [  # rows of the bound, held experts, D, F, mean rows
     ("ling3f", 8192, 8, 2560, 768, 256),
     # the head-share cell: a row bound that is no whole row tile
     ("solar2", 6560, 8, 4096, 1280, 204),
+    # the 16k cell: two held rows a token, the narrowest expert (7 x 128)
+    ("mellum2", 40960, 16, 2304, 896, 2048),
 ]
 
 
@@ -490,6 +497,35 @@ def test_head_share_step_compiles_inside_the_programs_memory(one_chip,
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
         < 12.3 * 2 ** 30
+
+
+def test_16k_step_holds_the_split_pair_under_the_kinds_names(one_chip,
+                                                             monkeypatch):
+    """The 16k window/full preset's step at the cell's shape (1 x 16384,
+    four layers), compiled for the described chip: the flash kernel's
+    forward AND both kernels of its split backward (dQ; dK/dV) carry their
+    module's name, three events a layer, so `swa_kernel_pattern` finds the
+    three window layers' nine and `gqa_kernel_pattern` the full layer's
+    three (a roofline that missed one kernel of the pair would divide by
+    too little time); the bank's grouped kernels twelve a layer (the row
+    kernel nine times with the forward run again, the weights kernel
+    three); and the
+    program fits the chip with room for what the trainer holds beside it
+    (10.93 GiB of 15.75; sandbox compile, PR 48)."""
+    lowered, bench, cfg = _lowered_step(
+        "mellum2_12b_a2_5b_lm_ep4", one_chip, monkeypatch)
+    assert (cfg.data.batch_size, cfg.data.seq_len) == (1, 16384)
+    compiled = lowered.compile()
+    names = _custom_calls(compiled.as_text())
+    count = lambda key: sum(  # noqa: E731
+        bool(re.search(bench[key], n)) for n in names)
+    assert (count("swa_kernel_pattern"), count("gqa_kernel_pattern"),
+            count("flash_kernel_pattern")) == (9, 3, 12)
+    assert (sum("grouped_matmul_rows" in n for n in names),
+            sum("grouped_matmul_weights" in n for n in names)) == (36, 12)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        < 11.3 * 2 ** 30
 
 
 def test_dp4_gpt2_step_reduces_the_tied_table_once(topo, monkeypatch):
